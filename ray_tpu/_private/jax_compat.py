@@ -1,21 +1,14 @@
-"""Version bridge for jax APIs spelled differently across releases.
+"""Plain re-export of ``jax.shard_map``.
 
-``shard_map`` went top-level in jax 0.4.35, renaming the replication
-check kwarg from ``check_rep`` to ``check_vma``. Older versions only
-ship ``jax.experimental.shard_map``. Import ``shard_map`` from here and
-use the modern spelling; on old jax the kwarg is translated.
+The repo runs on one installation (jax 0.9.0), where ``shard_map`` is
+top-level and takes ``check_vma``; there is no other version to bridge.
+The module stays because ten call sites import ``shard_map`` from here and
+the linter resolves the name through it (``devtools/linter.py``,
+``shardprop.py``); ROADMAP D10 moves them to ``from jax import shard_map``.
 """
 
 from __future__ import annotations
 
+from jax import shard_map
+
 __all__ = ["shard_map"]
-
-try:
-    from jax import shard_map
-except ImportError:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    def shard_map(f, /, **kwargs):
-        if "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-        return _shard_map(f, **kwargs)

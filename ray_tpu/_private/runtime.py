@@ -859,9 +859,16 @@ class Runtime:
         try:
             import jax
             devs = jax.devices()
-        except Exception:  # raylint: allow(swallow) capability probe: no jax backend
+        except Exception as e:  # noqa: BLE001 - whatever a backend raises at start-up
+            logger.warning("granted %d TPU but no jax backend came up; the "
+                           "task gets no devices: %s: %s", n,
+                           type(e).__name__, e)
             return None
-        return devs[:n] if len(devs) >= n else devs
+        if len(devs) < n:
+            raise RuntimeError(
+                f"granted {n} TPU but this process has {len(devs)} "
+                f"device(s): {devs}")
+        return devs[:n]
 
     def _execute_task(self, spec: TaskSpec, node: Node, request: ResourceSet,
                       alloc_target, cancel: threading.Event):

@@ -52,7 +52,9 @@ def _detect_num_tpus() -> int:
     try:
         import jax
         return len([d for d in jax.devices() if d.platform == "tpu"])
-    except Exception:  # raylint: allow(swallow) capability probe: no jax backend
+    except Exception as e:  # noqa: BLE001 - whatever a backend raises at start-up
+        logger.warning("TPU detection failed; this node advertises no TPU: "
+                       "%s: %s", type(e).__name__, e)
         return 0
 
 

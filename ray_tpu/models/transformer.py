@@ -21,10 +21,12 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh
+from jax import shard_map
+from jax.sharding import Mesh, PartitionSpec
 
 from ray_tpu.ops import flash_attention
 from ray_tpu.parallel.sequence import ring_attention
+from ray_tpu.parallel.sharding import ShardingRules
 
 
 @dataclass(frozen=True)
@@ -138,11 +140,30 @@ def _rope(x: jax.Array, theta: float, positions: jax.Array) -> jax.Array:
     return rotated.astype(x.dtype)
 
 
-def _attention(q, k, v, cfg: TransformerConfig, mesh: Optional[Mesh]):
+@functools.lru_cache(maxsize=128)
+def _flash_sharded(mesh: Mesh, spec: PartitionSpec):
+    """The flash kernel under ``shard_map``, memoized on its statics like
+    the ``parallel/`` wrappers. XLA cannot partition a Mosaic kernel, so
+    each device runs it on its own shard; attention is independent across
+    batch and heads, so no collective is needed."""
+    return shard_map(functools.partial(flash_attention, causal=True),
+                     mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+                     check_vma=False)
+
+
+def _attention(q, k, v, cfg: TransformerConfig, mesh: Optional[Mesh],
+               rules: Optional[ShardingRules] = None):
+    """``mesh`` is the mesh of the enclosing jit, or None when the caller
+    already runs per device (one chip, or inside a ``shard_map``)."""
     if mesh is not None and "seq" in mesh.axis_names and mesh.shape["seq"] > 1:
         return ring_attention(q, k, v, mesh, causal=True)
     if cfg.use_flash:
-        return flash_attention(q, k, v, causal=True)
+        if mesh is None:
+            return flash_attention(q, k, v, causal=True)
+        # batch over the rules' batch axes, heads over tensor
+        spec = (rules or ShardingRules()).sharding(
+            mesh, ("batch", None, "act_heads", None)).spec
+        return _flash_sharded(mesh, spec)(q, k, v)
     D = q.shape[-1]
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) / math.sqrt(D)
     L, Lk = q.shape[1], k.shape[1]
@@ -152,7 +173,7 @@ def _attention(q, k, v, cfg: TransformerConfig, mesh: Optional[Mesh]):
     return jnp.einsum("bhqk,bkhd->bqhd", p, v)
 
 
-def _block(params, x, positions, cfg: TransformerConfig, mesh):
+def _block(params, x, positions, cfg: TransformerConfig, mesh, rules=None):
     B, L, d = x.shape
     h = _rmsnorm(x, params["ln1"])
     q = jnp.einsum("bld,dhk->blhk", h, params["attn"]["wq"].astype(x.dtype))
@@ -164,7 +185,7 @@ def _block(params, x, positions, cfg: TransformerConfig, mesh):
         rep = cfg.n_heads // cfg.kv_heads
         k = jnp.repeat(k, rep, axis=2)
         v = jnp.repeat(v, rep, axis=2)
-    attn = _attention(q, k, v, cfg, mesh)
+    attn = _attention(q, k, v, cfg, mesh, rules)
     x = x + jnp.einsum("blhk,hkd->bld", attn,
                        params["attn"]["wo"].astype(x.dtype))
     h = _rmsnorm(x, params["ln2"])
@@ -176,14 +197,14 @@ def _block(params, x, positions, cfg: TransformerConfig, mesh):
 
 
 def backbone(params: Dict[str, Any], tokens: jax.Array,
-             cfg: TransformerConfig,
-             mesh: Optional[Mesh] = None) -> jax.Array:
+             cfg: TransformerConfig, mesh: Optional[Mesh] = None,
+             rules: Optional[ShardingRules] = None) -> jax.Array:
     """Embedding + all transformer blocks; returns pre-final-norm states."""
     B, L = tokens.shape
     x = params["embed"].astype(cfg.dtype)[tokens]
     positions = jnp.broadcast_to(jnp.arange(L)[None], (B, L))
 
-    block_fn = functools.partial(_block, cfg=cfg, mesh=mesh)
+    block_fn = functools.partial(_block, cfg=cfg, mesh=mesh, rules=rules)
     if cfg.remat:
         block_fn = jax.checkpoint(block_fn)
 
@@ -207,9 +228,10 @@ def head(params: Dict[str, Any], x: jax.Array,
 
 
 def apply(params: Dict[str, Any], tokens: jax.Array,
-          cfg: TransformerConfig, mesh: Optional[Mesh] = None) -> jax.Array:
+          cfg: TransformerConfig, mesh: Optional[Mesh] = None,
+          rules: Optional[ShardingRules] = None) -> jax.Array:
     """tokens: [B, L] int32 -> logits [B, L, vocab] (float32)."""
-    x = backbone(params, tokens, cfg, mesh)
+    x = backbone(params, tokens, cfg, mesh, rules)
     return head(params, x, cfg)
 
 
@@ -224,9 +246,10 @@ def head_and_loss(params, x: jax.Array, targets: jax.Array,
 
 
 def loss_fn(params, tokens, cfg: TransformerConfig,
-            mesh: Optional[Mesh] = None) -> jax.Array:
+            mesh: Optional[Mesh] = None,
+            rules: Optional[ShardingRules] = None) -> jax.Array:
     """Next-token cross entropy (tokens serve as their own labels)."""
-    x = backbone(params, tokens[:, :-1], cfg, mesh)
+    x = backbone(params, tokens[:, :-1], cfg, mesh, rules)
     return head_and_loss(params, x, tokens[:, 1:], cfg)
 
 

@@ -8,8 +8,11 @@ The backward pass recomputes P from the saved log-sum-exp (flash-style
 rematerialization) in two kernels: one accumulating dQ over K blocks, one
 accumulating dK/dV over Q blocks.
 
-Falls back to interpreter mode off-TPU so the same code path is exercised by
-the CPU test mesh. Role in the stack: the per-shard kernel under
+Runs in interpreter mode only where the backend is ``cpu`` (the CPU test
+mesh exercises the same code path); on any other backend the Mosaic kernel
+compiles or the call fails. A Mosaic kernel cannot be partitioned by XLA:
+under a mesh, call it inside a ``shard_map`` (``models.transformer``
+does). Role in the stack: the per-shard kernel under
 ``ray_tpu.parallel.sequence.ring_attention`` and the dense-attention op for
 ``ray_tpu.models`` (the reference delegates attention to torch; here it is a
 first-class TPU kernel).
@@ -30,11 +33,8 @@ NEG_INF = -1e30
 _LANES = 128  # stats buffers keep a full lane dim (TPU tiling)
 
 
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # raylint: allow(swallow) capability probe: no jax backend
-        return False
+def _backend_is_cpu() -> bool:
+    return jax.default_backend() == "cpu"
 
 
 # --------------------------------------------------------------------------- #
@@ -339,7 +339,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     Lk = k.shape[1]
     scale = scale if scale is not None else 1.0 / math.sqrt(D)
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = _backend_is_cpu()
     # [B, L, H, D] -> [B*H, L, D]
     qb = q.transpose(0, 2, 1, 3).reshape(B * H, Lq, D)
     kb = k.transpose(0, 2, 1, 3).reshape(B * H, Lk, D)

@@ -77,14 +77,18 @@ def build_mesh(config: MeshConfig, devices: Optional[Sequence] = None) -> Mesh:
     devices = list(devices if devices is not None else jax.devices())
     cfg = config.resolved(len(devices))
     shape = cfg.axis_sizes()
-    try:
+    arr = None
+    if devices and devices[0].platform == "tpu":
         from jax.experimental import mesh_utils
-        if devices and devices[0].platform == "tpu":
+        try:
             arr = mesh_utils.create_device_mesh(shape, devices=devices)
-        else:
-            arr = np.array(devices).reshape(shape)
-    except Exception as e:
-        logger.debug("mesh_utils failed; naive reshape fallback: %s", e)
+        except Exception as e:  # noqa: BLE001 - mesh_utils raises several types
+            logger.warning(
+                "create_device_mesh%s failed on %d %s device(s); axis "
+                "adjacency will not match the physical torus: %s: %s",
+                shape, len(devices), devices[0].device_kind,
+                type(e).__name__, e)
+    if arr is None:
         arr = np.array(devices).reshape(shape)
     return Mesh(arr, AXIS_ORDER)
 

@@ -41,6 +41,10 @@ DEFAULT_RULES: Dict[str, MeshAxes] = {
     "expert": "expert",
     # pipeline
     "stage": "pipe",
+    "layers": None,                 # stacked-layer dim; train.step maps it
+                                    # to "pipe" when the mesh has stages
+    "layers": None,                 # stacked-layer dim; train.step maps it
+                                    # to "pipe" when the mesh has stages
 }
 
 

@@ -70,8 +70,10 @@ class RayTrainWorker:
                     per = len(devs) // workers_per_host
                     local = devs[local_rank * per:(local_rank + 1) * per]
                     mesh = build_mesh(MeshConfig(data=len(local)), local)
-        except Exception as e:
-            logger.debug("mesh detection failed; no local mesh: %s", e)
+        except Exception as e:  # noqa: BLE001 - whatever a backend raises at start-up
+            logger.warning("worker %d: building the session mesh failed; "
+                           "session.get_mesh() will return None: %s: %s",
+                           self.rank, type(e).__name__, e)
             mesh = None
         self.session = session_mod._init_session(
             world_rank=self.rank, world_size=self.world_size,

@@ -21,7 +21,7 @@ from ray_tpu.models import transformer
 from ray_tpu.models.transformer import TransformerConfig
 from ray_tpu.observability import goodput
 from ray_tpu.parallel import (ShardingRules, batch_sharding, pipeline_apply,
-                              shard_pytree)
+                              replicated)
 
 
 def make_lm_train_step(cfg: TransformerConfig, mesh: Mesh,
@@ -45,13 +45,17 @@ def make_lm_train_step(cfg: TransformerConfig, mesh: Mesh,
         if cfg.n_layers % pipe != 0:
             raise ValueError(f"n_layers {cfg.n_layers} not divisible by "
                              f"pipe={pipe}")
+        if mesh.shape.get("seq", 1) > 1:
+            raise ValueError(
+                f"pipe={pipe} with seq={mesh.shape['seq']}: ring attention "
+                "cannot nest inside the pipeline's shard_map")
         # Stage-shard the stacked layer dim so each stage holds only its
         # layers' params.
         rules = rules.with_overrides(layers="pipe")
 
     def loss_fn(params, tokens):
         if pipe == 1:
-            return transformer.loss_fn(params, tokens, cfg, mesh)
+            return transformer.loss_fn(params, tokens, cfg, mesh, rules)
         # Pipeline path: embed -> pipelined blocks -> head.
         inputs, targets = tokens[:, :-1], tokens[:, 1:]
         x = params["embed"].astype(cfg.dtype)[inputs]
@@ -60,7 +64,9 @@ def make_lm_train_step(cfg: TransformerConfig, mesh: Mesh,
         def stage_fn(stage_params, h):
             B, L, _ = h.shape
             positions = jnp.broadcast_to(jnp.arange(L)[None], (B, L))
-            block = functools.partial(transformer._block, cfg=cfg, mesh=mesh)
+            # mesh=None: stage_fn already runs per device, inside
+            # pipeline_apply's shard_map.
+            block = functools.partial(transformer._block, cfg=cfg, mesh=None)
             if cfg.remat:
                 block = jax.checkpoint(block)
 
@@ -78,21 +84,36 @@ def make_lm_train_step(cfg: TransformerConfig, mesh: Mesh,
                            num_microbatches=num_microbatches)
         return transformer.head_and_loss(params, x, targets, cfg)
 
-    def init_fn(key) -> Tuple[Any, Any]:
+    def init(key) -> Tuple[Any, Any]:
         params = transformer.init_params(key, cfg)
-        axes = transformer.logical_axes(cfg)
-        params = shard_pytree(params, axes, mesh, rules)
-        opt_state = optimizer.init(params)
-        return params, opt_state
+        return params, optimizer.init(params)
 
-    @functools.partial(jax.jit, donate_argnums=(0,))
-    def step_fn(state, tokens):
+    def step(state, tokens):
         params, opt_state = state
         loss, grads = jax.value_and_grad(loss_fn)(params, tokens)
         updates, opt_state = optimizer.update(grads, opt_state, params)
         params = optax.apply_updates(params, updates)
         gnorm = optax.global_norm(grads)
         return (params, opt_state), {"loss": loss, "grad_norm": gnorm}
+
+    # One layout for the train state, going in and coming out: init_fn makes
+    # it there and step_fn returns it there, so the second step sees what
+    # the first saw and the step compiles once. Left to the compiler, the
+    # state came back in another layout than it went in (the optimizer's
+    # count committed to the mesh, norm weights sharded over fsdp) and the
+    # second call compiled the whole step again.
+    param_shardings = jax.tree.map(
+        lambda axes: rules.sharding(mesh, axes),
+        transformer.logical_axes(cfg),
+        is_leaf=lambda axes: isinstance(axes, tuple))
+    state_shardings = (param_shardings, optax.tree_utils.tree_map_params(
+        optimizer, lambda _, sharding: sharding,
+        jax.eval_shape(init, jax.ShapeDtypeStruct((2,), jnp.uint32))[1],
+        param_shardings,
+        transform_non_params=lambda _: replicated(mesh)))
+    init_fn = jax.jit(init, out_shardings=state_shardings)
+    step_fn = jax.jit(step, donate_argnums=(0,),
+                      out_shardings=(state_shardings, None))
 
     def shard_batch(tokens):
         return jax.device_put(tokens, batch_sharding(mesh, rules, ndim=2))

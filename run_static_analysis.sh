@@ -79,7 +79,7 @@ then
 fi
 stage_done "stage 0 (self-check)" "$t0" "$st"
 
-echo "== [stage 1] raylint (ray_tpu bench.py bench_micro.py tests) =="
+echo "== [stage 1] raylint (ray_tpu bench.py bench_micro.py chip_smoke.py tests) =="
 t0=$SECONDS
 st=OK
 # tests/ allow profile: test code legitimately pokes checkpoint
@@ -98,7 +98,7 @@ LINT_SARIF="${RAYLINT_SARIF_OUT:-/tmp/raytpu_lint.sarif.json}"
 # manifest-vs-ledger cross-check (doctor --comms-baseline __manifest__,
 # run_sanitizers.sh).
 LINT_MANIFEST="${RAYLINT_MANIFEST_OUT:-/tmp/raytpu_comms_manifest.json}"
-if python -m ray_tpu.devtools.lint ray_tpu bench.py bench_micro.py tests \
+if python -m ray_tpu.devtools.lint ray_tpu bench.py bench_micro.py chip_smoke.py tests \
      --allow-in "tests/:R9,R12,R22,R23,R24,R25,R26" --json --sarif "$LINT_SARIF" \
      --comms-manifest "$LINT_MANIFEST" \
      > "$LINT_JSON" 2> "$LINT_ERR"; then

@@ -1,0 +1,141 @@
+"""Compiles for a described TPU v5e 2x2 host, without a chip.
+
+The TPU compiler is installed where the tests run, and it compiles for a
+topology that is described and not attached. These tests keep the main
+path's programs compiling at their real widths: the flash kernel forward
+and backward, the whole LM train step on one chip and on three four-chip
+meshes, and the serve forward at its batch buckets. Each asserts the Pallas
+kernel is in the compiled program (``tpu_custom_call``). Nothing runs, so
+they say nothing about results or times.
+
+The topology is described inside the module-scoped ``topo`` fixture and
+nowhere else: only one process may load the TPU library, so a call made
+while a module is imported would give xdist workers different tests to
+collect. Keep every such test in this one file for the same reason.
+"""
+
+import sys
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import Mesh, SingleDeviceSharding
+
+import ray_tpu.ops.flash_attention  # noqa: F401  (the module, not the function)
+from ray_tpu.models import transformer
+from ray_tpu.models.transformer import TransformerConfig
+from ray_tpu.parallel import (MeshConfig, ShardingRules, batch_sharding,
+                              build_mesh)
+from ray_tpu.train.step import make_lm_train_step
+
+KERNEL = "tpu_custom_call"
+
+# chip_smoke.py's width: the widest transformer the repo runs.
+CFG = TransformerConfig(vocab_size=32000, d_model=1024, n_layers=12,
+                        n_heads=16, max_seq_len=1024, dtype=jnp.bfloat16,
+                        use_flash=True)
+BATCH, SEQ = 8, 1024
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - whatever libtpu raises here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def no_compile_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without a chip; keep these tests out of it."""
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def mosaic(monkeypatch, topo, no_compile_cache):
+    """The process's backend is the CPU, where the kernel would take
+    interpret mode; these compiles are for the described chip."""
+    monkeypatch.setattr(sys.modules["ray_tpu.ops.flash_attention"],
+                        "_backend_is_cpu", lambda: False)
+
+
+def _mesh(devices, **axes) -> Mesh:
+    return build_mesh(MeshConfig(**axes), devices)
+
+
+def _shape(leaf, sharding):
+    return jax.ShapeDtypeStruct(leaf.shape, leaf.dtype, sharding=sharding)
+
+
+@pytest.mark.parametrize("shape,dtype", [
+    ((8, 1024, 16, 64), jnp.bfloat16),
+    ((4, 2048, 16, 128), jnp.bfloat16),
+    ((2, 1000, 8, 64), jnp.bfloat16),      # ragged last block
+    ((4, 512, 8, 64), jnp.float32),
+], ids=["8x1024x16x64-bf16", "4x2048x16x128-bf16", "2x1000x8x64-ragged",
+        "4x512x8x64-f32"])
+def test_flash_kernel_fwd_bwd_compiles(topo, mosaic, shape, dtype):
+    from ray_tpu.ops import flash_attention
+    x = jax.ShapeDtypeStruct(shape, dtype,
+                             sharding=SingleDeviceSharding(topo.devices[0]))
+
+    def fwd_bwd(q, k, v):
+        return jax.value_and_grad(
+            lambda q, k, v: flash_attention(q, k, v).astype(
+                jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v)
+
+    text = jax.jit(fwd_bwd).lower(x, x, x).compile().as_text()
+    # forward, dq and dk/dv kernels
+    assert text.count(KERNEL) >= 3
+
+
+def _lower_train_step(mesh: Mesh, cfg: TransformerConfig = CFG):
+    """A described device cannot hold an array: lower ``step_fn`` on the
+    shapes and shardings ``init_fn`` would have produced."""
+    rules = ShardingRules()
+    init_fn, step_fn, _ = make_lm_train_step(cfg, mesh, rules)
+    state = init_fn.eval_shape(jax.ShapeDtypeStruct((2,), jnp.uint32))
+    tokens = jax.ShapeDtypeStruct((BATCH, SEQ + 1), jnp.int32,
+                                  sharding=batch_sharding(mesh, rules, 2))
+    # __wrapped__: the jitted step under goodput.instrument_jit
+    return step_fn.__wrapped__.lower(state, tokens)
+
+
+def test_train_step_compiles_on_one_chip(topo, mosaic):
+    compiled = _lower_train_step(_mesh(topo.devices[:1], data=1)).compile()
+    assert KERNEL in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            < 16 * 2 ** 30)
+
+
+@pytest.mark.parametrize("axes", [
+    dict(data=4), dict(data=2, tensor=2), dict(data=1, fsdp=4)],
+    ids=["data4", "data2xtensor2", "fsdp4"])
+def test_train_step_compiles_on_four_chip_mesh(topo, mosaic, axes):
+    text = _lower_train_step(_mesh(topo.devices, **axes)).compile().as_text()
+    assert KERNEL in text
+    # the gradient reduction over the batch axes
+    assert "all-reduce" in text or "reduce-scatter" in text
+
+
+@pytest.mark.parametrize("bucket", [2, 4, 8])
+def test_serve_forward_compiles_at_bucket(topo, mosaic, bucket):
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    params = jax.eval_shape(
+        lambda: transformer.init_params(jax.random.PRNGKey(0), CFG))
+    params = jax.tree.map(lambda leaf: _shape(leaf, one_chip), params)
+    tokens = jax.ShapeDtypeStruct((bucket, SEQ), jnp.int32,
+                                  sharding=one_chip)
+    text = jax.jit(lambda p, t: transformer.apply(p, t, CFG)).lower(
+        params, tokens).compile().as_text()
+    assert KERNEL in text
