@@ -7,9 +7,9 @@ behind the ``ray timeline`` CLI (``scripts.py:1755``). Spans are recorded
 in-process (the host-granular runtime has no cross-process hop) and
 dumped in the chrome://tracing "X" (complete-event) format.
 
-For device-side detail the TPU story is strictly better than py-spy:
-``start_device_trace``/``stop_device_trace`` wrap ``jax.profiler`` so an
-XLA trace (HLO timings, HBM usage) lands next to the host spans.
+For device-side detail start a ``jax.profiler`` session in the process
+that holds the chip: every :class:`ray_tpu.observability.span` open while
+it records is also written into its ``.xplane.pb``, on the device's clock.
 """
 
 from __future__ import annotations
@@ -158,27 +158,6 @@ def dump_timeline(filename: Optional[str] = None) -> Any:
     from ray_tpu.checkpoint.manifest import atomic_write_bytes
     atomic_write_bytes(filename, json.dumps(trace).encode())
     return filename
-
-
-# -- device-side tracing ----------------------------------------------------
-
-_device_trace_dir: Optional[str] = None
-
-
-def start_device_trace(log_dir: str) -> None:
-    """Begin an XLA profiler trace (TPU timeline; jax.profiler)."""
-    global _device_trace_dir
-    import jax
-    jax.profiler.start_trace(log_dir)
-    _device_trace_dir = log_dir
-
-
-def stop_device_trace() -> Optional[str]:
-    global _device_trace_dir
-    import jax
-    jax.profiler.stop_trace()
-    out, _device_trace_dir = _device_trace_dir, None
-    return out
 
 
 class profile_span:

@@ -260,7 +260,7 @@ class RpcClient:
             self._pending[seq] = pending
         env = pb.Envelope(seq=seq, method=method, body=body)
         t0 = 0.0
-        if observability.ENABLED:
+        if observability.live():
             tctx = observability.wire_context()
             if tctx:
                 env.trace = tctx
@@ -296,7 +296,7 @@ class RpcClient:
         ``raw``: bulk-lane payload (one bytes-like or a gather list)
         shipped with the request, no protobuf copy."""
         tctx = ""
-        if observability.ENABLED:
+        if observability.live():
             tctx = observability.wire_context()
         if perf.ENABLED:
             _t0, _cb = time.monotonic(), callback
@@ -351,7 +351,7 @@ class RpcClient:
                 self._pending[self._seq] = pending
                 pendings.append(self._seq)
         # Tiny control bodies: one contiguous buffer beats a long iovec.
-        tctx = observability.wire_context() if observability.ENABLED else ""
+        tctx = observability.wire_context() if observability.live() else ""
         buf = bytearray()
         for seq, (method, body) in zip(pendings, items):
             env = pb.Envelope(seq=seq, method=method, body=body)
@@ -367,7 +367,7 @@ class RpcClient:
 
     def send_oneway(self, method: int, body: bytes = b"") -> None:
         env = pb.Envelope(seq=0, method=method, body=body)
-        if observability.ENABLED:
+        if observability.live():
             tctx = observability.wire_context()
             if tctx:
                 env.trace = tctx
@@ -773,7 +773,7 @@ class RpcServer:
         # Adopt the caller's trace context around dispatch so spans the
         # handler opens (fetch, task execute, ...) join the caller's tree.
         token = None
-        if observability.ENABLED and ctx.trace:
+        if observability.live() and ctx.trace:
             token = observability.adopt_wire(ctx.trace)
         lw_token = None
         if _lockwatch is not None and _lockwatch.installed():

@@ -554,7 +554,7 @@ class Runtime:
         """Propagate the submitting span's trace context into the spec
         (tracing_helper.py:160-175 role): children inherit the trace id
         with the current span as parent; a root submission mints a fresh
-        trace id when profiling is on (tracing is free when it's off)."""
+        trace id when a span sink is on (tracing is free when none is)."""
         if spec.trace_id:
             return  # retries keep their original identity
         async_ctx = _trace_var.get()
@@ -565,15 +565,17 @@ class Runtime:
             spec.trace_id = ctx.trace_id
             spec.parent_span_id = ctx.span_id
         else:
-            obs_ctx = (observability.current()
-                       if observability.ENABLED else None)
+            live = observability.live()
+            obs_ctx = observability.current() if live else None
             if obs_ctx:  # explicit span (serve request, user span(...))
                 spec.trace_id, spec.parent_span_id = obs_ctx
-            elif _prof().enabled:
-                spec.trace_id = os.urandom(8).hex()
+            elif live or _prof().enabled:
+                spec.trace_id = observability.mint_id()
 
     def submit_task(self, spec: TaskSpec) -> List[ObjectID]:
         self._attach_trace(spec)
+        if spec.trace_id:  # a sink is on: the task's span reports its wait
+            spec.queued_ns = time.monotonic_ns()
         if perf.ENABLED and not spec.perf_submit_s:
             spec.perf_submit_s = time.time()
         if not spec.return_ids:
@@ -883,74 +885,86 @@ class Runtime:
         ctx.devices = self._assign_devices(request, node)
         ctx.cancel_flag = cancel
         ctx.placement_group = spec.options.placement_group
-        # Trace context for this span: children submitted by the task
-        # body inherit (trace_id, span_id) via _attach_trace.
-        ctx.trace_id = spec.trace_id
-        span_id = os.urandom(8).hex() if spec.trace_id else ""
-        ctx.span_id = span_id
-        if _flight.ENABLED:
-            # flight recorder: a hard-killed process's bundle names what
-            # was RUNNING (and which trace it belonged to) when it died
-            _flight.task_started(spec.task_id.hex(), spec.function_name,
-                                 trace_id=spec.trace_id, span_id=span_id)
-        t0 = time.monotonic()
         try:
-            if cancel.is_set():
-                raise exc.TaskCancelledError(spec.task_id)
-            if chaos.ENABLED:
-                # delay stalls the worker; error fails the task (retryable
-                # per retry_exceptions); exit kills this PROCESS mid-task —
-                # the injected host-loss scenario resubmission must survive
-                chaos.inject("task.execute", task=spec.task_id.hex()[:8],
-                             name=spec.function_name)
-            args = _resolve_refs(spec.args, self)
-            kwargs = _resolve_refs(spec.kwargs, self)
-            env = _materialize_env(spec)
-            if env is not None:
-                with env.applied():
-                    result = spec.function(*args, **kwargs)
-            else:
-                result = spec.function(*args, **kwargs)
-            if cancel.is_set():
-                raise exc.TaskCancelledError(spec.task_id)
-            self._seal_results(spec, node, result)
-            with self.lock:
-                self.task_states[spec.task_id] = "FINISHED"
-        except BaseException as e:  # noqa: BLE001
-            self._handle_task_failure(spec, node, e)
+            self._run_task(spec, node, request, alloc_target, cancel, ctx)
         finally:
-            if _flight.ENABLED:
-                _flight.task_finished(spec.task_id.hex())
-            alloc_target.release(request)
-            self._unpin_args(spec)
-            dur = time.monotonic() - t0
-            if perf.ENABLED:
-                perf.observe("task.execute", dur * 1e3)
-                if spec.perf_submit_s:
-                    # Cross-host stamps are rebased onto this clock via
-                    # clocksync (heartbeat-beacon offset), so the delta is
-                    # already skew-corrected; residual error is bounded by
-                    # the heartbeat RTTs. Clamp instead of discard: a
-                    # stamp that still lands inside the execution window
-                    # means ~zero scheduling wait, not a bogus sample.
-                    e2e = max(time.time() - spec.perf_submit_s, dur)
-                    perf.observe("task.e2e", e2e * 1e3)
-                    perf.observe("task.sched", (e2e - dur) * 1e3)
-            self.emit_event("TASK_DONE", task=spec.function_name,
-                            ms=round(dur * 1e3, 3))
-            span_args = {"task_id": spec.task_id.hex()}
-            if spec.trace_id:
-                span_args.update(trace_id=spec.trace_id, span_id=span_id,
-                                 parent_span_id=spec.parent_span_id)
-            _prof().record(spec.function_name, "task",
-                           pid=f"node:{node.node_id.hex()[:8]}",
-                           start_s=time.time() - dur, dur_s=dur,
-                           args=span_args)
+            # after the span has closed, and whatever it or the failure
+            # path raised: waiters must not hang on a task that is over
             (ctx.node_id, ctx.task_id, ctx.job_id, ctx.put_counter,
              ctx.devices, ctx.cancel_flag, ctx.placement_group,
              ctx.trace_id, ctx.span_id) = prev
             self._fire_completion(spec)
             self._kick()
+
+    def _run_task(self, spec: TaskSpec, node: Node, request: ResourceSet,
+                  alloc_target, cancel: threading.Event, ctx) -> None:
+        """The task's body inside its ``task.execute`` span."""
+        with observability.task_span(
+                "task.execute", spec.function_name, "task",
+                f"node:{node.node_id.hex()[:8]}", _span_parent(spec)) as sp:
+            if sp.live:
+                sp.set(task_id=spec.task_id.hex(),
+                       function=spec.function_name,
+                       sched_wait_us=_wait_us(spec),
+                       devices=_device_ids(ctx.devices))
+            # Trace context for this span: children submitted by the task
+            # body inherit (trace_id, span_id) via _attach_trace.
+            ctx.trace_id = sp.trace_id or spec.trace_id
+            span_id = sp.span_id or spec.parent_span_id
+            ctx.span_id = span_id
+            if _flight.ENABLED:
+                # flight recorder: a hard-killed process's bundle names
+                # what was RUNNING (and which trace it belonged to) when
+                # it died
+                _flight.task_started(spec.task_id.hex(), spec.function_name,
+                                     trace_id=ctx.trace_id, span_id=span_id)
+            t0 = time.monotonic()
+            try:
+                if cancel.is_set():
+                    raise exc.TaskCancelledError(spec.task_id)
+                if chaos.ENABLED:
+                    # delay stalls the worker; error fails the task
+                    # (retryable per retry_exceptions); exit kills this
+                    # PROCESS mid-task — the injected host-loss scenario
+                    # resubmission must survive
+                    chaos.inject("task.execute", task=spec.task_id.hex()[:8],
+                                 name=spec.function_name)
+                args = _resolve_refs(spec.args, self)
+                kwargs = _resolve_refs(spec.kwargs, self)
+                env = _materialize_env(spec)
+                if env is not None:
+                    with env.applied():
+                        result = spec.function(*args, **kwargs)
+                else:
+                    result = spec.function(*args, **kwargs)
+                if cancel.is_set():
+                    raise exc.TaskCancelledError(spec.task_id)
+                self._seal_results(spec, node, result)
+                with self.lock:
+                    self.task_states[spec.task_id] = "FINISHED"
+            except BaseException as e:  # noqa: BLE001
+                self._handle_task_failure(spec, node, e)
+            finally:
+                if _flight.ENABLED:
+                    _flight.task_finished(spec.task_id.hex())
+                alloc_target.release(request)
+                self._unpin_args(spec)
+                dur = time.monotonic() - t0
+                if perf.ENABLED:
+                    perf.observe("task.execute", dur * 1e3)
+                    if spec.perf_submit_s:
+                        # Cross-host stamps are rebased onto this clock
+                        # via clocksync (heartbeat-beacon offset), so the
+                        # delta is already skew-corrected; residual error
+                        # is bounded by the heartbeat RTTs. Clamp instead
+                        # of discard: a stamp that still lands inside the
+                        # execution window means ~zero scheduling wait,
+                        # not a bogus sample.
+                        e2e = max(time.time() - spec.perf_submit_s, dur)
+                        perf.observe("task.e2e", e2e * 1e3)
+                        perf.observe("task.sched", (e2e - dur) * 1e3)
+                self.emit_event("TASK_DONE", task=spec.function_name,
+                                ms=round(dur * 1e3, 3))
 
     def _seal_results(self, spec: TaskSpec, node: Node, result: Any):
         n = spec.options.num_returns
@@ -1110,11 +1124,20 @@ class Runtime:
                     args = _resolve_refs(state.args, self)
                     kwargs = _resolve_refs(state.kwargs, self)
                     env = _materialize_env_for_actor(state)
-                    if env is not None:
-                        with env.applied():
+                    # the constructor, with the devices the actor was
+                    # granted (R8: every grant gets the first n)
+                    with observability.task_span(
+                            "actor.init", f"{state.cls.__name__}.__init__",
+                            "actor_init", f"node:{node.node_id.hex()[:8]}",
+                            None) as sp:
+                        if sp.live:
+                            sp.set(actor_id=state.actor_id.hex(),
+                                   devices=_device_ids(state.devices))
+                        if env is not None:
+                            with env.applied():
+                                state.instance = state.cls(*args, **kwargs)
+                        else:
                             state.instance = state.cls(*args, **kwargs)
-                    else:
-                        state.instance = state.cls(*args, **kwargs)
                 state.status = ActorState.ALIVE
                 state.ready.set()
                 self.emit_event("ACTOR_ALIVE", actor=state.cls.__name__)
@@ -1163,14 +1186,31 @@ class Runtime:
             if item is None or state.status == ActorState.DEAD:
                 return
             spec, cancel = item
+            # stamped specs only (a span sink was live at submit)
+            got_ns = time.monotonic_ns() if spec.queued_ns else 0
             ctx = task_context
             ctx.task_id = spec.task_id
             ctx.cancel_flag = cancel
             ctx.put_counter = 0
-            ctx.trace_id = spec.trace_id
-            span_id = os.urandom(8).hex() if spec.trace_id else ""
-            ctx.span_id = span_id
-            t0 = time.monotonic()
+            try:
+                self._call_actor_method(state, spec, node, cancel, ctx,
+                                        got_ns)
+            finally:
+                # after the span has closed, and whatever it or the
+                # failure path raised: waiters must not hang
+                self._fire_completion(spec)
+                self._kick()
+
+    def _call_actor_method(self, state: ActorState, spec: TaskSpec,
+                           node: Node, cancel: threading.Event, ctx,
+                           got_ns: int) -> None:
+        """One method call of a threaded actor inside its ``actor.call``
+        span."""
+        with _actor_call_span(state, spec, node) as sp:
+            if sp.live:
+                _describe_actor_call(sp, state, spec, got_ns)
+            ctx.trace_id = sp.trace_id or spec.trace_id
+            ctx.span_id = sp.span_id or spec.parent_span_id
             try:
                 if cancel.is_set():
                     raise exc.TaskCancelledError(spec.task_id)
@@ -1201,19 +1241,6 @@ class Runtime:
                     self.task_states[spec.task_id] = "FAILED"
             finally:
                 self._unpin_args(spec)
-                dur = time.monotonic() - t0
-                span_args = {"actor_id": state.actor_id.hex()}
-                if spec.trace_id:
-                    span_args.update(trace_id=spec.trace_id,
-                                     span_id=span_id,
-                                     parent_span_id=spec.parent_span_id)
-                _prof().record(
-                    f"{state.cls.__name__}.{spec.method_name}",
-                    "actor_task", pid=f"node:{node.node_id.hex()[:8]}",
-                    start_s=time.time() - dur, dur_s=dur,
-                    args=span_args)
-                self._fire_completion(spec)
-                self._kick()
 
     def _run_async_actor_loop(self, state: ActorState, max_concurrency: int):
         import asyncio
@@ -1223,11 +1250,26 @@ class Runtime:
         sem = asyncio.Semaphore(max_concurrency)
 
         async def _run_one(spec: TaskSpec, cancel):
+            got_ns = time.monotonic_ns() if spec.queued_ns else 0
             async with sem:
-                span_id = os.urandom(8).hex() if spec.trace_id else ""
-                token = (_trace_var.set((spec.trace_id, span_id))
-                         if spec.trace_id else None)
-                t0 = time.monotonic()
+                try:
+                    await _call(spec, cancel, got_ns)
+                finally:
+                    # after the span has closed, whatever it raised
+                    self._fire_completion(spec)
+                    self._kick()
+
+        async def _call(spec: TaskSpec, cancel, got_ns: int):
+            # Interleaved coroutines share the loop's thread, so their
+            # annotations overlap there without nesting; each still
+            # carries its own start, end and ids.
+            with _actor_call_span(state, spec, node) as sp:
+                if sp.live:
+                    _describe_actor_call(sp, state, spec, got_ns)
+                trace_id = sp.trace_id or spec.trace_id
+                token = (_trace_var.set(
+                    (trace_id, sp.span_id or spec.parent_span_id))
+                    if trace_id else None)
                 try:
                     if cancel.is_set():
                         raise exc.TaskCancelledError(spec.task_id)
@@ -1256,20 +1298,6 @@ class Runtime:
                     if token is not None:
                         _trace_var.reset(token)
                     self._unpin_args(spec)
-                    dur = time.monotonic() - t0
-                    span_args = {"actor_id": state.actor_id.hex()}
-                    if spec.trace_id:
-                        span_args.update(
-                            trace_id=spec.trace_id, span_id=span_id,
-                            parent_span_id=spec.parent_span_id)
-                    _prof().record(
-                        f"{state.cls.__name__}.{spec.method_name}",
-                        "actor_task",
-                        pid=f"node:{node.node_id.hex()[:8]}",
-                        start_s=time.time() - dur, dur_s=dur,
-                        args=span_args)
-                    self._fire_completion(spec)
-                    self._kick()
 
         async def _pump():
             while state.status != ActorState.DEAD:
@@ -1308,6 +1336,8 @@ class Runtime:
             return list(spec.return_ids)
         for oid in _ref_ids_in(spec.args, spec.kwargs):
             self.reference_counter.pin_for_task(oid)
+        if spec.trace_id:  # a sink is on: actor.call reports the wait
+            spec.queued_ns = time.monotonic_ns()
         state.mailbox.put((spec, cancel))
         return list(spec.return_ids)
 
@@ -1539,6 +1569,45 @@ class Runtime:
 def _prof():
     from ray_tpu._private.profiling import get_profiler
     return get_profiler()
+
+
+def _span_parent(spec: TaskSpec):
+    """The context a task's or an actor call's span joins: the trace the
+    spec carries, under the span that submitted it."""
+    return (spec.trace_id, spec.parent_span_id) if spec.trace_id else None
+
+
+def _actor_call_span(state: ActorState, spec: TaskSpec,
+                     node: "Node") -> observability.task_span:
+    """The span round one method call of an actor, sync or async."""
+    return observability.task_span(
+        "actor.call", f"{state.cls.__name__}.{spec.method_name}",
+        "actor_task", f"node:{node.node_id.hex()[:8]}", _span_parent(spec))
+
+
+def _describe_actor_call(sp: observability.task_span, state: ActorState,
+                         spec: TaskSpec, got_ns: int) -> None:
+    """The attributes of a live ``actor.call`` span; ``got_ns`` is when the
+    actor's loop took the call out of the mailbox."""
+    sp.set(actor_id=state.actor_id.hex(), method=spec.method_name,
+           mailbox_wait_us=_wait_us(spec, got_ns))
+
+
+def _wait_us(spec: TaskSpec, until_ns: int = 0) -> int:
+    """Microseconds from the spec's queueing to ``until_ns`` (now, where
+    none is given): a wait that begins on the submitter's thread and ends
+    on the executor's, so it is a number on the span and not a span.  -1
+    where the spec was not stamped (no sink was on at submit, or it came
+    from another process)."""
+    if not spec.queued_ns:
+        return -1
+    return ((until_ns or time.monotonic_ns()) - spec.queued_ns) // 1000
+
+
+def _device_ids(devices) -> str:
+    """The ids of the devices ``_assign_devices`` granted, as a span
+    attribute (``"0/1"``; ``""`` for none)."""
+    return "/".join(str(d.id) for d in devices) if devices else ""
 
 
 def _materialize_env(spec: TaskSpec, actor_state=None):

@@ -61,6 +61,11 @@ class TaskSpec:
     # state-service timebase (clocksync) so the execute-site delta is
     # skew-corrected, and residual negatives clamp to the execution time.
     perf_submit_s: float = 0.0
+    # time.monotonic_ns() when the spec was queued (submit_task, an actor's
+    # mailbox), stamped only while a span sink is live: the wait a task's
+    # or an actor call's span reports.  This process's clock, so it is not
+    # carried to another process (TaskSpecMsg has no such field).
+    queued_ns: int = 0
 
     def is_actor_task(self) -> bool:
         return self.actor_id is not None

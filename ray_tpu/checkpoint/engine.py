@@ -374,7 +374,7 @@ class CheckpointEngine:
         skeleton = _extract_arrays(tree, (), leaves, self._make_leaf)
         handle = SaveHandle(step, rank)
         trace: Tuple[str, str] = ("", "")
-        if observability.ENABLED:
+        if observability.live():
             # checkpoint save is a trace entry point: join the caller's
             # trace when one is active, mint a fresh one otherwise
             trace = observability.current() or (observability.mint_id(), "")
@@ -509,7 +509,7 @@ class CheckpointEngine:
         # Writer thread: adopt the context captured at save() so the
         # stage spans below land in the submitting trace.
         token = (observability.set_current(*job.trace)
-                 if observability.ENABLED and job.trace[0] else None)
+                 if job.trace[0] and observability.live() else None)
         t0 = time.monotonic() if perf.ENABLED else 0.0
         try:
             with observability.span("checkpoint.save", cat="checkpoint",

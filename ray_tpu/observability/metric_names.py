@@ -74,6 +74,39 @@ PERF_HISTOGRAMS = frozenset({
                             # tier, collective/quantization.py)
 })
 
+# Every span the program opens by a literal name (``observability.span`` /
+# ``task_span``).  In a profiler session each appears in the ``.xplane.pb``
+# as ``ray_tpu.<name>``; these names are the contract with whatever reads
+# the trace (``benchmark/program_spans.py``), so a rename shows up here.
+# ``rpc:<METHOD>`` spans and user ``profile_span``s are named at run time
+# and are not listed.
+SPANS = frozenset({
+    # serve: the proxy's handler thread, from arrival to the reply written
+    "serve.request",
+    "serve.route",          # handle lookup + Router._pick + the submit
+    "serve.await_replica",  # handle.remote(...).result() after the submit
+    "serve.reply",          # the reply encoded and written
+    # serve: the replica batcher's flusher thread
+    "serve.batch.linger",   # wake-up with a non-empty queue -> batch cut
+    "serve.batch.execute",  # _run_batch: pad, call, deliver
+    "serve.batch.call",     # the user's callable alone
+    # runtime
+    "task.execute",         # one task on a worker thread
+    "actor.call",           # one method call on an actor's thread
+    "actor.init",           # the actor's constructor, with the device grant
+    # object plane
+    "object.fetch",
+    # checkpoint engine
+    "checkpoint.save",
+    "checkpoint.hash",
+    "checkpoint.write",
+    "checkpoint.gather",
+    "checkpoint.commit",
+})
+
+# The gauge a replica sets once, when its constructor returns.
+REPLICA_INIT_GAUGE = "serve_replica_init_seconds"
+
 # Comms-plane sample families.  Not literal-checked by a lint rule the
 # way perf.observe names are — they are declared here so the exporters
 # (observability/comms.py, collective/tensor_plane.py) and their

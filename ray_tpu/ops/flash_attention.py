@@ -132,6 +132,7 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret):
             pltpu.VMEM((block_q, D), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd",  # the XLA Ops line of a device trace carries it
     )(q, k, v)
     return out, lse[:, :, 0]
 
@@ -271,6 +272,7 @@ def _flash_bwd(scale, causal, block_q, block_k, interpret, residuals, g):
         out_shape=jax.ShapeDtypeStruct((BH, Lq, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
         interpret=interpret,
+        name="flash_dq",
     )(q, k, v, do, lse_c, delta_c)
 
     dk, dv = pl.pallas_call(
@@ -299,6 +301,7 @@ def _flash_bwd(scale, causal, block_q, block_k, interpret, residuals, g):
             pltpu.VMEM((block_k, D), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_dkv",
     )(q, k, v, do, lse_c, delta_c)
     return dq, dk, dv
 

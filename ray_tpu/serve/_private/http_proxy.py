@@ -85,18 +85,22 @@ class HTTPProxy:
                 self.end_headers()
                 self.wfile.write(body)
 
-            def _stream(self, items):
+            def _stream(self, items) -> int:
+                """Returns the bytes of the pieces written."""
                 self._headers_sent = True
                 self.send_response(200)
                 self.send_header("Content-Type", "application/json")
                 self.send_header("Transfer-Encoding", "chunked")
                 self.end_headers()
+                written = 0
                 for item in items:
                     piece = (json.dumps(item) + "\n").encode()
                     self.wfile.write(
                         f"{len(piece):x}\r\n".encode() + piece + b"\r\n")
                     self.wfile.flush()
+                    written += len(piece)
                 self.wfile.write(b"0\r\n\r\n")
+                return written
 
             def _dispatch(self, body: Optional[bytes]):
                 t_arrival = time.monotonic() if perf.ENABLED else 0.0
@@ -139,27 +143,34 @@ class HTTPProxy:
                                 arg = body
                         if isinstance(arg, (bytes, bytearray)):
                             arg = proxy._maybe_put_ingress(arg)
-                        handle = proxy._get_handle(name)
-                        # Perf breakdown: queue_wait (semaphore + routing
-                        # + body handling, the pre-dispatch share) vs
-                        # execute (replica round-trip) vs serialize
-                        # (response encode + write).
+                        # Perf breakdown: execute (routing + replica
+                        # round-trip) vs serialize (response encode +
+                        # write).  The pre-dispatch share is the
+                        # serve.route span; serve.queue_wait is the
+                        # replica batcher's alone.
                         t_exec = time.monotonic() if t_arrival else 0.0
-                        if t_arrival:
-                            perf.observe("serve.queue_wait",
-                                         (t_exec - t_arrival) * 1e3)
-                        result = handle.remote(arg).result(
-                            timeout=proxy._timeout_s)
+                        with observability.span("serve.route",
+                                                cat="serve") as route:
+                            response = proxy._get_handle(name).remote(arg)
+                            if route.live:
+                                route.set(**response._tracked.routed())
+                        with observability.span("serve.await_replica",
+                                                cat="serve"):
+                            result = response.result(
+                                timeout=proxy._timeout_s)
                         t_ser = time.monotonic() if t_arrival else 0.0
                         if t_arrival:
                             perf.observe("serve.execute",
                                          (t_ser - t_exec) * 1e3)
                         try:
-                            if (isinstance(result, (list, tuple))
-                                    and self.headers.get("X-Serve-Stream")):
-                                self._stream(result)
-                                return
-                            self._send_value(result)
+                            with observability.span("serve.reply",
+                                                    cat="serve") as reply:
+                                if (isinstance(result, (list, tuple))
+                                        and self.headers.get(
+                                            "X-Serve-Stream")):
+                                    reply.set(bytes=self._stream(result))
+                                    return
+                                reply.set(bytes=self._send_value(result))
                         finally:
                             if t_arrival:
                                 now = time.monotonic()
@@ -193,13 +204,15 @@ class HTTPProxy:
                         perf.observe("serve.request",
                                      (time.monotonic() - t_arrival) * 1e3)
 
-            def _send_value(self, result):
+            def _send_value(self, result) -> int:
+                """Returns the bytes of the body written."""
                 body = json.dumps(result).encode()
                 self.send_response(200)
                 self.send_header("Content-Type", "application/json")
                 self.send_header("Content-Length", str(len(body)))
                 self.end_headers()
                 self.wfile.write(body)
+                return len(body)
 
             def do_GET(self):
                 self._dispatch(None)

@@ -45,11 +45,12 @@ import threading
 import time
 from typing import Any, List, Optional
 
-from ray_tpu import chaos
+from ray_tpu import chaos, observability
 from ray_tpu._private.config import _config
 from ray_tpu.exceptions import BatchExecutionError, ServeOverloadedError
 from ray_tpu.observability import perf
-from ray_tpu.serve.batching import next_request_id, pad_items
+from ray_tpu.observability.metric_names import REPLICA_INIT_GAUGE
+from ray_tpu.serve.batching import next_bucket, next_request_id, pad_items
 
 # EWMA weight for the per-item execution-time estimate that sizes batches
 # and the queue_est_ms backpressure signal (local smoothing; the
@@ -88,7 +89,7 @@ class _BatchSlot:
     """One queued request parked in the replica batcher."""
 
     __slots__ = ("item", "event", "value", "error", "request_id",
-                 "t_enqueue")
+                 "t_enqueue", "trace")
 
     def __init__(self, item):
         self.item = item
@@ -97,6 +98,10 @@ class _BatchSlot:
         self.error: Optional[BaseException] = None
         self.request_id = next_request_id()
         self.t_enqueue = time.monotonic()
+        # the submitting actor thread's (trace_id, span_id): the flusher's
+        # spans for the batch this request heads join its trace
+        self.trace = (observability.current() if observability.live()
+                      else None)
 
 
 class _ReplicaBatcher:
@@ -120,6 +125,7 @@ class _ReplicaBatcher:
         self._wakeup = threading.Event()
         self._thread: Optional[threading.Thread] = None
         self._stop = False
+        self._batches = 0  # batches run; only the flusher thread counts
 
     def depth(self) -> int:
         with self._lock:
@@ -177,41 +183,16 @@ class _ReplicaBatcher:
             self._wakeup.wait()
             if self._stop:
                 return
-            cap = self._effective_max()
-            # Linger window anchored on the OLDEST queued request: fire
-            # when the batch fills (to the adaptive cap) or the oldest
-            # request has waited batch_wait_timeout_s.
-            while True:
-                with self._lock:
-                    depth = len(self._queue)
-                    oldest = (self._queue[0].t_enqueue
-                              if self._queue else None)
-                    wait_s = self._wait_s
-                if oldest is None:
-                    break
-                if (depth >= cap
-                        or time.monotonic() - oldest >= wait_s):
-                    break
-                time.sleep(min(0.0005, max(wait_s / 10.0, 1e-4)))
-            deadline_ms = float(_config.get("serve_queue_deadline_ms"))
-            expired: List[_BatchSlot] = []
             with self._lock:
                 if not self._queue:
                     self._wakeup.clear()
                     continue
-                if deadline_ms > 0:
-                    now = time.monotonic()
-                    live: List[_BatchSlot] = []
-                    for s in self._queue:
-                        if (now - s.t_enqueue) * 1e3 > deadline_ms:
-                            expired.append(s)
-                        else:
-                            live.append(s)
-                    self._queue = live
-                batch = self._queue[:cap]
-                self._queue = self._queue[cap:]
-                if not self._queue:
-                    self._wakeup.clear()
+                head = self._queue[0].trace
+            # From the wake-up with requests in hand to the batch cut:
+            # what the batching policy spends on waiting for neighbours.
+            with observability.span("serve.batch.linger", cat="serve",
+                                    parent=head) as linger:
+                batch, expired, deadline_ms = self._cut_batch(linger)
             for s in expired:
                 wait_ms = (time.monotonic() - s.t_enqueue) * 1e3
                 self._replica._observe_queue_wait(wait_ms)
@@ -223,6 +204,44 @@ class _ReplicaBatcher:
                 s.event.set()
             if batch:
                 self._run_batch(batch)
+
+    def _cut_batch(self, linger: observability.span):
+        """Linger, then cut: ``(the batch, the requests that aged out, the
+        deadline they aged past)``.  Only this thread takes requests off
+        the queue, so it is not empty here."""
+        cap = self._effective_max()
+        # Linger window anchored on the OLDEST queued request: fire
+        # when the batch fills (to the adaptive cap) or the oldest
+        # request has waited batch_wait_timeout_s.
+        while True:
+            with self._lock:
+                depth = len(self._queue)
+                oldest = self._queue[0].t_enqueue
+                wait_s = self._wait_s
+            waited = time.monotonic() - oldest
+            if depth >= cap or waited >= wait_s:
+                break
+            time.sleep(min(0.0005, max(wait_s / 10.0, 1e-4)))
+        if linger.live:
+            linger.set(depth=depth, cap=cap,
+                       oldest_wait_us=int(waited * 1e6))
+        deadline_ms = float(_config.get("serve_queue_deadline_ms"))
+        expired: List[_BatchSlot] = []
+        with self._lock:
+            if deadline_ms > 0:
+                now = time.monotonic()
+                live: List[_BatchSlot] = []
+                for s in self._queue:
+                    if (now - s.t_enqueue) * 1e3 > deadline_ms:
+                        expired.append(s)
+                    else:
+                        live.append(s)
+                self._queue = live
+            batch = self._queue[:cap]
+            self._queue = self._queue[cap:]
+            if not self._queue:
+                self._wakeup.clear()
+        return batch, expired, deadline_ms
 
     def _call(self, items: List[Any]) -> List[Any]:
         n = len(items)
@@ -237,6 +256,21 @@ class _ReplicaBatcher:
         return results
 
     def _run_batch(self, batch: List[_BatchSlot]) -> None:
+        self._batches += 1
+        # Pad, call, read back and deliver: device idle under this span
+        # and outside serve.batch.call's device work is the batcher's own
+        # host time.
+        with observability.span("serve.batch.execute", cat="serve",
+                                parent=batch[0].trace) as execute:
+            if execute.live:
+                with self._lock:
+                    buckets = self._buckets
+                execute.set(n=len(batch),
+                            padded_n=next_bucket(len(batch), buckets),
+                            batch=self._batches)
+            self._execute(batch)
+
+    def _execute(self, batch: List[_BatchSlot]) -> None:
         r = self._replica
         t_start = time.monotonic()
         for s in batch:
@@ -283,6 +317,26 @@ class _ReplicaBatcher:
             s.event.set()
 
 
+_init_gauge = None
+
+
+def _record_init_seconds(deployment: str, replica: str, seconds: float):
+    """Of a deployment's start-up, the share that is the user's own
+    constructor (weights, warm-up compiles) and not the controller's.
+    A gauge in the process's registry, so it outlives the replica and is
+    there for whoever reads after ``serve.shutdown()``."""
+    global _init_gauge
+    if _init_gauge is None:
+        from ray_tpu.util.metrics import Gauge
+        _init_gauge = Gauge(  # raylint: allow(data-race) idempotent: the registry keeps one metric per name
+            REPLICA_INIT_GAUGE,
+            "Seconds a replica's user constructor took",
+            tag_keys=("deployment", "replica"))
+    # raylint: allow(metrics-cardinality) one series per replica, bounded by the deployments' replica counts
+    _init_gauge.set(seconds, tags={"deployment": deployment,
+                                   "replica": replica})
+
+
 class Replica:
     def __init__(self, deployment_name: str, replica_tag: str,
                  func_or_class, init_args, init_kwargs,
@@ -314,7 +368,10 @@ class Replica:
         if self._is_function:
             self._callable = func_or_class
         else:
+            t_init = time.monotonic()
             self._callable = func_or_class(*init_args, **(init_kwargs or {}))
+            _record_init_seconds(deployment_name, replica_tag,
+                                 time.monotonic() - t_init)
         # Replica-local latency sensors (always on — they are the
         # router/autoscaler inputs, not optional observability).
         self._hist_queue_wait = perf.PerfHistogram("queue_wait")
@@ -343,9 +400,10 @@ class Replica:
         # take a LIST of requests, return a list of equal length.  An
         # async callable is run to completion here — the flusher thread
         # has no event loop of its own, and the result must be a list.
-        result = self._callable(items)
-        if inspect.iscoroutine(result):
-            result = asyncio.run(result)
+        with observability.span("serve.batch.call", cat="serve"):
+            result = self._callable(items)
+            if inspect.iscoroutine(result):
+                result = asyncio.run(result)
         return result
 
     def _observe_queue_wait(self, ms: float) -> None:

@@ -204,6 +204,14 @@ class _TrackedRef:
         self._released = False
         self._retries = 0
 
+    def routed(self) -> dict:
+        """Where the router sent the request: the replica's tag and how
+        many requests it has in flight there, this one included (the
+        attributes of the proxy's ``serve.route`` span; read without the
+        router's lock, so a neighbour's pick may already be counted)."""
+        return {"replica": self._tag,
+                "in_flight": self._router._in_flight.get(self._tag, 0)}
+
     def _settle(self) -> None:
         if not self._released:
             self._released = True

@@ -292,6 +292,7 @@ def test_autoscale_scales_from_target_not_live():
 
     ctrl = object.__new__(ServeController)
     ctrl._autoscale_state = {}
+    ctrl._lock = threading.RLock()   # _autoscale decides under it
     state = DeploymentState("scaling")
     state.config = serve.DeploymentConfig(
         autoscaling_config=serve.AutoscalingConfig(
