@@ -145,25 +145,43 @@ def _flash_sharded(mesh: Mesh, spec: PartitionSpec):
     """The flash kernel under ``shard_map``, memoized on its statics like
     the ``parallel/`` wrappers. XLA cannot partition a Mosaic kernel, so
     each device runs it on its own shard; attention is independent across
-    batch and heads, so no collective is needed."""
+    batch and heads, so no collective is needed. K and V come with their
+    own head count, split over the same axes as q's heads, so a device's
+    query heads find their K/V heads on that device."""
     return shard_map(functools.partial(flash_attention, causal=True),
                      mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
                      check_vma=False)
 
 
+def _repeat_kv(q, k, v):
+    """GQA for the paths that want K and V at q's head count."""
+    rep = q.shape[2] // k.shape[2]
+    if rep == 1:
+        return k, v
+    return jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2)
+
+
 def _attention(q, k, v, cfg: TransformerConfig, mesh: Optional[Mesh],
                rules: Optional[ShardingRules] = None):
     """``mesh`` is the mesh of the enclosing jit, or None when the caller
-    already runs per device (one chip, or inside a ``shard_map``)."""
+    already runs per device (one chip, or inside a ``shard_map``). ``k`` and
+    ``v`` carry ``cfg.kv_heads`` heads: the flash kernel takes them so, the
+    other two paths repeat them first."""
     if mesh is not None and "seq" in mesh.axis_names and mesh.shape["seq"] > 1:
-        return ring_attention(q, k, v, mesh, causal=True)
+        return ring_attention(q, *_repeat_kv(q, k, v), mesh, causal=True)
     if cfg.use_flash:
         if mesh is None:
             return flash_attention(q, k, v, causal=True)
         # batch over the rules' batch axes, heads over tensor
         spec = (rules or ShardingRules()).sharding(
             mesh, ("batch", None, "act_heads", None)).spec
+        split = math.prod(mesh.shape[a] for a in jax.tree.leaves(spec[2]))
+        if cfg.kv_heads % split:
+            raise ValueError(
+                f"flash attention under a mesh splits heads {split} ways "
+                f"({spec[2]}): n_kv_heads={cfg.kv_heads} does not divide")
         return _flash_sharded(mesh, spec)(q, k, v)
+    k, v = _repeat_kv(q, k, v)
     D = q.shape[-1]
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) / math.sqrt(D)
     L, Lk = q.shape[1], k.shape[1]
@@ -181,10 +199,6 @@ def _block(params, x, positions, cfg: TransformerConfig, mesh, rules=None):
     v = jnp.einsum("bld,dhk->blhk", h, params["attn"]["wv"].astype(x.dtype))
     q = _rope(q, cfg.rope_theta, positions)
     k = _rope(k, cfg.rope_theta, positions)
-    if cfg.kv_heads != cfg.n_heads:  # GQA: repeat kv heads
-        rep = cfg.n_heads // cfg.kv_heads
-        k = jnp.repeat(k, rep, axis=2)
-        v = jnp.repeat(v, rep, axis=2)
     attn = _attention(q, k, v, cfg, mesh, rules)
     x = x + jnp.einsum("blhk,hkd->bld", attn,
                        params["attn"]["wo"].astype(x.dtype))

@@ -14,6 +14,7 @@ while a module is imported would give xdist workers different tests to
 collect. Keep every such test in this one file for the same reason.
 """
 
+import dataclasses
 import sys
 
 import jax
@@ -34,6 +35,10 @@ KERNEL = "tpu_custom_call"
 CFG = TransformerConfig(vocab_size=32000, d_model=1024, n_layers=12,
                         n_heads=16, max_seq_len=1024, dtype=jnp.bfloat16,
                         use_flash=True)
+# The same with grouped K/V heads (4 query heads a K/V head, as Mistral-7B):
+# the kernel takes K and V un-repeated, and under a mesh their head axis is
+# split like q's.
+CFG_GQA = dataclasses.replace(CFG, n_kv_heads=4)
 BATCH, SEQ = 8, 1024
 
 
@@ -76,26 +81,39 @@ def _shape(leaf, sharding):
     return jax.ShapeDtypeStruct(leaf.shape, leaf.dtype, sharding=sharding)
 
 
-@pytest.mark.parametrize("shape,dtype", [
-    ((8, 1024, 16, 64), jnp.bfloat16),
-    ((4, 2048, 16, 128), jnp.bfloat16),
-    ((2, 1000, 8, 64), jnp.bfloat16),      # ragged last block
-    ((4, 512, 8, 64), jnp.float32),
-], ids=["8x1024x16x64-bf16", "4x2048x16x128-bf16", "2x1000x8x64-ragged",
-        "4x512x8x64-f32"])
-def test_flash_kernel_fwd_bwd_compiles(topo, mosaic, shape, dtype):
+# (q shape, kv heads, dtype, with the backward): four are the benchmark
+# cells' own calls (Mistral 32/8 heads at 4,096 tokens, one and two
+# sequences a chip; InternLM2 16/8 heads at the largest and smallest bucket).
+KERNEL_SHAPES = {
+    "8x1024x16x64-bf16": ((8, 1024, 16, 64), 16, jnp.bfloat16, True),
+    "4x2048x16x128-bf16": ((4, 2048, 16, 128), 16, jnp.bfloat16, True),
+    "2x1000x8x64-ragged": ((2, 1000, 8, 64), 8, jnp.bfloat16, True),
+    "4x512x8x64-f32": ((4, 512, 8, 64), 8, jnp.float32, True),
+    "1x4096x32x128-kv8-bf16": ((1, 4096, 32, 128), 8, jnp.bfloat16, True),
+    "2x4096x32x128-kv8-bf16": ((2, 4096, 32, 128), 8, jnp.bfloat16, True),
+    "8x2048x16x128-kv8-fwd": ((8, 2048, 16, 128), 8, jnp.bfloat16, False),
+    "2x256x16x128-kv8-fwd": ((2, 256, 16, 128), 8, jnp.bfloat16, False),
+    # 8,192 resident rows: the tiles ask for more than the default VMEM
+    "1x16384x4x128-kv2-long": ((1, 16384, 4, 128), 2, jnp.bfloat16, True),
+}
+
+
+@pytest.mark.parametrize("case", list(KERNEL_SHAPES))
+def test_flash_kernel_fwd_bwd_compiles(topo, mosaic, case):
     from ray_tpu.ops import flash_attention
-    x = jax.ShapeDtypeStruct(shape, dtype,
-                             sharding=SingleDeviceSharding(topo.devices[0]))
+    shape, kv_heads, dtype, backward = KERNEL_SHAPES[case]
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    q = jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct(shape[:2] + (kv_heads,) + shape[3:], dtype,
+                              sharding=one_chip)
 
-    def fwd_bwd(q, k, v):
-        return jax.value_and_grad(
-            lambda q, k, v: flash_attention(q, k, v).astype(
-                jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v)
+    def loss(q, k, v):
+        return flash_attention(q, k, v).astype(jnp.float32).sum()
 
-    text = jax.jit(fwd_bwd).lower(x, x, x).compile().as_text()
+    fn = (jax.value_and_grad(loss, argnums=(0, 1, 2)) if backward else loss)
+    text = jax.jit(fn).lower(q, kv, kv).compile().as_text()
     # forward, dq and dk/dv kernels
-    assert text.count(KERNEL) >= 3
+    assert text.count(KERNEL) >= (3 if backward else 1)
 
 
 def _lower_train_step(mesh: Mesh, cfg: TransformerConfig = CFG):
@@ -111,7 +129,8 @@ def _lower_train_step(mesh: Mesh, cfg: TransformerConfig = CFG):
 
 
 def test_train_step_compiles_on_one_chip(topo, mosaic):
-    compiled = _lower_train_step(_mesh(topo.devices[:1], data=1)).compile()
+    compiled = _lower_train_step(_mesh(topo.devices[:1], data=1),
+                                 CFG_GQA).compile()
     assert KERNEL in compiled.as_text()
     mem = compiled.memory_analysis()
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
@@ -122,7 +141,8 @@ def test_train_step_compiles_on_one_chip(topo, mosaic):
     dict(data=4), dict(data=2, tensor=2), dict(data=1, fsdp=4)],
     ids=["data4", "data2xtensor2", "fsdp4"])
 def test_train_step_compiles_on_four_chip_mesh(topo, mosaic, axes):
-    text = _lower_train_step(_mesh(topo.devices, **axes)).compile().as_text()
+    text = _lower_train_step(_mesh(topo.devices, **axes),
+                             CFG_GQA).compile().as_text()
     assert KERNEL in text
     # the gradient reduction over the batch axes
     assert "all-reduce" in text or "reduce-scatter" in text

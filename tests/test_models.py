@@ -67,6 +67,71 @@ def test_transformer_flash_matches_dense():
                                rtol=2e-4, atol=2e-4)
 
 
+GQA = TransformerConfig(**{**TINY.__dict__, "n_kv_heads": 2})
+GQA_FLASH = TransformerConfig(**{**GQA.__dict__, "use_flash": True})
+
+
+def _loss_and_grads(cfg, params, tokens, mesh=None):
+    return jax.jit(jax.value_and_grad(
+        lambda p: transformer.loss_fn(p, tokens, cfg, mesh)))(params)
+
+
+def _assert_same_loss_and_grads(got, want, tol):
+    np.testing.assert_allclose(float(got[0]), float(want[0]), rtol=tol)
+    for g, w in zip(jax.tree.leaves(got[1]), jax.tree.leaves(want[1])):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=tol,
+                                   atol=tol)
+
+
+def test_transformer_gqa_flash_matches_dense():
+    """The kernel reads K/V heads by index; the einsum path repeats them.
+    Loss and every gradient (wk and wv sum their group's query heads)."""
+    params = transformer.init_params(jax.random.PRNGKey(0), GQA)
+    assert params["blocks"]["attn"]["wk"].shape[2] == 2
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 129), 0, 256)
+    _assert_same_loss_and_grads(_loss_and_grads(GQA_FLASH, params, tokens),
+                                _loss_and_grads(GQA, params, tokens), 2e-4)
+
+
+@pytest.mark.parametrize("axes", [dict(data=4, tensor=2),
+                                  dict(data=2, fsdp=2, tensor=2)],
+                         ids=["data4xtensor2", "data2xfsdp2xtensor2"])
+def test_transformer_gqa_flash_under_a_mesh(eight_device_mesh, axes):
+    """Under a mesh K and V go into the kernel's shard_map with their own
+    head count split like q's: each device's query heads find their K/V
+    heads on it, and the result is the single-device one."""
+    params = transformer.init_params(jax.random.PRNGKey(0), GQA)
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (8, 65), 0, 256)
+    want = _loss_and_grads(GQA, params, tokens)
+    mesh = build_mesh(MeshConfig(**axes), eight_device_mesh)
+    rules = ShardingRules()
+    sharded = shard_pytree(params, transformer.logical_axes(GQA), mesh, rules)
+    toks = jax.device_put(tokens, batch_sharding(mesh, rules, ndim=2))
+    got = _loss_and_grads(GQA_FLASH, sharded, toks, mesh)
+    _assert_same_loss_and_grads(got, want, 5e-4)
+
+
+def test_transformer_gqa_flash_refuses_heads_that_do_not_split(
+        eight_device_mesh):
+    cfg = TransformerConfig(**{**GQA_FLASH.__dict__, "n_kv_heads": 1})
+    params = transformer.init_params(jax.random.PRNGKey(0), cfg)
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (8, 33), 0, 256)
+    mesh = build_mesh(MeshConfig(data=4, tensor=2), eight_device_mesh)
+    with pytest.raises(ValueError, match="n_kv_heads=1"):
+        transformer.apply(params, tokens, cfg, mesh=mesh)
+
+
+def test_transformer_gqa_ring_attention_matches(eight_device_mesh):
+    """Ring attention still gets K and V repeated to q's heads."""
+    params = transformer.init_params(jax.random.PRNGKey(0), GQA)
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 64), 0, 256)
+    ref = transformer.apply(params, tokens, GQA, mesh=None)
+    mesh = build_mesh(MeshConfig(data=2, seq=4), eight_device_mesh)
+    out = transformer.apply(params, tokens, GQA_FLASH, mesh=mesh)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-3, atol=2e-3)
+
+
 def test_transformer_sharded_train_step(eight_device_mesh):
     """Full fsdp+tp sharded train step over the 8-device mesh."""
     mesh = build_mesh(MeshConfig(data=2, fsdp=2, tensor=2),

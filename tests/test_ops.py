@@ -1,5 +1,7 @@
 """Pallas kernels vs dense references (interpreter mode on the CPU mesh)."""
 
+import sys
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -100,3 +102,127 @@ def test_flash_attention_ragged_grad():
         _dense_attention(q, k, v, False) ** 2))(q)
     np.testing.assert_allclose(np.asarray(g_flash), np.asarray(g_dense),
                                rtol=2e-4, atol=2e-4)
+
+
+# -- tiles from the shape, GQA by index, operands in their own dtype --------
+
+
+def _qkv(key, B, Lq, Lk, H, KVH, D, dtype=jnp.float32):
+    key = jax.random.PRNGKey(key)
+    shapes = [(B, Lq, H, D), (B, Lk, KVH, D), (B, Lk, KVH, D)]
+    return [jax.random.normal(jax.random.fold_in(key, i), s, dtype=dtype)
+            for i, s in enumerate(shapes)]
+
+
+def _dense_gqa(q, k, v, causal):
+    """The dense reference on K and V repeated to q's heads, in float32."""
+    rep = q.shape[2] // k.shape[2]
+    q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
+    return _dense_attention(q, jnp.repeat(k, rep, axis=2),
+                            jnp.repeat(v, rep, axis=2), causal)
+
+
+def _fwd_and_grads(attn, q, k, v):
+    """Output and the three gradients of a loss with an uneven cotangent."""
+    w = jnp.cos(jnp.arange(q.size, dtype=jnp.float32)).reshape(q.shape)
+
+    def loss(q, k, v):
+        out = attn(q, k, v)
+        return jnp.sum(out.astype(jnp.float32) * w), out
+
+    (_, out), grads = jax.value_and_grad(loss, argnums=(0, 1, 2),
+                                         has_aux=True)(q, k, v)
+    return (out,) + grads
+
+
+def _assert_matches_dense(q, k, v, causal, tol, **blocks):
+    got = _fwd_and_grads(
+        lambda q, k, v: flash_attention(q, k, v, causal=causal, **blocks),
+        q, k, v)
+    want = _fwd_and_grads(lambda q, k, v: _dense_gqa(q, k, v, causal),
+                          q, k, v)
+    for g, w, name in zip(got, want, ("out", "dq", "dk", "dv")):
+        assert g.shape == w.shape, name
+        np.testing.assert_allclose(
+            np.asarray(g, dtype=np.float32), np.asarray(w), rtol=tol,
+            atol=tol, err_msg=f"{name} mismatch")
+
+
+@pytest.mark.parametrize("heads,kv_heads", [(8, 2), (4, 2), (4, 1)],
+                         ids=["ratio4", "ratio2", "mqa"])
+@pytest.mark.parametrize("causal", [True, False])
+def test_flash_attention_gqa_unrepeated(heads, kv_heads, causal):
+    """K and V with their own head count: query head h reads K/V head
+    h // ratio, and dk/dv sum the group's query heads."""
+    q, k, v = _qkv(6, 2, 256, 256, heads, kv_heads, 32)
+    _assert_matches_dense(q, k, v, causal, 2e-4, block_q=128, block_k=128)
+
+
+# (Lq, Lk, block_q, block_k, block_major): interior, diagonal, skipped and
+# ragged-last tiles, one or several tiles a grid step, several major blocks.
+TILINGS = [
+    (200, 200, 128, 128, None),    # two tiles, ragged last, one major block
+    (640, 640, 128, 128, 256),     # 5x5 tiles, 3 major blocks, last half out
+    (640, 640, 256, 128, 128),     # block_q != block_k, one tile a step
+    (640, 640, 128, 256, 512),
+    (1000, 1000, 256, 256, 512),   # ragged last tile inside a major block
+    (1000, 1000, 128, 256, None),
+    (384, 640, 128, 128, 256),     # Lq != Lk
+    (640, 384, 128, 128, 256),
+    (200, 1000, None, None, None),  # tiles from the shape
+]
+
+
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("Lq,Lk,block_q,block_k,block_major", TILINGS, ids=[
+    "-".join(str(x) for x in t) for t in TILINGS])
+def test_flash_attention_tilings(Lq, Lk, block_q, block_k, block_major,
+                                 causal):
+    q, k, v = _qkv(7, 1, Lq, Lk, 2, 1, 32)
+    _assert_matches_dense(q, k, v, causal, 2e-4, block_q=block_q,
+                          block_k=block_k, block_major=block_major)
+
+
+@pytest.mark.parametrize("heads,kv_heads", [(2, 2), (4, 1)],
+                         ids=["mha", "gqa4"])
+def test_flash_attention_bf16_grads(heads, kv_heads):
+    """bfloat16 operands, float32 accumulation: forward and all three
+    gradients against the float32 dense reference on the same inputs."""
+    q, k, v = _qkv(8, 1, 384, 384, heads, kv_heads, 64, jnp.bfloat16)
+    got = _fwd_and_grads(
+        lambda q, k, v: flash_attention(q, k, v, causal=True, block_q=128,
+                                        block_k=128, block_major=256),
+        q, k, v)
+    want = _fwd_and_grads(lambda q, k, v: _dense_gqa(q, k, v, True), q, k, v)
+    for g, w, name in zip(got, want, ("out", "dq", "dk", "dv")):
+        assert g.dtype == jnp.bfloat16, name
+        g, w = np.asarray(g, dtype=np.float32), np.asarray(w, np.float32)
+        # bfloat16 keeps 8 bits: element-wise to 3e-2 of the tensor's scale
+        assert np.max(np.abs(g - w)) <= 3e-2 * np.max(np.abs(w)), name
+        assert np.linalg.norm(g - w) <= 1e-2 * np.linalg.norm(w), name
+
+
+def test_flash_attention_refuses_mismatched_kv_heads():
+    q, k, v = _qkv(9, 1, 128, 128, 4, 3, 32)
+    with pytest.raises(ValueError, match="heads"):
+        flash_attention(q, k, v)
+
+
+@pytest.mark.parametrize("Lq,Lk,D,itemsize", [
+    (4096, 4096, 128, 2), (2048, 2048, 128, 2), (256, 256, 128, 2),
+    (1024, 1024, 64, 2), (1000, 1000, 64, 2), (512, 512, 64, 4),
+    (200, 200, 32, 4), (64, 64, 32, 4), (32768, 32768, 128, 2)])
+@pytest.mark.parametrize("stream_q", [False, True])
+def test_flash_tiles_from_the_shape(Lq, Lk, D, itemsize, stream_q):
+    """Tiles never exceed their side, are lane multiples where the side has
+    128 rows, and the streamed side stays inside its VMEM budget."""
+    fa = sys.modules["ray_tpu.ops.flash_attention"]  # the module
+    bq, bk, n, vmem = fa._tiles(fa._Blocks(None, None, None), Lq, Lk, D,
+                                itemsize, stream_q)
+    assert bq <= Lq and bk <= Lk
+    for b, L in ((bq, Lq), (bk, Lk)):
+        assert b % 128 == 0 if L >= 128 else b == L
+    tile, length = (bq, Lq) if stream_q else (bk, Lk)
+    assert 1 <= n and n * tile <= length
+    assert 4 * n * tile * D * itemsize <= fa._STREAM_BYTES
+    assert vmem < 64 * 2 ** 20
