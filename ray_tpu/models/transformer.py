@@ -10,6 +10,21 @@ Design points (vs the reference, which delegates all modeling to torch):
   attention when the mesh has a nontrivial ``seq`` axis (long-context path).
 - ``jax.checkpoint`` (remat) per block trades FLOPs for HBM.
 - RoPE positions, SwiGLU MLP, RMSNorm: the standard modern decoder recipe.
+
+A looped decoder (Ouro / LoopLM: ``n_passes``, ``post_norm``, ``exit_beta``)
+is the same stack applied ``n_passes`` times with the same weights
+(``pass_states``): each pass ends in the shared final norm, whose output
+feeds the next pass and that pass's exit. A block then also norms what each
+sub-layer returns, before the residual add (``ln1_post``, ``ln2_post``).
+The exit gate, one ``Linear(d_model, 1)`` on a pass's normed state, gives
+the probability ``lambda_t`` of stopping there; the probability of leaving
+at pass t is ``p_t = lambda_t * prod_{j<t} (1 - lambda_j)``, the last pass
+taking the remainder. Training minimises ``sum_t p_t * CE_t - exit_beta *
+H(p)`` a position (``loss_and_metrics``); serving replies with the last
+pass's logits (``backbone`` + ``head``: the published
+``early_exit_threshold`` of 1 never leaves early, and there is no decode
+loop to leave). With the defaults none of this exists: the parameter tree
+and the compiled programs are the plain decoder's.
 """
 
 from __future__ import annotations
@@ -42,6 +57,18 @@ class TransformerConfig:
     remat: bool = True
     use_flash: bool = True
     rope_theta: float = 10000.0
+    # RMSNorm's epsilon (published ``rms_norm_eps``).
+    norm_eps: float = 1e-6
+    # How many times the whole stack is applied, with the same weights and
+    # the final norm between passes (published ``total_ut_steps``).
+    n_passes: int = 1
+    # A block norms each sub-layer's output too, before the residual add:
+    # two more norm weights a block (the Ouro block's sandwich norms).
+    post_norm: bool = False
+    # None: no exit gate. A number: the gate exists (``exit_gate`` in the
+    # tree) and the loss is the exit-weighted objective with this weight on
+    # the exit distribution's entropy (the LoopLM paper's beta).
+    exit_beta: Optional[float] = None
 
     @property
     def head_dim(self) -> int:
@@ -67,7 +94,11 @@ def init_params(key: jax.Array, cfg: TransformerConfig) -> Dict[str, Any]:
 
     def layer(k):
         ks = jax.random.split(k, 7)
+        post = ({"ln1_post": jnp.ones((d,), jnp.float32),
+                 "ln2_post": jnp.ones((d,), jnp.float32)}
+                if cfg.post_norm else {})
         return {
+            **post,
             "attn": {
                 "wq": dense(ks[0], (d, h, hd), d),
                 "wk": dense(ks[1], (d, kvh, hd), d),
@@ -84,12 +115,18 @@ def init_params(key: jax.Array, cfg: TransformerConfig) -> Dict[str, Any]:
         }
 
     layer_keys = jax.random.split(k_layers, cfg.n_layers)
+    # the gate's key is folded in beside the three, so that a configuration
+    # without one draws the weights it always drew
+    gate = ({"exit_gate": {"w": dense(jax.random.fold_in(key, 3), (d,), d),
+                           "b": jnp.zeros((), jnp.float32)}}
+            if cfg.exit_beta is not None else {})
     return {
         "embed": jax.random.normal(k_embed, (cfg.vocab_size, d),
                                    jnp.float32) * 0.02,
         "blocks": jax.vmap(layer)(layer_keys),      # stacked: [L, ...]
         "ln_f": jnp.ones((d,), jnp.float32),
         "lm_head": dense(k_head, (d, cfg.vocab_size), d),
+        **gate,
     }
 
 
@@ -112,18 +149,23 @@ def logical_axes(cfg: TransformerConfig) -> Dict[str, Any]:
         "ln1": ("layers", None),
         "ln2": ("layers", None),
     }
-    return {
+    if cfg.post_norm:
+        blk.update(ln1_post=("layers", None), ln2_post=("layers", None))
+    axes = {
         "embed": ("vocab", "embed"),
         "blocks": blk,
         "ln_f": (None,),
         "lm_head": ("embed", "vocab"),
     }
+    if cfg.exit_beta is not None:
+        axes["exit_gate"] = {"w": (None,), "b": ()}
+    return axes
 
 
-def _rmsnorm(x: jax.Array, w: jax.Array) -> jax.Array:
+def _rmsnorm(x: jax.Array, w: jax.Array, eps: float) -> jax.Array:
     xf = x.astype(jnp.float32)
     var = jnp.mean(xf * xf, axis=-1, keepdims=True)
-    return (xf * jax.lax.rsqrt(var + 1e-6)).astype(x.dtype) * w.astype(x.dtype)
+    return (xf * jax.lax.rsqrt(var + eps)).astype(x.dtype) * w.astype(x.dtype)
 
 
 def _rope(x: jax.Array, theta: float, positions: jax.Array) -> jax.Array:
@@ -192,32 +234,41 @@ def _attention(q, k, v, cfg: TransformerConfig, mesh: Optional[Mesh],
 
 
 def _block(params, x, positions, cfg: TransformerConfig, mesh, rules=None):
-    B, L, d = x.shape
-    h = _rmsnorm(x, params["ln1"])
+    norm = functools.partial(_rmsnorm, eps=cfg.norm_eps)
+    h = norm(x, params["ln1"])
     q = jnp.einsum("bld,dhk->blhk", h, params["attn"]["wq"].astype(x.dtype))
     k = jnp.einsum("bld,dhk->blhk", h, params["attn"]["wk"].astype(x.dtype))
     v = jnp.einsum("bld,dhk->blhk", h, params["attn"]["wv"].astype(x.dtype))
     q = _rope(q, cfg.rope_theta, positions)
     k = _rope(k, cfg.rope_theta, positions)
     attn = _attention(q, k, v, cfg, mesh, rules)
-    x = x + jnp.einsum("blhk,hkd->bld", attn,
-                       params["attn"]["wo"].astype(x.dtype))
-    h = _rmsnorm(x, params["ln2"])
+    out = jnp.einsum("blhk,hkd->bld", attn,
+                     params["attn"]["wo"].astype(x.dtype))
+    if cfg.post_norm:
+        out = norm(out, params["ln1_post"])
+    x = x + out
+    h = norm(x, params["ln2"])
     gate = jnp.einsum("bld,df->blf", h, params["mlp"]["wi"].astype(x.dtype))
     up = jnp.einsum("bld,df->blf", h, params["mlp"]["wg"].astype(x.dtype))
     ff = jax.nn.silu(gate) * up
-    x = x + jnp.einsum("blf,fd->bld", ff, params["mlp"]["wo"].astype(x.dtype))
-    return x
+    out = jnp.einsum("blf,fd->bld", ff, params["mlp"]["wo"].astype(x.dtype))
+    if cfg.post_norm:
+        out = norm(out, params["ln2_post"])
+    return x + out
 
 
-def backbone(params: Dict[str, Any], tokens: jax.Array,
-             cfg: TransformerConfig, mesh: Optional[Mesh] = None,
-             rules: Optional[ShardingRules] = None) -> jax.Array:
-    """Embedding + all transformer blocks; returns pre-final-norm states."""
-    B, L = tokens.shape
-    x = params["embed"].astype(cfg.dtype)[tokens]
+def apply_layers(blocks, x: jax.Array, cfg: TransformerConfig,
+                 mesh: Optional[Mesh] = None,
+                 rules: Optional[ShardingRules] = None) -> jax.Array:
+    """A run of stacked layers (``blocks`` leaves: [n, ...]) applied to the
+    states ``x`` [B, L, d]: the whole stack for ``pass_states``, one stage's
+    layers for the pipeline (``train.step``, which passes ``mesh=None``
+    because a stage already runs per device).
+
+    One scan over the stacked layer params: compiles a single block body
+    (fast compiles at depth) and keeps the layer dim shardable for PP."""
+    B, L, _ = x.shape
     positions = jnp.broadcast_to(jnp.arange(L)[None], (B, L))
-
     block_fn = functools.partial(_block, cfg=cfg, mesh=mesh, rules=rules)
     if cfg.remat:
         block_fn = jax.checkpoint(block_fn)
@@ -225,17 +276,49 @@ def backbone(params: Dict[str, Any], tokens: jax.Array,
     def scan_body(x, layer_params):
         return block_fn(layer_params, x, positions), None
 
-    # One scan over the stacked layer params: compiles a single block body
-    # (fast compiles at depth) and keeps the layer dim shardable for PP.
-    x, _ = jax.lax.scan(scan_body, x, params["blocks"])
+    x, _ = jax.lax.scan(scan_body, x, blocks)
     return x
+
+
+def pass_states(params: Dict[str, Any], tokens: jax.Array,
+                cfg: TransformerConfig, mesh: Optional[Mesh] = None,
+                rules: Optional[ShardingRules] = None) -> jax.Array:
+    """Embedding, then the stack ``cfg.n_passes`` times over: every pass's
+    pre-final-norm states, [n_passes, B, L, d]. Pass t + 1 starts from the
+    final norm of pass t's states, which is also what ``head`` makes of
+    them.
+
+    The passes are a second ``lax.scan`` round the layers' scan, not
+    unrolled calls: one block body is compiled whatever ``n_passes`` is, and
+    on the v5e the step's memory was the smaller that way (PERF.md section
+    4). A single pass builds no loop at all: the plain decoder's program."""
+    x = params["embed"].astype(cfg.dtype)[tokens]
+    layers = functools.partial(apply_layers, params["blocks"], cfg=cfg,
+                               mesh=mesh, rules=rules)
+    if cfg.n_passes == 1:
+        return layers(x)[None]
+
+    def one_pass(x, _):
+        h = layers(x)
+        return _rmsnorm(h, params["ln_f"], cfg.norm_eps), h
+
+    _, states = jax.lax.scan(one_pass, x, None, length=cfg.n_passes)
+    return states
+
+
+def backbone(params: Dict[str, Any], tokens: jax.Array,
+             cfg: TransformerConfig, mesh: Optional[Mesh] = None,
+             rules: Optional[ShardingRules] = None) -> jax.Array:
+    """Embedding + all transformer blocks, every pass of them; returns the
+    last pass's pre-final-norm states."""
+    return pass_states(params, tokens, cfg, mesh, rules)[-1]
 
 
 def head(params: Dict[str, Any], x: jax.Array,
          cfg: TransformerConfig) -> jax.Array:
     """Final norm + lm-head projection -> float32 logits. The single logits
-    path shared by inference (``apply``) and training (``head_and_loss``)."""
-    x = _rmsnorm(x, params["ln_f"])
+    path shared by inference (``apply``) and training (``token_nll``)."""
+    x = _rmsnorm(x, params["ln_f"], cfg.norm_eps)
     logits = jnp.einsum("bld,dv->blv", x,
                         params["lm_head"].astype(cfg.dtype))
     return logits.astype(jnp.float32)
@@ -249,22 +332,74 @@ def apply(params: Dict[str, Any], tokens: jax.Array,
     return head(params, x, cfg)
 
 
-def head_and_loss(params, x: jax.Array, targets: jax.Array,
-                  cfg: TransformerConfig) -> jax.Array:
-    """Final norm + lm head + next-token cross entropy, shared by the scan
-    path (``loss_fn``) and the pipeline-parallel path (train.step)."""
+def token_nll(params, x: jax.Array, targets: jax.Array,
+              cfg: TransformerConfig) -> jax.Array:
+    """Final norm + lm head + each position's next-token cross entropy,
+    [B, L] float32."""
     logits = head(params, x, cfg)
     logp = jax.nn.log_softmax(logits, axis=-1)
-    nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
-    return jnp.mean(nll)
+    return -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
+
+
+def exit_log_probs(params, states: jax.Array,
+                   cfg: TransformerConfig) -> jax.Array:
+    """log p_t of leaving at each pass, [n_passes, B, L] float32, from the
+    gate on each pass's normed states: p_t = lambda_t * prod_{j<t} (1 -
+    lambda_j), and the last pass takes what is left (so one pass has p = 1
+    whatever its gate says). Kept in logs: log(1 - sigmoid(z)) is
+    log_sigmoid(-z)."""
+    x = _rmsnorm(states, params["ln_f"], cfg.norm_eps).astype(jnp.float32)
+    # a sum of float32 products, not a matmul: the MXU would round x to
+    # bfloat16 again
+    z = jnp.sum(x * params["exit_gate"]["w"], -1) + params["exit_gate"]["b"]
+    stay = jnp.cumsum(jax.nn.log_sigmoid(-z), axis=0)      # sum_{j<=t}
+    before = jnp.concatenate([jnp.zeros_like(stay[:1]), stay[:-1]])
+    leave = jax.nn.log_sigmoid(z).at[-1].set(0.0)
+    return before + leave
+
+
+def loss_from_states(params, states: jax.Array, targets: jax.Array,
+                     cfg: TransformerConfig
+                     ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """The training loss from every pass's pre-final-norm states
+    [n_passes, B, L, d], and the metrics that come with it; shared by the
+    scan path (``loss_and_metrics``) and the pipeline-parallel path
+    (train.step).
+
+    Without an exit gate: the last pass's mean next-token cross entropy, no
+    metrics. With one: the mean over positions of ``sum_t p_t * CE_t -
+    exit_beta * H(p)``, with ``exit_p`` [n_passes] (mean p_t) and
+    ``exit_entropy`` (mean H(p)). Each exit's head runs under
+    ``jax.checkpoint`` inside a ``lax.map``, so one exit's float32 logits
+    [B, L, vocab] are alive at a time, forward and backward."""
+    if cfg.exit_beta is None:
+        return jnp.mean(token_nll(params, states[-1], targets, cfg)), {}
+    nll = jax.lax.map(
+        jax.checkpoint(lambda x: token_nll(params, x, targets, cfg)), states)
+    logp = exit_log_probs(params, states, cfg)
+    p = jnp.exp(logp)
+    entropy = -jnp.sum(p * logp, axis=0)
+    loss = jnp.mean(jnp.sum(p * nll, axis=0) - cfg.exit_beta * entropy)
+    return loss, {"exit_p": jnp.mean(p, axis=(1, 2)),
+                  "exit_entropy": jnp.mean(entropy)}
+
+
+def loss_and_metrics(params, tokens, cfg: TransformerConfig,
+                     mesh: Optional[Mesh] = None,
+                     rules: Optional[ShardingRules] = None
+                     ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """The training loss of ``tokens`` (which serve as their own labels) and
+    its metrics, as ``loss_from_states`` gives them."""
+    states = pass_states(params, tokens[:, :-1], cfg, mesh, rules)
+    return loss_from_states(params, states, tokens[:, 1:], cfg)
 
 
 def loss_fn(params, tokens, cfg: TransformerConfig,
             mesh: Optional[Mesh] = None,
             rules: Optional[ShardingRules] = None) -> jax.Array:
-    """Next-token cross entropy (tokens serve as their own labels)."""
-    x = backbone(params, tokens[:, :-1], cfg, mesh, rules)
-    return head_and_loss(params, x, tokens[:, 1:], cfg)
+    """The training loss alone: next-token cross entropy, exit-weighted
+    where the configuration has an exit gate."""
+    return loss_and_metrics(params, tokens, cfg, mesh, rules)[0]
 
 
 def num_params(params) -> int:

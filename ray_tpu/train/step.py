@@ -37,6 +37,15 @@ def make_lm_train_step(cfg: TransformerConfig, mesh: Mesh,
     - data (+fsdp) shards the batch; XLA inserts the gradient psum.
 
     step_fn(state, tokens) -> (state, metrics); state = (params, opt_state).
+    ``metrics`` are device values (reading one is the caller's sync):
+    ``loss``, ``grad_norm`` and, for a configuration with an exit gate
+    (``cfg.exit_beta``), ``exit_p`` [n_passes] (the mean probability of
+    leaving at each pass) and ``exit_entropy`` (the mean entropy of that
+    distribution).
+
+    Raises ``ValueError`` for pipe > 1 with ``cfg.n_passes`` > 1: the GPipe
+    schedule sends a microbatch through the stages once, and a looped stack
+    would have to come back round to the first stage.
     """
     rules = rules or ShardingRules()
     optimizer = optimizer or optax.adamw(3e-4, weight_decay=0.01)
@@ -45,6 +54,11 @@ def make_lm_train_step(cfg: TransformerConfig, mesh: Mesh,
         if cfg.n_layers % pipe != 0:
             raise ValueError(f"n_layers {cfg.n_layers} not divisible by "
                              f"pipe={pipe}")
+        if cfg.n_passes > 1:
+            raise ValueError(
+                f"pipe={pipe} with n_passes={cfg.n_passes}: the pipeline "
+                "schedule applies the stack once; a looped stack cannot be "
+                "pipelined yet")
         if mesh.shape.get("seq", 1) > 1:
             raise ValueError(
                 f"pipe={pipe} with seq={mesh.shape['seq']}: ring attention "
@@ -55,34 +69,23 @@ def make_lm_train_step(cfg: TransformerConfig, mesh: Mesh,
 
     def loss_fn(params, tokens):
         if pipe == 1:
-            return transformer.loss_fn(params, tokens, cfg, mesh, rules)
+            return transformer.loss_and_metrics(params, tokens, cfg, mesh,
+                                                rules)
         # Pipeline path: embed -> pipelined blocks -> head.
         inputs, targets = tokens[:, :-1], tokens[:, 1:]
         x = params["embed"].astype(cfg.dtype)[inputs]
         layers_per_stage = cfg.n_layers // pipe
-
-        def stage_fn(stage_params, h):
-            B, L, _ = h.shape
-            positions = jnp.broadcast_to(jnp.arange(L)[None], (B, L))
-            # mesh=None: stage_fn already runs per device, inside
-            # pipeline_apply's shard_map.
-            block = functools.partial(transformer._block, cfg=cfg, mesh=None)
-            if cfg.remat:
-                block = jax.checkpoint(block)
-
-            def body(h, layer_params):
-                return block(layer_params, h, positions), None
-
-            h, _ = jax.lax.scan(body, h, stage_params)
-            return h
-
+        # mesh=None: a stage already runs per device, inside
+        # pipeline_apply's shard_map.
+        stage_fn = functools.partial(transformer.apply_layers, cfg=cfg,
+                                     mesh=None)
         # blocks leaves: [n_layers, ...] -> [pipe, layers_per_stage, ...]
         stage_params = jax.tree.map(
             lambda p: p.reshape((pipe, layers_per_stage) + p.shape[1:]),
             params["blocks"])
         x = pipeline_apply(stage_fn, stage_params, x, mesh,
                            num_microbatches=num_microbatches)
-        return transformer.head_and_loss(params, x, targets, cfg)
+        return transformer.loss_from_states(params, x[None], targets, cfg)
 
     def init(key) -> Tuple[Any, Any]:
         params = transformer.init_params(key, cfg)
@@ -90,11 +93,13 @@ def make_lm_train_step(cfg: TransformerConfig, mesh: Mesh,
 
     def step(state, tokens):
         params, opt_state = state
-        loss, grads = jax.value_and_grad(loss_fn)(params, tokens)
+        (loss, metrics), grads = jax.value_and_grad(
+            loss_fn, has_aux=True)(params, tokens)
         updates, opt_state = optimizer.update(grads, opt_state, params)
         params = optax.apply_updates(params, updates)
         gnorm = optax.global_norm(grads)
-        return (params, opt_state), {"loss": loss, "grad_norm": gnorm}
+        return (params, opt_state), {"loss": loss, "grad_norm": gnorm,
+                                     **metrics}
 
     # One layout for the train state, going in and coming out: init_fn makes
     # it there and step_fn returns it there, so the second step sees what

@@ -137,6 +137,23 @@ def test_train_step_compiles_on_one_chip(topo, mosaic):
             < 16 * 2 ** 30)
 
 
+def test_looped_train_step_compiles_on_one_chip(topo, mosaic):
+    """The looped decoder (the stack applied four times, sandwich norms,
+    the exit gate and its loss): the kernel is inside two nested scans and
+    the per-exit heads inside a checkpointed map."""
+    looped = dataclasses.replace(CFG, n_layers=2, n_passes=4, post_norm=True,
+                                 exit_beta=0.05)
+    compiled = _lower_train_step(_mesh(topo.devices[:1], data=1),
+                                 looped).compile()
+    text = compiled.as_text()
+    assert KERNEL in text
+    # one exit's float32 logits at a time: [8, 1024, 32000] is 1.05 GB, and
+    # four of them alive with their backward would not leave it here
+    mem = compiled.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            < 8 * 2 ** 30)
+
+
 @pytest.mark.parametrize("axes", [
     dict(data=4), dict(data=2, tensor=2), dict(data=1, fsdp=4)],
     ids=["data4", "data2xtensor2", "fsdp4"])

@@ -1,5 +1,7 @@
 """Model stack: transformer + resnet forward/grad, sharded training step."""
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -190,3 +192,219 @@ def test_resnet50_params_count():
     n = transformer.num_params(params)
     # torchvision resnet50 has ~25.6M params
     assert 20e6 < n < 30e6, n
+
+
+# -- the looped decoder (Ouro / LoopLM) against its plain reference -----------
+
+LOOPED = TransformerConfig(
+    vocab_size=96, d_model=64, n_layers=3, n_heads=4, d_ff=96, max_seq_len=32,
+    dtype=jnp.float32, use_flash=False, remat=True, rope_theta=1e6,
+    norm_eps=1e-6, n_passes=3, post_norm=True, exit_beta=0.05)
+LOOPED_DIMS = {"n_heads": 4, "n_kv_heads": 4, "rope_theta": 1e6,
+               "rms_norm_eps": 1e-6, "total_ut_steps": 3, "exit_beta": 0.05}
+
+
+@pytest.fixture(scope="module")
+def looped():
+    """Seeded weights (norm weights and the gate's bias moved off their
+    initial 1 and 0, so that every leaf matters) and tokens [2, 17]."""
+    params = transformer.init_params(jax.random.PRNGKey(30), LOOPED)
+    leaves, tree = jax.tree_util.tree_flatten_with_path(params)
+    keys = jax.random.split(jax.random.PRNGKey(31), len(leaves))
+    moved = [p + 0.1 * jax.random.normal(k, p.shape)
+             if "ln" in jax.tree_util.keystr(path)
+             or "exit_gate" in jax.tree_util.keystr(path) else p
+             for (path, p), k in zip(leaves, keys)]
+    tokens = jax.random.randint(jax.random.PRNGKey(32), (2, 17), 0, 96)
+    return jax.tree.unflatten(tree, moved), tokens
+
+
+def test_default_fields_keep_todays_parameter_tree():
+    params = transformer.init_params(jax.random.PRNGKey(0), TINY)
+    assert sorted(params) == ["blocks", "embed", "lm_head", "ln_f"]
+    assert sorted(params["blocks"]) == ["attn", "ln1", "ln2", "mlp"]
+    axes = transformer.logical_axes(TINY)
+    assert jax.tree.structure(params) == jax.tree.structure(
+        axes, is_leaf=lambda a: isinstance(a, tuple))
+    # the looped configuration grows two norms a block and the gate
+    grown = transformer.init_params(jax.random.PRNGKey(0), LOOPED)
+    assert sorted(grown) == ["blocks", "embed", "exit_gate", "lm_head",
+                             "ln_f"]
+    assert sorted(grown["blocks"]) == ["attn", "ln1", "ln1_post", "ln2",
+                                       "ln2_post", "mlp"]
+    assert grown["exit_gate"]["w"].shape == (64,)
+    assert float(grown["exit_gate"]["b"]) == 0.0
+    assert jax.tree.structure(grown) == jax.tree.structure(
+        transformer.logical_axes(LOOPED),
+        is_leaf=lambda a: isinstance(a, tuple))
+    # the gate's key is beside the others: the weights both have are equal
+    plain = transformer.init_params(jax.random.PRNGKey(0), dataclasses.replace(
+        LOOPED, n_passes=1, post_norm=False, exit_beta=None))
+    np.testing.assert_array_equal(plain["lm_head"], grown["lm_head"])
+    np.testing.assert_array_equal(plain["blocks"]["mlp"]["wi"],
+                                  grown["blocks"]["mlp"]["wi"])
+
+
+def test_looped_every_exit_and_the_exit_distribution_match_the_reference(
+        looped):
+    from benchmark import looped_reference
+    params, tokens = looped
+    inputs = tokens[:, :-1]
+    states = transformer.pass_states(params, inputs, LOOPED)
+    assert states.shape == (3, 2, 16, 64)
+    want = looped_reference.every_exit_logits(params, inputs, LOOPED_DIMS)
+    for t in range(3):
+        got = transformer.head(params, states[t], LOOPED)
+        np.testing.assert_allclose(got, want[t], rtol=1e-4, atol=1e-4)
+    # served: the last pass's logits
+    np.testing.assert_allclose(transformer.apply(params, inputs, LOOPED),
+                               want[-1], rtol=1e-4, atol=1e-4)
+    np.testing.assert_allclose(
+        looped_reference.last_logits(params, inputs, LOOPED_DIMS),
+        want[-1][:, -1], rtol=1e-5, atol=1e-5)
+    p = jnp.exp(transformer.exit_log_probs(params, states, LOOPED))
+    np.testing.assert_allclose(p.sum(0), np.ones((2, 16)), atol=1e-6)
+    np.testing.assert_allclose(
+        p, looped_reference.exit_distribution(params, inputs, LOOPED_DIMS),
+        rtol=1e-4, atol=1e-6)
+    assert 0.0 < float(p.min()) and float(p.max()) < 1.0
+
+
+def test_looped_loss_metrics_and_every_gradient_leaf_match_the_reference(
+        looped):
+    from benchmark import looped_reference
+    params, tokens = looped
+    (loss, metrics), grads = jax.value_and_grad(
+        transformer.loss_and_metrics, has_aux=True)(params, tokens, LOOPED)
+    want, want_grads = looped_reference.loss_and_grads(params, tokens,
+                                                       LOOPED_DIMS)
+    assert float(loss) == pytest.approx(float(want), rel=1e-5)
+    assert float(transformer.loss_fn(params, tokens, LOOPED)) == float(loss)
+    _, exit_p, entropy = looped_reference.loss_and_exits(params, tokens,
+                                                         LOOPED_DIMS)
+    np.testing.assert_allclose(metrics["exit_p"], exit_p, rtol=1e-4)
+    assert float(metrics["exit_entropy"]) == pytest.approx(float(entropy),
+                                                           rel=1e-4)
+    assert float(jnp.sum(metrics["exit_p"])) == pytest.approx(1.0, abs=1e-6)
+    assert jax.tree.structure(grads) == jax.tree.structure(want_grads)
+    paths = jax.tree_util.tree_flatten_with_path(grads)[0]
+    for (path, got), ref in zip(paths, jax.tree.leaves(want_grads)):
+        scale = float(jnp.max(jnp.abs(ref)))
+        assert scale > 0, path          # the gate's leaves too
+        np.testing.assert_allclose(got, ref, rtol=2e-3, atol=2e-4 * scale,
+                                   err_msg=str(path))
+
+
+def test_shared_blocks_gradient_is_the_sum_over_unshared_copies(looped):
+    """Weight sharing tied to the mathematics: give every pass its own
+    copy of the stack, and the copies' gradients add up to the shared
+    stack's."""
+    params, tokens = looped
+    inputs, targets = tokens[:, :-1], tokens[:, 1:]
+
+    def unshared(copies):          # leaves [passes, layers, ...]
+        x = params["embed"][inputs]
+        states = []
+        for t in range(LOOPED.n_passes):
+            h = transformer.apply_layers(
+                jax.tree.map(lambda p: p[t], copies), x, LOOPED)
+            states.append(h)
+            x = transformer._rmsnorm(h, params["ln_f"], LOOPED.norm_eps)
+        return transformer.loss_from_states(params, jnp.stack(states),
+                                            targets, LOOPED)[0]
+
+    copies = jax.tree.map(lambda p: jnp.stack([p] * LOOPED.n_passes),
+                          params["blocks"])
+    each = jax.grad(unshared)(copies)
+    shared = jax.grad(transformer.loss_fn)(params, tokens, LOOPED)["blocks"]
+    for got, parts in zip(jax.tree.leaves(shared), jax.tree.leaves(each)):
+        assert float(jnp.max(jnp.abs(parts[0] - parts[1]))) > 0
+        np.testing.assert_allclose(got, parts.sum(0), rtol=1e-3,
+                                   atol=1e-5 * float(jnp.max(jnp.abs(got))))
+
+
+def test_one_pass_leaves_at_the_only_exit_and_is_the_plain_cross_entropy(
+        looped):
+    params, tokens = looped
+    once = dataclasses.replace(LOOPED, n_passes=1)
+    (loss, metrics), grads = jax.value_and_grad(
+        transformer.loss_and_metrics, has_aux=True)(params, tokens, once)
+    plain = dataclasses.replace(once, exit_beta=None)
+    assert float(loss) == pytest.approx(
+        float(transformer.loss_fn(params, tokens, plain)), rel=1e-6)
+    np.testing.assert_allclose(metrics["exit_p"], [1.0])
+    assert float(metrics["exit_entropy"]) == 0.0
+    assert float(jnp.max(jnp.abs(grads["exit_gate"]["w"]))) == 0.0
+    # without a gate there are no exit metrics, whatever the passes
+    assert transformer.loss_and_metrics(params, tokens, plain)[1] == {}
+    several = dataclasses.replace(LOOPED, exit_beta=None)
+    loss, metrics = transformer.loss_and_metrics(params, tokens, several)
+    assert metrics == {}
+    states = transformer.pass_states(params, tokens[:, :-1], several)
+    assert float(loss) == pytest.approx(float(jnp.mean(transformer.token_nll(
+        params, states[-1], tokens[:, 1:], several))), rel=1e-6)
+
+
+def _loss_of_states(params, states, targets, drop_exit=None):
+    """``loss_from_states`` rebuilt from its parts, one exit's cross
+    entropy left out of the sum where asked."""
+    logp = transformer.exit_log_probs(params, states, LOOPED)
+    nll = jnp.stack([transformer.token_nll(params, s, targets, LOOPED)
+                     for s in states])
+    if drop_exit is not None:
+        nll = nll.at[drop_exit].set(0.0)
+    p = jnp.exp(logp)
+    return jnp.mean(jnp.sum(p * nll, 0) + LOOPED.exit_beta
+                    * jnp.sum(p * logp, 0))
+
+
+def _control(name, params, tokens):
+    """A loss function that leaves out one part of the mathematics."""
+    inputs, targets = tokens[:, :-1], tokens[:, 1:]
+    if name == "sound":
+        return lambda p: transformer.loss_fn(p, tokens, LOOPED)
+    if name == "one_pass_fewer":
+        fewer = dataclasses.replace(LOOPED, n_passes=2)
+        return lambda p: transformer.loss_fn(p, tokens, fewer)
+    if name == "no_post_norms":
+        bare = dataclasses.replace(LOOPED, post_norm=False)
+        return lambda p: transformer.loss_fn(p, tokens, bare)
+    if name == "eight_bit_rounding":
+        def rounded(p):
+            p8 = jax.tree.map(lambda w: w.astype(jnp.float8_e4m3fn)
+                              .astype(jnp.float32), p)
+            return transformer.loss_fn(p8, tokens, LOOPED)
+        return rounded
+    if name == "exit_dropped":
+        return lambda p: _loss_of_states(
+            p, transformer.pass_states(p, inputs, LOOPED), targets,
+            drop_exit=1)
+    assert name == "no_inter_pass_norm"
+
+    def unnormed(p):
+        x, states = p["embed"][inputs], []
+        for _ in range(LOOPED.n_passes):
+            x = transformer.apply_layers(p["blocks"], x, LOOPED)
+            states.append(x)
+        return _loss_of_states(p, jnp.stack(states), targets)
+    return unnormed
+
+
+@pytest.mark.parametrize("name", [
+    "sound", "one_pass_fewer", "exit_dropped", "no_inter_pass_norm",
+    "no_post_norms", "eight_bit_rounding"])
+def test_a_part_left_out_fails_by_the_looped_adapters_own_tolerances(
+        looped, name):
+    import optax
+    from benchmark import looped_reference
+    from benchmark.adapters import looped_decoder
+    params, tokens = looped
+    value, grads = jax.value_and_grad(_control(name, params, tokens))(params)
+    want, want_norm = looped_reference.loss_and_grad_norm(params, tokens,
+                                                          LOOPED_DIMS)
+    tol = looped_decoder.TOLERANCES
+    inside = (abs(float(value) - float(want))
+              <= tol["loss_rtol"] * abs(float(want))
+              and abs(float(optax.global_norm(grads)) - float(want_norm))
+              <= tol["grad_norm_rtol"] * float(want_norm))
+    assert inside == (name == "sound")

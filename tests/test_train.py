@@ -196,3 +196,63 @@ def test_trainer_dataset_shards(ray_start_regular):
     assert sum(rows) == 40          # full partition, no overlap/loss
     assert abs(max(rows) - min(rows)) <= 1
     assert sum(totals) == float(sum(range(40)))
+
+
+# -- make_lm_train_step: the looped decoder and the pipeline's shared layers --
+
+def _lm_cfg(**kw):
+    from ray_tpu.models.transformer import TransformerConfig
+    return TransformerConfig(**{**dict(
+        vocab_size=96, d_model=32, n_layers=2, n_heads=4, d_ff=64,
+        max_seq_len=32, dtype=jnp.float32, use_flash=False), **kw})
+
+
+def _one_step(cfg, mesh, tokens, **kw):
+    from ray_tpu.train import make_lm_train_step
+    init_fn, step_fn, shard_batch = make_lm_train_step(cfg, mesh, **kw)
+    _, metrics = step_fn(init_fn(jax.random.PRNGKey(0)), shard_batch(tokens))
+    return jax.device_get(metrics)
+
+
+def test_the_steps_metrics_carry_the_exit_distribution_only_when_looped(
+        eight_device_mesh):
+    from ray_tpu.parallel import MeshConfig, build_mesh
+    mesh = build_mesh(MeshConfig(data=2), eight_device_mesh[:2])
+    tokens = np.random.default_rng(0).integers(0, 96, (4, 17), dtype=np.int32)
+    plain = _one_step(_lm_cfg(), mesh, tokens)
+    assert sorted(plain) == ["grad_norm", "loss"]
+    looped = _one_step(_lm_cfg(n_passes=3, post_norm=True, exit_beta=0.05),
+                       mesh, tokens)
+    assert sorted(looped) == ["exit_entropy", "exit_p", "grad_norm", "loss"]
+    assert looped["exit_p"].shape == (3,)
+    assert float(looped["exit_p"].sum()) == pytest.approx(1.0, abs=1e-5)
+    assert 0.0 < float(looped["exit_entropy"]) <= np.log(3) + 1e-6
+    assert np.isfinite(looped["loss"]) and looped["grad_norm"] > 0
+
+
+def test_pipeline_with_several_passes_is_refused_by_name(eight_device_mesh):
+    from ray_tpu.parallel import MeshConfig, build_mesh
+    from ray_tpu.train import make_lm_train_step
+    mesh = build_mesh(MeshConfig(pipe=2, data=1), eight_device_mesh[:2])
+    with pytest.raises(ValueError, match=r"pipe=2 with n_passes=3"):
+        make_lm_train_step(_lm_cfg(n_passes=3), mesh)
+    make_lm_train_step(_lm_cfg(n_passes=1), mesh)      # one pass is fine
+
+
+@pytest.mark.parametrize("remat", [True, False])
+def test_the_pipelines_stages_run_the_models_own_layers(eight_device_mesh,
+                                                        remat):
+    """pipe=2 through ``transformer.apply_layers`` gives the scan path's
+    loss and gradient norm."""
+    from ray_tpu.parallel import MeshConfig, build_mesh
+    tokens = np.random.default_rng(1).integers(0, 96, (4, 17), dtype=np.int32)
+    cfg = _lm_cfg(n_layers=4, remat=remat)
+    flat = _one_step(cfg, build_mesh(MeshConfig(data=1),
+                                     eight_device_mesh[:1]), tokens)
+    piped = _one_step(cfg, build_mesh(MeshConfig(pipe=2, data=1),
+                                      eight_device_mesh[:2]), tokens,
+                      num_microbatches=2)
+    assert float(piped["loss"]) == pytest.approx(float(flat["loss"]),
+                                                 rel=1e-5)
+    assert float(piped["grad_norm"]) == pytest.approx(
+        float(flat["grad_norm"]), rel=1e-4)
