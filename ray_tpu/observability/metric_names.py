@@ -87,8 +87,13 @@ SPANS = frozenset({
     "serve.await_replica",  # handle.remote(...).result() after the submit
     "serve.reply",          # the reply encoded and written
     # serve: the replica batcher's flusher thread
-    "serve.batch.linger",   # wake-up with a non-empty queue -> batch cut
-    "serve.batch.execute",  # _run_batch: pad, call, deliver
+    # wake-up with a non-empty queue -> batch cut; depth, cap,
+    # oldest_wait_us and left (queued at the cut and not taken by it)
+    "serve.batch.linger",
+    # _run_batch: pad, call, deliver; n, padded_n (rows after
+    # pad_batch_to), size_sum and size_max (the members' observed sizes):
+    # the padded rectangle's fill is size_sum / (padded_n x size_max)
+    "serve.batch.execute",
     "serve.batch.call",     # the user's callable alone
     # runtime
     "task.execute",         # one task on a worker thread
@@ -106,6 +111,12 @@ SPANS = frozenset({
 
 # The gauge a replica sets once, when its constructor returns.
 REPLICA_INIT_GAUGE = "serve_replica_init_seconds"
+
+# ``Replica.get_metrics()``: the same two numbers summed over every batch a
+# replica has run (real sizes; rows x largest size), so that the fill is
+# there without a trace.
+REPLICA_BATCH_SIZE_SUM = "batch_size_sum"
+REPLICA_BATCH_PADDED_SUM = "batch_padded_sum"
 
 # Comms-plane sample families.  Not literal-checked by a lint rule the
 # way perf.observe names are — they are declared here so the exporters

@@ -22,9 +22,16 @@ batch size — and invokes the user callable once per batch with a LIST of
 requests.  Batch size adapts to observed queue depth, capped so the
 EWMA-predicted batch time stays inside the replica's latency budget
 (``target_latency_ms`` falling back to the ``serve_target_latency_ms``
-knob).  Requests that age past ``serve_queue_deadline_ms`` in the queue
-are shed with :class:`ServeOverloadedError` instead of executing — the
-proxy maps that to 503 + Retry-After.  A failed batch isolates per item:
+knob).  Which of the queued requests share a call is cut by what the call
+will be padded to (``batching.cut_by_size``): the oldest request and the
+queued requests of like size (``len()`` of a sequence, observed), so a
+short prompt neither pays for nor waits out a long neighbour's rows; with
+equal sizes that is arrival order.  A request a cut passed over has had
+its linger and is cut next without a second one.  Requests that age
+past ``serve_queue_deadline_ms`` in the queue — the wait for the calls cut
+before theirs included — are shed with :class:`ServeOverloadedError`
+instead of executing; the proxy maps that to 503 + Retry-After.  A failed
+batch isolates per item:
 singleton batches get their own error raw; larger batches re-run members
 alone once (``serve_batch_retry_singletons``) or receive a batch-level
 :class:`BatchExecutionError` naming the batch size and request ids.
@@ -43,14 +50,17 @@ import asyncio
 import inspect
 import threading
 import time
-from typing import Any, List, Optional
+from typing import Any, List, Optional, Tuple
 
 from ray_tpu import chaos, observability
 from ray_tpu._private.config import _config
 from ray_tpu.exceptions import BatchExecutionError, ServeOverloadedError
 from ray_tpu.observability import perf
-from ray_tpu.observability.metric_names import REPLICA_INIT_GAUGE
-from ray_tpu.serve.batching import next_bucket, next_request_id, pad_items
+from ray_tpu.observability.metric_names import (REPLICA_BATCH_PADDED_SUM,
+                                                 REPLICA_BATCH_SIZE_SUM,
+                                                 REPLICA_INIT_GAUGE)
+from ray_tpu.serve.batching import (cut_by_size, item_size, next_bucket,
+                                    next_request_id, pad_items)
 
 # EWMA weight for the per-item execution-time estimate that sizes batches
 # and the queue_est_ms backpressure signal (local smoothing; the
@@ -88,11 +98,15 @@ def _resolve_arg_refs(args):
 class _BatchSlot:
     """One queued request parked in the replica batcher."""
 
-    __slots__ = ("item", "event", "value", "error", "request_id",
-                 "t_enqueue", "trace")
+    __slots__ = ("item", "size", "passed", "event", "value", "error",
+                 "request_id", "t_enqueue", "trace")
 
     def __init__(self, item):
         self.item = item
+        self.size = item_size(item)
+        # a cut took others and left this one queued: only the flusher
+        # thread writes and reads it
+        self.passed = False
         self.event = threading.Event()
         self.value = None
         self.error: Optional[BaseException] = None
@@ -106,8 +120,8 @@ class _BatchSlot:
 
 class _ReplicaBatcher:
     """Adaptive micro-batcher owned by one replica (see module docstring
-    for the state machine: admit → linger → shed-expired → pad-to-bucket
-    execute → per-item deliver)."""
+    for the state machine: admit → linger → shed-expired → cut by size →
+    pad-to-bucket execute → per-item deliver)."""
 
     def __init__(self, replica: "Replica", cfg: dict):
         self._replica = replica
@@ -126,10 +140,18 @@ class _ReplicaBatcher:
         self._thread: Optional[threading.Thread] = None
         self._stop = False
         self._batches = 0  # batches run; only the flusher thread counts
+        # real sizes, and the padded rectangles they were run in, summed
+        # over every batch: their quotient is the fill
+        self._size_sum = 0  # raylint: guarded-by(self._lock)
+        self._padded_sum = 0  # raylint: guarded-by(self._lock)
 
     def depth(self) -> int:
         with self._lock:
             return len(self._queue)
+
+    def fill_sums(self) -> Tuple[int, int]:
+        with self._lock:
+            return self._size_sum, self._padded_sum
 
     def retune(self, cfg: dict) -> None:
         """Live-update the batch shape (autopilot serve policy): the
@@ -211,15 +233,16 @@ class _ReplicaBatcher:
         the queue, so it is not empty here."""
         cap = self._effective_max()
         # Linger window anchored on the OLDEST queued request: fire
-        # when the batch fills (to the adaptive cap) or the oldest
-        # request has waited batch_wait_timeout_s.
+        # when the batch fills (to the adaptive cap), the oldest
+        # request has waited batch_wait_timeout_s, or an earlier cut
+        # passed it over (it has had its linger; the device is idle).
         while True:
             with self._lock:
                 depth = len(self._queue)
-                oldest = self._queue[0].t_enqueue
+                oldest = self._queue[0]
                 wait_s = self._wait_s
-            waited = time.monotonic() - oldest
-            if depth >= cap or waited >= wait_s:
+            waited = time.monotonic() - oldest.t_enqueue
+            if depth >= cap or waited >= wait_s or oldest.passed:
                 break
             time.sleep(min(0.0005, max(wait_s / 10.0, 1e-4)))
         if linger.live:
@@ -237,10 +260,18 @@ class _ReplicaBatcher:
                     else:
                         live.append(s)
                 self._queue = live
-            batch = self._queue[:cap]
-            self._queue = self._queue[cap:]
-            if not self._queue:
+            taken = cut_by_size([s.size for s in self._queue], cap,
+                                self._buckets) if self._queue else []
+            batch = [self._queue[i] for i in taken]
+            for i in reversed(taken):
+                del self._queue[i]
+            for s in self._queue:
+                s.passed = True
+            left = len(self._queue)
+            if not left:
                 self._wakeup.clear()
+        if linger.live:
+            linger.set(left=left)
         return batch, expired, deadline_ms
 
     def _call(self, items: List[Any]) -> List[Any]:
@@ -257,16 +288,20 @@ class _ReplicaBatcher:
 
     def _run_batch(self, batch: List[_BatchSlot]) -> None:
         self._batches += 1
+        size_sum = sum(s.size for s in batch)
+        size_max = max(s.size for s in batch)
+        with self._lock:
+            padded_n = next_bucket(len(batch), self._buckets)
+            self._size_sum += size_sum
+            self._padded_sum += padded_n * size_max
         # Pad, call, read back and deliver: device idle under this span
         # and outside serve.batch.call's device work is the batcher's own
         # host time.
         with observability.span("serve.batch.execute", cat="serve",
                                 parent=batch[0].trace) as execute:
             if execute.live:
-                with self._lock:
-                    buckets = self._buckets
-                execute.set(n=len(batch),
-                            padded_n=next_bucket(len(batch), buckets),
+                execute.set(n=len(batch), padded_n=padded_n,
+                            size_sum=size_sum, size_max=size_max,
                             batch=self._batches)
             self._execute(batch)
 
@@ -483,6 +518,8 @@ class Replica:
         ex_counts, ex_sum = self._hist_execute.merged()
         batcher = self._batcher
         depth = batcher.depth() if batcher is not None else 0
+        size_sum, padded_sum = (batcher.fill_sums() if batcher is not None
+                                else (0, 0))
         with self._lock:
             ongoing = self._ongoing
             total = self._total
@@ -497,6 +534,10 @@ class Replica:
                 "queue_depth": depth,
                 "queue_est_ms": pending * ewma,
                 "ewma_item_ms": ewma,
+                # real request sizes over the padded rectangles they ran
+                # in: the batches' fill, readable without a trace
+                REPLICA_BATCH_SIZE_SUM: size_sum,
+                REPLICA_BATCH_PADDED_SUM: padded_sum,
                 "perf": {
                     "bounds": list(perf.bucket_bounds()),
                     "queue_wait": {"counts": qw_counts, "sum_ms": qw_sum},

@@ -11,6 +11,13 @@ When set, the invoked batch list is padded (by repeating the last element)
 up to the next bucket so the wrapped ``jax.jit`` function sees only a few
 static batch shapes and never recompiles per batch size; padded outputs
 are dropped before delivery.
+
+The rules of the padded batch live here, as pure functions, for both
+batchers: ``next_bucket`` / ``pad_items`` (how many rows a batch is padded
+to) and ``item_size`` / ``cut_by_size`` (which queued requests share a
+batch, by the rectangle of rows x largest size they would be padded to).
+The replica-side micro-batcher cuts by size; ``_BatchQueue`` below still
+cuts in arrival order.
 """
 
 from __future__ import annotations
@@ -18,6 +25,7 @@ from __future__ import annotations
 import functools
 import itertools
 import threading
+from collections.abc import Mapping
 from typing import Any, Callable, List, Optional, Sequence, Tuple
 
 from ray_tpu.exceptions import BatchExecutionError
@@ -50,6 +58,50 @@ def pad_items(items: List[Any], buckets: Optional[Tuple[int, ...]]
     if target > len(items):
         return items + [items[-1]] * (target - len(items))
     return items
+
+
+def item_size(item: Any) -> int:
+    """What a request weighs in a padded batch, observed and not
+    configured: ``len(item)`` of a sized sequence (a list or tuple of token
+    ids, a string, bytes, an array); 1 for a mapping, a scalar or anything
+    else, so that a deployment of such requests sees every size equal."""
+    if isinstance(item, Mapping):
+        return 1
+    try:
+        return max(1, len(item))
+    except TypeError:
+        return 1
+
+
+def cut_by_size(sizes: Sequence[int], cap: int,
+                buckets: Optional[Tuple[int, ...]]) -> List[int]:
+    """Which queued requests share the next call: their places in the
+    queue (``sizes`` is in arrival order), ascending, at most ``cap``.
+
+    A batch is padded to a rectangle of ``next_bucket(n, buckets)`` rows by
+    its largest member, so requests go together by the rectangle they
+    make.  Sizes are read to the power of two (the batcher does not know
+    the deployment's own length buckets, and 100 and 120 tokens are alike
+    to any).  The candidates are the runs of neighbouring size classes
+    round the oldest request's class, each with every queued member of its
+    classes (the oldest ``cap`` of them); the one whose real sizes fill
+    most of their rectangle wins, ties to the larger batch.  The oldest is
+    in every candidate, so each cut retires the head of the queue and no
+    request starves; sizes of one class (a deployment of scalars, of
+    dicts, of equal prompts) give ``queue[:cap]`` to the letter."""
+    classes = [(size - 1).bit_length() for size in sizes]
+    present = sorted(set(classes))
+    home = present.index(classes[0])
+    best, best_key = [0], (0.0, 0)
+    for lo in present[:home + 1]:
+        for hi in present[home:]:
+            take = [i for i, c in enumerate(classes) if lo <= c <= hi][:cap]
+            rows = max(len(take), next_bucket(len(take), buckets))
+            filled = sum(sizes[i] for i in take) / (
+                rows * max(sizes[i] for i in take))
+            if (filled, len(take)) > best_key:
+                best, best_key = take, (filled, len(take))
+    return best
 
 
 class _Slot:

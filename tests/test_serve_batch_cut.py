@@ -1,0 +1,213 @@
+"""The replica batcher's cut: which queued requests share a call.
+
+The rule is a pure function (``serve/batching.py`` ``cut_by_size``) and is
+tested as one, with no clock; then through a real ``Replica`` whose first
+call is held open while the queue is filled in a known order, so that what
+each later cut takes, leaves and reports is counted and not timed.
+"""
+
+import threading
+
+import pytest
+
+from ray_tpu import observability
+from ray_tpu._private.config import _config
+from ray_tpu._private.profiling import get_profiler
+from ray_tpu.observability import metric_names
+from ray_tpu.serve._private.replica import Replica
+from ray_tpu.serve.batching import cut_by_size, item_size
+
+B248 = (2, 4, 8)
+MIXED = [100, 2000, 120, 1900, 90, 2040, 300, 310]
+
+
+def drain(sizes, cap, buckets):
+    """Cut after cut until the queue is empty: the batches, as sizes."""
+    queue, batches = list(sizes), []
+    while queue:
+        taken = cut_by_size(queue, cap, buckets)
+        batches.append([queue[i] for i in taken])
+        queue = [s for i, s in enumerate(queue) if i not in set(taken)]
+    return batches
+
+
+def sizes_of(items):
+    return [item_size(x) for x in items]
+
+
+# (what is queued, in arrival order; cap; count buckets; the batches that
+# cutting until the queue is empty gives)
+CASES = {
+    "equal sizes under the cap are arrival order":
+        ([7, 7, 7], 8, None, [[7, 7, 7]]),
+    "equal sizes over the cap are queue[:cap], then the rest":
+        ([5] * 11, 8, None, [[5] * 8, [5] * 3]),
+    "equal sizes are not trimmed to a count bucket":
+        ([64] * 5, 8, B248, [[64] * 5]),
+    "scalars have no size and go in arrival order":
+        (sizes_of([3, 1.5, None, True, object()]), 4, B248,
+         [[1] * 4, [1]]),
+    "mappings count as size 1 whatever they hold":
+        (sizes_of([{"a": 1}, {"a": 1, "b": [0] * 900}, {}]), 8, B248,
+         [[1, 1, 1]]),
+    "sequences are sized by len: lists, tuples, strings, bytes":
+        (sizes_of([[0] * 100, (0,) * 120, "x" * 90, b"y" * 2000]), 8, B248,
+         [[100, 120, 90], [2000]]),
+    "sizes of one power-of-two class are alike":
+        ([65, 128, 100, 97], 8, B248, [[65, 128, 100, 97]]),
+    "a mixed queue is cut into its length classes":
+        (MIXED, 8, B248, [[100, 120, 90], [2000, 1900, 2040], [300, 310]]),
+    "the same with no count buckets":
+        (MIXED, 8, None, [[100, 120, 90], [2000, 1900, 2040], [300, 310]]),
+    "a 100 goes with the nearer neighbour, not with the 2,000":
+        ([100, 2000, 300], 8, B248, [[100, 300], [2000]]),
+    "40 and 200 share a call":
+        ([40, 200], 8, B248, [[40, 200]]),
+    "a lone long request takes a short one in its padded row":
+        ([2000, 100], 8, B248, [[2000, 100]]),
+    "with no padded row to fill the long one goes alone":
+        ([2000, 100], 8, None, [[2000], [100]]),
+    "the oldest is in the cut though the others outnumber it":
+        ([2000] + [50] * 7, 8, B248, [[2000], [50] * 7]),
+    "short requests hold a long one for one cut at most":
+        ([50, 2000] + [50] * 9, 8, B248,
+         [[50] * 8, [2000], [50, 50]]),
+    "the cap holds inside a class":
+        ([100, 2000, 110, 120, 125], 2, B248,
+         [[100, 110], [2000, 120], [125]]),
+    "a class over the cap gives its oldest":
+        ([300, 310, 2000, 320, 330, 340], 4, (2, 4),
+         [[300, 310, 320, 330], [2000, 340]]),
+}
+
+
+@pytest.mark.parametrize("case", CASES)
+def test_cut_by_size(case):
+    sizes, cap, buckets, batches = CASES[case]
+    taken = cut_by_size(sizes, cap, buckets)
+    assert taken[0] == 0, "the oldest request is in every cut"
+    assert taken == sorted(set(taken)), "arrival order inside the batch"
+    assert 1 <= len(taken) <= cap
+    assert drain(sizes, cap, buckets) == batches
+    if len(set(sizes)) == 1:
+        assert taken == list(range(min(cap, len(sizes))))
+
+
+# -- through a real replica --------------------------------------------------
+
+WAIT_S = 10.0       # a join or a poll that takes this long has failed
+
+
+def held_deployment():
+    """A batched function deployment whose first call stays open until
+    released: every reply is ``(len(item), len(items))``.  A function, so
+    that the replica sets no init gauge for other files' tests to find."""
+    calls, started, release = [], threading.Event(), threading.Event()
+
+    def held(items):
+        calls.append([item_size(x) for x in items])
+        if len(calls) == 1:
+            started.set()
+            assert release.wait(WAIT_S)
+        return [(item_size(x), len(items)) for x in items]
+
+    held.calls, held.started, held.release = calls, started, release
+    return held
+
+
+def _spans(name):
+    return [e["args"] for e in get_profiler().chrome_trace()
+            if e["name"] == name]
+
+
+def run_held(requests, cap, buckets):
+    """Fill the queue with ``requests`` in order behind a held first call,
+    with a linger no request could sit out twice, then let the flusher cut:
+    the replies in the order of ``requests``, the deployment, the replica's
+    metrics and the batcher's spans (the ring is on)."""
+    profiling = _config.get("profiling_enabled")
+    _config.set("profiling_enabled", True)
+    get_profiler().clear()
+    observability.enable()
+    held = held_deployment()
+    replica = Replica("held", "held#1", held, (), {}, batch_config={
+        "max_batch_size": cap, "batch_wait_timeout_s": 0.0,
+        "pad_batch_to": buckets, "target_latency_ms": 1e9})
+    replies = {}
+
+    def call(key, item):
+        replies[key] = replica.handle_request("__call__", (item,), {})
+
+    threads = [threading.Thread(target=call, args=("held", "g"))]
+    try:
+        threads[0].start()
+        assert held.started.wait(WAIT_S)
+        # from here on a fresh request would linger for minutes
+        replica.set_batch_config({"batch_wait_timeout_s": 600.0})
+        for k, item in enumerate(requests):
+            threads.append(threading.Thread(target=call, args=(k, item)))
+            threads[-1].start()
+            poll = threading.Event()
+            for _ in range(int(WAIT_S / 0.002)):
+                if replica._batcher.depth() == k + 1:
+                    break
+                poll.wait(0.002)
+            assert replica._batcher.depth() == k + 1
+        held.release.set()
+        for t in threads:
+            t.join(WAIT_S)
+        assert not any(t.is_alive() for t in threads)
+        metrics = replica.get_metrics()
+        spans = {"linger": _spans("serve.batch.linger"),
+                 "execute": _spans("serve.batch.execute")}
+    finally:
+        held.release.set()
+        replica.prepare_for_shutdown(timeout_s=WAIT_S)
+        observability.disable()
+        _config.set("profiling_enabled", profiling)
+        get_profiler().clear()
+    return {"replies": [replies[k] for k in range(len(requests))],
+            "calls": held.calls, "metrics": metrics, **spans}
+
+
+@pytest.fixture(scope="module")
+def reordered():
+    """100, 2,000, 120 and 1,900 tokens queued in that order, cap 4."""
+    return run_held([[0] * 100, [0] * 2000, [0] * 120, [0] * 1900],
+                    cap=4, buckets=(2, 4))
+
+
+def test_each_caller_gets_its_own_result_when_the_cut_reorders(reordered):
+    assert reordered["calls"] == [[1, 1], [100, 120], [2000, 1900]]
+    assert reordered["replies"] == [(100, 2), (2000, 2), (120, 2), (1900, 2)]
+
+
+def test_a_passed_over_request_is_cut_without_a_second_linger(reordered):
+    # the queue was full (4 = cap) at the first cut, which left two; the
+    # next cut took them at depth 2 < cap with 600 s of linger ahead: three
+    # calls in all, and the test is here
+    assert len(reordered["calls"]) == 3
+    assert [s["left"] for s in reordered["linger"]] == [0, 2, 0]
+    assert [s["depth"] for s in reordered["linger"]] == [1, 4, 2]
+
+
+def test_the_fill_is_on_the_spans_and_in_get_metrics(reordered):
+    execute = reordered["execute"]
+    assert [(s["n"], s["padded_n"], s["size_sum"], s["size_max"])
+            for s in execute] == [(1, 2, 1, 1), (2, 2, 220, 120),
+                                  (2, 2, 3900, 2000)]
+    assert all(s["size_sum"] <= s["padded_n"] * s["size_max"]
+               for s in execute)
+    metrics = reordered["metrics"]
+    assert metrics[metric_names.REPLICA_BATCH_SIZE_SUM] == sum(
+        s["size_sum"] for s in execute) == 4121
+    assert metrics[metric_names.REPLICA_BATCH_PADDED_SUM] == sum(
+        s["padded_n"] * s["size_max"] for s in execute) == 4242
+
+
+def test_scalars_and_dicts_are_cut_in_arrival_order():
+    got = run_held([1, {"x": 2}, 3, {"y": [0] * 500}, 5, 6], cap=4,
+                   buckets=(2, 4))
+    assert got["calls"] == [[1, 1], [1, 1, 1, 1], [1, 1]]
+    assert got["replies"] == [(1, 4)] * 4 + [(1, 2)] * 2
+    assert [s["left"] for s in got["linger"]] == [0, 2, 0]

@@ -402,6 +402,10 @@ def one_request(tmp_path_factory):
         assert post(1) == 2            # the router and the flusher exist
         with Session(tmp_path_factory.mktemp("session")) as trace:
             assert post(21) == 42
+            # the reply is written inside serve.reply inside serve.request:
+            # the handler's thread closes them after the client has its
+            # answer, and a span still open when the session stops is lost
+            time.sleep(0.2)
         serve.shutdown()
         gauges = [s for f in metrics.snapshot()
                   if f["name"] == metric_names.REPLICA_INIT_GAUGE
