@@ -332,13 +332,93 @@ def apply(params: Dict[str, Any], tokens: jax.Array,
     return head(params, x, cfg)
 
 
+def _exit_nll(x, w_head, targets):
+    """One exit's float32 logits [B, L, V] straight from the MXU's
+    accumulator, each position's cross entropy [B, L], and what the
+    gradient needs of both: the log-sum-exp and where the target sits. No
+    ``log_softmax`` array, and a comparison in place of a gather."""
+    logits = jnp.einsum("bld,dv->blv", x, w_head,
+                        preferred_element_type=jnp.float32)
+    lse = jax.nn.logsumexp(logits, axis=-1)
+    hit = jax.lax.broadcasted_iota(jnp.int32, logits.shape, 2) \
+        == targets[..., None]
+    nll = lse - jnp.sum(jnp.where(hit, logits, 0.0), axis=-1)
+    return logits, lse, hit, nll
+
+
+def _scan_exits(fn, carry, xs):
+    """``lax.scan`` of ``fn`` over the exits (the leading axis of ``xs``'s
+    leaves). A single exit builds no loop, as ``pass_states`` builds none
+    for a single pass: the plain decoder's program."""
+    if jax.tree.leaves(xs)[0].shape[0] > 1:
+        return jax.lax.scan(fn, carry, xs)
+    carry, out = fn(carry, jax.tree.map(lambda a: a[0], xs))
+    return carry, jax.tree.map(lambda a: a[None], out)
+
+
+@jax.custom_vjp
+def weighted_nll(x: jax.Array, w_head: jax.Array, targets: jax.Array,
+                 weights: jax.Array) -> jax.Array:
+    """``sum(weights * nll)``, float32: the lm head and the next-token cross
+    entropy of every exit, from the exits' *normed* states ``x`` [T, B, L,
+    d], the head's weight ``w_head`` [d, V] in ``x``'s dtype, ``targets``
+    [B, L] and float32 ``weights`` [T, B, L].
+
+    Differentiated, it takes its own gradient on the way forward
+    (``_weighted_nll_fwd``): an exit's logits are made once, used for the
+    loss and for ``softmax - onehot``, and dropped. Three vocabulary-sized
+    matmuls an exit (logits, the gradient to the states, the gradient to
+    the head) and nothing vocabulary-sized kept for the backward pass but
+    the head's gradient itself."""
+    _, nll = _scan_exits(
+        lambda _, x_t: (None, _exit_nll(x_t, w_head, targets)[3]), None, x)
+    return jnp.sum(weights * nll)
+
+
+def _weighted_nll_fwd(x, w_head, targets, weights):
+    def one_exit(d_head, exit_t):
+        x_t, weight_t = exit_t
+        logits, lse, hit, nll = _exit_nll(x_t, w_head, targets)
+        # d(sum weights * nll) / d logits, rounded once to the operands'
+        # dtype: where autodiff's cotangent met the head's cast
+        d_logits = ((jnp.exp(logits - lse[..., None]) - hit)
+                    * weight_t[..., None]).astype(x.dtype)
+        d_x = jnp.einsum("blv,dv->bld", d_logits, w_head)
+        d_head = d_head + jnp.einsum("bld,blv->dv", x_t, d_logits,
+                                     preferred_element_type=d_head.dtype)
+        return d_head, (nll, d_x)
+
+    # several exits sum the head's gradient in float32 and round it once at
+    # the end; a single exit has nothing to sum, and its gradient leaves the
+    # MXU in the head's dtype as autodiff's did
+    sum_dtype = jnp.float32 if x.shape[0] > 1 else w_head.dtype
+    d_head, (nll, d_x) = _scan_exits(
+        one_exit, jnp.zeros(w_head.shape, sum_dtype), (x, weights))
+    # finished here, while the logits are: left to itself XLA fuses this
+    # matmul into the head's optimizer update at the far end of the step and
+    # keeps an exit's float32 logits alive until then
+    d_head = jax.lax.optimization_barrier(d_head.astype(w_head.dtype))
+    return jnp.sum(weights * nll), (d_x, d_head, nll)
+
+
+def _weighted_nll_bwd(residuals, c):
+    d_x, d_head, nll = residuals
+    # linear in the weights, so nll is their exact gradient (it reaches the
+    # exit gate through p_t); targets are integers
+    return ((c * d_x).astype(d_x.dtype), (c * d_head).astype(d_head.dtype),
+            None, c * nll)
+
+
+weighted_nll.defvjp(_weighted_nll_fwd, _weighted_nll_bwd)
+
+
 def token_nll(params, x: jax.Array, targets: jax.Array,
               cfg: TransformerConfig) -> jax.Array:
     """Final norm + lm head + each position's next-token cross entropy,
-    [B, L] float32."""
-    logits = head(params, x, cfg)
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    return -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
+    [B, L] float32 (evaluation and the tests' oracles; training goes
+    through ``weighted_nll``)."""
+    x = _rmsnorm(x, params["ln_f"], cfg.norm_eps)
+    return _exit_nll(x, params["lm_head"].astype(cfg.dtype), targets)[3]
 
 
 def exit_log_probs(params, states: jax.Array,
@@ -369,17 +449,22 @@ def loss_from_states(params, states: jax.Array, targets: jax.Array,
     Without an exit gate: the last pass's mean next-token cross entropy, no
     metrics. With one: the mean over positions of ``sum_t p_t * CE_t -
     exit_beta * H(p)``, with ``exit_p`` [n_passes] (mean p_t) and
-    ``exit_entropy`` (mean H(p)). Each exit's head runs under
-    ``jax.checkpoint`` inside a ``lax.map``, so one exit's float32 logits
-    [B, L, vocab] are alive at a time, forward and backward."""
+    ``exit_entropy`` (mean H(p)). Either way the heads are one call of
+    ``weighted_nll``, with each position's share of the mean as its
+    weight: one exit's float32 logits [B, L, vocab] are alive at a time,
+    and only while the forward pass is there."""
+    def heads(states, weights):
+        return weighted_nll(_rmsnorm(states, params["ln_f"], cfg.norm_eps),
+                            params["lm_head"].astype(cfg.dtype), targets,
+                            weights / targets.size)
+
     if cfg.exit_beta is None:
-        return jnp.mean(token_nll(params, states[-1], targets, cfg)), {}
-    nll = jax.lax.map(
-        jax.checkpoint(lambda x: token_nll(params, x, targets, cfg)), states)
+        last = states[-1:]
+        return heads(last, jnp.ones(last.shape[:3], jnp.float32)), {}
     logp = exit_log_probs(params, states, cfg)
     p = jnp.exp(logp)
     entropy = -jnp.sum(p * logp, axis=0)
-    loss = jnp.mean(jnp.sum(p * nll, axis=0) - cfg.exit_beta * entropy)
+    loss = heads(states, p) - cfg.exit_beta * jnp.mean(entropy)
     return loss, {"exit_p": jnp.mean(p, axis=(1, 2)),
                   "exit_entropy": jnp.mean(entropy)}
 

@@ -116,13 +116,14 @@ def test_flash_kernel_fwd_bwd_compiles(topo, mosaic, case):
     assert text.count(KERNEL) >= (3 if backward else 1)
 
 
-def _lower_train_step(mesh: Mesh, cfg: TransformerConfig = CFG):
+def _lower_train_step(mesh: Mesh, cfg: TransformerConfig = CFG,
+                      batch: int = BATCH, seq: int = SEQ):
     """A described device cannot hold an array: lower ``step_fn`` on the
     shapes and shardings ``init_fn`` would have produced."""
     rules = ShardingRules()
     init_fn, step_fn, _ = make_lm_train_step(cfg, mesh, rules)
     state = init_fn.eval_shape(jax.ShapeDtypeStruct((2,), jnp.uint32))
-    tokens = jax.ShapeDtypeStruct((BATCH, SEQ + 1), jnp.int32,
+    tokens = jax.ShapeDtypeStruct((batch, seq + 1), jnp.int32,
                                   sharding=batch_sharding(mesh, rules, 2))
     # __wrapped__: the jitted step under goodput.instrument_jit
     return step_fn.__wrapped__.lower(state, tokens)
@@ -140,7 +141,7 @@ def test_train_step_compiles_on_one_chip(topo, mosaic):
 def test_looped_train_step_compiles_on_one_chip(topo, mosaic):
     """The looped decoder (the stack applied four times, sandwich norms,
     the exit gate and its loss): the kernel is inside two nested scans and
-    the per-exit heads inside a checkpointed map."""
+    the per-exit heads inside ``weighted_nll``'s scan."""
     looped = dataclasses.replace(CFG, n_layers=2, n_passes=4, post_norm=True,
                                  exit_beta=0.05)
     compiled = _lower_train_step(_mesh(topo.devices[:1], data=1),
@@ -152,6 +153,32 @@ def test_looped_train_step_compiles_on_one_chip(topo, mosaic):
     mem = compiled.memory_analysis()
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
             < 8 * 2 ** 30)
+
+
+# A one-chip training cell of the benchmark and the most its step may take
+# by ``memory_analysis()`` (arguments + temporaries): what the step took
+# before the loss head computed its own gradient (PERF.md section 4: 13.51 GB
+# at 2 layers, 14.80 GB at 7 layers x 4 passes). The head may not cost memory.
+CELL_STEP_BYTES = {"mistral7b-train-4k": 13.51e9, "ouro2.6b-train-4k": 14.80e9}
+
+
+@pytest.mark.parametrize("cell_name", list(CELL_STEP_BYTES))
+def test_benchmark_cells_train_step_fits_what_it_took(topo, mosaic,
+                                                      cell_name):
+    from benchmark import manifest
+    cell = manifest.Manifest().cell(cell_name)
+    adapter = manifest.adapter(cell.config)
+    seq_len = int(cell.traffic["seq_len"])
+    cfg = adapter.program_config(
+        adapter.dims(cell.config, cell.job, cell.chips), seq_len,
+        cell.deploy.get("model", {}))
+    compiled = _lower_train_step(
+        _mesh(topo.devices[:1], data=1), cfg,
+        int(cell.traffic["sequences_per_step"]), seq_len).compile()
+    assert KERNEL in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            <= CELL_STEP_BYTES[cell_name])
 
 
 @pytest.mark.parametrize("axes", [

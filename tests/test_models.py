@@ -408,3 +408,165 @@ def test_a_part_left_out_fails_by_the_looped_adapters_own_tolerances(
               and abs(float(optax.global_norm(grads)) - float(want_norm))
               <= tol["grad_norm_rtol"] * float(want_norm))
     assert inside == (name == "sound")
+
+
+# -- the loss head that takes its own gradient on the way forward ------------
+# (``transformer.weighted_nll``): against the formulation it replaced, kept
+# here as the oracle.
+
+def _autodiff_loss_and_metrics(params, tokens, cfg):
+    """``loss_and_metrics`` as it was: ``head`` -> ``log_softmax`` ->
+    ``take_along_axis`` -> the exit-weighted sum, all left to autodiff."""
+    states = transformer.pass_states(params, tokens[:, :-1], cfg)
+    targets = tokens[:, 1:]
+
+    def nll(x):
+        logp = jax.nn.log_softmax(transformer.head(params, x, cfg), axis=-1)
+        return -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
+
+    if cfg.exit_beta is None:
+        return jnp.mean(nll(states[-1])), {}
+    logp = transformer.exit_log_probs(params, states, cfg)
+    p = jnp.exp(logp)
+    entropy = -jnp.sum(p * logp, axis=0)
+    loss = jnp.mean(jnp.sum(p * jnp.stack([nll(s) for s in states]), axis=0)
+                    - cfg.exit_beta * entropy)
+    return loss, {"exit_p": jnp.mean(p, axis=(1, 2)),
+                  "exit_entropy": jnp.mean(entropy)}
+
+
+HEAD_CASES = {
+    "plain": dataclasses.replace(LOOPED, n_passes=1, exit_beta=None),
+    "looped": LOOPED,
+    "four_passes": dataclasses.replace(LOOPED, n_passes=4),
+}
+
+
+def _head_case(looped, name, dtype=jnp.float32):
+    params, tokens = looped
+    cfg = dataclasses.replace(HEAD_CASES[name], dtype=dtype)
+    if cfg.exit_beta is None:
+        params = {k: v for k, v in params.items() if k != "exit_gate"}
+    return params, tokens, cfg
+
+
+VOCAB_APART = 101      # no other dimension of LOOPED has this size
+
+
+def _vocab_matmuls(fn, *args, vocab=VOCAB_APART) -> int:
+    """How many ``dot_general``s with a vocabulary-sized operand or result
+    run in one call of ``fn``: a scan's body counts once a turn."""
+    def count(jaxpr, turns):
+        total = 0
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "dot_general" and any(
+                    vocab in v.aval.shape for v in eqn.invars + eqn.outvars):
+                total += turns
+            inner = turns * (eqn.params["length"]
+                             if eqn.primitive.name == "scan" else 1)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                total += count(sub, inner)
+        return total
+    return count(jax.make_jaxpr(fn)(*args).jaxpr, 1)
+
+
+@pytest.mark.parametrize("name", ["plain", "looped"])
+def test_loss_head_matches_autodiff_in_float32(looped, name):
+    params, tokens, cfg = _head_case(looped, name)
+    (loss, metrics), grads = jax.value_and_grad(
+        transformer.loss_and_metrics, has_aux=True)(params, tokens, cfg)
+    (want, want_metrics), want_grads = jax.value_and_grad(
+        _autodiff_loss_and_metrics, has_aux=True)(params, tokens, cfg)
+    assert float(loss) == pytest.approx(float(want), rel=1e-5)
+    assert sorted(metrics) == sorted(want_metrics)
+    for key in metrics:
+        np.testing.assert_allclose(metrics[key], want_metrics[key],
+                                   rtol=1e-5)
+    assert jax.tree.structure(grads) == jax.tree.structure(want_grads)
+    paths = jax.tree_util.tree_flatten_with_path(grads)[0]
+    for (path, got), ref in zip(paths, jax.tree.leaves(want_grads)):
+        scale = float(jnp.max(jnp.abs(ref)))
+        assert scale > 0, path      # the gate's w and b, ln_f, lm_head, ...
+        np.testing.assert_allclose(got, ref, rtol=1e-5, atol=1e-5 * scale,
+                                   err_msg=str(path))
+
+
+@pytest.mark.parametrize("name", ["plain", "looped"])
+def test_loss_head_matches_autodiff_in_bfloat16(looped, name):
+    """In bfloat16 the two differ by roundings (the float32 logits no longer
+    pass through a bfloat16 array): each leaf to the reference tests'
+    tolerances, taken over the leaf."""
+    params, tokens, cfg = _head_case(looped, name, jnp.bfloat16)
+    loss, grads = jax.value_and_grad(transformer.loss_fn)(params, tokens, cfg)
+    want, want_grads = jax.value_and_grad(
+        lambda p: _autodiff_loss_and_metrics(p, tokens, cfg)[0])(params)
+    assert float(loss) == pytest.approx(float(want), rel=2e-3)
+    paths = jax.tree_util.tree_flatten_with_path(grads)[0]
+    for (path, got), ref in zip(paths, jax.tree.leaves(want_grads)):
+        assert got.dtype == ref.dtype == jnp.float32, path
+        off = float(jnp.linalg.norm(got - ref) / jnp.linalg.norm(ref))
+        assert off < 3e-2, (path, off)
+    assert float(optax.global_norm(grads)) == pytest.approx(
+        float(optax.global_norm(want_grads)), rel=1e-2)
+
+
+@pytest.mark.parametrize("name", ["plain", "looped"])
+def test_loss_head_scales_with_a_cotangent_that_is_not_one(looped, name):
+    params, tokens, cfg = _head_case(looped, name)
+    grads = jax.grad(transformer.loss_fn)(params, tokens, cfg)
+    tripled = jax.grad(
+        lambda p: 3.0 * transformer.loss_fn(p, tokens, cfg))(params)
+    for got, ref in zip(jax.tree.leaves(tripled), jax.tree.leaves(grads)):
+        np.testing.assert_allclose(got, 3.0 * ref, rtol=1e-5,
+                                   atol=1e-5 * float(jnp.max(jnp.abs(ref))))
+
+
+@pytest.mark.parametrize("name", ["plain", "looped", "four_passes"])
+def test_loss_head_vocabulary_matmuls_are_three_an_exit(looped, name):
+    """The static witness that the mechanism engaged: logits, the gradient
+    to the states and the gradient to the head, once each an exit (the
+    checkpointed map it replaced ran the logits twice: four an exit). The
+    primal alone, with nothing differentiated, runs the logits and no
+    more, and gives the same loss."""
+    # a vocabulary of its own: LOOPED's 96 is also its d_ff
+    cfg = dataclasses.replace(HEAD_CASES[name], vocab_size=VOCAB_APART)
+    params = transformer.init_params(jax.random.PRNGKey(34), cfg)
+    tokens = looped[1]
+    exits = cfg.n_passes if cfg.exit_beta is not None else 1
+    assert _vocab_matmuls(
+        lambda p: transformer.loss_fn(p, tokens, cfg), params) == exits
+    assert _vocab_matmuls(
+        jax.grad(lambda p: transformer.loss_fn(p, tokens, cfg)),
+        params) == 3 * exits
+    assert _vocab_matmuls(
+        jax.grad(lambda p: _autodiff_loss_and_metrics(p, tokens, cfg)[0]),
+        params) == 3 * exits        # the counter, on the unrolled oracle
+    loss = transformer.loss_fn(params, tokens, cfg)
+    assert float(loss) == pytest.approx(float(jax.value_and_grad(
+        transformer.loss_fn)(params, tokens, cfg)[0]), rel=1e-6)
+    assert float(loss) == pytest.approx(
+        float(_autodiff_loss_and_metrics(params, tokens, cfg)[0]), rel=1e-5)
+
+
+@pytest.mark.parametrize("name", ["plain", "looped"])
+def test_loss_head_under_fsdp4_matches_one_device(eight_device_mesh, looped,
+                                                  name):
+    """One train step over ``fsdp=4`` (the state and the batch both split)
+    against the same step on one device: the loss and the gradient norm."""
+    from ray_tpu.train.step import make_lm_train_step
+    _, _, cfg = _head_case(looped, name)
+    rows = jax.random.randint(jax.random.PRNGKey(34), (4, 17), 0,
+                              cfg.vocab_size)
+    seen = []
+    for mesh in (build_mesh(MeshConfig(data=1), eight_device_mesh[:1]),
+                 build_mesh(MeshConfig(fsdp=4), eight_device_mesh[:4])):
+        init_fn, step_fn, shard_batch = make_lm_train_step(cfg, mesh)
+        _, metrics = step_fn(init_fn(jax.random.PRNGKey(0)),
+                             shard_batch(rows))
+        seen.append(jax.device_get(metrics))
+    one, four = seen
+    assert float(four["loss"]) == pytest.approx(float(one["loss"]), rel=1e-5)
+    assert float(four["grad_norm"]) == pytest.approx(float(one["grad_norm"]),
+                                                     rel=1e-4)
+    if cfg.exit_beta is not None:
+        np.testing.assert_allclose(four["exit_p"], one["exit_p"], rtol=1e-4)
