@@ -4,9 +4,16 @@
 (``url``, ``plan`` as ``traffic.request_plan`` makes it, ``seed``,
 ``vocab_size``, ``t0`` and ``t_end`` on ``time.monotonic()``, which Linux
 shares between processes, and ``timeout_s``), sends the requests over HTTP
-and prints one JSON object: a record for each request with the instant it
-was due, was sent and was answered, and for one that was not answered with
-200 the status and the start of the body (status 0: the exception).
+and prints one JSON object. ``"records"``: a record for each request with
+the instant it was due, was sent and was answered, and for one that was not
+answered with 200 the status and the start of the body (status 0: the
+exception). ``"holds"``: every instant at which this process's own clock
+skipped, as ``[seconds from t0, seconds late]``: a thread that sleeps 10 ms
+at a time, from before the pre-roll until the last reply is in, notes each
+wake-up that came 0.25 s late or more (``"skip_max_s"`` is the latest of
+all its wake-ups, noted or not). The process shares no interpreter and
+no lock with the system under test, so such a skip is the host holding
+every process, and ``serve_job`` measures a window again that one fell in.
 
 Open loop: a request is sent when it is due whether or not earlier ones
 have been answered, and is timed from the instant it was *due*, so a
@@ -30,6 +37,11 @@ from benchmark import traffic
 
 MAX_IN_FLIGHT = 128   # under the proxy's 200
 ERROR_BYTES = 200     # of a refusal's body: enough to name who refused
+WATCH_SLEEP_S = 0.01  # the watcher's nap
+# a wake-up this late is a hold, and freezes the window it falls in: 0.44 s
+# lifted the steady cell's p95 from 138 to 190 ms and 0.82 s to 354, while
+# quiet windows skip 0.12 s at most (PERF.md section 6, PRs 36 and 29)
+HOLD_S = 0.25
 
 
 class _Client(threading.local):
@@ -68,7 +80,46 @@ def _post(client: _Client, url: urllib.parse.SplitResult, body: bytes,
     raise AssertionError("unreachable")
 
 
+class Watcher:
+    """A thread that naps ``WATCH_SLEEP_S`` at a time and keeps every
+    wake-up that came ``HOLD_S`` late or more as ``[when it woke, in
+    seconds from t0; how late]``."""
+
+    def __init__(self, t0: float):
+        self.t0 = t0
+        self.holds: List[List[float]] = []
+        self.skip_max_s = 0.0
+        self._stop = threading.Event()
+        self._thread = threading.Thread(target=self._watch, daemon=True)
+
+    def _watch(self) -> None:
+        last = time.monotonic()
+        while not self._stop.is_set():
+            time.sleep(WATCH_SLEEP_S)
+            now = time.monotonic()
+            late = now - last - WATCH_SLEEP_S
+            self.skip_max_s = max(self.skip_max_s, late)
+            if late >= HOLD_S:
+                self.holds.append([now - self.t0, late])
+            last = now
+
+    def __enter__(self) -> "Watcher":
+        self._thread.start()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self._stop.set()
+        self._thread.join()
+
+
 def run(job: Dict[str, Any]) -> Dict[str, Any]:
+    with Watcher(job["t0"]) as watcher:
+        records = _send_all(job)
+    return {"records": records, "holds": watcher.holds,
+            "skip_max_s": watcher.skip_max_s}
+
+
+def _send_all(job: Dict[str, Any]) -> List[Dict[str, Any]]:
     plan, seed, vocab = job["plan"], job["seed"], job["vocab_size"]
     url = urllib.parse.urlsplit(job["url"])
     t0, t_end, timeout_s = job["t0"], job["t_end"], job["timeout_s"]
@@ -119,7 +170,7 @@ def run(job: Dict[str, Any]) -> Dict[str, Any]:
         for t in threads:
             t.join()
     records.sort(key=lambda r: r["i"])
-    return {"records": records}
+    return records
 
 
 def main() -> int:

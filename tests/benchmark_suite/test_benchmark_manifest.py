@@ -22,9 +22,24 @@ PUBLISHED = {
 }
 
 
-@pytest.fixture(scope="module")
-def real():
-    return manifest.Manifest(REPO)
+ROOTS = ("as accepted", "with later cells")
+
+
+def real_root(which, tmp_path_factory):
+    """The repo as accepted, or a copy of it with what later PRs bring: a
+    later configuration and later cells beside the real ones, as new files
+    and entries (``benchmark_tiny.make_root``). Every guard written against
+    the real manifest runs on both, so a guard that pins what a later PR
+    must change (the number of cells, the last cell) fails in the PR that
+    writes it."""
+    if which == "as accepted":
+        return REPO
+    return benchmark_tiny.make_root(tmp_path_factory.mktemp("later"))
+
+
+@pytest.fixture(scope="module", params=ROOTS)
+def real(request, tmp_path_factory):
+    return manifest.Manifest(real_root(request.param, tmp_path_factory))
 
 
 def test_manifest_passes_every_check_of_form(real):
@@ -118,7 +133,7 @@ def test_a_fifth_cell_in_a_copy_of_the_real_manifest_passes_every_guard(
 @pytest.mark.parametrize("name", sorted(PUBLISHED))
 def test_configuration_keeps_every_published_width(real, name):
     entry = next(c for c in real.data["configs"] if c["name"] == name)
-    with open(os.path.join(REPO, entry["file"])) as f:
+    with open(os.path.join(real.root, entry["file"])) as f:
         config = json.load(f)
     *widths, layers = PUBLISHED[name]
     assert [config[k] for k in WIDTHS] == widths
@@ -153,7 +168,7 @@ def test_every_moves_names_a_metric_its_cells_report(real):
 
 def test_every_per_layer_metric_has_a_file_with_a_known_reader(real):
     for m in real.data["per_layer"]:
-        path = os.path.join(REPO, "benchmark", "layer_metrics",
+        path = os.path.join(real.root, "benchmark", "layer_metrics",
                             m["name"] + ".json")
         with open(path) as f:
             spec = json.load(f)
