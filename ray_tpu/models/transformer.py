@@ -25,11 +25,25 @@ pass's logits (``backbone`` + ``head``: the published
 ``early_exit_threshold`` of 1 never leaves early, and there is no decode
 loop to leave). With the defaults none of this exists: the parameter tree
 and the compiled programs are the plain decoder's.
+
+A stack of several kinds of block (MiniCPM-SALA: ``layer_kinds``) holds one
+stacked parameter tree a kind, ``blocks[kind]``, and scans each run of
+neighbouring layers of one kind, in the published order. Such a stack is
+made of two kinds, both forward only (``dense``, the block above, stands in
+a stack of its own kind alone): ``sparse``
+(per-head RMSNorm of q and k, no positions, causal flash attention up to
+``sparse.dense_len`` tokens and block-sparse attention with a learned
+selection past it, a sigmoid output gate) and ``linear`` (the same norms,
+rotary positions, Lightning linear attention with a per-head decay, an output
+norm over the joined heads, the gate). ``embed_scale``, ``residual_scale``
+and ``logit_scale`` are the muP factors of such a model; at 1 they emit
+nothing.
 """
 
 from __future__ import annotations
 
 import functools
+import itertools
 import math
 from dataclasses import dataclass, field
 from typing import Any, Dict, Optional, Tuple
@@ -39,9 +53,14 @@ import jax.numpy as jnp
 from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec
 
-from ray_tpu.ops import flash_attention
+from ray_tpu.ops import flash_attention, linear_attention, sparse_attention
+from ray_tpu.ops.linear_attention import decay_rates
+from ray_tpu.ops.sparse_attention import SparseConfig
 from ray_tpu.parallel.sequence import ring_attention
 from ray_tpu.parallel.sharding import ShardingRules
+
+
+DENSE, SPARSE, LINEAR = "dense", "sparse", "linear"      # a layer's kind
 
 
 @dataclass(frozen=True)
@@ -69,6 +88,45 @@ class TransformerConfig:
     # tree) and the loss is the exit-weighted objective with this weight on
     # the exit distribution's entropy (the LoopLM paper's beta).
     exit_beta: Optional[float] = None
+    # Each layer's kind, in order (``DENSE``, ``SPARSE``, ``LINEAR``); None:
+    # every layer is dense and ``blocks`` is one stacked tree.
+    layer_kinds: Optional[Tuple[str, ...]] = None
+    # Each layer's index in the published stack of ``decay_depth`` layers
+    # (a linear layer's decay depends on it); None: 0 .. n_layers - 1.
+    layer_ids: Optional[Tuple[int, ...]] = None
+    decay_depth: Optional[int] = None
+    # muP: the embedding times ``embed_scale``, every sub-layer's output
+    # times ``residual_scale`` before the residual add, the head's input
+    # times ``logit_scale``.
+    embed_scale: float = 1.0
+    residual_scale: float = 1.0
+    logit_scale: float = 1.0
+    sparse: SparseConfig = SparseConfig()
+
+    def __post_init__(self):
+        kinds = self.layer_kinds
+        if kinds is None:
+            return
+        unknown = sorted(set(kinds) - {DENSE, SPARSE, LINEAR})
+        if unknown or len(kinds) != self.n_layers:
+            raise ValueError(
+                f"layer_kinds {kinds}: {self.n_layers} layers, each one of "
+                f"{DENSE!r}, {SPARSE!r}, {LINEAR!r}")
+        if DENSE in kinds and set(kinds) != {DENSE}:
+            raise ValueError(
+                f"layer_kinds {kinds}: a {DENSE!r} layer stands in a stack "
+                f"of its own kind only (its block has no residual_scale)")
+        if self.layer_ids is not None and len(self.layer_ids) != len(kinds):
+            raise ValueError(f"layer_ids {self.layer_ids} for {kinds}")
+
+    @property
+    def kinds(self) -> Tuple[str, ...]:
+        return self.layer_kinds or (DENSE,) * self.n_layers
+
+    @property
+    def mixed(self) -> bool:
+        """Whether ``blocks`` holds a tree a kind (any layer not dense)."""
+        return set(self.kinds) != {DENSE}
 
     @property
     def head_dim(self) -> int:
@@ -92,18 +150,30 @@ def init_params(key: jax.Array, cfg: TransformerConfig) -> Dict[str, Any]:
     def dense(k, shape, fan_in):
         return jax.random.normal(k, shape, jnp.float32) / math.sqrt(fan_in)
 
-    def layer(k):
+    def layer(k, kind=DENSE):
         ks = jax.random.split(k, 7)
         post = ({"ln1_post": jnp.ones((d,), jnp.float32),
                  "ln2_post": jnp.ones((d,), jnp.float32)}
                 if cfg.post_norm else {})
+        # a linear layer has as many K/V heads as query heads
+        kv = h if kind == LINEAR else kvh
+        extra = {}
+        if kind != DENSE:
+            # the gate's key is folded in beside the seven, as the exit
+            # gate's is below
+            extra = {"wg": dense(jax.random.fold_in(k, 7), (d, h, hd), d),
+                     "q_norm": jnp.ones((hd,), jnp.float32),
+                     "k_norm": jnp.ones((hd,), jnp.float32)}
+        if kind == LINEAR:
+            extra["o_norm"] = jnp.ones((h * hd,), jnp.float32)
         return {
             **post,
             "attn": {
                 "wq": dense(ks[0], (d, h, hd), d),
-                "wk": dense(ks[1], (d, kvh, hd), d),
-                "wv": dense(ks[2], (d, kvh, hd), d),
+                "wk": dense(ks[1], (d, kv, hd), d),
+                "wv": dense(ks[2], (d, kv, hd), d),
                 "wo": dense(ks[3], (h, hd, d), h * hd),
+                **extra,
             },
             "mlp": {
                 "wi": dense(ks[4], (d, f), d),       # gate
@@ -115,6 +185,16 @@ def init_params(key: jax.Array, cfg: TransformerConfig) -> Dict[str, Any]:
         }
 
     layer_keys = jax.random.split(k_layers, cfg.n_layers)
+
+    def blocks():
+        if not cfg.mixed:
+            return jax.vmap(layer)(layer_keys)      # stacked: [L, ...]
+        # a stacked tree a kind, each layer drawing from its own place's key
+        return {kind: jax.vmap(functools.partial(layer, kind=kind))(
+            layer_keys[jnp.array([i for i, k in enumerate(cfg.kinds)
+                                  if k == kind])])
+            for kind in dict.fromkeys(cfg.kinds)}
+
     # the gate's key is folded in beside the three, so that a configuration
     # without one draws the weights it always drew
     gate = ({"exit_gate": {"w": dense(jax.random.fold_in(key, 3), (d,), d),
@@ -123,7 +203,7 @@ def init_params(key: jax.Array, cfg: TransformerConfig) -> Dict[str, Any]:
     return {
         "embed": jax.random.normal(k_embed, (cfg.vocab_size, d),
                                    jnp.float32) * 0.02,
-        "blocks": jax.vmap(layer)(layer_keys),      # stacked: [L, ...]
+        "blocks": blocks(),
         "ln_f": jnp.ones((d,), jnp.float32),
         "lm_head": dense(k_head, (d, cfg.vocab_size), d),
         **gate,
@@ -151,6 +231,14 @@ def logical_axes(cfg: TransformerConfig) -> Dict[str, Any]:
     }
     if cfg.post_norm:
         blk.update(ln1_post=("layers", None), ln2_post=("layers", None))
+    if cfg.mixed:
+        def of_kind(kind):
+            extra = {"wg": ("layers", "embed", "heads", "kv"),
+                     "q_norm": ("layers", None), "k_norm": ("layers", None)}
+            if kind == LINEAR:
+                extra["o_norm"] = ("layers", None)
+            return {**blk, "attn": {**blk["attn"], **extra}}
+        blk = {kind: of_kind(kind) for kind in dict.fromkeys(cfg.kinds)}
     axes = {
         "embed": ("vocab", "embed"),
         "blocks": blk,
@@ -248,13 +336,71 @@ def _block(params, x, positions, cfg: TransformerConfig, mesh, rules=None):
         out = norm(out, params["ln1_post"])
     x = x + out
     h = norm(x, params["ln2"])
-    gate = jnp.einsum("bld,df->blf", h, params["mlp"]["wi"].astype(x.dtype))
-    up = jnp.einsum("bld,df->blf", h, params["mlp"]["wg"].astype(x.dtype))
-    ff = jax.nn.silu(gate) * up
-    out = jnp.einsum("blf,fd->bld", ff, params["mlp"]["wo"].astype(x.dtype))
+    out = _mlp(params["mlp"], h)
     if cfg.post_norm:
         out = norm(out, params["ln2_post"])
     return x + out
+
+
+def _mlp(mlp, h):
+    gate = jnp.einsum("bld,df->blf", h, mlp["wi"].astype(h.dtype))
+    up = jnp.einsum("bld,df->blf", h, mlp["wg"].astype(h.dtype))
+    ff = jax.nn.silu(gate) * up
+    return jnp.einsum("blf,fd->bld", ff, mlp["wo"].astype(h.dtype))
+
+
+def _mixed_block(layer, x, positions, cfg: TransformerConfig, kind: str):
+    """One ``SPARSE`` or ``LINEAR`` layer. ``layer``: the layer's
+    parameters and, for a linear layer, its heads' decay rates [h]."""
+    params, rates = layer
+    B, L, _ = x.shape
+    norm = functools.partial(_rmsnorm, eps=cfg.norm_eps)
+    attn = {k: w.astype(x.dtype) for k, w in params["attn"].items()}
+    h = norm(x, params["ln1"])
+    q = norm(jnp.einsum("bld,dhk->blhk", h, attn["wq"]), attn["q_norm"])
+    k = norm(jnp.einsum("bld,dhk->blhk", h, attn["wk"]), attn["k_norm"])
+    v = jnp.einsum("bld,dhk->blhk", h, attn["wv"])
+    if kind == LINEAR:
+        q = _rope(q, cfg.rope_theta, positions)
+        k = _rope(k, cfg.rope_theta, positions)
+        o = linear_attention(q, k, v, rates, use_kernel=cfg.use_flash)
+        o = norm(o.reshape(B, L, -1), attn["o_norm"]).reshape(o.shape)
+    elif L <= cfg.sparse.dense_len:
+        o = _attention(q, k, v, cfg, None)
+    else:
+        o = sparse_attention(q, k, v, cfg.sparse, use_kernel=cfg.use_flash)
+    o = o * jax.nn.sigmoid(jnp.einsum("bld,dhk->blhk", h, attn["wg"]))
+    x = x + cfg.residual_scale * jnp.einsum("blhk,hkd->bld", o, attn["wo"])
+    out = _mlp(params["mlp"], norm(x, params["ln2"]))
+    return x + cfg.residual_scale * out
+
+
+def _apply_mixed(blocks, x, positions, cfg: TransformerConfig, mesh):
+    """``blocks[kind]`` stacked over that kind's layers: each run of
+    neighbouring layers of one kind is one scan, the runs in the published
+    order."""
+    if mesh is not None:
+        raise ValueError(f"layer kinds {sorted(set(cfg.kinds))} run on one "
+                         "device only: no mesh")
+    ids = cfg.layer_ids or tuple(range(cfg.n_layers))
+    taken = dict.fromkeys(cfg.kinds, 0)
+    at = 0
+    for kind, run in itertools.groupby(cfg.kinds):
+        n = len(list(run))
+        start = taken[kind]
+        layers = jax.tree.map(lambda p: p[start:start + n], blocks[kind])
+        rates = jnp.stack([decay_rates(cfg.n_heads, i,
+                                       cfg.decay_depth or cfg.n_layers)
+                           for i in ids[at:at + n]])
+        fn = functools.partial(_mixed_block, cfg=cfg, kind=kind)
+        if cfg.remat:
+            fn = jax.checkpoint(fn)
+        x, _ = jax.lax.scan(
+            lambda x, layer: (fn(layer, x, positions), None), x,
+            (layers, rates))
+        taken[kind] += n
+        at += n
+    return x
 
 
 def apply_layers(blocks, x: jax.Array, cfg: TransformerConfig,
@@ -269,6 +415,8 @@ def apply_layers(blocks, x: jax.Array, cfg: TransformerConfig,
     (fast compiles at depth) and keeps the layer dim shardable for PP."""
     B, L, _ = x.shape
     positions = jnp.broadcast_to(jnp.arange(L)[None], (B, L))
+    if cfg.mixed:
+        return _apply_mixed(blocks, x, positions, cfg, mesh)
     block_fn = functools.partial(_block, cfg=cfg, mesh=mesh, rules=rules)
     if cfg.remat:
         block_fn = jax.checkpoint(block_fn)
@@ -293,6 +441,8 @@ def pass_states(params: Dict[str, Any], tokens: jax.Array,
     on the v5e the step's memory was the smaller that way (PERF.md section
     4). A single pass builds no loop at all: the plain decoder's program."""
     x = params["embed"].astype(cfg.dtype)[tokens]
+    if cfg.embed_scale != 1.0:
+        x = x * cfg.embed_scale
     layers = functools.partial(apply_layers, params["blocks"], cfg=cfg,
                                mesh=mesh, rules=rules)
     if cfg.n_passes == 1:
@@ -319,6 +469,8 @@ def head(params: Dict[str, Any], x: jax.Array,
     """Final norm + lm-head projection -> float32 logits. The single logits
     path shared by inference (``apply``) and training (``token_nll``)."""
     x = _rmsnorm(x, params["ln_f"], cfg.norm_eps)
+    if cfg.logit_scale != 1.0:
+        x = x * cfg.logit_scale
     logits = jnp.einsum("bld,dv->blv", x,
                         params["lm_head"].astype(cfg.dtype))
     return logits.astype(jnp.float32)

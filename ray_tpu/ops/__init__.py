@@ -1,3 +1,5 @@
 from ray_tpu.ops.flash_attention import flash_attention
+from ray_tpu.ops.linear_attention import linear_attention
+from ray_tpu.ops.sparse_attention import sparse_attention
 
-__all__ = ["flash_attention"]
+__all__ = ["flash_attention", "linear_attention", "sparse_attention"]

@@ -45,8 +45,15 @@ def make_lm_train_step(cfg: TransformerConfig, mesh: Mesh,
 
     Raises ``ValueError`` for pipe > 1 with ``cfg.n_passes`` > 1: the GPipe
     schedule sends a microbatch through the stages once, and a looped stack
-    would have to come back round to the first stage.
+    would have to come back round to the first stage. And for a stack with
+    a kind of layer other than ``dense`` (``cfg.layer_kinds``): their
+    operations are forward only.
     """
+    forward_only = sorted(set(cfg.kinds) - {transformer.DENSE})
+    if forward_only:
+        raise ValueError(
+            f"layer kinds {forward_only} have no backward pass: a stack "
+            f"with them ({cfg.kinds}) is served, not trained")
     rules = rules or ShardingRules()
     optimizer = optimizer or optax.adamw(3e-4, weight_decay=0.01)
     pipe = mesh.shape.get("pipe", 1)

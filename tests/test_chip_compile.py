@@ -4,7 +4,9 @@ The TPU compiler is installed where the tests run, and it compiles for a
 topology that is described and not attached. These tests keep the main
 path's programs compiling at their real widths: the flash kernel forward
 and backward, the whole LM train step on one chip and on three four-chip
-meshes, and the serve forward at its batch buckets. Each asserts the Pallas
+meshes, the serve forward at its batch buckets, and the two operations of
+a mixed stack (chunked linear attention, block-sparse attention) alone and
+inside the served forward of the long-document cell. Each asserts the Pallas
 kernel is in the compiled program (``tpu_custom_call``). Nothing runs, so
 they say nothing about results or times.
 
@@ -23,6 +25,8 @@ import pytest
 from jax.sharding import Mesh, SingleDeviceSharding
 
 import ray_tpu.ops.flash_attention  # noqa: F401  (the module, not the function)
+import ray_tpu.ops.linear_attention  # noqa: F401
+import ray_tpu.ops.sparse_attention  # noqa: F401
 from ray_tpu.models import transformer
 from ray_tpu.models.transformer import TransformerConfig
 from ray_tpu.parallel import (MeshConfig, ShardingRules, batch_sharding,
@@ -69,8 +73,9 @@ def no_compile_cache():
 def mosaic(monkeypatch, topo, no_compile_cache):
     """The process's backend is the CPU, where the kernel would take
     interpret mode; these compiles are for the described chip."""
-    monkeypatch.setattr(sys.modules["ray_tpu.ops.flash_attention"],
-                        "_backend_is_cpu", lambda: False)
+    for module in ("flash_attention", "linear_attention", "sparse_attention"):
+        monkeypatch.setattr(sys.modules[f"ray_tpu.ops.{module}"],
+                            "_backend_is_cpu", lambda: False)
 
 
 def _mesh(devices, **axes) -> Mesh:
@@ -203,3 +208,85 @@ def test_serve_forward_compiles_at_bucket(topo, mosaic, bucket):
     text = jax.jit(lambda p, t: transformer.apply(p, t, CFG)).lower(
         params, tokens).compile().as_text()
     assert KERNEL in text
+
+
+# -- a stack of several kinds of block ------------------------------------------
+# (batch, tokens): the long-document cell's largest shape and its first
+# selecting bucket, at MiniCPM-SALA's 32 heads of 128 (2 K/V heads).
+MIXED_SHAPES = [(1, 32768), (2, 16384)]
+
+
+@pytest.mark.parametrize("batch,length", MIXED_SHAPES)
+def test_linear_attention_kernel_compiles(topo, mosaic, batch, length):
+    from ray_tpu.ops.linear_attention import KERNEL_NAME, linear_attention
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    x = jax.ShapeDtypeStruct((batch, length, 32, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    rates = jax.ShapeDtypeStruct((32,), jnp.float32, sharding=one_chip)
+    text = jax.jit(linear_attention).lower(x, x, x, rates).compile().as_text()
+    assert KERNEL in text and KERNEL_NAME in text
+
+
+@pytest.mark.parametrize("batch,length", MIXED_SHAPES)
+def test_sparse_attention_kernels_compile(topo, mosaic, batch, length):
+    from ray_tpu.ops.sparse_attention import (ATTEND_KERNEL, SCORES_KERNEL,
+                                              sparse_attention)
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    q = jax.ShapeDtypeStruct((batch, length, 32, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((batch, length, 2, 128), jnp.bfloat16,
+                              sharding=one_chip)
+    text = jax.jit(sparse_attention).lower(q, kv, kv).compile().as_text()
+    assert text.count(KERNEL) >= 2
+    assert SCORES_KERNEL in text and ATTEND_KERNEL in text
+
+
+@pytest.mark.parametrize("length,calls", [(8192, ["flash_fwd"]),
+                                          (16384, ["sparse_attn_fwd"])])
+def test_mixed_stack_serve_forward_compiles(topo, mosaic, length, calls):
+    """The long-document cell's forward at its published widths, one period
+    of the stack (a sparse layer and three linear ones): up to ``dense_len``
+    the sparse layer is the flash kernel, past it the selection."""
+    from benchmark import manifest
+    cell = manifest.Manifest().cell("minicpm-sala-serve-longdoc")
+    adapter = manifest.adapter(cell.config)
+    dims = adapter.dims(cell.config, cell.job, cell.chips)
+    dims = {**dims, "n_layers": 4, "mixer_types": dims["mixer_types"][:4],
+            "layer_ids": dims["layer_ids"][:4]}
+    cfg = adapter.program_config(dims, length, cell.deploy["model"])
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    params = jax.eval_shape(lambda: jax.tree.map(
+        lambda p: p.astype(cfg.dtype),
+        transformer.init_params(jax.random.PRNGKey(0), cfg)))
+    params = jax.tree.map(lambda leaf: _shape(leaf, one_chip), params)
+    tokens = jax.ShapeDtypeStruct((1, length), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(lambda p, t: transformer.head(
+        p, transformer.backbone(p, t, cfg)[:, -1:], cfg)).lower(
+            params, tokens).compile()
+    text = compiled.as_text()
+    for call in calls + ["linear_attn_fwd"]:
+        assert call in text
+    mem = compiled.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            < 15 * 10 ** 9)
+
+
+@pytest.mark.parametrize("length", [16384, 32768])
+def test_the_operations_check_compiles_beside_the_references_weights(
+        topo, mosaic, length):
+    """The long-document cell's check of the two operations at its buckets:
+    the three Mosaic calls are in it, and it takes less than the reference's
+    own temporaries (3.2 GB at 32,768 tokens), so that with 11.3 GB of
+    float32 weights on the device the comparison still fits."""
+    from benchmark import manifest
+    cell = manifest.Manifest().cell("minicpm-sala-serve-longdoc")
+    adapter = manifest.adapter(cell.config)
+    dims = adapter.dims(cell.config, cell.job, cell.chips)
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32,
+                               sharding=SingleDeviceSharding(topo.devices[0]))
+    compiled = jax.jit(lambda k: adapter.operations_rows_off(
+        k, length, dims)).lower(key).compile()
+    text = compiled.as_text()
+    for call in ("sparse_attn_scores", "sparse_attn_fwd", "linear_attn_fwd"):
+        assert call in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 3.2 * 10 ** 9
