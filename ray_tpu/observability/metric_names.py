@@ -88,7 +88,11 @@ SPANS = frozenset({
     "serve.reply",          # the reply encoded and written
     # serve: the replica batcher's flusher thread
     # wake-up with a non-empty queue -> batch cut; depth, cap,
-    # oldest_wait_us and left (queued at the cut and not taken by it)
+    # oldest_wait_us and left (queued at the cut and not taken by it);
+    # cut (the reason that fired: full, waited, passed, not_due) and the
+    # two estimates the last one compares, gap_est_us (EWMA of the gaps
+    # between admissions) and call_est_us (the per-item EWMA), -1 where
+    # the replica has none yet
     "serve.batch.linger",
     # _run_batch: pad, call, deliver; n, padded_n (rows after
     # pad_batch_to), size_sum and size_max (the members' observed sizes):
@@ -117,6 +121,12 @@ REPLICA_INIT_GAUGE = "serve_replica_init_seconds"
 # there without a trace.
 REPLICA_BATCH_SIZE_SUM = "batch_size_sum"
 REPLICA_BATCH_PADDED_SUM = "batch_padded_sum"
+# The cuts a replica's batcher has made, and those of them made because no
+# neighbour was due (``cut`` == "not_due" on ``serve.batch.linger``) and not
+# because the batch was full, the oldest request had waited the bound out
+# or an earlier cut had passed it over.
+REPLICA_BATCH_CUTS = "batch_cuts"
+REPLICA_BATCH_CUTS_NOT_DUE = "batch_cuts_not_due"
 
 # Comms-plane sample families.  Not literal-checked by a lint rule the
 # way perf.observe names are — they are declared here so the exporters

@@ -26,14 +26,24 @@ knob).  Which of the queued requests share a call is cut by what the call
 will be padded to (``batching.cut_by_size``): the oldest request and the
 queued requests of like size (``len()`` of a sequence, observed), so a
 short prompt neither pays for nor waits out a long neighbour's rows; with
-equal sizes that is arrival order.  A request a cut passed over has had
-its linger and is cut next without a second one.  Requests that age
-past ``serve_queue_deadline_ms`` in the queue — the wait for the calls cut
-before theirs included — are shed with :class:`ServeOverloadedError`
-instead of executing; the proxy maps that to 503 + Retry-After.  A failed
-batch isolates per item:
-singleton batches get their own error raw; larger batches re-run members
-alone once (``serve_batch_retry_singletons``) or receive a batch-level
+equal sizes that is arrival order.  When the batcher stops waiting for more:
+``batch_wait_timeout_s`` is the LONGEST the oldest queued request may be
+held, and within it the flusher cuts as soon as the batch is full to the
+cap, an earlier cut passed the request over (it has had its linger and
+gets no second one), or no neighbour is due in time to be worth the wait.
+The last is worked out from what the replica sees of its own traffic: an
+EWMA of the gaps between admissions (taken in ``submit``) against the
+per-item call estimate (``_HOLD_GAP_SHARE``); a replica that has not yet
+seen two admissions and one call holds for the configured bound, so a
+deployment's first burst batches as it always did.  Which reason fired is
+``cut`` on the ``serve.batch.linger`` span (``full``, ``waited``,
+``passed``, ``not_due``) and is counted in ``get_metrics()``.  Requests
+that age past ``serve_queue_deadline_ms`` in the queue — the wait for the
+calls cut before theirs included — are shed with
+:class:`ServeOverloadedError` instead of executing; the proxy maps that to
+503 + Retry-After.  A failed batch isolates per item: singleton batches
+get their own error raw; larger batches re-run members alone once
+(``serve_batch_retry_singletons``) or receive a batch-level
 :class:`BatchExecutionError` naming the batch size and request ids.
 
 Every request — batched or direct — feeds two replica-local
@@ -50,13 +60,15 @@ import asyncio
 import inspect
 import threading
 import time
-from typing import Any, List, Optional, Tuple
+from typing import Any, List, Optional
 
 from ray_tpu import chaos, observability
 from ray_tpu._private.config import _config
 from ray_tpu.exceptions import BatchExecutionError, ServeOverloadedError
 from ray_tpu.observability import perf
-from ray_tpu.observability.metric_names import (REPLICA_BATCH_PADDED_SUM,
+from ray_tpu.observability.metric_names import (REPLICA_BATCH_CUTS,
+                                                 REPLICA_BATCH_CUTS_NOT_DUE,
+                                                 REPLICA_BATCH_PADDED_SUM,
                                                  REPLICA_BATCH_SIZE_SUM,
                                                  REPLICA_INIT_GAUGE)
 from ray_tpu.serve.batching import (cut_by_size, item_size, next_bucket,
@@ -66,6 +78,17 @@ from ray_tpu.serve.batching import (cut_by_size, item_size, next_bucket,
 # and the queue_est_ms backpressure signal (local smoothing; the
 # autoscaler's cross-tick smoothing uses serve_autoscale_ewma_alpha).
 _ITEM_EWMA_ALPHA = 0.3
+
+# A queued request is held for a neighbour only while the gap between
+# admissions is expected to be under this share of what its call would
+# take.  Holding never shortens the held request's own latency; it can only
+# pay for the next one.  With a call of t and a neighbour due after a gap g:
+# run now, and the two wait t and 2t - g (the neighbour sits out the first
+# call), 3t - g together; hold, and they wait g + t and t, g + 2t together,
+# if the shared call costs what one does (a lone request is padded to the
+# first row bucket, so a neighbour of like size rides free).  Holding wins
+# only if g < t / 2; where a call grows with its rows it never does.
+_HOLD_GAP_SHARE = 0.5
 
 
 def _load_checkpoint(checkpoint: Any) -> Any:
@@ -120,8 +143,9 @@ class _BatchSlot:
 
 class _ReplicaBatcher:
     """Adaptive micro-batcher owned by one replica (see module docstring
-    for the state machine: admit → linger → shed-expired → cut by size →
-    pad-to-bucket execute → per-item deliver)."""
+    for the state machine: admit → linger while a neighbour is due →
+    shed-expired → cut by size → pad-to-bucket execute → per-item
+    deliver)."""
 
     def __init__(self, replica: "Replica", cfg: dict):
         self._replica = replica
@@ -144,14 +168,27 @@ class _ReplicaBatcher:
         # over every batch: their quotient is the fill
         self._size_sum = 0  # raylint: guarded-by(self._lock)
         self._padded_sum = 0  # raylint: guarded-by(self._lock)
+        # when the next request is due: the last admission and an EWMA of
+        # the gaps between admissions (None until two have been seen)
+        # raylint: guarded-by(self._lock)
+        self._t_admit: Optional[float] = None
+        # raylint: guarded-by(self._lock)
+        self._gap_ewma_s: Optional[float] = None
+        # cuts made, and those made because no neighbour was due
+        self._cuts = 0  # raylint: guarded-by(self._lock)
+        self._cuts_not_due = 0  # raylint: guarded-by(self._lock)
 
     def depth(self) -> int:
         with self._lock:
             return len(self._queue)
 
-    def fill_sums(self) -> Tuple[int, int]:
+    def counts(self) -> dict:
+        """What ``get_metrics()`` carries of the batches and the cuts."""
         with self._lock:
-            return self._size_sum, self._padded_sum
+            return {REPLICA_BATCH_SIZE_SUM: self._size_sum,
+                    REPLICA_BATCH_PADDED_SUM: self._padded_sum,
+                    REPLICA_BATCH_CUTS: self._cuts,
+                    REPLICA_BATCH_CUTS_NOT_DUE: self._cuts_not_due}
 
     def retune(self, cfg: dict) -> None:
         """Live-update the batch shape (autopilot serve policy): the
@@ -176,6 +213,13 @@ class _ReplicaBatcher:
                     target=self._flush_loop, daemon=True,
                     name=f"serve-replica-batch-{self._replica.replica_tag}")
                 self._thread.start()
+            if self._t_admit is not None:
+                # two callers' threads may get here out of order
+                gap = max(0.0, slot.t_enqueue - self._t_admit)
+                prev = self._gap_ewma_s
+                self._gap_ewma_s = (gap if prev is None else
+                                    prev + _ITEM_EWMA_ALPHA * (gap - prev))
+            self._t_admit = slot.t_enqueue
             self._queue.append(slot)
         self._wakeup.set()
         slot.event.wait()
@@ -187,17 +231,15 @@ class _ReplicaBatcher:
         self._stop = True
         self._wakeup.set()
 
-    def _effective_max(self) -> int:
+    def _effective_max(self, item_ms: float) -> int:
         """Latency-guarded batch-size cap: never form a batch whose
         EWMA-predicted execution time (items × per-item estimate) would
         blow the replica's latency budget."""
         with self._lock:
             want = self._max
         budget = self._replica._batch_budget_ms()
-        with self._replica._lock:
-            ewma = self._replica._ewma_item_ms
-        if budget > 0 and ewma > 0:
-            want = min(want, max(1, int(budget / ewma)))
+        if budget > 0 and item_ms > 0:
+            want = min(want, max(1, int(budget / item_ms)))
         return max(1, want)
 
     def _flush_loop(self) -> None:
@@ -231,23 +273,40 @@ class _ReplicaBatcher:
         """Linger, then cut: ``(the batch, the requests that aged out, the
         deadline they aged past)``.  Only this thread takes requests off
         the queue, so it is not empty here."""
-        cap = self._effective_max()
-        # Linger window anchored on the OLDEST queued request: fire
-        # when the batch fills (to the adaptive cap), the oldest
-        # request has waited batch_wait_timeout_s, or an earlier cut
-        # passed it over (it has had its linger; the device is idle).
+        # what the oldest request's call would take alone: a call ends on
+        # this thread, so the estimate stands still while the cut waits
+        with self._replica._lock:
+            call_ms = self._replica._ewma_item_ms
+        cap = self._effective_max(call_ms)
+        # Linger window anchored on the OLDEST queued request.  Four
+        # reasons to cut: the batch is full (to the adaptive cap); the
+        # oldest request has waited batch_wait_timeout_s, the longest it
+        # may be held; an earlier cut passed it over (it has had its
+        # linger; the device is idle); or no neighbour is due in time to
+        # be worth the wait (_HOLD_GAP_SHARE).  Until the replica has seen
+        # two admissions and one call there is no estimate, and the
+        # configured linger holds.
         while True:
             with self._lock:
                 depth = len(self._queue)
                 oldest = self._queue[0]
                 wait_s = self._wait_s
+                gap_s = self._gap_ewma_s
             waited = time.monotonic() - oldest.t_enqueue
-            if depth >= cap or waited >= wait_s or oldest.passed:
+            not_due = (gap_s is not None and call_ms > 0
+                       and gap_s * 1e3 > _HOLD_GAP_SHARE * call_ms)
+            cut = ("full" if depth >= cap else
+                   "waited" if waited >= wait_s else
+                   "passed" if oldest.passed else
+                   "not_due" if not_due else None)
+            if cut:
                 break
             time.sleep(min(0.0005, max(wait_s / 10.0, 1e-4)))
         if linger.live:
-            linger.set(depth=depth, cap=cap,
-                       oldest_wait_us=int(waited * 1e6))
+            linger.set(depth=depth, cap=cap, cut=cut,
+                       oldest_wait_us=int(waited * 1e6),
+                       gap_est_us=-1 if gap_s is None else int(gap_s * 1e6),
+                       call_est_us=int(call_ms * 1e3) if call_ms > 0 else -1)
         deadline_ms = float(_config.get("serve_queue_deadline_ms"))
         expired: List[_BatchSlot] = []
         with self._lock:
@@ -270,6 +329,9 @@ class _ReplicaBatcher:
             left = len(self._queue)
             if not left:
                 self._wakeup.clear()
+            self._cuts += 1
+            if cut == "not_due":
+                self._cuts_not_due += 1
         if linger.live:
             linger.set(left=left)
         return batch, expired, deadline_ms
@@ -351,6 +413,11 @@ class _ReplicaBatcher:
             s.error = tagged
             s.event.set()
 
+
+# what get_metrics() carries of a replica that does not batch
+_NO_BATCHES = dict.fromkeys((REPLICA_BATCH_SIZE_SUM, REPLICA_BATCH_PADDED_SUM,
+                             REPLICA_BATCH_CUTS, REPLICA_BATCH_CUTS_NOT_DUE),
+                            0)
 
 _init_gauge = None
 
@@ -518,8 +585,6 @@ class Replica:
         ex_counts, ex_sum = self._hist_execute.merged()
         batcher = self._batcher
         depth = batcher.depth() if batcher is not None else 0
-        size_sum, padded_sum = (batcher.fill_sums() if batcher is not None
-                                else (0, 0))
         with self._lock:
             ongoing = self._ongoing
             total = self._total
@@ -535,9 +600,9 @@ class Replica:
                 "queue_est_ms": pending * ewma,
                 "ewma_item_ms": ewma,
                 # real request sizes over the padded rectangles they ran
-                # in: the batches' fill, readable without a trace
-                REPLICA_BATCH_SIZE_SUM: size_sum,
-                REPLICA_BATCH_PADDED_SUM: padded_sum,
+                # in (the batches' fill) and the cuts, of them those made
+                # because no neighbour was due: readable without a trace
+                **(batcher.counts() if batcher is not None else _NO_BATCHES),
                 "perf": {
                     "bounds": list(perf.bucket_bounds()),
                     "queue_wait": {"counts": qw_counts, "sum_ms": qw_sum},

@@ -75,6 +75,8 @@ class DeploymentConfig:
     # then accept a LIST of requests and return a list of equal length.
     max_batch_size: int = 1
     # Max linger the oldest queued request waits for its batch to fill.
+    # A bound, not a wait: the replica holds a request only while a
+    # neighbour is due, by its own count of arrivals and call times.
     batch_wait_timeout_s: float = 0.005
     # Pad-to-bucket shapes: batches are padded (repeating the last item)
     # up to the next bucket so a jitted forward sees only these static

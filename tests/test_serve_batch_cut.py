@@ -1,11 +1,19 @@
-"""The replica batcher's cut: which queued requests share a call.
+"""The replica batcher's cut: which queued requests share a call, and when
+the batcher stops waiting for more.
 
-The rule is a pure function (``serve/batching.py`` ``cut_by_size``) and is
-tested as one, with no clock; then through a real ``Replica`` whose first
-call is held open while the queue is filled in a known order, so that what
-each later cut takes, leaves and reports is counted and not timed.
+Which: the rule is a pure function (``serve/batching.py`` ``cut_by_size``)
+and is tested as one, with no clock; then through a real ``Replica`` whose
+first call is held open while the queue is filled in a known order, so that
+what each later cut takes, leaves and reports is counted and not timed.
+
+When: the four reasons to cut (``cut`` on ``serve.batch.linger``: ``full``,
+``waited``, ``passed``, ``not_due``).  The last compares two estimates the
+replica keeps of its own traffic; the tests put them into the batcher's own
+fields, so that which reason fires does not hang on how fast the test's
+threads run.
 """
 
+import contextlib
 import threading
 
 import pytest
@@ -120,19 +128,55 @@ def _spans(name):
             if e["name"] == name]
 
 
-def run_held(requests, cap, buckets):
-    """Fill the queue with ``requests`` in order behind a held first call,
-    with a linger no request could sit out twice, then let the flusher cut:
-    the replies in the order of ``requests``, the deployment, the replica's
-    metrics and the batcher's spans (the ring is on)."""
+@contextlib.contextmanager
+def spans_on():
+    """The ring takes the batcher's spans while the block runs."""
     profiling = _config.get("profiling_enabled")
     _config.set("profiling_enabled", True)
     get_profiler().clear()
     observability.enable()
+    try:
+        yield
+    finally:
+        observability.disable()
+        _config.set("profiling_enabled", profiling)
+        get_profiler().clear()
+
+
+def _seen(replica):
+    """The replica's metrics and its batcher's spans so far."""
+    return {"metrics": replica.get_metrics(),
+            "linger": _spans("serve.batch.linger"),
+            "execute": _spans("serve.batch.execute")}
+
+
+def estimates(replica, gap_s, call_ms):
+    """Put the two estimates the fourth reason compares into the fields
+    the batcher keeps them in (``None`` / 0.0: none yet, as in a new
+    replica).  The first admission after this has no admission before it,
+    so it moves neither."""
+    with replica._batcher._lock:
+        replica._batcher._gap_ewma_s = gap_s
+    with replica._lock:
+        replica._ewma_item_ms = call_ms
+
+
+@spans_on()
+def run_held(requests, cap, buckets, gap_s=None, call_ms=0.0, bound_s=600.0,
+             aged_s=0.0, release_bound_s=None):
+    """Fill the queue with ``requests`` in order behind a held first call,
+    with a linger of ``bound_s`` (by default one no request could sit out
+    twice), then let the flusher cut: the replies in the order of
+    ``requests``, the deployment, the replica's metrics and the batcher's
+    spans (the ring is on).  ``gap_s`` and ``call_ms`` are the replica's
+    estimates before its first request; ``aged_s`` puts the queued
+    requests' admission that far back, as a call the host held would;
+    ``release_bound_s`` is a linger retuned just before the release."""
     held = held_deployment()
     replica = Replica("held", "held#1", held, (), {}, batch_config={
         "max_batch_size": cap, "batch_wait_timeout_s": 0.0,
         "pad_batch_to": buckets, "target_latency_ms": 1e9})
+    estimates(replica, gap_s, call_ms)
     replies = {}
 
     def call(key, item):
@@ -143,7 +187,7 @@ def run_held(requests, cap, buckets):
         threads[0].start()
         assert held.started.wait(WAIT_S)
         # from here on a fresh request would linger for minutes
-        replica.set_batch_config({"batch_wait_timeout_s": 600.0})
+        replica.set_batch_config({"batch_wait_timeout_s": bound_s})
         for k, item in enumerate(requests):
             threads.append(threading.Thread(target=call, args=(k, item)))
             threads[-1].start()
@@ -153,21 +197,23 @@ def run_held(requests, cap, buckets):
                     break
                 poll.wait(0.002)
             assert replica._batcher.depth() == k + 1
+        if aged_s:
+            with replica._batcher._lock:
+                for slot in replica._batcher._queue:
+                    slot.t_enqueue -= aged_s
+        if release_bound_s is not None:
+            replica.set_batch_config(
+                {"batch_wait_timeout_s": release_bound_s})
         held.release.set()
         for t in threads:
             t.join(WAIT_S)
         assert not any(t.is_alive() for t in threads)
-        metrics = replica.get_metrics()
-        spans = {"linger": _spans("serve.batch.linger"),
-                 "execute": _spans("serve.batch.execute")}
+        seen = _seen(replica)
     finally:
         held.release.set()
         replica.prepare_for_shutdown(timeout_s=WAIT_S)
-        observability.disable()
-        _config.set("profiling_enabled", profiling)
-        get_profiler().clear()
     return {"replies": [replies[k] for k in range(len(requests))],
-            "calls": held.calls, "metrics": metrics, **spans}
+            "calls": held.calls, **seen}
 
 
 @pytest.fixture(scope="module")
@@ -211,3 +257,108 @@ def test_scalars_and_dicts_are_cut_in_arrival_order():
     assert got["calls"] == [[1, 1], [1, 1, 1, 1], [1, 1]]
     assert got["replies"] == [(1, 4)] * 4 + [(1, 2)] * 2
     assert [s["left"] for s in got["linger"]] == [0, 2, 0]
+
+
+# -- when the batcher stops waiting ------------------------------------------
+
+SPARSE = {"gap_s": 1e3, "call_ms": 1.0}     # a neighbour is not due
+CLUMP = {"gap_s": 1e-9, "call_ms": 1e6}     # the next is due any moment
+SHORT = [0] * 100
+
+
+@spans_on()
+def run_alone(requests, bound_s, cap=4, buckets=(2, 4), gap_s=None,
+              call_ms=0.0):
+    """``requests`` one after the other, each answered before the next is
+    sent, through a replica with the given estimates and a linger of
+    ``bound_s``: what ``run_held`` returns."""
+    calls = []
+
+    def echo(items):
+        calls.append([item_size(x) for x in items])
+        return [(item_size(x), len(items)) for x in items]
+
+    replica = Replica("alone", "alone#1", echo, (), {}, batch_config={
+        "max_batch_size": cap, "batch_wait_timeout_s": bound_s,
+        "pad_batch_to": buckets, "target_latency_ms": 1e9})
+    estimates(replica, gap_s, call_ms)
+    try:
+        replies = [replica.handle_request("__call__", (item,), {})
+                   for item in requests]
+        seen = _seen(replica)
+    finally:
+        replica.prepare_for_shutdown(timeout_s=WAIT_S)
+    return {"replies": replies, "calls": calls, **seen}
+
+
+# (the driver, the requests, its other arguments) -> (the reason each cut
+# fired, the depth it found, the calls as the deployment saw them, rows of
+# padding included; the least the oldest request of the last cut had
+# waited, in seconds)
+CUTS = {
+    "sparse admissions are each cut not_due though the bound is 600 s":
+        ((run_alone, [SHORT] * 4, dict(bound_s=600.0, **SPARSE)),
+         ["not_due"] * 4, [1] * 4, [[100] * 2] * 4, None),
+    "what is queued when no neighbour is due goes in one call":
+        ((run_held, [SHORT] * 2, dict(cap=4, buckets=(2, 4), **SPARSE)),
+         ["waited", "not_due"], [1, 2], [[1, 1], [100] * 2], None),
+    "a clump shares one call inside the bound: full":
+        ((run_held, [SHORT] * 3, dict(cap=3, buckets=(2, 4), **CLUMP)),
+         ["waited", "full"], [1, 3], [[1, 1], [100] * 4], None),
+    "a clump short of the cap is cut when the bound is out: waited":
+        ((run_held, [SHORT] * 2, dict(cap=4, buckets=(2, 4),
+                                      release_bound_s=0.0, **CLUMP)),
+         ["waited", "waited"], [1, 2], [[1, 1], [100] * 2], None),
+    "a replica with no estimate yet holds for the configured bound":
+        ((run_alone, [SHORT], dict(bound_s=0.15)),
+         ["waited"], [1], [[100] * 2], 0.15),
+    "with a gap estimate and no call yet the bound holds":
+        ((run_alone, [SHORT], dict(bound_s=0.15, gap_s=1e3)),
+         ["waited"], [1], [[100] * 2], 0.15),
+    "with a call estimate and no second admission yet the bound holds":
+        ((run_alone, [SHORT], dict(bound_s=0.15, call_ms=1.0)),
+         ["waited"], [1], [[100] * 2], 0.15),
+    "a gap just over half the call is not worth the wait":
+        ((run_alone, [SHORT], dict(bound_s=600.0, gap_s=0.051,
+                                   call_ms=100.0)),
+         ["not_due"], [1], [[100] * 2], None),
+    "a gap just under half the call is, and the bound is never exceeded":
+        ((run_alone, [SHORT], dict(bound_s=0.15, gap_s=0.049,
+                                   call_ms=100.0)),
+         ["waited"], [1], [[100] * 2], 0.15),
+    "a request passed over is cut passed, without a second linger":
+        ((run_held, [[0] * 100, [0] * 2000, [0] * 120, [0] * 1900],
+          dict(cap=4, buckets=(2, 4), **CLUMP)),
+         ["waited", "full", "passed"], [1, 4, 2],
+         [[1, 1], [100, 120], [2000, 1900]], None),
+    "after a held first call the whole queue is cut without waiting":
+        ((run_held, [SHORT] * 3, dict(cap=8, buckets=B248, bound_s=0.05,
+                                      aged_s=1.0)),
+         ["waited", "waited"], [1, 3], [[1, 1], [100] * 4], 1.0),
+}
+
+
+@pytest.mark.parametrize("case", CUTS)
+def test_why_the_batcher_cut(case):
+    (run, requests, more), reasons, depths, calls, waited_s = CUTS[case]
+    got = run(requests, **more)
+    linger = got["linger"]
+    assert [s["cut"] for s in linger] == reasons
+    assert [s["depth"] for s in linger] == depths
+    assert got["calls"] == calls
+    # every request was answered, and nothing expired in the queue
+    assert [r[0] for r in got["replies"]] == [len(x) for x in requests]
+    # the two counts in get_metrics() are the spans' reasons, counted
+    assert got["metrics"][metric_names.REPLICA_BATCH_CUTS] == len(linger)
+    assert got["metrics"][metric_names.REPLICA_BATCH_CUTS_NOT_DUE] == \
+        reasons.count("not_due")
+    for s, reason in zip(linger, reasons):
+        if reason == "not_due":
+            # the estimates on the span are the two the rule compared
+            assert s["gap_est_us"] > 0.5 * s["call_est_us"] > 0
+    if waited_s is not None:
+        # held for the bound (or for the stall) and let go at the first
+        # look after it: WAIT_S is no limit of the batcher's, it is room
+        # for a machine busy with other tests
+        assert waited_s * 1e6 <= linger[-1]["oldest_wait_us"] \
+            < (waited_s + WAIT_S) * 1e6

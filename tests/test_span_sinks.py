@@ -452,7 +452,12 @@ def test_the_requests_spans_cross_three_threads_with_their_numbers(
     assert 0 <= call["mailbox_wait_us"] < 1_000_000
     assert linger["parent_span_id"] == call["span_id"]
     assert linger["depth"] == 1 and linger["cap"] == 4
-    assert linger["oldest_wait_us"] >= 20_000     # the 20 ms linger
+    # the replica's second request: the first came 20 ms and more before
+    # it and a call takes microseconds, so no neighbour is due and the
+    # 20 ms linger is a bound that is not waited out
+    assert linger["cut"] == "not_due"
+    assert linger["gap_est_us"] >= 20_000 > linger["call_est_us"] >= 0
+    assert linger["oldest_wait_us"] >= 0
     execute = one("serve.batch.execute")[1]
     assert (execute["n"], execute["padded_n"]) == (1, 2)
     assert execute["batch"] == 2                  # the second batch run
