@@ -38,6 +38,16 @@ rotary positions, Lightning linear attention with a per-head decay, an output
 norm over the joined heads, the gate). ``embed_scale``, ``residual_scale``
 and ``logit_scale`` are the muP factors of such a model; at 1 they emit
 nothing.
+
+A third kind, ``shortcut`` (LongCat-Flash), stands in a stack of its own
+kind and is forward only too: one layer holds two latent-attention blocks
+(MLA: low-rank q and k/v projections with their norms and scalings, heads of
+``nope_dim + rope_dim`` for q and k beside ``v_dim`` for v, interleaved
+rotary positions on the ``rope_dim`` part, which all heads share for k), two
+dense SwiGLU FFNs and a routed mixture of experts whose sum joins the stream
+one sub-layer late (``_shortcut_block``; sizes in ``LatentConfig`` and
+``parallel.expert.ExpertConfig``). The mixture is this device's share of an
+expert-parallel layer: ``parallel.expert.held_experts_apply``.
 """
 
 from __future__ import annotations
@@ -56,11 +66,28 @@ from jax.sharding import Mesh, PartitionSpec
 from ray_tpu.ops import flash_attention, linear_attention, sparse_attention
 from ray_tpu.ops.linear_attention import decay_rates
 from ray_tpu.ops.sparse_attention import SparseConfig
+from ray_tpu.parallel import expert
+from ray_tpu.parallel.expert import ExpertConfig
 from ray_tpu.parallel.sequence import ring_attention
 from ray_tpu.parallel.sharding import ShardingRules
 
 
-DENSE, SPARSE, LINEAR = "dense", "sparse", "linear"      # a layer's kind
+# a layer's kind
+DENSE, SPARSE, LINEAR, SHORTCUT = KINDS = ("dense", "sparse", "linear",
+                                           "shortcut")
+
+
+@dataclass(frozen=True)
+class LatentConfig:
+    """Latent attention's (MLA's) sizes: the ranks of the q and the k/v
+    bottlenecks, and a head's widths: q and k are ``nope_dim + rope_dim``
+    wide (the ``rope_dim`` part rotated, interleaved pairs, and for k shared
+    by every head), v is ``v_dim`` wide."""
+    q_rank: int
+    kv_rank: int
+    nope_dim: int
+    rope_dim: int
+    v_dim: int
 
 
 @dataclass(frozen=True)
@@ -102,20 +129,30 @@ class TransformerConfig:
     residual_scale: float = 1.0
     logit_scale: float = 1.0
     sparse: SparseConfig = SparseConfig()
+    # A ``SHORTCUT`` layer's attention and mixture of experts.
+    latent: Optional[LatentConfig] = None
+    experts: Optional[ExpertConfig] = None
 
     def __post_init__(self):
         kinds = self.layer_kinds
         if kinds is None:
             return
-        unknown = sorted(set(kinds) - {DENSE, SPARSE, LINEAR})
+        unknown = sorted(set(kinds) - set(KINDS))
         if unknown or len(kinds) != self.n_layers:
             raise ValueError(
                 f"layer_kinds {kinds}: {self.n_layers} layers, each one of "
-                f"{DENSE!r}, {SPARSE!r}, {LINEAR!r}")
-        if DENSE in kinds and set(kinds) != {DENSE}:
+                + ", ".join(repr(k) for k in KINDS))
+        for alone in (DENSE, SHORTCUT):
+            if alone in kinds and set(kinds) != {alone}:
+                raise ValueError(
+                    f"layer_kinds {kinds}: a {alone!r} layer stands in a "
+                    f"stack of its own kind only (its block has no "
+                    f"residual_scale)")
+        if SHORTCUT in kinds and (self.latent is None
+                                  or self.experts is None):
             raise ValueError(
-                f"layer_kinds {kinds}: a {DENSE!r} layer stands in a stack "
-                f"of its own kind only (its block has no residual_scale)")
+                f"layer_kinds {kinds}: a {SHORTCUT!r} layer needs latent= "
+                "(LatentConfig) and experts= (ExpertConfig)")
         if self.layer_ids is not None and len(self.layer_ids) != len(kinds):
             raise ValueError(f"layer_ids {self.layer_ids} for {kinds}")
 
@@ -150,7 +187,54 @@ def init_params(key: jax.Array, cfg: TransformerConfig) -> Dict[str, Any]:
     def dense(k, shape, fan_in):
         return jax.random.normal(k, shape, jnp.float32) / math.sqrt(fan_in)
 
+    def shortcut_layer(k):
+        """Two latent-attention blocks and two FFNs (leaves stacked [2, ...]
+        in the order they run), the router, and the held experts, each
+        drawn from a key folded with its *published* index: the shares of
+        different devices draw disjoint, consistent experts."""
+        a, e = cfg.latent, cfg.experts
+        qk = a.nope_dim + a.rope_dim
+
+        def attention(k):
+            # the up-projections draw at 1 / sqrt(d), not 1 / sqrt(rank):
+            # the forward multiplies what they read by sqrt(d / rank), so q,
+            # k and v come out at unit variance and the scores at order 1,
+            # as the dense block's do (at 1 / sqrt(rank) the scores' spread
+            # is 5.7, attention all but picks one key, and a rounding of
+            # 2^-8 grows 2.5 times a layer)
+            ks = jax.random.split(k, 5)
+            return {"wq_a": dense(ks[0], (d, a.q_rank), d),
+                    "q_norm": jnp.ones((a.q_rank,), jnp.float32),
+                    "wq_b": dense(ks[1], (a.q_rank, h, qk), d),
+                    "wkv_a": dense(ks[2], (d, a.kv_rank + a.rope_dim), d),
+                    "kv_norm": jnp.ones((a.kv_rank,), jnp.float32),
+                    "wkv_b": dense(ks[3], (a.kv_rank, h,
+                                           a.nope_dim + a.v_dim), d),
+                    "wo": dense(ks[4], (h, a.v_dim, d), h * a.v_dim)}
+
+        def ffn(k, width):
+            ks = jax.random.split(k, 3)
+            return {"wi": dense(ks[0], (d, width), d),       # gate
+                    "wg": dense(ks[1], (d, width), d),       # up
+                    "wo": dense(ks[2], (width, d), width)}
+
+        ks = jax.random.split(k, 4)
+        first, count = e.held
+        return {
+            "attn": jax.vmap(attention)(jax.random.split(ks[0], 2)),
+            "mlp": jax.vmap(functools.partial(ffn, width=f))(
+                jax.random.split(ks[1], 2)),
+            "router": dense(ks[2], (d, e.n_outputs), d),
+            "experts": jax.vmap(lambda i: ffn(jax.random.fold_in(ks[3], i),
+                                              e.width))(
+                first + jnp.arange(count)),
+            "ln_attn": jnp.ones((2, d), jnp.float32),
+            "ln_mlp": jnp.ones((2, d), jnp.float32),
+        }
+
     def layer(k, kind=DENSE):
+        if kind == SHORTCUT:
+            return shortcut_layer(k)
         ks = jax.random.split(k, 7)
         post = ({"ln1_post": jnp.ones((d,), jnp.float32),
                  "ln2_post": jnp.ones((d,), jnp.float32)}
@@ -233,6 +317,27 @@ def logical_axes(cfg: TransformerConfig) -> Dict[str, Any]:
         blk.update(ln1_post=("layers", None), ln2_post=("layers", None))
     if cfg.mixed:
         def of_kind(kind):
+            if kind == SHORTCUT:
+                # forward only and on one device: only the layers' axis is
+                # named (the two sub-blocks' axis and the experts' are not)
+                return {
+                    "attn": {"wq_a": ("layers", None, "embed", None),
+                             "q_norm": ("layers", None, None),
+                             "wq_b": ("layers", None, None, "heads", "kv"),
+                             "wkv_a": ("layers", None, "embed", None),
+                             "kv_norm": ("layers", None, None),
+                             "wkv_b": ("layers", None, None, "heads", "kv"),
+                             "wo": ("layers", None, "heads", "kv", "embed")},
+                    "mlp": {"wi": ("layers", None, "embed", "mlp"),
+                            "wg": ("layers", None, "embed", "mlp"),
+                            "wo": ("layers", None, "mlp", "embed")},
+                    "router": ("layers", "embed", None),
+                    "experts": {"wi": ("layers", None, "embed", "mlp"),
+                                "wg": ("layers", None, "embed", "mlp"),
+                                "wo": ("layers", None, "mlp", "embed")},
+                    "ln_attn": ("layers", None, None),
+                    "ln_mlp": ("layers", None, None),
+                }
             extra = {"wg": ("layers", "embed", "heads", "kv"),
                      "q_norm": ("layers", None), "k_norm": ("layers", None)}
             if kind == LINEAR:
@@ -256,18 +361,34 @@ def _rmsnorm(x: jax.Array, w: jax.Array, eps: float) -> jax.Array:
     return (xf * jax.lax.rsqrt(var + eps)).astype(x.dtype) * w.astype(x.dtype)
 
 
-def _rope(x: jax.Array, theta: float, positions: jax.Array) -> jax.Array:
-    """x: [B, L, H, D]; rotate pairs along D."""
-    d = x.shape[-1]
-    half = d // 2
+def _rope_cos_sin(half: int, theta: float, positions: jax.Array):
+    """cos and sin of position times ``theta ** (-i / half)``, [B, L, 1,
+    half] float32."""
     freqs = jnp.exp(-jnp.arange(0, half, dtype=jnp.float32)
                     * (math.log(theta) / half))
     angles = positions[:, :, None, None].astype(jnp.float32) * freqs  # B L 1 half
-    cos, sin = jnp.cos(angles), jnp.sin(angles)
+    return jnp.cos(angles), jnp.sin(angles)
+
+
+def _rope(x: jax.Array, theta: float, positions: jax.Array) -> jax.Array:
+    """x: [B, L, H, D]; rotate pairs along D."""
+    half = x.shape[-1] // 2
+    cos, sin = _rope_cos_sin(half, theta, positions)
     x1, x2 = x[..., :half], x[..., half:]
     rotated = jnp.concatenate(
         [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return rotated.astype(x.dtype)
+
+
+def _rope_interleaved(x: jax.Array, theta: float, positions: jax.Array
+                      ) -> jax.Array:
+    """x: [B, L, H, D]; rotate the neighbouring pairs (2i, 2i + 1) of D."""
+    half = x.shape[-1] // 2
+    cos, sin = _rope_cos_sin(half, theta, positions)
+    pairs = x.reshape(*x.shape[:-1], half, 2)
+    x1, x2 = pairs[..., 0], pairs[..., 1]
+    rotated = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+    return rotated.reshape(x.shape).astype(x.dtype)
 
 
 @functools.lru_cache(maxsize=128)
@@ -375,6 +496,75 @@ def _mixed_block(layer, x, positions, cfg: TransformerConfig, kind: str):
     return x + cfg.residual_scale * out
 
 
+def _latent_attention(params, h, positions, cfg: TransformerConfig):
+    """MLA on the normed states ``h`` [B, L, d]: ``c_q = N(h W_qa)``, ``q =
+    c_q W_qb * sqrt(d / q_rank)`` as heads of ``nope_dim + rope_dim``;
+    ``[c_kv | k_r] = h W_kva``, ``c_kv = N(c_kv) * sqrt(d / kv_rank)``,
+    ``[k_n | v] = c_kv W_kvb`` as heads of ``nope_dim | v_dim``; rotary
+    positions on q's last ``rope_dim`` and on ``k_r``, which every head
+    shares; causal softmax attention at ``(nope_dim + rope_dim) ** -0.5``
+    with v (and o) at their own width; ``W_o``. Prefill expands the latent
+    to per-head K and V, as the published forward does."""
+    a = cfg.latent
+    norm = functools.partial(_rmsnorm, eps=cfg.norm_eps)
+    w = {k: p.astype(h.dtype) for k, p in params.items()}
+    c_q = norm(jnp.einsum("bld,dr->blr", h, w["wq_a"]), w["q_norm"])
+    q = jnp.einsum("blr,rhk->blhk", c_q, w["wq_b"]) \
+        * math.sqrt(cfg.d_model / a.q_rank)
+    kv = jnp.einsum("bld,dr->blr", h, w["wkv_a"])
+    c_kv = norm(kv[..., :a.kv_rank], w["kv_norm"]) \
+        * math.sqrt(cfg.d_model / a.kv_rank)
+    k_v = jnp.einsum("blr,rhk->blhk", c_kv, w["wkv_b"])
+    k_r = _rope_interleaved(kv[..., None, a.kv_rank:], cfg.rope_theta,
+                            positions)
+    q = jnp.concatenate(
+        [q[..., :a.nope_dim],
+         _rope_interleaved(q[..., a.nope_dim:], cfg.rope_theta, positions)],
+        axis=-1)
+    k = jnp.concatenate(
+        [k_v[..., :a.nope_dim],
+         jnp.broadcast_to(k_r, (*k_v.shape[:3], a.rope_dim))], axis=-1)
+    o = _attention(q, k, k_v[..., a.nope_dim:], cfg, None)
+    return jnp.einsum("blhk,hkd->bld", o, w["wo"])
+
+
+def _shortcut_block(params, x, positions, cfg: TransformerConfig):
+    """One ``SHORTCUT`` layer: ``h = x + MLA_0(N(x))``; ``u = N(h)``; ``s =
+    MoE(u)``; ``h = h + FFN_0(u)``; ``h = h + MLA_1(N(h))``; ``y = h +
+    FFN_1(N(h)) + s``. The experts' sum joins the stream one sub-layer late
+    (in a deployment its exchange overlaps the second attention). Returns
+    the states and the mixture's load (``expert.held_experts_apply``)."""
+    B, L, d = x.shape
+    norm = functools.partial(_rmsnorm, eps=cfg.norm_eps)
+
+    def part(name, i):
+        return jax.tree.map(lambda p: p[i], params[name])
+
+    h = x + _latent_attention(part("attn", 0),
+                              norm(x, params["ln_attn"][0]), positions, cfg)
+    u = norm(h, params["ln_mlp"][0])
+    s, load = expert.held_experts_apply(
+        u.reshape(B * L, d), params["router"], params["experts"],
+        cfg.experts)
+    h = h + _mlp(part("mlp", 0), u)
+    h = h + _latent_attention(part("attn", 1),
+                              norm(h, params["ln_attn"][1]), positions, cfg)
+    y = h + _mlp(part("mlp", 1), norm(h, params["ln_mlp"][1]))
+    return y + s.reshape(B, L, d), load
+
+
+def _apply_shortcut(blocks, x, positions, cfg: TransformerConfig):
+    """The scan over a stack of ``SHORTCUT`` layers; the layers' loads go
+    to the program's counters in one call-back a forward."""
+    fn = functools.partial(_shortcut_block, cfg=cfg)
+    if cfg.remat:
+        fn = jax.checkpoint(fn)
+    x, loads = jax.lax.scan(lambda x, layer: fn(layer, x, positions), x,
+                            blocks)
+    expert.record_load(loads, cfg.experts)
+    return x
+
+
 def _apply_mixed(blocks, x, positions, cfg: TransformerConfig, mesh):
     """``blocks[kind]`` stacked over that kind's layers: each run of
     neighbouring layers of one kind is one scan, the runs in the published
@@ -382,6 +572,8 @@ def _apply_mixed(blocks, x, positions, cfg: TransformerConfig, mesh):
     if mesh is not None:
         raise ValueError(f"layer kinds {sorted(set(cfg.kinds))} run on one "
                          "device only: no mesh")
+    if SHORTCUT in cfg.kinds:       # a stack of its own kind
+        return _apply_shortcut(blocks[SHORTCUT], x, positions, cfg)
     ids = cfg.layer_ids or tuple(range(cfg.n_layers))
     taken = dict.fromkeys(cfg.kinds, 0)
     at = 0

@@ -111,6 +111,11 @@ SPANS = frozenset({
     "checkpoint.write",
     "checkpoint.gather",
     "checkpoint.commit",
+    # parallel/expert.py: one a forward, from the host side of the expert
+    # layer's call-back; held, absent, zero (routed pairs by where the
+    # expert lives), load_max (the most-loaded held expert's pairs, summed
+    # over the layers), layers, experts: the counters' increments
+    "moe.route",
 })
 
 # The gauge a replica sets once, when its constructor returns.

@@ -33,6 +33,11 @@ GQA. K and V keep their own head count: query head ``h`` reads K/V head
 ``h // (H // KVH)`` through the index map, and dk/dv runs its grid over the
 K/V heads, accumulating the group's query heads in scratch before one write.
 
+Head widths. q and k share one width and v (and with it o) may have another
+(latent attention: q and k of 192, v of 128), forward only: v is never padded
+to q's width in HBM, the accumulator and the output tile are v's width, and
+the backward refuses unequal widths by name.
+
 Runs in interpreter mode only where the backend is ``cpu`` (the CPU test
 mesh exercises the same code path); on any other backend the Mosaic kernel
 compiles or the call fails. A Mosaic kernel cannot be partitioned by XLA:
@@ -205,7 +210,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
                 *, scale: float, causal: bool, block_q: int, block_k: int,
                 tiles: int, seq_k: int):
     iq, jk = pl.program_id(1), pl.program_id(2)
-    D = q_ref.shape[-1]
+    Dv = v_ref.shape[-1]           # the accumulator's and the output's width
 
     @pl.when(jk == 0)
     def _init():
@@ -223,7 +228,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
         rows = pl.ds(pl.multiple_of((t - lo) * block_k, block_k), block_k)
         q = q_ref[0]                                          # [bq, d]
         k = k_ref[0, rows, :]                                 # [bk, d]
-        v = _zero_pad_rows(v_ref[0, rows, :], col0, ragged_k)
+        v = _zero_pad_rows(v_ref[0, rows, :], col0, ragged_k)  # [bk, dv]
         s = _dot(q, k, _NT) * scale                           # [bq, bk]
         s = _masked(s, 0, row0, col0, causal, None, ragged_k)
         # Every row meets an attended column in the first tile it walks
@@ -235,7 +240,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
         p = jnp.exp(s - _lanes(m_new, block_k))               # [bq, bk]
         l_scr[...] = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
         m_scr[...] = m_new
-        acc_scr[...] = (acc_scr[...] * _lanes(alpha, D)
+        acc_scr[...] = (acc_scr[...] * _lanes(alpha, Dv)
                         + _dot(p.astype(v.dtype), v, _NN))
 
     _walk(lo, hi, tile)
@@ -243,7 +248,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
     @pl.when(jk == pl.num_programs(2) - 1)
     def _finalize():
         l = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0] = (acc_scr[...] * _lanes(1.0 / l, D)).astype(o_ref.dtype)
+        o_ref[0] = (acc_scr[...] * _lanes(1.0 / l, Dv)).astype(o_ref.dtype)
         # lse is stored compact [BH, Lq, 1]: same column orientation as the
         # scratch stats, single lane (Mosaic allows full-dim lane blocks).
         lse_ref[0] = m_scr[:, :1] + jnp.log(l[:, :1])         # [bq, 1]
@@ -252,7 +257,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
 def _query_stationary(blocks: _Blocks, q, k, causal: bool):
     """The layout the forward and dq share: a query tile stays, ``major``
     rows of K and V stream past it. Returns the kernels' tile arguments, the
-    VMEM estimate, the grid and the specs of a q-shaped and a K/V operand."""
+    VMEM estimate, the grid and the makers of a q-shaped and a K/V operand's
+    spec at a head width (q's and k's, or v's and o's)."""
     BH, Lq, D = q.shape
     Lk = k.shape[1]
     group = BH // k.shape[0]
@@ -266,11 +272,16 @@ def _query_stationary(blocks: _Blocks, q, k, causal: bool):
             return nk - 1
         return jnp.minimum(((i + 1) * block_q - 1) // major, nk - 1)
 
-    q_spec = pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0))
+    def q_spec(width):
+        return pl.BlockSpec((1, block_q, width), lambda b, i, j: (b, i, 0))
+
     # Query head b reads K/V head b // group; a step past the diagonal
     # names the block already held, so the pipeline copies nothing for it.
-    kv_spec = pl.BlockSpec(
-        (1, major, D), lambda b, i, j: (b // group, jnp.minimum(j, last(i)), 0))
+    def kv_spec(width):
+        return pl.BlockSpec(
+            (1, major, width),
+            lambda b, i, j: (b // group, jnp.minimum(j, last(i)), 0))
+
     args = dict(causal=causal, block_q=block_q, block_k=block_k, tiles=tiles,
                 seq_k=Lk)
     return args, vmem, (BH, nq, nk), q_spec, kv_spec
@@ -278,24 +289,25 @@ def _query_stationary(blocks: _Blocks, q, k, causal: bool):
 
 def _flash_fwd(q, k, v, scale, causal, blocks, interpret):
     BH, Lq, D = q.shape
+    Dv = v.shape[-1]
     args, vmem, grid, q_spec, kv_spec = _query_stationary(blocks, q, k, causal)
     block_q = args["block_q"]
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, **args),
         grid=grid,
-        in_specs=[q_spec, kv_spec, kv_spec],
+        in_specs=[q_spec(D), kv_spec(D), kv_spec(Dv)],
         out_specs=[
-            q_spec,
+            q_spec(Dv),
             pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((BH, Lq, D), q.dtype),
+            jax.ShapeDtypeStruct((BH, Lq, Dv), q.dtype),
             jax.ShapeDtypeStruct((BH, Lq, 1), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, _LANES), jnp.float32),
             pltpu.VMEM((block_q, _LANES), jnp.float32),
-            pltpu.VMEM((block_q, D), jnp.float32),
+            pltpu.VMEM((block_q, Dv), jnp.float32),
         ],
         compiler_params=_compiler_params(vmem, 3),
         interpret=interpret,
@@ -392,6 +404,11 @@ def _flash_bwd(scale, causal, blocks, interpret, residuals, g):
     q, k, v, out, lse = residuals
     do = g
     BH, Lq, D = q.shape
+    if v.shape[-1] != D:
+        raise NotImplementedError(
+            f"flash_attention has no backward pass for unequal head widths "
+            f"(q and k of {D}, v of {v.shape[-1]}): such attention is "
+            "forward only")
     BKV, Lk, _ = k.shape
     group = BH // BKV
     itemsize = q.dtype.itemsize
@@ -399,6 +416,7 @@ def _flash_bwd(scale, causal, blocks, interpret, residuals, g):
                     axis=-1)                                   # [BH, Lq]
 
     args, vmem, grid, q_spec, kv_spec = _query_stationary(blocks, q, k, causal)
+    q_spec, kv_spec = q_spec(D), kv_spec(D)
     row_spec = pl.BlockSpec((1, args["block_q"], 1),
                             lambda b, i, j: (b, i, 0))
     dq = pl.pallas_call(
@@ -480,29 +498,33 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     block_k: Optional[int] = None,
                     block_major: Optional[int] = None,
                     interpret: Optional[bool] = None) -> jax.Array:
-    """Flash attention. q: [batch, seqlen, heads, head_dim]; k and v:
-    [batch, seqlen_k, kv_heads, head_dim] with ``heads`` a multiple of
-    ``kv_heads`` (query head h attends K/V head ``h // (heads // kv_heads)``).
+    """Flash attention. q: [batch, seqlen, heads, head_dim]; k:
+    [batch, seqlen_k, kv_heads, head_dim] and v: [batch, seqlen_k, kv_heads,
+    v_dim] with ``heads`` a multiple of ``kv_heads`` (query head h attends
+    K/V head ``h // (heads // kv_heads)``).
 
-    Returns [batch, seqlen, heads, head_dim]. Differentiable (custom VJP).
+    Returns [batch, seqlen, heads, v_dim]. Differentiable (custom VJP) where
+    ``v_dim`` is ``head_dim``; any other ``v_dim`` is forward only.
     ``block_q x block_k`` is the score tile; ``block_major`` is how many rows
     of the streamed side (K/V in the forward and dq, q/dO in dk/dv) a grid
     step holds in VMEM. ``None`` = chosen from the shape.
     """
     B, Lq, H, D = q.shape
     Lk, KVH = k.shape[1], k.shape[2]
-    if H % KVH or v.shape != k.shape:
+    if H % KVH or k.shape[-1] != D or v.shape[:3] != k.shape[:3]:
         raise ValueError(
-            f"flash_attention: {H} query heads over K {k.shape} / V {v.shape}:"
-            " K and V must agree and their heads divide the query's")
+            f"flash_attention: q {q.shape} over K {k.shape} / V {v.shape}: K "
+            "has q's head width, V has K's batch, length and heads (its head "
+            "width is its own) and their heads divide the query's")
+    Dv = v.shape[-1]
     scale = scale if scale is not None else 1.0 / math.sqrt(D)
     if interpret is None:
         interpret = _backend_is_cpu()
     # [B, L, H, D] -> [B*H, L, D]
     qb = q.transpose(0, 2, 1, 3).reshape(B * H, Lq, D)
     kb = k.transpose(0, 2, 1, 3).reshape(B * KVH, Lk, D)
-    vb = v.transpose(0, 2, 1, 3).reshape(B * KVH, Lk, D)
+    vb = v.transpose(0, 2, 1, 3).reshape(B * KVH, Lk, Dv)
     out = _flash_attention_bhld(qb, kb, vb, scale, causal,
                                 _Blocks(block_q, block_k, block_major),
                                 interpret)
-    return out.reshape(B, H, Lq, D).transpose(0, 2, 1, 3)
+    return out.reshape(B, H, Lq, Dv).transpose(0, 2, 1, 3)

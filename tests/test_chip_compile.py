@@ -290,3 +290,42 @@ def test_the_operations_check_compiles_beside_the_references_weights(
     for call in ("sparse_attn_scores", "sparse_attn_fwd", "linear_attn_fwd"):
         assert call in text
     assert compiled.memory_analysis().temp_size_in_bytes < 3.2 * 10 ** 9
+
+
+# -- the shortcut layer (LongCat-Flash): latent attention's head widths -------------
+
+
+@pytest.mark.parametrize("batch,length", [(1, 8192), (2, 2048)])
+def test_flash_kernel_compiles_at_unequal_head_widths(topo, mosaic, batch,
+                                                      length):
+    """q and k heads of 192 beside v heads of 128, 64 heads, forward only:
+    the prefill cell's calls at its largest and smallest bucket."""
+    from ray_tpu.ops import flash_attention
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    qk = jax.ShapeDtypeStruct((batch, length, 64, 192), jnp.bfloat16,
+                              sharding=one_chip)
+    v = jax.ShapeDtypeStruct((batch, length, 64, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    compiled = jax.jit(flash_attention).lower(qk, qk, v).compile()
+    assert "flash_fwd" in compiled.as_text()
+    assert compiled.output_shardings is not None
+
+
+def test_shortcut_layer_serve_forward_compiles(topo, mosaic):
+    """The prefill cell's forward at its published widths, one layer of the
+    four: both kernels are in it, the flash call and the ragged product the
+    compiler makes of ``lax.ragged_dot``."""
+    from benchmark import manifest
+    cell = manifest.Manifest().cell("longcat-flash-serve-prefill")
+    adapter = manifest.adapter(cell.config)
+    dims = {**adapter.dims(cell.config, cell.job, cell.chips), "n_layers": 1}
+    cfg = adapter.program_config(dims, 2048, cell.deploy["model"])
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    params = jax.eval_shape(lambda: jax.tree.map(
+        lambda p: p.astype(cfg.dtype),
+        transformer.init_params(jax.random.PRNGKey(0), cfg)))
+    params = jax.tree.map(lambda leaf: _shape(leaf, one_chip), params)
+    tokens = jax.ShapeDtypeStruct((1, 2048), jnp.int32, sharding=one_chip)
+    text = jax.jit(lambda p, t: transformer.apply(p, t, cfg)).lower(
+        params, tokens).compile().as_text()
+    assert "flash_fwd" in text and "ragged-dot" in text

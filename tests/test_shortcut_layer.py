@@ -1,0 +1,361 @@
+"""The ``shortcut`` layer kind (LongCat-Flash: two latent-attention blocks,
+two FFNs and a routed mixture with zero-compute experts a layer), the flash
+kernel at unequal head widths, and the expert layer that is told which
+experts it holds: at tiny sizes on the CPU, the kernel in interpreter mode,
+against ``benchmark/longcat_reference.py`` and plain einsums."""
+
+import dataclasses
+import math
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark import longcat_reference
+from ray_tpu.models import transformer
+from ray_tpu.models.transformer import (KINDS, SHORTCUT, LatentConfig,
+                                        TransformerConfig)
+from ray_tpu.ops import flash_attention
+from ray_tpu.parallel import expert
+from ray_tpu.parallel.expert import ExpertConfig, held_experts_apply
+from ray_tpu.train.step import make_lm_train_step
+from test_mixed_stack import _digest
+
+# d 64, 4 heads of 16 + 8 / 16, ranks 32 / 16, 16 routed + 8 zero experts,
+# top-4, 2 layers: every ratio of the published layer at a sixteenth or so
+LATENT = LatentConfig(q_rank=32, kv_rank=16, nope_dim=16, rope_dim=8,
+                      v_dim=16)
+EXPERTS = ExpertConfig(n_routed=16, n_zero=8, top_k=4, scale=6.0, width=32,
+                       held=(0, 16))
+TINY = TransformerConfig(
+    vocab_size=96, d_model=64, n_layers=2, n_heads=4, d_ff=96,
+    max_seq_len=64, dtype=jnp.float32, use_flash=False, remat=False,
+    rope_theta=1e7, norm_eps=1e-5, layer_kinds=(SHORTCUT,) * 2,
+    latent=LATENT, experts=EXPERTS)
+TINY_DIMS = {
+    "vocab_size": 96, "d_model": 64, "n_layers": 2, "n_heads": 4, "d_ff": 96,
+    "rope_theta": 1e7, "rms_norm_eps": 1e-5, "q_rank": 32, "kv_rank": 16,
+    "nope_dim": 16, "rope_dim": 8, "v_dim": 16, "n_routed": 16, "n_zero": 8,
+    "top_k": 4, "scale": 6.0, "expert_width": 32, "held": [0, 16]}
+
+
+def held(cfg, first, count):
+    return dataclasses.replace(cfg, experts=dataclasses.replace(
+        cfg.experts, held=(first, count)))
+
+
+@pytest.fixture(scope="module")
+def params():
+    """Seeded weights, every norm weight moved off its initial 1 so that a
+    norm left out, or its weight, shows."""
+    params = transformer.init_params(jax.random.PRNGKey(41), TINY)
+    leaves, tree = jax.tree_util.tree_flatten_with_path(params)
+    keys = jax.random.split(jax.random.PRNGKey(42), len(leaves))
+    moved = [p * (1 + 0.3 * jax.random.normal(k, p.shape))
+             if "norm" in jax.tree_util.keystr(path)
+             or "ln" in jax.tree_util.keystr(path) else p
+             for (path, p), k in zip(leaves, keys)]
+    return jax.tree.unflatten(tree, moved)
+
+
+def _last_logits(params, tokens, last, cfg):
+    x = transformer.backbone(params, tokens, cfg)
+    x = jnp.take_along_axis(x, last[:, None, None], axis=1)
+    return transformer.head(params, x, cfg)[:, 0]
+
+
+# -- the kernel at unequal head widths -----------------------------------------------
+
+
+def _einsum_attention(q, k, v):
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / math.sqrt(q.shape[-1])
+    mask = jnp.tril(jnp.ones((q.shape[1], k.shape[1]), bool))
+    p = jax.nn.softmax(jnp.where(mask[None, None], s, -jnp.inf), axis=-1)
+    return jnp.einsum("bhqk,bkhd->bqhd", p, v)
+
+
+@pytest.mark.parametrize("length,kv_heads", [(200, 4), (128, 4), (333, 2)])
+def test_flash_kernel_at_24_16_head_widths_against_an_einsum(length,
+                                                             kv_heads):
+    """q and k heads of 24, v heads of 16, causal, a ragged last tile."""
+    keys = jax.random.split(jax.random.PRNGKey(length), 3)
+    q = jax.random.normal(keys[0], (2, length, 4, 24))
+    k = jax.random.normal(keys[1], (2, length, kv_heads, 24))
+    v = jax.random.normal(keys[2], (2, length, kv_heads, 16))
+    got = flash_attention(q, k, v, causal=True, block_q=128, block_k=128)
+    rep = 4 // kv_heads
+    want = _einsum_attention(q, jnp.repeat(k, rep, 2), jnp.repeat(v, rep, 2))
+    assert got.shape == (2, length, 4, 16)
+    np.testing.assert_allclose(got, want, atol=2e-5)
+
+
+def test_flash_backward_refuses_unequal_widths_by_name():
+    q = jnp.ones((1, 16, 2, 24))
+    v = jnp.ones((1, 16, 2, 16))
+    with pytest.raises(NotImplementedError, match="unequal head widths"):
+        jax.grad(lambda q: flash_attention(q, q, v).sum())(q)
+
+
+def test_flash_shape_error_names_the_widths_it_takes():
+    q = jnp.ones((1, 16, 2, 24))
+    with pytest.raises(ValueError, match="head width is its own"):
+        flash_attention(q, jnp.ones((1, 16, 2, 16)), jnp.ones((1, 16, 2, 16)))
+    with pytest.raises(ValueError, match="head width is its own"):
+        flash_attention(q, q, jnp.ones((1, 8, 2, 16)))
+
+
+def test_flash_at_equal_head_widths_traces_what_the_parent_commit_traced():
+    """Every accepted cell calls the kernel with v as wide as q and k: the
+    forward's and the backward's jaxprs are the parent commit's, to the
+    letter (read off commit cb00583 by this function)."""
+    q = jax.ShapeDtypeStruct((2, 200, 4, 16), jnp.bfloat16)
+    k = jax.ShapeDtypeStruct((2, 200, 2, 16), jnp.bfloat16)
+
+    def attend(q, k, v):
+        return flash_attention(q, k, v, causal=True)
+
+    def loss(q, k, v):
+        return attend(q, k, v).astype(jnp.float32).sum()
+
+    assert (_digest(attend, q, k, k),
+            _digest(jax.grad(loss, argnums=(0, 1, 2)), q, k, k)) == (
+        "56ae453cd98670d3", "46ccfd123954c22c")
+
+
+# -- the expert layer ----------------------------------------------------------------
+
+
+def _mixture_weights(seed=0, d=64, cfg=EXPERTS):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    count = cfg.held[1]
+    return (jax.random.normal(ks[0], (d, cfg.n_outputs)),
+            {"wi": jax.random.normal(ks[1], (count, d, cfg.width)) / 8,
+             "wg": jax.random.normal(ks[2], (count, d, cfg.width)) / 8,
+             "wo": jax.random.normal(ks[3], (count, cfg.width, d)) / 6})
+
+
+def _every_expert_on_every_token(u, router, experts, cfg):
+    """The plain sum: no sort, no groups."""
+    idx, w = expert.route(u, router, cfg)
+    out = jnp.sum(jnp.where(idx >= cfg.n_routed, w, 0), -1)[:, None] * u
+    for e in range(cfg.held[1]):
+        mine = jnp.sum(jnp.where(idx == cfg.held[0] + e, w, 0), -1)
+        y = (jax.nn.silu(u @ experts["wi"][e]) * (u @ experts["wg"][e])) \
+            @ experts["wo"][e]
+        out = out + mine[:, None] * y
+    return out
+
+
+@pytest.mark.parametrize("first,count,rows", [(0, 16, 1024), (4, 4, 16),
+                                              (12, 4, 7)])
+def test_held_experts_part_is_the_plain_sum(monkeypatch, first, count, rows):
+    """Whatever the share held and however many steps the dropless loop
+    takes (``rows`` pairs a step)."""
+    monkeypatch.setattr(expert, "CHUNK_ROWS", rows)
+    cfg = dataclasses.replace(EXPERTS, held=(first, count))
+    router, experts = _mixture_weights(1, cfg=cfg)
+    u = jax.random.normal(jax.random.PRNGKey(2), (50, 64))
+    got, load = held_experts_apply(u, router, experts, cfg)
+    want = _every_expert_on_every_token(u, router, experts, cfg)
+    np.testing.assert_allclose(got, want, atol=2e-5)
+    idx, _ = expert.route(u, router, cfg)
+    n_held = int(jnp.sum((idx >= first) & (idx < first + count)))
+    n_zero = int(jnp.sum(idx >= cfg.n_routed))
+    assert load.tolist()[:3] == [n_held, 200 - n_held - n_zero, n_zero]
+    assert load[3] == max(int(jnp.sum(idx == first + e))
+                          for e in range(count))
+
+
+def _forced_router(chosen, cfg=EXPERTS, d=64):
+    """A router whose every token picks exactly ``chosen`` (by a large
+    logit on a constant feature) and tokens that carry that feature."""
+    router = np.zeros((d, cfg.n_outputs), np.float32)
+    router[0, list(chosen)] = 50.0 + np.arange(len(chosen))
+    u = np.array(jax.random.normal(jax.random.PRNGKey(3), (40, d)))
+    u[:, 0] = 1.0
+    return jnp.asarray(router), jnp.asarray(u)
+
+
+@pytest.mark.parametrize("rows", [1024, 16])
+def test_dropless_when_every_token_goes_to_one_held_expert(monkeypatch,
+                                                           rows):
+    """The worst imbalance: held expert 5 is handed every token (and three
+    absent experts the rest of the picks), the other three held experts
+    none; no capacity, nothing dropped."""
+    monkeypatch.setattr(expert, "CHUNK_ROWS", rows)
+    cfg = dataclasses.replace(EXPERTS, held=(4, 4))
+    router, u = _forced_router((5, 0, 1, 2))
+    _, experts = _mixture_weights(4, cfg=cfg)
+    got, load = held_experts_apply(u, router, experts, cfg)
+    np.testing.assert_allclose(
+        got, _every_expert_on_every_token(u, router, experts, cfg),
+        rtol=1e-5, atol=1e-5)
+    assert load.tolist() == [40, 120, 0, 40]
+    assert float(jnp.abs(got).max()) > 0
+
+
+def test_no_token_for_any_held_expert_gives_the_zero_compute_part_alone():
+    cfg = dataclasses.replace(EXPERTS, held=(4, 4))
+    router, u = _forced_router((0, 1, 2, 17))
+    _, experts = _mixture_weights(5, cfg=cfg)
+    got, load = held_experts_apply(u, router, experts, cfg)
+    _, w = expert.route(u, router, cfg)
+    # index 17 has the largest logit: top_k lists it first
+    np.testing.assert_allclose(got, w[:, :1] * u, rtol=1e-6)
+    assert load.tolist() == [0, 120, 40, 0]
+
+
+def test_a_router_forced_onto_zero_compute_indices_returns_sum_w_times_u():
+    router, u = _forced_router((16, 18, 20, 23))
+    _, experts = _mixture_weights(6)
+    got, load = held_experts_apply(u, router, experts, EXPERTS)
+    _, w = expert.route(u, router, EXPERTS)
+    np.testing.assert_allclose(got, jnp.sum(w, -1, keepdims=True) * u,
+                               rtol=1e-6)
+    np.testing.assert_allclose(jnp.sum(w, -1), 6.0, rtol=1e-5)  # 6 * sum p
+    assert load.tolist() == [0, 0, 160, 0]
+
+
+def test_the_router_is_float32_whatever_the_operands_are():
+    router, _ = _mixture_weights(7)
+    u = jax.random.normal(jax.random.PRNGKey(8), (30, 64))
+    idx, w = expert.route(u.astype(jnp.bfloat16), router, EXPERTS)
+    assert w.dtype == jnp.float32
+    # the operands' bfloat16 values, multiplied and summed in float32
+    exact = jax.nn.softmax(
+        u.astype(jnp.bfloat16).astype(jnp.float32)
+        @ router.astype(jnp.bfloat16).astype(jnp.float32), axis=-1)
+    p, want = jax.lax.top_k(exact, 4)
+    np.testing.assert_array_equal(idx, want)
+    np.testing.assert_allclose(w, 6.0 * p, rtol=1e-5)
+
+
+def test_the_layers_counters_are_in_the_registry_after_a_forward(params):
+    from ray_tpu.util import metrics
+
+    def held_pairs():
+        return sum(v for f in metrics.snapshot()
+                   if f["name"] == "moe_routed_pairs_total"
+                   for _, tags, v in f["samples"]
+                   if dict(map(tuple, tags)).get("dest") == "held")
+
+    before = held_pairs()
+    tokens = jax.random.randint(jax.random.PRNGKey(9), (2, 24), 0, 96)
+    jax.block_until_ready(jax.jit(
+        lambda p, t: transformer.backbone(p, t, TINY))(params, tokens))
+    jax.effects_barrier()
+    names = {f["name"] for f in metrics.snapshot()}
+    assert {"moe_routed_pairs_total", "moe_held_load_max_total",
+            "moe_layer_calls_total"} <= names
+    # every routed pair of 2 layers x 48 tokens x 4 is counted once; with
+    # all 16 routed experts held, those not on zero-compute indices are held
+    assert 0 < held_pairs() - before <= 2 * 48 * 4
+
+
+# -- the layer against the reference ---------------------------------------------------
+
+
+@pytest.mark.parametrize("use_flash", [False, True])
+def test_prefill_at_two_lengths_with_right_padding_agrees_with_the_reference(
+        params, use_flash):
+    """Prompts of 9 and 24 tokens in one padded batch of 32: each prompt's
+    logits at its last real position are the reference's on the prompt
+    alone, within float32 rounding (1e-4 on logits of size 1)."""
+    cfg = dataclasses.replace(TINY, use_flash=use_flash)
+    key = jax.random.PRNGKey(10)
+    prompts = [jax.random.randint(k, (n,), 0, 96)
+               for k, n in zip(jax.random.split(key, 2), (9, 24))]
+    tokens = jnp.stack([jnp.pad(p, (0, 32 - len(p))) for p in prompts])
+    last = jnp.array([len(p) - 1 for p in prompts])
+    got = _last_logits(params, tokens, last, cfg)
+    for row, prompt in zip(got, prompts):
+        want = longcat_reference.tree_last_logits(params, prompt[None],
+                                                  TINY_DIMS)[0]
+        np.testing.assert_allclose(row, want, atol=1e-4)
+
+
+def test_the_shares_add_up_to_the_uncut_layer(params):
+    """Four shares of four experts each, the zero-compute part counted
+    once, are the uncut reference's mixture; and so for the whole layer:
+    the layer is linear in the mixture's sum."""
+    layer = jax.tree.map(lambda p: p[0], params["blocks"][SHORTCUT])
+    u = jax.random.normal(jax.random.PRNGKey(11), (40, 64))
+    _, w = expert.route(u, layer["router"], EXPERTS)
+    idx, _ = expert.route(u, layer["router"], EXPERTS)
+    zero = jnp.sum(jnp.where(idx >= 16, w, 0), -1, keepdims=True) * u
+    total = zero
+    for share in range(4):
+        cfg = dataclasses.replace(EXPERTS, held=(4 * share, 4))
+        mine = jax.tree.map(lambda p: p[4 * share:4 * share + 4],
+                            layer["experts"])
+        part, _ = held_experts_apply(u, layer["router"], mine, cfg)
+        total = total + (part - zero)
+    with jax.default_matmul_precision("highest"):
+        want = longcat_reference.experts_part(
+            u, longcat_reference.from_tree(layer), TINY_DIMS)
+    np.testing.assert_allclose(total, want, atol=2e-5)
+
+    def layer_out(cfg, tree, x):
+        positions = jnp.arange(x.shape[1])[None]
+        return transformer._shortcut_block(tree, x, positions, cfg)[0]
+
+    x = jax.random.normal(jax.random.PRNGKey(12), (1, 20, 64))
+    uncut = layer_out(TINY, layer, x)
+    none = layer_out(held(TINY, 0, 4), {**layer, "experts": jax.tree.map(
+        lambda p: jnp.zeros_like(p[:4]), layer["experts"])}, x)
+    parts = sum(layer_out(held(TINY, 4 * s, 4), {
+        **layer, "experts": jax.tree.map(lambda p: p[4 * s:4 * s + 4],
+                                         layer["experts"])}, x) - none
+        for s in range(4))
+    np.testing.assert_allclose(none + parts, uncut, atol=5e-5)
+    with jax.default_matmul_precision("highest"):
+        np.testing.assert_allclose(
+            uncut[0], longcat_reference.block(
+                longcat_reference.from_tree(layer), x[0], TINY_DIMS),
+            atol=5e-5)
+
+
+def test_shares_of_different_devices_draw_consistent_experts():
+    """An expert's weights come from a key folded with its published index:
+    the share (4, 4) holds what experts 4 to 7 of the whole are."""
+    whole = transformer.init_params(jax.random.PRNGKey(13), TINY)
+    share = transformer.init_params(jax.random.PRNGKey(13),
+                                    held(TINY, 4, 4))
+    for name in ("wi", "wg", "wo"):
+        np.testing.assert_array_equal(
+            share["blocks"][SHORTCUT]["experts"][name],
+            whole["blocks"][SHORTCUT]["experts"][name][:, 4:8])
+    np.testing.assert_array_equal(share["blocks"][SHORTCUT]["router"],
+                                  whole["blocks"][SHORTCUT]["router"])
+
+
+# -- the configuration's guards ----------------------------------------------------------
+
+
+def test_layer_kinds_message_lists_the_kinds_from_one_tuple():
+    with pytest.raises(ValueError) as e:
+        TransformerConfig(n_layers=1, layer_kinds=("conv",))
+    assert all(repr(kind) in str(e.value) for kind in KINDS)
+    assert len(KINDS) == 4 and SHORTCUT in KINDS
+
+
+def test_a_shortcut_layer_stands_among_its_own_kind_and_needs_its_sizes():
+    with pytest.raises(ValueError, match="of its own kind only"):
+        dataclasses.replace(TINY, layer_kinds=(SHORTCUT, transformer.LINEAR))
+    with pytest.raises(ValueError, match="LatentConfig"):
+        dataclasses.replace(TINY, latent=None)
+
+
+def test_train_step_refuses_the_shortcut_kind_by_name():
+    with pytest.raises(ValueError, match="shortcut.*no backward pass"):
+        make_lm_train_step(TINY, mesh=None)
+
+
+def test_logical_axes_mirror_the_shortcut_tree(params):
+    axes = transformer.logical_axes(TINY)
+    is_axes = lambda a: isinstance(a, tuple)   # noqa: E731
+    flat_axes = jax.tree_util.tree_flatten_with_path(axes, is_leaf=is_axes)[0]
+    flat = jax.tree_util.tree_flatten_with_path(params)[0]
+    assert [p for p, _ in flat] == [p for p, _ in flat_axes]
+    assert all(a.ndim == len(b) for (_, a), (_, b) in zip(flat, flat_axes))
