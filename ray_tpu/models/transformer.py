@@ -23,8 +23,11 @@ taking the remainder. Training minimises ``sum_t p_t * CE_t - exit_beta *
 H(p)`` a position (``loss_and_metrics``); serving replies with the last
 pass's logits (``backbone`` + ``head``: the published
 ``early_exit_threshold`` of 1 never leaves early, and there is no decode
-loop to leave). With the defaults none of this exists: the parameter tree
-and the compiled programs are the plain decoder's.
+loop to leave). The training loss differentiates the looped stack by a
+backward pass of its own (``_looped_states_summing``): the shared weights'
+gradient is one float32 accumulator, each layer's share added into its
+slice where it is made. With the defaults none of this exists: the
+parameter tree and the compiled programs are the plain decoder's.
 
 A stack of several kinds of block (MiniCPM-SALA: ``layer_kinds``) holds one
 stacked parameter tree a kind, ``blocks[kind]``, and scans each run of
@@ -620,6 +623,121 @@ def apply_layers(blocks, x: jax.Array, cfg: TransformerConfig,
     return x
 
 
+def _looped_states(blocks, ln_f, x: jax.Array, cfg: TransformerConfig,
+                   mesh: Optional[Mesh], rules: Optional[ShardingRules]
+                   ) -> jax.Array:
+    """The stack ``cfg.n_passes`` times over from the states ``x``, the
+    shared final norm between passes: every pass's pre-final-norm states.
+
+    The passes are a second ``lax.scan`` round the layers' scan, not
+    unrolled calls: one block body is compiled whatever ``n_passes`` is, and
+    on the v5e the step's memory was the smaller that way (PERF.md section
+    4)."""
+    def one_pass(x, _):
+        h = apply_layers(blocks, x, cfg, mesh, rules)
+        return _rmsnorm(h, ln_f, cfg.norm_eps), h
+
+    _, states = jax.lax.scan(one_pass, x, None, length=cfg.n_passes)
+    return states
+
+
+def _looped_states_summing(blocks, ln_f, x: jax.Array,
+                           cfg: TransformerConfig, mesh: Optional[Mesh],
+                           rules: Optional[ShardingRules]) -> jax.Array:
+    """``_looped_states`` of a dense, rematerialised stack with a backward
+    pass of its own: the same two loops run backwards, and the step that
+    makes layer l's weight gradient adds it into slice l of **one float32
+    accumulator of the stacked blocks' shape**, carried through both loops.
+
+    Left to autodiff, the layers' scan hands each pass's weight gradients
+    on as a stacked float32 tree of their own and the passes' scan adds
+    that tree to its running sum: 12 bytes a parameter a pass read and
+    written for nothing (PERF.md section 6, PR 43). The sum is the same
+    float32 values added in the same order, last pass first.
+
+    The forward keeps what ``jax.checkpoint`` kept (each block
+    application's input, and the passes' states, which are the result);
+    the backward recomputes one block at a time, as ``remat`` did. Not
+    differentiated, it is ``_looped_states`` itself; and so it is for a
+    stack that keeps its blocks' residuals (``cfg.remat`` false) or is
+    made of other kinds of layer."""
+    if not cfg.remat or cfg.mixed:
+        return _looped_states(blocks, ln_f, x, cfg, mesh, rules)
+    norm = functools.partial(_rmsnorm, eps=cfg.norm_eps)
+    B, L, _ = x.shape
+    positions = jnp.broadcast_to(jnp.arange(L)[None], (B, L))
+    block = functools.partial(_block, positions=positions, cfg=cfg,
+                              mesh=mesh, rules=rules)
+
+    @jax.custom_vjp
+    def states_of(blocks, ln_f, x):
+        return _looped_states(blocks, ln_f, x, cfg, mesh, rules)
+
+    def forward(blocks, ln_f, x):
+        def one_pass(x, _):
+            h, inputs = jax.lax.scan(lambda x, layer: (block(layer, x), x),
+                                     x, blocks)
+            return norm(h, ln_f), (h, inputs)
+
+        _, (states, inputs) = jax.lax.scan(one_pass, x, None,
+                                           length=cfg.n_passes)
+        return states, (blocks, ln_f, inputs, states)
+
+    def backward(kept, d_states):
+        blocks, ln_f, inputs, states = kept
+        acc = jax.tree.map(jnp.zeros_like, blocks)      # float32, as they are
+        if mesh is not None:        # the accumulator lies as the blocks do
+            acc = jax.tree.map(
+                lambda a, axes: jax.lax.with_sharding_constraint(
+                    a, (rules or ShardingRules()).sharding(mesh, axes)),
+                acc, logical_axes(cfg)["blocks"],
+                is_leaf=lambda axes: isinstance(axes, tuple))
+
+        def one_layer(carry, at):
+            d_x, acc = carry
+            l, layer, x = at
+            _, pull = jax.vjp(block, layer, x)
+            d_layer, d_x = pull(d_x)
+            # read slice l, add, write slice l: in place, and on the TPU in
+            # the fusion of the matmul that made the gradient
+            acc = jax.tree.map(
+                lambda a, g: jax.lax.dynamic_update_index_in_dim(
+                    a, jax.lax.dynamic_index_in_dim(a, l, 0) + g[None], l, 0),
+                acc, d_layer)
+            return (d_x, acc), None
+
+        def one_pass(carry, at):
+            d_next, acc, d_ln_f = carry    # d_next: of this pass's normed states
+            h, pass_inputs, d_h = at
+            _, pull = jax.vjp(norm, h, ln_f)
+            through_norm, d_w = pull(d_next)
+            (d_x, acc), _ = jax.lax.scan(
+                one_layer, (d_h + through_norm, acc),
+                (jnp.arange(cfg.n_layers), blocks, pass_inputs),
+                reverse=True)
+            return (d_x, acc, d_ln_f + d_w), None
+
+        (d_x, acc, d_ln_f), _ = jax.lax.scan(
+            one_pass, (jnp.zeros_like(d_states[0]), acc,
+                       jnp.zeros_like(ln_f)),
+            (states, inputs, d_states), reverse=True)
+        return acc, d_ln_f, d_x
+
+    states_of.defvjp(forward, backward)
+    return states_of(blocks, ln_f, x)
+
+
+def _pass_states(params, tokens, cfg: TransformerConfig, mesh, rules,
+                 looped) -> jax.Array:
+    """``pass_states``, several passes being ``looped``'s to run."""
+    x = params["embed"].astype(cfg.dtype)[tokens]
+    if cfg.embed_scale != 1.0:
+        x = x * cfg.embed_scale
+    if cfg.n_passes == 1:
+        return apply_layers(params["blocks"], x, cfg, mesh, rules)[None]
+    return looped(params["blocks"], params["ln_f"], x, cfg, mesh, rules)
+
+
 def pass_states(params: Dict[str, Any], tokens: jax.Array,
                 cfg: TransformerConfig, mesh: Optional[Mesh] = None,
                 rules: Optional[ShardingRules] = None) -> jax.Array:
@@ -628,24 +746,11 @@ def pass_states(params: Dict[str, Any], tokens: jax.Array,
     final norm of pass t's states, which is also what ``head`` makes of
     them.
 
-    The passes are a second ``lax.scan`` round the layers' scan, not
-    unrolled calls: one block body is compiled whatever ``n_passes`` is, and
-    on the v5e the step's memory was the smaller that way (PERF.md section
-    4). A single pass builds no loop at all: the plain decoder's program."""
-    x = params["embed"].astype(cfg.dtype)[tokens]
-    if cfg.embed_scale != 1.0:
-        x = x * cfg.embed_scale
-    layers = functools.partial(apply_layers, params["blocks"], cfg=cfg,
-                               mesh=mesh, rules=rules)
-    if cfg.n_passes == 1:
-        return layers(x)[None]
-
-    def one_pass(x, _):
-        h = layers(x)
-        return _rmsnorm(h, params["ln_f"], cfg.norm_eps), h
-
-    _, states = jax.lax.scan(one_pass, x, None, length=cfg.n_passes)
-    return states
+    Several passes are ``_looped_states``' two scans (the training loss
+    takes the same two with a backward pass of its own,
+    ``_looped_states_summing``). A single pass builds no loop over passes
+    at all: the plain decoder's program."""
+    return _pass_states(params, tokens, cfg, mesh, rules, _looped_states)
 
 
 def backbone(params: Dict[str, Any], tokens: jax.Array,
@@ -819,7 +924,8 @@ def loss_and_metrics(params, tokens, cfg: TransformerConfig,
                      ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """The training loss of ``tokens`` (which serve as their own labels) and
     its metrics, as ``loss_from_states`` gives them."""
-    states = pass_states(params, tokens[:, :-1], cfg, mesh, rules)
+    states = _pass_states(params, tokens[:, :-1], cfg, mesh, rules,
+                          _looped_states_summing)
     return loss_from_states(params, states, tokens[:, 1:], cfg)
 
 

@@ -153,6 +153,17 @@ def test_looped_train_step_compiles_on_one_chip(topo, mosaic):
                                  looped).compile()
     text = compiled.as_text()
     assert KERNEL in text
+    # the shared weights' gradient is summed where each layer makes it: no
+    # operation adds a whole stacked float32 leaf to another (autodiff's
+    # ``add_any`` in the passes' loop, a ``select_add_fusion`` over
+    # f32[2, ...] a leaf on this chip)
+    blocks = jax.eval_shape(lambda: transformer.init_params(
+        jax.random.PRNGKey(0), looped))["blocks"]
+    stacked = {"f32[" + ",".join(map(str, p.shape)) + "]"
+               for p in jax.tree.leaves(blocks)}
+    assert [line for line in text.splitlines()
+            if "add_any" in line and " = " in line
+            and line.split(" = ")[1].split("{")[0] in stacked] == []
     # one exit's float32 logits at a time: [8, 1024, 32000] is 1.05 GB, and
     # four of them alive with their backward would not leave it here
     mem = compiled.memory_analysis()

@@ -88,12 +88,17 @@ DEFAULTS = {
                    exit_beta=0.05),
 }
 # (parameter tree, init_params, loss_fn, backbone + head, grad of loss_fn):
-# what the commit before this file's read, by ``_digests`` below
+# what the commit before this file's read, by ``_digests`` below. The looped
+# stack's loss and its gradient are as PR 43 left them (its loss goes
+# through a ``custom_vjp`` whose backward pass sums the shared weights'
+# gradient in place; they read e088e082885428c7 and 648cd66b3866c34c
+# before); its tree, its ``init_params`` and its served forward are still
+# that commit's.
 PARENTS = {
     "gqa_flash": ("63404d2623127361", "99f2a7b645963f3c", "2c1b73ef55decd15",
                   "240904abf0065166", "a0ff16809fa92166"),
-    "looped": ("d130dc7b3d9d7533", "05bd95983f06787a", "e088e082885428c7",
-               "e75bf9fc4ba4aac0", "648cd66b3866c34c"),
+    "looped": ("d130dc7b3d9d7533", "05bd95983f06787a", "cd3c2ca959cf9922",
+               "e75bf9fc4ba4aac0", "11e311ad6b7780a9"),
     "plain": ("38bdac6aed5a5dd1", "69fd1c7846b0dcc9", "8971081d5fc69b5d",
               "8edc61409c69836f", "0c2530f2c152b09c"),
 }

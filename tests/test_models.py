@@ -1,6 +1,9 @@
 """Model stack: transformer + resnet forward/grad, sharded training step."""
 
+import collections
 import dataclasses
+import hashlib
+import re
 
 import jax
 import jax.numpy as jnp
@@ -570,3 +573,159 @@ def test_loss_head_under_fsdp4_matches_one_device(eight_device_mesh, looped,
                                                      rel=1e-4)
     if cfg.exit_beta is not None:
         np.testing.assert_allclose(four["exit_p"], one["exit_p"], rtol=1e-4)
+
+
+# -- the looped stack's own backward pass (``_looped_states_summing``): one ----
+# float32 accumulator of the stacked blocks' shape, each layer's weight
+# gradient added into its slice where it is made.
+
+def _unrolled_states(blocks, ln_f, x, cfg):
+    """The plain reference: the stack called pass by pass on the same shared
+    tree, everything left to autodiff."""
+    states = []
+    for _ in range(cfg.n_passes):
+        h = transformer.apply_layers(blocks, x, cfg)
+        states.append(h)
+        x = transformer._rmsnorm(h, ln_f, cfg.norm_eps)
+    return jnp.stack(states)
+
+
+def _loss_from(states_of, params, nudge, tokens, cfg):
+    """The training loss with the looped stack ``states_of``'s to run, from
+    the embedded tokens plus ``nudge`` (whose gradient is the cotangent of
+    the stack's input)."""
+    x = params["embed"].astype(cfg.dtype)[tokens[:, :-1]] + nudge
+    states = states_of(params["blocks"], params["ln_f"], x, cfg)
+    return transformer.loss_from_states(params, states, tokens[:, 1:], cfg)[0]
+
+
+@pytest.mark.parametrize("remat", [True, False], ids=["remat", "kept"])
+@pytest.mark.parametrize("gate", [True, False], ids=["gate", "no_gate"])
+@pytest.mark.parametrize("post_norm", [True, False],
+                         ids=["post_norm", "pre_norm"])
+@pytest.mark.parametrize("n_passes", [2, 3, 4])
+def test_looped_backward_matches_autodiff_of_the_unrolled_stack(
+        looped, n_passes, post_norm, gate, remat):
+    """Every leaf of the loss's gradient (``blocks``, ``ln_f``, ``embed``,
+    the head, the gate) and the cotangent of the stack's input, in float32:
+    each within 1e-6 of the reference's, taken over the leaf."""
+    params, tokens = looped
+    cfg = dataclasses.replace(LOOPED, n_passes=n_passes, post_norm=post_norm,
+                              exit_beta=0.05 if gate else None, remat=remat)
+    if not gate:
+        params = {k: v for k, v in params.items() if k != "exit_gate"}
+    if not post_norm:
+        params = {**params, "blocks": {
+            k: v for k, v in params["blocks"].items()
+            if not k.endswith("_post")}}
+    nudge = jnp.zeros((2, 16, 64))
+
+    def summing(*args):
+        return transformer._looped_states_summing(*args, None, None)
+
+    loss, grads = jax.jit(jax.value_and_grad(
+        lambda p, n: _loss_from(summing, p, n, tokens, cfg),
+        argnums=(0, 1)))(params, nudge)
+    want_loss, want = jax.jit(jax.value_and_grad(
+        lambda p, n: _loss_from(_unrolled_states, p, n, tokens, cfg),
+        argnums=(0, 1)))(params, nudge)
+    assert float(loss) == pytest.approx(float(want_loss), rel=1e-6)
+    assert jax.tree.structure(grads) == jax.tree.structure(want)
+    paths = jax.tree_util.tree_flatten_with_path(grads)[0]
+    for (path, got), ref in zip(paths, jax.tree.leaves(want)):
+        assert float(jnp.linalg.norm(ref)) > 0, path
+        off = float(jnp.linalg.norm(got - ref) / jnp.linalg.norm(ref))
+        assert off < 1e-6, (jax.tree_util.keystr(path), off)
+
+
+def _eqns(jaxpr, loops=()):
+    """Every equation of a jaxpr and of the jaxprs inside it, with the
+    ``scan`` equations it stands in (outermost first)."""
+    for eqn in jaxpr.eqns:
+        yield eqn, loops
+        inner = loops + (eqn,) if eqn.primitive.name == "scan" else loops
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub, inner)
+
+
+def _stacked_shapes(cfg):
+    """How many leaves of ``blocks`` have each shape."""
+    blocks = jax.eval_shape(
+        lambda: transformer.init_params(jax.random.PRNGKey(0), cfg))["blocks"]
+    return collections.Counter(p.shape for p in jax.tree.leaves(blocks))
+
+
+def _adds_over_a_stacked_leaf(jaxpr, shapes):
+    """The ``add`` and ``add_any`` equations of a jaxpr, wherever they
+    stand, one of whose operands has a stacked block leaf's shape."""
+    return [eqn for eqn, _ in _eqns(jaxpr)
+            if eqn.primitive.name in ("add", "add_any")
+            and any(v.aval.shape in shapes for v in eqn.invars)]
+
+
+def test_looped_backward_adds_no_stacked_tree_and_carries_one_accumulator(
+        looped):
+    """The static witness: nowhere in the gradient's jaxpr is an array of a
+    stacked block leaf's shape added to another (left to autodiff, the
+    passes' loop adds a pass's stacked gradients to its running sum: the
+    counter finds those on the unrolled oracle's shared tree and on the
+    scans without ``remat``), and both loops of the backward pass carry one
+    float32 array a leaf of ``blocks``."""
+    _, tokens = looped
+    # 4 passes round 3 layers: no shape of the passes' is a stacked leaf's
+    cfg = dataclasses.replace(LOOPED, n_passes=4)
+    params = transformer.init_params(jax.random.PRNGKey(43), cfg)
+    shapes = _stacked_shapes(cfg)
+    assert sum(shapes.values()) == len(jax.tree.leaves(params["blocks"]))
+
+    def grad_jaxpr(loss):
+        return jax.make_jaxpr(jax.grad(loss))(params).jaxpr
+
+    summing = grad_jaxpr(lambda p: transformer.loss_fn(p, tokens, cfg))
+    assert _adds_over_a_stacked_leaf(summing, shapes) == []
+    for summed_tree_to_tree in (
+            lambda p: _loss_from(_unrolled_states, p, 0.0, tokens, cfg),
+            lambda p: transformer.loss_fn(
+                p, tokens, dataclasses.replace(cfg, remat=False))):
+        assert _adds_over_a_stacked_leaf(grad_jaxpr(summed_tree_to_tree),
+                                         shapes)
+
+    def carried(eqn):
+        n = eqn.params["num_consts"]
+        return collections.Counter(
+            v.aval.shape for v in eqn.invars[n:n + eqn.params["num_carry"]]
+            if v.aval.shape in shapes and v.aval.dtype == jnp.float32)
+
+    backward = {(len(loops), eqn.params["length"]): carried(eqn)
+                for eqn, loops in _eqns(summing)
+                if eqn.primitive.name == "scan" and eqn.params["reverse"]}
+    assert backward == {(0, cfg.n_passes): shapes, (1, cfg.n_layers): shapes}
+
+
+def _digest(fn, *args):
+    """A short hash of a function's jaxpr, addresses struck out (as
+    ``tests/test_mixed_stack.py`` takes it)."""
+    text = re.sub(r"0x[0-9a-f]+", "0x", str(jax.make_jaxpr(fn)(*args)))
+    return hashlib.sha256(text.encode()).hexdigest()[:16]
+
+
+@pytest.mark.parametrize("name,digest", [
+    ("one_pass_trained", "31779b5804fe3248"),
+    ("looped_served", "e3ac1b976ebbb548")])
+def test_one_pass_trains_and_a_looped_stack_serves_the_parents_program(
+        name, digest):
+    """The looped backward is taken by a stack applied more than once, where
+    its loss is differentiated, and by nothing else: a single pass's
+    training jaxpr and the looped forward as a replica calls it
+    (``backbone``) are what commit 2a81911 traced, to the letter."""
+    cfg = dataclasses.replace(LOOPED, dtype=jnp.bfloat16)
+    params = jax.eval_shape(
+        lambda: transformer.init_params(jax.random.PRNGKey(0), cfg))
+    if name == "one_pass_trained":
+        once = dataclasses.replace(cfg, n_passes=1)
+        got = _digest(jax.grad(lambda p, t: transformer.loss_fn(p, t, once)),
+                      params, jax.ShapeDtypeStruct((2, 17), jnp.int32))
+    else:
+        got = _digest(lambda p, t: transformer.backbone(p, t, cfg),
+                      params, jax.ShapeDtypeStruct((2, 16), jnp.int32))
+    assert got == digest

@@ -230,6 +230,35 @@ def test_the_steps_metrics_carry_the_exit_distribution_only_when_looped(
     assert np.isfinite(looped["loss"]) and looped["grad_norm"] > 0
 
 
+def test_the_looped_step_over_fsdp2_compiles_once_and_matches_one_device(
+        eight_device_mesh):
+    """The looped stack's own backward pass (one float32 accumulator of the
+    stacked blocks' shape, carried through the pass x layer loop) with the
+    state split over ``fsdp=2``: two steps run one compiled program, and
+    each step's loss and gradient norm are the one-device step's."""
+    from ray_tpu.parallel import MeshConfig, build_mesh
+    from ray_tpu.train import make_lm_train_step
+    cfg = _lm_cfg(n_passes=3, post_norm=True, exit_beta=0.05)
+    tokens = np.random.default_rng(2).integers(0, 96, (4, 17), dtype=np.int32)
+    seen = []
+    for mesh in (build_mesh(MeshConfig(data=1), eight_device_mesh[:1]),
+                 build_mesh(MeshConfig(data=1, fsdp=2),
+                            eight_device_mesh[:2])):
+        init_fn, step_fn, shard_batch = make_lm_train_step(cfg, mesh)
+        state, steps = init_fn(jax.random.PRNGKey(0)), []
+        for _ in range(2):
+            state, metrics = step_fn(state, shard_batch(tokens))
+            steps.append(jax.device_get(metrics))
+        assert step_fn.__wrapped__._cache_size() == 1
+        seen.append(steps)
+    for one, two in zip(*seen):
+        assert float(two["loss"]) == pytest.approx(float(one["loss"]),
+                                                   rel=1e-5)
+        assert float(two["grad_norm"]) == pytest.approx(
+            float(one["grad_norm"]), rel=1e-4)
+    assert seen[0][1]["loss"] < seen[0][0]["loss"]      # the state moved
+
+
 def test_pipeline_with_several_passes_is_refused_by_name(eight_device_mesh):
     from ray_tpu.parallel import MeshConfig, build_mesh
     from ray_tpu.train import make_lm_train_step
