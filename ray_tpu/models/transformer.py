@@ -447,23 +447,29 @@ def _attention(q, k, v, cfg: TransformerConfig, mesh: Optional[Mesh],
 
 def _block(params, x, positions, cfg: TransformerConfig, mesh, rules=None):
     norm = functools.partial(_rmsnorm, eps=cfg.norm_eps)
-    h = norm(x, params["ln1"])
-    q = jnp.einsum("bld,dhk->blhk", h, params["attn"]["wq"].astype(x.dtype))
-    k = jnp.einsum("bld,dhk->blhk", h, params["attn"]["wk"].astype(x.dtype))
-    v = jnp.einsum("bld,dhk->blhk", h, params["attn"]["wv"].astype(x.dtype))
-    q = _rope(q, cfg.rope_theta, positions)
-    k = _rope(k, cfg.rope_theta, positions)
-    attn = _attention(q, k, v, cfg, mesh, rules)
-    out = jnp.einsum("blhk,hkd->bld", attn,
-                     params["attn"]["wo"].astype(x.dtype))
-    if cfg.post_norm:
-        out = norm(out, params["ln1_post"])
-    x = x + out
-    h = norm(x, params["ln2"])
-    out = _mlp(params["mlp"], h)
-    if cfg.post_norm:
-        out = norm(out, params["ln2_post"])
-    return x + out
+    with jax.named_scope("attn"):
+        h = norm(x, params["ln1"])
+        q = jnp.einsum("bld,dhk->blhk", h,
+                       params["attn"]["wq"].astype(x.dtype))
+        k = jnp.einsum("bld,dhk->blhk", h,
+                       params["attn"]["wk"].astype(x.dtype))
+        v = jnp.einsum("bld,dhk->blhk", h,
+                       params["attn"]["wv"].astype(x.dtype))
+        q = _rope(q, cfg.rope_theta, positions)
+        k = _rope(k, cfg.rope_theta, positions)
+        with jax.named_scope("core"):
+            attn = _attention(q, k, v, cfg, mesh, rules)
+        out = jnp.einsum("blhk,hkd->bld", attn,
+                         params["attn"]["wo"].astype(x.dtype))
+        if cfg.post_norm:
+            out = norm(out, params["ln1_post"])
+        x = x + out
+    with jax.named_scope("mlp"):
+        h = norm(x, params["ln2"])
+        out = _mlp(params["mlp"], h)
+        if cfg.post_norm:
+            out = norm(out, params["ln2_post"])
+        return x + out
 
 
 def _mlp(mlp, h):
@@ -479,24 +485,31 @@ def _mixed_block(layer, x, positions, cfg: TransformerConfig, kind: str):
     params, rates = layer
     B, L, _ = x.shape
     norm = functools.partial(_rmsnorm, eps=cfg.norm_eps)
-    attn = {k: w.astype(x.dtype) for k, w in params["attn"].items()}
-    h = norm(x, params["ln1"])
-    q = norm(jnp.einsum("bld,dhk->blhk", h, attn["wq"]), attn["q_norm"])
-    k = norm(jnp.einsum("bld,dhk->blhk", h, attn["wk"]), attn["k_norm"])
-    v = jnp.einsum("bld,dhk->blhk", h, attn["wv"])
-    if kind == LINEAR:
-        q = _rope(q, cfg.rope_theta, positions)
-        k = _rope(k, cfg.rope_theta, positions)
-        o = linear_attention(q, k, v, rates, use_kernel=cfg.use_flash)
-        o = norm(o.reshape(B, L, -1), attn["o_norm"]).reshape(o.shape)
-    elif L <= cfg.sparse.dense_len:
-        o = _attention(q, k, v, cfg, None)
-    else:
-        o = sparse_attention(q, k, v, cfg.sparse, use_kernel=cfg.use_flash)
-    o = o * jax.nn.sigmoid(jnp.einsum("bld,dhk->blhk", h, attn["wg"]))
-    x = x + cfg.residual_scale * jnp.einsum("blhk,hkd->bld", o, attn["wo"])
-    out = _mlp(params["mlp"], norm(x, params["ln2"]))
-    return x + cfg.residual_scale * out
+    with jax.named_scope("attn"):
+        attn = {k: w.astype(x.dtype) for k, w in params["attn"].items()}
+        h = norm(x, params["ln1"])
+        q = norm(jnp.einsum("bld,dhk->blhk", h, attn["wq"]), attn["q_norm"])
+        k = norm(jnp.einsum("bld,dhk->blhk", h, attn["wk"]), attn["k_norm"])
+        v = jnp.einsum("bld,dhk->blhk", h, attn["wv"])
+        if kind == LINEAR:
+            q = _rope(q, cfg.rope_theta, positions)
+            k = _rope(k, cfg.rope_theta, positions)
+            with jax.named_scope("core"):
+                o = linear_attention(q, k, v, rates, use_kernel=cfg.use_flash)
+            o = norm(o.reshape(B, L, -1), attn["o_norm"]).reshape(o.shape)
+        else:
+            with jax.named_scope("core"):   # scores, ranking and forward
+                if L <= cfg.sparse.dense_len:
+                    o = _attention(q, k, v, cfg, None)
+                else:
+                    o = sparse_attention(q, k, v, cfg.sparse,
+                                         use_kernel=cfg.use_flash)
+        o = o * jax.nn.sigmoid(jnp.einsum("bld,dhk->blhk", h, attn["wg"]))
+        x = x + cfg.residual_scale * jnp.einsum("blhk,hkd->bld", o,
+                                                attn["wo"])
+    with jax.named_scope("mlp"):
+        out = _mlp(params["mlp"], norm(x, params["ln2"]))
+        return x + cfg.residual_scale * out
 
 
 def _latent_attention(params, h, positions, cfg: TransformerConfig):
@@ -507,7 +520,9 @@ def _latent_attention(params, h, positions, cfg: TransformerConfig):
     positions on q's last ``rope_dim`` and on ``k_r``, which every head
     shares; causal softmax attention at ``(nope_dim + rope_dim) ** -0.5``
     with v (and o) at their own width; ``W_o``. Prefill expands the latent
-    to per-head K and V, as the published forward does."""
+    to per-head K and V, as the published forward does. The caller enters
+    the ``attn`` scope (its norm and residual belong there too); the
+    attention alone is ``core`` here."""
     a = cfg.latent
     norm = functools.partial(_rmsnorm, eps=cfg.norm_eps)
     w = {k: p.astype(h.dtype) for k, p in params.items()}
@@ -527,7 +542,8 @@ def _latent_attention(params, h, positions, cfg: TransformerConfig):
     k = jnp.concatenate(
         [k_v[..., :a.nope_dim],
          jnp.broadcast_to(k_r, (*k_v.shape[:3], a.rope_dim))], axis=-1)
-    o = _attention(q, k, k_v[..., a.nope_dim:], cfg, None)
+    with jax.named_scope("core"):
+        o = _attention(q, k, k_v[..., a.nope_dim:], cfg, None)
     return jnp.einsum("blhk,hkd->bld", o, w["wo"])
 
 
@@ -543,17 +559,25 @@ def _shortcut_block(params, x, positions, cfg: TransformerConfig):
     def part(name, i):
         return jax.tree.map(lambda p: p[i], params[name])
 
-    h = x + _latent_attention(part("attn", 0),
-                              norm(x, params["ln_attn"][0]), positions, cfg)
-    u = norm(h, params["ln_mlp"][0])
+    def attention(h, i):
+        with jax.named_scope("attn"):
+            return h + _latent_attention(
+                part("attn", i), norm(h, params["ln_attn"][i]), positions,
+                cfg)
+
+    h = attention(x, 0)
+    with jax.named_scope("mlp"):    # the norm both FFN_0 and the mixture read
+        u = norm(h, params["ln_mlp"][0])
     s, load = expert.held_experts_apply(
         u.reshape(B * L, d), params["router"], params["experts"],
         cfg.experts)
-    h = h + _mlp(part("mlp", 0), u)
-    h = h + _latent_attention(part("attn", 1),
-                              norm(h, params["ln_attn"][1]), positions, cfg)
-    y = h + _mlp(part("mlp", 1), norm(h, params["ln_mlp"][1]))
-    return y + s.reshape(B, L, d), load
+    with jax.named_scope("mlp"):
+        h = h + _mlp(part("mlp", 0), u)
+    h = attention(h, 1)
+    with jax.named_scope("mlp"):
+        y = h + _mlp(part("mlp", 1), norm(h, params["ln_mlp"][1]))
+    with jax.named_scope("moe"):
+        return y + s.reshape(B, L, d), load
 
 
 def _apply_shortcut(blocks, x, positions, cfg: TransformerConfig):
@@ -623,6 +647,19 @@ def apply_layers(blocks, x: jax.Array, cfg: TransformerConfig,
     return x
 
 
+def _by_sublayer(fn, acc, grads):
+    """``jax.tree.map(fn, acc, grads)`` over a dense block's parameter
+    trees, each top-level part under the scope of the sub-layer it belongs
+    to (``mlp`` and its norms ``ln2*``; the rest is ``attn``): a weight's
+    gradient is that sub-layer's backward work."""
+    out = {}
+    for part in sorted(acc):            # the order jax.tree.map takes
+        with (jax.named_scope("mlp") if part in ("mlp", "ln2", "ln2_post")
+              else jax.named_scope("attn")):
+            out[part] = jax.tree.map(fn, acc[part], grads[part])
+    return out
+
+
 def _looped_states(blocks, ln_f, x: jax.Array, cfg: TransformerConfig,
                    mesh: Optional[Mesh], rules: Optional[ShardingRules]
                    ) -> jax.Array:
@@ -635,7 +672,8 @@ def _looped_states(blocks, ln_f, x: jax.Array, cfg: TransformerConfig,
     4)."""
     def one_pass(x, _):
         h = apply_layers(blocks, x, cfg, mesh, rules)
-        return _rmsnorm(h, ln_f, cfg.norm_eps), h
+        with jax.named_scope("head"):       # the final norm, between passes
+            return _rmsnorm(h, ln_f, cfg.norm_eps), h
 
     _, states = jax.lax.scan(one_pass, x, None, length=cfg.n_passes)
     return states
@@ -677,7 +715,8 @@ def _looped_states_summing(blocks, ln_f, x: jax.Array,
         def one_pass(x, _):
             h, inputs = jax.lax.scan(lambda x, layer: (block(layer, x), x),
                                      x, blocks)
-            return norm(h, ln_f), (h, inputs)
+            with jax.named_scope("head"):
+                return norm(h, ln_f), (h, inputs)
 
         _, (states, inputs) = jax.lax.scan(one_pass, x, None,
                                            length=cfg.n_passes)
@@ -685,7 +724,8 @@ def _looped_states_summing(blocks, ln_f, x: jax.Array,
 
     def backward(kept, d_states):
         blocks, ln_f, inputs, states = kept
-        acc = jax.tree.map(jnp.zeros_like, blocks)      # float32, as they are
+        # float32, as they are
+        acc = _by_sublayer(lambda _, p: jnp.zeros_like(p), blocks, blocks)
         if mesh is not None:        # the accumulator lies as the blocks do
             acc = jax.tree.map(
                 lambda a, axes: jax.lax.with_sharding_constraint(
@@ -700,7 +740,7 @@ def _looped_states_summing(blocks, ln_f, x: jax.Array,
             d_layer, d_x = pull(d_x)
             # read slice l, add, write slice l: in place, and on the TPU in
             # the fusion of the matmul that made the gradient
-            acc = jax.tree.map(
+            acc = _by_sublayer(
                 lambda a, g: jax.lax.dynamic_update_index_in_dim(
                     a, jax.lax.dynamic_index_in_dim(a, l, 0) + g[None], l, 0),
                 acc, d_layer)
@@ -709,18 +749,24 @@ def _looped_states_summing(blocks, ln_f, x: jax.Array,
         def one_pass(carry, at):
             d_next, acc, d_ln_f = carry    # d_next: of this pass's normed states
             h, pass_inputs, d_h = at
-            _, pull = jax.vjp(norm, h, ln_f)
-            through_norm, d_w = pull(d_next)
+            # a custom_vjp's backward is traced where it is transposed: the
+            # final norm's part of it enters the norm's scope itself (the
+            # recomputed block's scopes ride its ``jax.vjp``)
+            with jax.named_scope("head"):
+                _, pull = jax.vjp(norm, h, ln_f)
+                through_norm, d_w = pull(d_next)
+                d_h = d_h + through_norm
             (d_x, acc), _ = jax.lax.scan(
-                one_layer, (d_h + through_norm, acc),
+                one_layer, (d_h, acc),
                 (jnp.arange(cfg.n_layers), blocks, pass_inputs),
                 reverse=True)
-            return (d_x, acc, d_ln_f + d_w), None
+            with jax.named_scope("head"):
+                return (d_x, acc, d_ln_f + d_w), None
 
+        with jax.named_scope("head"):
+            first = (jnp.zeros_like(d_states[0]), acc, jnp.zeros_like(ln_f))
         (d_x, acc, d_ln_f), _ = jax.lax.scan(
-            one_pass, (jnp.zeros_like(d_states[0]), acc,
-                       jnp.zeros_like(ln_f)),
-            (states, inputs, d_states), reverse=True)
+            one_pass, first, (states, inputs, d_states), reverse=True)
         return acc, d_ln_f, d_x
 
     states_of.defvjp(forward, backward)
@@ -730,9 +776,10 @@ def _looped_states_summing(blocks, ln_f, x: jax.Array,
 def _pass_states(params, tokens, cfg: TransformerConfig, mesh, rules,
                  looped) -> jax.Array:
     """``pass_states``, several passes being ``looped``'s to run."""
-    x = params["embed"].astype(cfg.dtype)[tokens]
-    if cfg.embed_scale != 1.0:
-        x = x * cfg.embed_scale
+    with jax.named_scope("embed"):
+        x = params["embed"].astype(cfg.dtype)[tokens]
+        if cfg.embed_scale != 1.0:
+            x = x * cfg.embed_scale
     if cfg.n_passes == 1:
         return apply_layers(params["blocks"], x, cfg, mesh, rules)[None]
     return looped(params["blocks"], params["ln_f"], x, cfg, mesh, rules)
@@ -765,12 +812,13 @@ def head(params: Dict[str, Any], x: jax.Array,
          cfg: TransformerConfig) -> jax.Array:
     """Final norm + lm-head projection -> float32 logits. The single logits
     path shared by inference (``apply``) and training (``token_nll``)."""
-    x = _rmsnorm(x, params["ln_f"], cfg.norm_eps)
-    if cfg.logit_scale != 1.0:
-        x = x * cfg.logit_scale
-    logits = jnp.einsum("bld,dv->blv", x,
-                        params["lm_head"].astype(cfg.dtype))
-    return logits.astype(jnp.float32)
+    with jax.named_scope("head"):
+        x = _rmsnorm(x, params["ln_f"], cfg.norm_eps)
+        if cfg.logit_scale != 1.0:
+            x = x * cfg.logit_scale
+        logits = jnp.einsum("bld,dv->blv", x,
+                            params["lm_head"].astype(cfg.dtype))
+        return logits.astype(jnp.float32)
 
 
 def apply(params: Dict[str, Any], tokens: jax.Array,
@@ -819,9 +867,11 @@ def weighted_nll(x: jax.Array, w_head: jax.Array, targets: jax.Array,
     matmuls an exit (logits, the gradient to the states, the gradient to
     the head) and nothing vocabulary-sized kept for the backward pass but
     the head's gradient itself."""
-    _, nll = _scan_exits(
-        lambda _, x_t: (None, _exit_nll(x_t, w_head, targets)[3]), None, x)
-    return jnp.sum(weights * nll)
+    with jax.named_scope("head"):
+        _, nll = _scan_exits(
+            lambda _, x_t: (None, _exit_nll(x_t, w_head, targets)[3]), None,
+            x)
+        return jnp.sum(weights * nll)
 
 
 def _weighted_nll_fwd(x, w_head, targets, weights):
@@ -841,21 +891,25 @@ def _weighted_nll_fwd(x, w_head, targets, weights):
     # the end; a single exit has nothing to sum, and its gradient leaves the
     # MXU in the head's dtype as autodiff's did
     sum_dtype = jnp.float32 if x.shape[0] > 1 else w_head.dtype
-    d_head, (nll, d_x) = _scan_exits(
-        one_exit, jnp.zeros(w_head.shape, sum_dtype), (x, weights))
-    # finished here, while the logits are: left to itself XLA fuses this
-    # matmul into the head's optimizer update at the far end of the step and
-    # keeps an exit's float32 logits alive until then
-    d_head = jax.lax.optimization_barrier(d_head.astype(w_head.dtype))
-    return jnp.sum(weights * nll), (d_x, d_head, nll)
+    # autodiff calls this in weighted_nll's place, outside the scope its
+    # body enters
+    with jax.named_scope("head"):
+        d_head, (nll, d_x) = _scan_exits(
+            one_exit, jnp.zeros(w_head.shape, sum_dtype), (x, weights))
+        # finished here, while the logits are: left to itself XLA fuses this
+        # matmul into the head's optimizer update at the far end of the step
+        # and keeps an exit's float32 logits alive until then
+        d_head = jax.lax.optimization_barrier(d_head.astype(w_head.dtype))
+        return jnp.sum(weights * nll), (d_x, d_head, nll)
 
 
 def _weighted_nll_bwd(residuals, c):
     d_x, d_head, nll = residuals
     # linear in the weights, so nll is their exact gradient (it reaches the
     # exit gate through p_t); targets are integers
-    return ((c * d_x).astype(d_x.dtype), (c * d_head).astype(d_head.dtype),
-            None, c * nll)
+    with jax.named_scope("head"):   # traced where the loss is transposed
+        return ((c * d_x).astype(d_x.dtype),
+                (c * d_head).astype(d_head.dtype), None, c * nll)
 
 
 weighted_nll.defvjp(_weighted_nll_fwd, _weighted_nll_bwd)
@@ -866,8 +920,9 @@ def token_nll(params, x: jax.Array, targets: jax.Array,
     """Final norm + lm head + each position's next-token cross entropy,
     [B, L] float32 (evaluation and the tests' oracles; training goes
     through ``weighted_nll``)."""
-    x = _rmsnorm(x, params["ln_f"], cfg.norm_eps)
-    return _exit_nll(x, params["lm_head"].astype(cfg.dtype), targets)[3]
+    with jax.named_scope("head"):
+        x = _rmsnorm(x, params["ln_f"], cfg.norm_eps)
+        return _exit_nll(x, params["lm_head"].astype(cfg.dtype), targets)[3]
 
 
 def exit_log_probs(params, states: jax.Array,
@@ -877,14 +932,17 @@ def exit_log_probs(params, states: jax.Array,
     lambda_j), and the last pass takes what is left (so one pass has p = 1
     whatever its gate says). Kept in logs: log(1 - sigmoid(z)) is
     log_sigmoid(-z)."""
-    x = _rmsnorm(states, params["ln_f"], cfg.norm_eps).astype(jnp.float32)
-    # a sum of float32 products, not a matmul: the MXU would round x to
-    # bfloat16 again
-    z = jnp.sum(x * params["exit_gate"]["w"], -1) + params["exit_gate"]["b"]
-    stay = jnp.cumsum(jax.nn.log_sigmoid(-z), axis=0)      # sum_{j<=t}
-    before = jnp.concatenate([jnp.zeros_like(stay[:1]), stay[:-1]])
-    leave = jax.nn.log_sigmoid(z).at[-1].set(0.0)
-    return before + leave
+    with jax.named_scope("head"):
+        x = _rmsnorm(states, params["ln_f"],
+                     cfg.norm_eps).astype(jnp.float32)
+        # a sum of float32 products, not a matmul: the MXU would round x to
+        # bfloat16 again
+        z = jnp.sum(x * params["exit_gate"]["w"], -1) \
+            + params["exit_gate"]["b"]
+        stay = jnp.cumsum(jax.nn.log_sigmoid(-z), axis=0)      # sum_{j<=t}
+        before = jnp.concatenate([jnp.zeros_like(stay[:1]), stay[:-1]])
+        leave = jax.nn.log_sigmoid(z).at[-1].set(0.0)
+        return before + leave
 
 
 def loss_from_states(params, states: jax.Array, targets: jax.Array,
@@ -907,15 +965,16 @@ def loss_from_states(params, states: jax.Array, targets: jax.Array,
                             params["lm_head"].astype(cfg.dtype), targets,
                             weights / targets.size)
 
-    if cfg.exit_beta is None:
-        last = states[-1:]
-        return heads(last, jnp.ones(last.shape[:3], jnp.float32)), {}
-    logp = exit_log_probs(params, states, cfg)
-    p = jnp.exp(logp)
-    entropy = -jnp.sum(p * logp, axis=0)
-    loss = heads(states, p) - cfg.exit_beta * jnp.mean(entropy)
-    return loss, {"exit_p": jnp.mean(p, axis=(1, 2)),
-                  "exit_entropy": jnp.mean(entropy)}
+    with jax.named_scope("head"):
+        if cfg.exit_beta is None:
+            last = states[-1:]
+            return heads(last, jnp.ones(last.shape[:3], jnp.float32)), {}
+        logp = exit_log_probs(params, states, cfg)
+        p = jnp.exp(logp)
+        entropy = -jnp.sum(p * logp, axis=0)
+        loss = heads(states, p) - cfg.exit_beta * jnp.mean(entropy)
+        return loss, {"exit_p": jnp.mean(p, axis=(1, 2)),
+                      "exit_entropy": jnp.mean(entropy)}
 
 
 def loss_and_metrics(params, tokens, cfg: TransformerConfig,

@@ -118,6 +118,30 @@ SPANS = frozenset({
     "moe.route",
 })
 
+# Every scope the model enters on the device by a literal name
+# (``jax.named_scope``), the device-side twin of ``SPANS``: a scope lands
+# in the name stack of the operations traced inside it, the compiler
+# keeps that as each operation's ``op_name``, and the TPU's profiler
+# writes it to the ``.xplane.pb`` as the operation's ``tf_op`` (forward
+# ``jit(step)/.../attn/core/pallas_call``, backward inside
+# ``transpose(jvp(...))``).  Single tokens, none a host span's name; they
+# are the contract with whatever reads a device trace
+# (``benchmark/device_scopes.py``, TensorBoard's framework-operation
+# view), so a rename shows up here.
+DEVICE_SCOPES = frozenset({
+    "embed",      # the embedding lookup and its scale
+    # a layer's token mixer: norm, projections, rotary positions, the
+    # attention itself, output projection and the residual's add
+    "attn",
+    "core",       # inside ``attn``: the attention alone (a flash kernel)
+    "mlp",        # a layer's dense FFN with its norm and residual
+    "moe",        # the routed mixture (``parallel/expert.py``)
+    "router",     # inside ``moe``: logits, softmax, top-k
+    "experts",    # inside ``moe``: gather, grouped products, scatter-add
+    "head",       # final norm, lm head, cross entropy, exit gate
+    "optimizer",  # ``train/step.py``: the update and the gradient's norm
+})
+
 # The gauge a replica sets once, when its constructor returns.
 REPLICA_INIT_GAUGE = "serve_replica_init_seconds"
 

@@ -69,11 +69,12 @@ def route(u: jax.Array, router: jax.Array, cfg: ExpertConfig
     in float32. The weights are ``scale * p`` and are not renormalised; the
     choice is on ``p`` (the published correction bias is a buffer of zeros
     used for the choice alone)."""
-    logits = jnp.einsum("td,de->te", u, router.astype(u.dtype),
-                        precision=_precision(u.dtype),
-                        preferred_element_type=jnp.float32)
-    p, idx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), cfg.top_k)
-    return idx, cfg.scale * p
+    with jax.named_scope("router"):
+        logits = jnp.einsum("td,de->te", u, router.astype(u.dtype),
+                            precision=_precision(u.dtype),
+                            preferred_element_type=jnp.float32)
+        p, idx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), cfg.top_k)
+        return idx, cfg.scale * p
 
 
 def _held_rows(idx, weights, cfg: ExpertConfig):
@@ -130,42 +131,44 @@ def held_experts_apply(u: jax.Array, router: jax.Array,
     Returns the partial sum [T, d] in ``u``'s dtype and the layer's load,
     int32 [4]: pairs routed to held, absent and zero-compute experts, and
     the most-loaded held expert's pairs (``record_load``)."""
-    T, d = u.shape
-    count = cfg.held[1]
-    idx, weights = route(u, router, cfg)
-    zero = idx >= cfg.n_routed
-    out = (jnp.sum(jnp.where(zero, weights, 0.0), axis=-1, keepdims=True)
-           * u.astype(jnp.float32))
+    with jax.named_scope("moe"):
+        T, d = u.shape
+        count = cfg.held[1]
+        idx, weights = route(u, router, cfg)
+        zero = idx >= cfg.n_routed
+        out = (jnp.sum(jnp.where(zero, weights, 0.0), axis=-1, keepdims=True)
+               * u.astype(jnp.float32))
 
-    weight, running, bounds = _held_rows(idx, weights, cfg)
-    n_held = bounds[count]
-    rows = min(CHUNK_ROWS, T * cfg.top_k)
-    w = {name: p.astype(u.dtype) for name, p in experts.items()}
-    product = functools.partial(jax.lax.ragged_dot,
-                                precision=_precision(u.dtype))
+        weight, running, bounds = _held_rows(idx, weights, cfg)
+        n_held = bounds[count]
+        rows = min(CHUNK_ROWS, T * cfg.top_k)
+        w = {name: p.astype(u.dtype) for name, p in experts.items()}
+        product = functools.partial(jax.lax.ragged_dot,
+                                    precision=_precision(u.dtype))
 
-    def step(i, out):
-        start = i * rows
-        mine = start + jnp.arange(rows, dtype=jnp.int32)
-        valid = mine < n_held
-        tok, e = _rows_tokens(mine, running, bounds)
-        sizes = (jnp.clip(bounds[1:] - start, 0, rows)
-                 - jnp.clip(bounds[:-1] - start, 0, rows))
-        x = u[tok]
-        hidden = (jax.nn.silu(product(x, w["wi"], sizes))
-                  * product(x, w["wg"], sizes))
-        y = product(hidden, w["wo"], sizes,
-                    preferred_element_type=jnp.float32)
-        # rows past the held pairs belong to no group: whatever the product
-        # left there is dropped
-        y = jnp.where(valid[:, None], y * weight[tok, e][:, None], 0.0)
-        return out.at[jnp.where(valid, tok, T)].add(y, mode="drop")
+        def step(i, out):
+            start = i * rows
+            mine = start + jnp.arange(rows, dtype=jnp.int32)
+            valid = mine < n_held
+            tok, e = _rows_tokens(mine, running, bounds)
+            sizes = (jnp.clip(bounds[1:] - start, 0, rows)
+                     - jnp.clip(bounds[:-1] - start, 0, rows))
+            x = u[tok]
+            hidden = (jax.nn.silu(product(x, w["wi"], sizes))
+                      * product(x, w["wg"], sizes))
+            y = product(hidden, w["wo"], sizes,
+                        preferred_element_type=jnp.float32)
+            # rows past the held pairs belong to no group: whatever the product
+            # left there is dropped
+            y = jnp.where(valid[:, None], y * weight[tok, e][:, None], 0.0)
+            return out.at[jnp.where(valid, tok, T)].add(y, mode="drop")
 
-    out = jax.lax.fori_loop(0, (n_held + rows - 1) // rows, step, out)
-    n_zero = jnp.sum(zero, dtype=jnp.int32)
-    load = jnp.stack([n_held, idx.size - n_held - n_zero, n_zero,
-                      jnp.max(bounds[1:] - bounds[:-1])])
-    return out.astype(u.dtype), load
+        with jax.named_scope("experts"):
+            out = jax.lax.fori_loop(0, (n_held + rows - 1) // rows, step, out)
+        n_zero = jnp.sum(zero, dtype=jnp.int32)
+        load = jnp.stack([n_held, idx.size - n_held - n_zero, n_zero,
+                          jnp.max(bounds[1:] - bounds[:-1])])
+        return out.astype(u.dtype), load
 
 
 # -- the program's counters ----------------------------------------------------
@@ -208,7 +211,8 @@ def record_load(loads: jax.Array, cfg: ExpertConfig) -> None:
     """Feed the loads of a forward's layers (``held_experts_apply``'s
     second result, stacked [layers, 4]) to the program's counters, from
     inside a jitted program: one call-back a forward."""
-    jax.debug.callback(functools.partial(_record, cfg.held[1]), loads)
+    with jax.named_scope("moe"):
+        jax.debug.callback(functools.partial(_record, cfg.held[1]), loads)
 
 
 @functools.lru_cache(maxsize=128)
@@ -221,10 +225,11 @@ def _moe_sharded(expert_fn: Callable, mesh: Mesh, axis: str,
     def per_device(x_loc, rw, params):
         tokens, d = x_loc.shape
         capacity = max(1, int(capacity_factor * tokens / n_exp_total))
-        gates = jax.nn.softmax(x_loc @ rw, axis=-1)            # [T, E]
-        expert_idx = jnp.argmax(gates, axis=-1)                # [T]
-        gate_val = jnp.take_along_axis(
-            gates, expert_idx[:, None], axis=-1)[:, 0]         # [T]
+        with jax.named_scope("router"):
+            gates = jax.nn.softmax(x_loc @ rw, axis=-1)        # [T, E]
+            expert_idx = jnp.argmax(gates, axis=-1)            # [T]
+            gate_val = jnp.take_along_axis(
+                gates, expert_idx[:, None], axis=-1)[:, 0]     # [T]
         # Position of each token within its expert's capacity buffer.
         onehot = jax.nn.one_hot(expert_idx, n_exp_total, dtype=jnp.int32)
         pos_in_expert = (jnp.cumsum(onehot, axis=0) - 1) * onehot  # [T, E]
@@ -245,7 +250,8 @@ def _moe_sharded(expert_fn: Callable, mesh: Mesh, axis: str,
             exp_per_shard, n_shards * capacity, d)
         # in_specs P(axis) already hands this device its expert slice
         # (leading dim == exp_per_shard).
-        out = jax.vmap(expert_fn)(params, recv)
+        with jax.named_scope("experts"):
+            out = jax.vmap(expert_fn)(params, recv)
         # Undo: [exp_per_shard, n_shards, capacity, d] -> all_to_all back.
         out = out.reshape(exp_per_shard, n_shards, capacity, d).transpose(
             1, 0, 2, 3)
@@ -275,4 +281,5 @@ def moe_apply(x: jax.Array, router_weights: jax.Array, expert_params: Any,
                          f"{n_shards} expert shards")
     fn = _moe_sharded(expert_fn, mesh, axis, n_exp_total, n_shards,
                       n_exp_total // n_shards, capacity_factor)
-    return fn(x, router_weights, expert_params)
+    with jax.named_scope("moe"):
+        return fn(x, router_weights, expert_params)

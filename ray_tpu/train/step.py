@@ -80,7 +80,8 @@ def make_lm_train_step(cfg: TransformerConfig, mesh: Mesh,
                                                 rules)
         # Pipeline path: embed -> pipelined blocks -> head.
         inputs, targets = tokens[:, :-1], tokens[:, 1:]
-        x = params["embed"].astype(cfg.dtype)[inputs]
+        with jax.named_scope("embed"):
+            x = params["embed"].astype(cfg.dtype)[inputs]
         layers_per_stage = cfg.n_layers // pipe
         # mesh=None: a stage already runs per device, inside
         # pipeline_apply's shard_map.
@@ -102,9 +103,10 @@ def make_lm_train_step(cfg: TransformerConfig, mesh: Mesh,
         params, opt_state = state
         (loss, metrics), grads = jax.value_and_grad(
             loss_fn, has_aux=True)(params, tokens)
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
-        gnorm = optax.global_norm(grads)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = optimizer.update(grads, opt_state, params)
+            params = optax.apply_updates(params, updates)
+            gnorm = optax.global_norm(grads)
         return (params, opt_state), {"loss": loss, "grad_norm": gnorm,
                                      **metrics}
 

@@ -16,7 +16,9 @@ while a module is imported would give xdist workers different tests to
 collect. Keep every such test in this one file for the same reason.
 """
 
+import contextlib
 import dataclasses
+import re
 import sys
 
 import jax
@@ -178,9 +180,8 @@ def test_looped_train_step_compiles_on_one_chip(topo, mosaic):
 CELL_STEP_BYTES = {"mistral7b-train-4k": 13.51e9, "ouro2.6b-train-4k": 14.80e9}
 
 
-@pytest.mark.parametrize("cell_name", list(CELL_STEP_BYTES))
-def test_benchmark_cells_train_step_fits_what_it_took(topo, mosaic,
-                                                      cell_name):
+def _lower_cell_step(topo, cell_name):
+    """A one-chip training cell's step at the cell's own sizes."""
     from benchmark import manifest
     cell = manifest.Manifest().cell(cell_name)
     adapter = manifest.adapter(cell.config)
@@ -188,9 +189,15 @@ def test_benchmark_cells_train_step_fits_what_it_took(topo, mosaic,
     cfg = adapter.program_config(
         adapter.dims(cell.config, cell.job, cell.chips), seq_len,
         cell.deploy.get("model", {}))
-    compiled = _lower_train_step(
+    return _lower_train_step(
         _mesh(topo.devices[:1], data=1), cfg,
-        int(cell.traffic["sequences_per_step"]), seq_len).compile()
+        int(cell.traffic["sequences_per_step"]), seq_len)
+
+
+@pytest.mark.parametrize("cell_name", list(CELL_STEP_BYTES))
+def test_benchmark_cells_train_step_fits_what_it_took(topo, mosaic,
+                                                      cell_name):
+    compiled = _lower_cell_step(topo, cell_name).compile()
     assert KERNEL in compiled.as_text()
     mem = compiled.memory_analysis()
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
@@ -340,3 +347,126 @@ def test_shortcut_layer_serve_forward_compiles(topo, mosaic):
     text = jax.jit(lambda p, t: transformer.apply(p, t, cfg)).lower(
         params, tokens).compile().as_text()
     assert "flash_fwd" in text and "ragged-dot" in text
+
+
+# -- the device scopes (``metric_names.DEVICE_SCOPES``) ----------------------
+
+
+def _internlm2_forward(topo):
+    """The offline cell's served program at its largest shape
+    (``serve_job``'s ``first_token``)."""
+    from benchmark import manifest
+    cell = manifest.Manifest().cell("internlm2-serve-offline")
+    adapter = manifest.adapter(cell.config)
+    batch = max(cell.deploy["deployment"]["pad_batch_to"])
+    length = max(cell.deploy["deployment"]["length_buckets"])
+    cfg = adapter.program_config(
+        adapter.dims(cell.config, cell.job, cell.chips), length,
+        cell.deploy.get("model", {}))
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    params = jax.eval_shape(lambda: jax.tree.map(
+        lambda p: p.astype(cfg.dtype),
+        transformer.init_params(jax.random.PRNGKey(0), cfg)))
+    params = jax.tree.map(lambda leaf: _shape(leaf, one_chip), params)
+    tokens = jax.ShapeDtypeStruct((batch, length), jnp.int32,
+                                  sharding=one_chip)
+    last = jax.ShapeDtypeStruct((batch,), jnp.int32, sharding=one_chip)
+
+    def first_token(params, tokens, last):
+        x = transformer.backbone(params, tokens, cfg)
+        x = jnp.take_along_axis(x, last[:, None, None], axis=1)
+        logits = transformer.head(params, x, cfg)[:, 0]
+        return jnp.argmax(logits, axis=-1), jnp.max(logits, axis=-1)
+
+    return jax.jit(first_token).lower(params, tokens, last)
+
+
+PROGRAMS = {"mistral-step": lambda topo: _lower_cell_step(
+                topo, "mistral7b-train-4k"),
+            "internlm2-forward": _internlm2_forward}
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{$")
+_INSTRUCTION = re.compile(
+    r"^\s+(?:ROOT )?%?[\w.\-]+ = .*?\s(fusion|convolution|custom-call)\(")
+
+
+def _unscoped_work(text):
+    """The optimized module's ``fusion``, ``convolution`` and
+    ``custom-call`` instructions outside a fused computation whose
+    ``op_name`` names no scope, each as (kind, line): a fusion round a
+    convolution is a ``convolution fusion``, a Mosaic call ``mosaic``."""
+    from ray_tpu.observability.metric_names import DEVICE_SCOPES
+    bodies, name = {}, None
+    for line in text.splitlines():
+        m = _COMPUTATION.match(line)
+        if m:
+            name = m.group(1)
+            bodies[name] = []
+        elif name is not None:
+            bodies[name].append(line)
+    fused = {m.group(1) for body in bodies.values() for line in body
+             for m in [re.search(r"\scalls=%?([\w.\-]+)", line)]
+             if m and " fusion(" in line}
+    out = []
+    for name, body in bodies.items():
+        if name in fused:
+            continue
+        for line in body:
+            m = _INSTRUCTION.match(line)
+            if not m:
+                continue
+            op_name = re.search(r'op_name="([^"]*)"', line)
+            tokens = re.split(r"[/():]", op_name.group(1)) if op_name else ()
+            if DEVICE_SCOPES & set(tokens):
+                continue
+            kind = m.group(1)
+            calls = re.search(r"\scalls=%?([\w.\-]+)", line)
+            if kind == "fusion" and any(
+                    " convolution(" in inner
+                    for inner in bodies.get(calls.group(1), ())):
+                kind = "convolution fusion"
+            elif KERNEL in line:
+                kind = "mosaic"
+            out.append((kind, line.strip()))
+    return out
+
+
+@pytest.mark.parametrize("program", sorted(PROGRAMS))
+def test_the_chips_matmuls_and_kernels_all_name_a_scope(topo, mosaic,
+                                                        program):
+    """What the compiler for the chip keeps of the scopes: every matmul
+    (alone or as a fusion's root) and every Mosaic call of the Mistral step
+    and of the InternLM2 forward carries one in its ``op_name``; what does
+    not is the compiler's own (copies, slices of the stacked weights,
+    multi-output fusions, which carry no metadata at all)."""
+    text = PROGRAMS[program](topo).compile().as_text()
+    assert KERNEL in text and 'op_name="jit(' in text
+    left = _unscoped_work(text)
+    assert [entry for entry in left
+            if entry[0] in ("convolution", "convolution fusion", "mosaic")
+            ] == []
+    # and the list is no empty claim: the scan's own operations are on it
+    assert any("dynamic" in line for _, line in left)
+
+
+@pytest.mark.parametrize("program", sorted(PROGRAMS))
+def test_the_scopes_change_an_operations_metadata_and_nothing_else(
+        topo, mosaic, monkeypatch, program):
+    """The witness that the programs are equal: with every
+    ``metadata={...}`` struck out, and the tables of files, functions and
+    stack frames at the module's head that ``stack_frame_id`` indexes, the
+    optimized module is the same string with the scopes and with
+    ``jax.named_scope`` patched to a null context. No instruction's name
+    turned out to come from a scope, so nothing else is normalised."""
+    def strip(text):
+        assert "metadata={" in text and "\nStackFrames\n" in text
+        text = re.sub(r"(?ms)^FileNames\n.*?^StackFrames\n.*?\n\n", "",
+                      text)
+        return re.sub(r",? ?metadata=\{[^{}]*\}", "", text)
+
+    scoped = PROGRAMS[program](topo).compile().as_text()
+    assert "/attn/core/" in scoped and "/mlp/" in scoped
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    bare = PROGRAMS[program](topo).compile().as_text()
+    assert "/attn/" not in bare and "/mlp/" not in bare
+    assert strip(bare) == strip(scoped)
