@@ -522,18 +522,33 @@ def _latent_attention(params, h, positions, cfg: TransformerConfig):
     with v (and o) at their own width; ``W_o``. Prefill expands the latent
     to per-head K and V, as the published forward does. The caller enters
     the ``attn`` scope (its norm and residual belong there too); the
-    attention alone is ``core`` here."""
+    attention alone is ``core`` here.
+
+    The four projections take the tokens as rows, [1, B x L, ...]: over
+    ``[B, L]`` the TPU compiler asked at B = 2 for some weights in another
+    layout, and a weight that is a slice of a stacked leaf
+    (``_shortcut_block``) is then relaid as the whole leaf, once a forward
+    and held through the layers' loop (1.2 GB for the FFNs' ``wo``)."""
     a = cfg.latent
+    B, L, _ = h.shape
     norm = functools.partial(_rmsnorm, eps=cfg.norm_eps)
     w = {k: p.astype(h.dtype) for k, p in params.items()}
+
+    def rows(x):        # [B, L, ...] -> [1, B x L, ...]
+        return x.reshape(1, B * L, *x.shape[2:])
+
+    def seqs(x):        # and back
+        return x.reshape(B, L, *x.shape[2:])
+
+    h = rows(h)
     c_q = norm(jnp.einsum("bld,dr->blr", h, w["wq_a"]), w["q_norm"])
-    q = jnp.einsum("blr,rhk->blhk", c_q, w["wq_b"]) \
+    q = seqs(jnp.einsum("blr,rhk->blhk", c_q, w["wq_b"])) \
         * math.sqrt(cfg.d_model / a.q_rank)
     kv = jnp.einsum("bld,dr->blr", h, w["wkv_a"])
     c_kv = norm(kv[..., :a.kv_rank], w["kv_norm"]) \
         * math.sqrt(cfg.d_model / a.kv_rank)
-    k_v = jnp.einsum("blr,rhk->blhk", c_kv, w["wkv_b"])
-    k_r = _rope_interleaved(kv[..., None, a.kv_rank:], cfg.rope_theta,
+    k_v = seqs(jnp.einsum("blr,rhk->blhk", c_kv, w["wkv_b"]))
+    k_r = _rope_interleaved(seqs(kv[..., None, a.kv_rank:]), cfg.rope_theta,
                             positions)
     q = jnp.concatenate(
         [q[..., :a.nope_dim],
@@ -544,50 +559,73 @@ def _latent_attention(params, h, positions, cfg: TransformerConfig):
          jnp.broadcast_to(k_r, (*k_v.shape[:3], a.rope_dim))], axis=-1)
     with jax.named_scope("core"):
         o = _attention(q, k, k_v[..., a.nope_dim:], cfg, None)
-    return jnp.einsum("blhk,hkd->bld", o, w["wo"])
+    return seqs(jnp.einsum("blhk,hkd->bld", rows(o), w["wo"]))
 
 
-def _shortcut_block(params, x, positions, cfg: TransformerConfig):
-    """One ``SHORTCUT`` layer: ``h = x + MLA_0(N(x))``; ``u = N(h)``; ``s =
-    MoE(u)``; ``h = h + FFN_0(u)``; ``h = h + MLA_1(N(h))``; ``y = h +
-    FFN_1(N(h)) + s``. The experts' sum joins the stream one sub-layer late
-    (in a deployment its exchange overlaps the second attention). Returns
-    the states and the mixture's load (``expert.held_experts_apply``)."""
+def _at(p: jax.Array, *index) -> jax.Array:
+    """``p[index]`` for leading indices of which some are traced, as one
+    ``lax.dynamic_slice``: taken where the weight is used, it is the
+    product's own read of the stacked leaf and no copy of the slice."""
+    k = len(index)
+    cut = jax.lax.dynamic_slice(p, (*index, *(0,) * (p.ndim - k)),
+                                (*(1,) * k, *p.shape[k:]))
+    return cut.reshape(p.shape[k:])
+
+
+def _shortcut_block(blocks, l, x, positions, cfg: TransformerConfig):
+    """Layer ``l`` of the stacked ``SHORTCUT`` tree ``blocks``: ``h = x +
+    MLA_0(N(x))``; ``u = N(h)``; ``s = MoE(u)``; ``h = h + FFN_0(u)``; ``h =
+    h + MLA_1(N(h))``; ``y = h + FFN_1(N(h)) + s``. The experts' sum joins
+    the stream one sub-layer late (in a deployment its exchange overlaps the
+    second attention). Returns the states and the mixture's load
+    (``expert.held_experts_apply``).
+
+    The layer's weights are not cut out of the stack first: each leaf of the
+    two-a-layer trees is read at ``(l, i)`` and the router at ``(l,)`` where
+    it is used (``_at``), and the experts' leaves go to the mixture whole,
+    with ``l``."""
     B, L, d = x.shape
     norm = functools.partial(_rmsnorm, eps=cfg.norm_eps)
 
     def part(name, i):
-        return jax.tree.map(lambda p: p[i], params[name])
+        return jax.tree.map(lambda p: _at(p, l, i), blocks[name])
 
     def attention(h, i):
         with jax.named_scope("attn"):
             return h + _latent_attention(
-                part("attn", i), norm(h, params["ln_attn"][i]), positions,
-                cfg)
+                part("attn", i), norm(h, part("ln_attn", i)), positions, cfg)
+
+    def ffn(h, i):      # the tokens as rows: ``_latent_attention`` says why
+        return _mlp(part("mlp", i), h.reshape(1, B * L, d)).reshape(B, L, d)
 
     h = attention(x, 0)
     with jax.named_scope("mlp"):    # the norm both FFN_0 and the mixture read
-        u = norm(h, params["ln_mlp"][0])
+        u = norm(h, part("ln_mlp", 0))
     s, load = expert.held_experts_apply(
-        u.reshape(B * L, d), params["router"], params["experts"],
-        cfg.experts)
+        u.reshape(B * L, d), _at(blocks["router"], l), blocks["experts"],
+        cfg.experts, layer=l)
     with jax.named_scope("mlp"):
-        h = h + _mlp(part("mlp", 0), u)
+        h = h + ffn(u, 0)
     h = attention(h, 1)
     with jax.named_scope("mlp"):
-        y = h + _mlp(part("mlp", 1), norm(h, params["ln_mlp"][1]))
+        y = h + ffn(norm(h, part("ln_mlp", 1)), 1)
     with jax.named_scope("moe"):
         return y + s.reshape(B, L, d), load
 
 
 def _apply_shortcut(blocks, x, positions, cfg: TransformerConfig):
-    """The scan over a stack of ``SHORTCUT`` layers; the layers' loads go
-    to the program's counters in one call-back a forward."""
+    """A stack of ``SHORTCUT`` layers: one ``lax.scan`` (one compiled body)
+    over the layers' indices, the stacked tree closed over, so that the loop
+    slices no layer out of it (a scan over the tree itself copied every
+    leaf's slice, 2.5 GB a layer at the published widths; the block reads
+    each weight from the stack where it uses it). The layers' loads go to
+    the program's counters in one call-back a forward."""
     fn = functools.partial(_shortcut_block, cfg=cfg)
     if cfg.remat:
         fn = jax.checkpoint(fn)
-    x, loads = jax.lax.scan(lambda x, layer: fn(layer, x, positions), x,
-                            blocks)
+    n = blocks["router"].shape[0]
+    x, loads = jax.lax.scan(lambda x, l: fn(blocks, l, x, positions), x,
+                            jnp.arange(n))
     expert.record_load(loads, cfg.experts)
     return x
 

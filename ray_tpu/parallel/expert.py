@@ -9,7 +9,8 @@ own experts' part of the result for the tokens routed to them, dropless,
 and adds what a zero-compute expert returns (weight x token, computed where
 the token lives). What the absent experts would add is left out: on one
 device the layer runs without its exchange, and the partial sum is what
-goes on.
+goes on. The model's stack hands it the experts of all its layers and the
+layer's index: they are read as groups of the stacked leaves, not cut out.
 
 ``moe_apply`` is the older switch layer: top-1 routing with a capacity
 limit (dropped tokens pass through the residual path), experts sharded over
@@ -111,13 +112,22 @@ def _rows_tokens(rows, running, bounds):
 
 
 def held_experts_apply(u: jax.Array, router: jax.Array,
-                       experts: Dict[str, jax.Array], cfg: ExpertConfig
-                       ) -> Tuple[jax.Array, jax.Array]:
+                       experts: Dict[str, jax.Array], cfg: ExpertConfig,
+                       layer) -> Tuple[jax.Array, jax.Array]:
     """This device's part of a routed mixture, for tokens ``u`` [T, d]:
     ``sum_j w_tj Expert_e(u_t)`` over the chosen experts e that are held
     here, plus ``sum_j w_tj u_t`` over the chosen zero-compute indices
-    (``e >= n_routed``, identity). ``experts``: ``wi``, ``wg`` [count, d,
-    width] and ``wo`` [count, width, d] of the held experts (SwiGLU).
+    (``e >= n_routed``, identity). ``experts``: ``wi``, ``wg`` [n, count, d,
+    width] and ``wo`` [n, count, width, d] (SwiGLU), the held experts of a
+    stack's ``n`` layers (one layer's own leaves: ``p[None]``, layer 0), in
+    ``u``'s dtype; ``layer``, an index, traced or not, says whose are meant.
+
+    The leaves are viewed as ``n x count`` groups, of which the grouped
+    product is given sizes that are zero outside ``layer x count : (layer +
+    1) x count``. No layer's experts are cut out of the stack (the grouped
+    product takes a materialised operand: the cut would be a copy of them,
+    and so would a conversion, which is why the dtype is the caller's to
+    match); a group of no rows is not read.
 
     Dropless: no capacity. The pairs routed to held experts are listed by
     expert and taken ``CHUNK_ROWS`` at a time, for as many steps as they
@@ -134,6 +144,13 @@ def held_experts_apply(u: jax.Array, router: jax.Array,
     with jax.named_scope("moe"):
         T, d = u.shape
         count = cfg.held[1]
+        n = experts["wi"].shape[0]
+        for name, p in experts.items():
+            if p.dtype != u.dtype or p.shape[:2] != (n, count):
+                raise ValueError(
+                    f"experts[{name!r}] is {p.dtype}{list(p.shape)}: the "
+                    f"stacked leaves are [{n}, {count}, ...] in the tokens' "
+                    f"{u.dtype}")
         idx, weights = route(u, router, cfg)
         zero = idx >= cfg.n_routed
         out = (jnp.sum(jnp.where(zero, weights, 0.0), axis=-1, keepdims=True)
@@ -142,7 +159,8 @@ def held_experts_apply(u: jax.Array, router: jax.Array,
         weight, running, bounds = _held_rows(idx, weights, cfg)
         n_held = bounds[count]
         rows = min(CHUNK_ROWS, T * cfg.top_k)
-        w = {name: p.astype(u.dtype) for name, p in experts.items()}
+        w = {name: p.reshape(n * count, *p.shape[2:])
+             for name, p in experts.items()}
         product = functools.partial(jax.lax.ragged_dot,
                                     precision=_precision(u.dtype))
 
@@ -151,8 +169,10 @@ def held_experts_apply(u: jax.Array, router: jax.Array,
             mine = start + jnp.arange(rows, dtype=jnp.int32)
             valid = mine < n_held
             tok, e = _rows_tokens(mine, running, bounds)
-            sizes = (jnp.clip(bounds[1:] - start, 0, rows)
-                     - jnp.clip(bounds[:-1] - start, 0, rows))
+            sizes = jax.lax.dynamic_update_slice(
+                jnp.zeros((n * count,), jnp.int32),
+                jnp.clip(bounds[1:] - start, 0, rows)
+                - jnp.clip(bounds[:-1] - start, 0, rows), (layer * count,))
             x = u[tok]
             hidden = (jax.nn.silu(product(x, w["wi"], sizes))
                       * product(x, w["wg"], sizes))

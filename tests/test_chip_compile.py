@@ -18,6 +18,7 @@ collect. Keep every such test in this one file for the same reason.
 
 import contextlib
 import dataclasses
+import math
 import re
 import sys
 
@@ -352,17 +353,18 @@ def test_shortcut_layer_serve_forward_compiles(topo, mosaic):
 # -- the device scopes (``metric_names.DEVICE_SCOPES``) ----------------------
 
 
-def _internlm2_forward(topo):
-    """The offline cell's served program at its largest shape
-    (``serve_job``'s ``first_token``)."""
+def _first_token(topo, cell_name, batch=None, length=None):
+    """A serving cell's served program (``serve_job``'s ``first_token``),
+    lowered at ``[batch, length]``: the cell's largest shape by default."""
     from benchmark import manifest
-    cell = manifest.Manifest().cell("internlm2-serve-offline")
+    cell = manifest.Manifest().cell(cell_name)
     adapter = manifest.adapter(cell.config)
-    batch = max(cell.deploy["deployment"]["pad_batch_to"])
-    length = max(cell.deploy["deployment"]["length_buckets"])
+    deployment = cell.deploy["deployment"]
+    batch = batch or max(deployment["pad_batch_to"])
+    length = length or max(deployment["length_buckets"])
     cfg = adapter.program_config(
-        adapter.dims(cell.config, cell.job, cell.chips), length,
-        cell.deploy.get("model", {}))
+        adapter.dims(cell.config, cell.job, cell.chips),
+        max(deployment["length_buckets"]), cell.deploy.get("model", {}))
     one_chip = SingleDeviceSharding(topo.devices[0])
     params = jax.eval_shape(lambda: jax.tree.map(
         lambda p: p.astype(cfg.dtype),
@@ -378,7 +380,12 @@ def _internlm2_forward(topo):
         logits = transformer.head(params, x, cfg)[:, 0]
         return jnp.argmax(logits, axis=-1), jnp.max(logits, axis=-1)
 
-    return jax.jit(first_token).lower(params, tokens, last)
+    return jax.jit(first_token).lower(params, tokens, last), params
+
+
+def _internlm2_forward(topo):
+    """The offline cell's served program at its largest shape."""
+    return _first_token(topo, "internlm2-serve-offline")[0]
 
 
 PROGRAMS = {"mistral-step": lambda topo: _lower_cell_step(
@@ -389,20 +396,29 @@ _INSTRUCTION = re.compile(
     r"^\s+(?:ROOT )?%?[\w.\-]+ = .*?\s(fusion|convolution|custom-call)\(")
 
 
+def _computations(text):
+    """The optimized module's computations, ``{name: lines}``, and the
+    entry's name."""
+    bodies, entry, name = {}, None, None
+    for line in text.splitlines():
+        m = _COMPUTATION.match(line)
+        if m:
+            name = m.group(1)
+            bodies[name] = []
+            if line.startswith("ENTRY"):
+                entry = name
+        elif name is not None:
+            bodies[name].append(line)
+    return bodies, entry
+
+
 def _unscoped_work(text):
     """The optimized module's ``fusion``, ``convolution`` and
     ``custom-call`` instructions outside a fused computation whose
     ``op_name`` names no scope, each as (kind, line): a fusion round a
     convolution is a ``convolution fusion``, a Mosaic call ``mosaic``."""
     from ray_tpu.observability.metric_names import DEVICE_SCOPES
-    bodies, name = {}, None
-    for line in text.splitlines():
-        m = _COMPUTATION.match(line)
-        if m:
-            name = m.group(1)
-            bodies[name] = []
-        elif name is not None:
-            bodies[name].append(line)
+    bodies, _ = _computations(text)
     fused = {m.group(1) for body in bodies.values() for line in body
              for m in [re.search(r"\scalls=%?([\w.\-]+)", line)]
              if m and " fusion(" in line}
@@ -470,3 +486,162 @@ def test_the_scopes_change_an_operations_metadata_and_nothing_else(
     bare = PROGRAMS[program](topo).compile().as_text()
     assert "/attn/" not in bare and "/mlp/" not in bare
     assert strip(bare) == strip(scoped)
+
+
+# -- the shortcut stack reads its weights where they lie ---------------------
+
+_ASSIGNED = re.compile(r"^\s+(?:ROOT )?%?([\w.\-]+) = (.*?)\s([\w\-]+)\(")
+_ARRAY = re.compile(r"\b([a-z]+?)(\d*)\[([\d,]*)\]")
+
+
+def _loops(body):
+    """The bodies of a computation's ``while`` instructions."""
+    return [re.search(r"body=%?([\w.\-]+)", line).group(1)
+            for line in body if " while(" in line]
+
+
+def _result_bytes(shape):
+    """Bytes of an instruction's result, a tuple's elements summed."""
+    return sum(math.prod(int(n) for n in dims.split(",") if n)
+               * int(bits or 8) // 8
+               for _, bits, dims in _ARRAY.findall(shape))
+
+
+def _weight_copies(text, weights, least=32 * 2 ** 20):
+    """What the layers' loop (the entry's ``while``, and the loops nested in
+    it) writes of its weights before it uses them: every ``copy`` and every
+    fusion that holds no ``convolution`` and no custom call, outside a fused
+    computation, that reads a stacked weight (an operand of one of the
+    shapes ``weights``), a ``bitcast``, ``reshape`` or tuple element of one,
+    or such a copy of one, and whose result has ``least`` bytes or more, as
+    (name, bytes, op_name). A slice that a product reads for itself is a
+    fusion nested in the product's and is not on the list."""
+    bodies, entry = _computations(text)
+    inside, todo = [], _loops(bodies[entry])
+    while todo:
+        inside.append(todo.pop())
+        todo += _loops(bodies[inside[-1]])
+    found = []
+    for name in inside:
+        held = set()            # the computation's weights and their copies
+        for line in bodies[name]:
+            m = _ASSIGNED.match(line)
+            if not m:
+                continue
+            result, shape, kind = m.groups()
+            calls = re.search(r"\scalls=%?([\w.\-]+)", line)
+            operands = re.findall(r"%([\w.\-]+)", line.split(f" {kind}(")[1])
+            if kind in ("parameter", "get-tuple-element", "bitcast",
+                        "reshape"):     # no bytes written: a weight by its
+                if (shape.split("{")[0] in weights      # shape, or one's view
+                        or held & set(operands)):
+                    held.add(result)
+                continue
+            if (not held & set(operands) or kind not in ("copy", "fusion")
+                    or calls and any(
+                        " convolution(" in inner or " custom-call(" in inner
+                        for inner in bodies[calls.group(1)])):
+                continue
+            held.add(result)
+            size = _result_bytes(shape)
+            if size >= least:
+                op_name = re.search(r'op_name="([^"]*)"', line)
+                found.append((result, size,
+                              op_name.group(1) if op_name else ""))
+    return found
+
+
+_PLANTED = """\
+%steps (arg.1: (s32[], bf16[8,512,256])) -> (s32[], bf16[8,512,256]) {
+  %arg.1 = (s32[], bf16[8,512,256]{2,1,0}) parameter(0)
+  %groups = bf16[8,512,256]{2,1,0:T(8,128)(2,1)} get-tuple-element(%arg.1), index=1
+  %cut.1 = bf16[4,512,256]{2,1,0} fusion(%groups, %i), kind=kLoop, calls=%sliced, metadata={op_name="jit(f)/while/body/moe/experts/dynamic_slice"}
+  %small = bf16[1,512,256]{2,1,0} fusion(%groups, %i), kind=kLoop, calls=%sliced
+  %ragged-dot = bf16[64,256]{1,0} custom-call(%x, %cut.1), custom_call_target="ragged"
+  ROOT %tuple.1 = (s32[], bf16[8,512,256]{2,1,0}) tuple(%i, %groups)
+}
+
+%layers (arg.2: (s32[], bf16[2,4,512,256])) -> (s32[], bf16[2,4,512,256]) {
+  %arg.2 = (s32[], bf16[2,4,512,256]{3,2,1,0}) parameter(0)
+  %leaf = bf16[2,4,512,256]{3,2,1,0} get-tuple-element(%arg.2), index=1
+  %bitcast.1 = bf16[8,512,256]{2,1,0} bitcast(%leaf)
+  %copy.1 = bf16[8,512,256]{2,1,0} copy(%bitcast.1), metadata={op_name="jit(f)/while/body/moe/reshape"}
+  %used = bf16[64,256]{1,0} fusion(%x, %leaf), kind=kOutput, calls=%product
+  %tuple.2 = (s32[], bf16[8,512,256]{2,1,0}) tuple(%i, %copy.1)
+  %while.2 = (s32[], bf16[8,512,256]{2,1,0}) while(%tuple.2), condition=%cond, body=%steps
+  ROOT %tuple.3 = (s32[], bf16[2,4,512,256]{3,2,1,0}) tuple(%i, %leaf)
+}
+
+%sliced (p.0: bf16[8,512,256], p.1: s32[]) -> bf16[4,512,256] {
+  ROOT %dynamic-slice.1 = bf16[4,512,256]{2,1,0} dynamic-slice(%p.0, %p.1, %c, %c)
+}
+
+%product (p.2: bf16[64,512], p.3: bf16[2,4,512,256]) -> bf16[64,256] {
+  ROOT %convolution.1 = bf16[64,256]{1,0} convolution(%p.2, %slice.1), dim_labels=bf_io->bf
+}
+
+ENTRY %main (w: bf16[2,4,512,256]) -> bf16[2,4,512,256] {
+  %w = bf16[2,4,512,256]{3,2,1,0} parameter(0)
+  %outside = bf16[2,4,512,256]{3,2,1,0} copy(%w)
+  %tuple.4 = (s32[], bf16[2,4,512,256]{3,2,1,0}) tuple(%c, %outside)
+  %while.1 = (s32[], bf16[2,4,512,256]{3,2,1,0}) while(%tuple.4), condition=%cond, body=%layers
+  ROOT %out = bf16[2,4,512,256]{3,2,1,0} get-tuple-element(%while.1), index=1
+}
+"""
+
+
+def test_the_helper_follows_a_weight_through_a_bitcast_and_into_the_steps():
+    """``_weight_copies`` on a module written by hand: a copy of the stack
+    behind its ``bitcast`` to groups, in the layers' loop, and a cut of one
+    layer's groups in the loop nested in it are both found, with their
+    bytes and op_names; a copy outside the loops, a cut under the least
+    size and a product's own read of the leaf are not."""
+    weights = {"bf16[2,4,512,256]", "bf16[8,512,256]"}
+    found = _weight_copies(_PLANTED, weights, least=2 ** 20)
+    assert sorted(found) == [
+        ("copy.1", 2 * 8 * 512 * 256, "jit(f)/while/body/moe/reshape"),
+        ("cut.1", 2 * 4 * 512 * 256,
+         "jit(f)/while/body/moe/experts/dynamic_slice")]
+    # unseeded with the groups' shape, the nested loop's cut goes unseen
+    assert [name for name, _, _ in _weight_copies(
+        _PLANTED, {"bf16[2,4,512,256]"}, least=2 ** 20)] == ["copy.1"]
+
+
+def test_the_prefill_cells_layers_loop_copies_no_weight(topo, mosaic):
+    """The prefill cell's served program, four layers at ``[1, 2048]`` and
+    the published widths: the layers are one ``while`` with the dropless
+    steps' ``while`` nested in it (``longcat_counts.expert_ops`` tells the
+    mixture's operations by that), both kernels are in the text, and the
+    loop writes no copy of a weight: each product reads its slice of the
+    stacked leaf, the grouped product its groups. A scan over the stacked
+    tree wrote 2.50 GB of such copies a layer (eleven of 32 MB or more) and
+    held 1.35 GB of temporaries."""
+    lowered, params = _first_token(topo, "longcat-flash-serve-prefill",
+                                   batch=1, length=2048)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert "flash_fwd" in text and "ragged-dot" in text
+    bodies, entry = _computations(text)
+    layers = _loops(bodies[entry])
+    assert len(layers) == 1
+    steps = _loops(bodies[layers[0]])
+    assert len(steps) == 1 and any(
+        "ragged-dot" in line for line in bodies[steps[0]])
+    # the leaves of which one layer's slice is large enough to count (a norm's
+    # weight rides the fusion that applies it)
+    def named(*shape):
+        return "bf16[" + ",".join(map(str, shape)) + "]"
+
+    blocks = params["blocks"][transformer.SHORTCUT]
+    stacked = {named(*p.shape) for p in jax.tree.leaves(blocks)
+               if 2 * math.prod(p.shape[1:]) >= 32 * 2 ** 20}
+    # and the experts' as the dropless loop is handed them: n x count groups
+    stacked |= {named(p.shape[0] * p.shape[1], *p.shape[2:])
+                for p in blocks["experts"].values()}
+    assert any(f" {shape}" in line for shape in stacked
+               for line in bodies[steps[0]])
+    copies = _weight_copies(text, stacked)
+    print(f"weight copies in the layers' loop: {len(copies)}, "
+          f"{sum(size for _, size, _ in copies) / 1e9:.3f} GB a layer")
+    assert copies == []
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.8e9

@@ -45,11 +45,10 @@ def held(cfg, first, count):
         cfg.experts, held=(first, count)))
 
 
-@pytest.fixture(scope="module")
-def params():
+def _seeded(cfg):
     """Seeded weights, every norm weight moved off its initial 1 so that a
     norm left out, or its weight, shows."""
-    params = transformer.init_params(jax.random.PRNGKey(41), TINY)
+    params = transformer.init_params(jax.random.PRNGKey(41), cfg)
     leaves, tree = jax.tree_util.tree_flatten_with_path(params)
     keys = jax.random.split(jax.random.PRNGKey(42), len(leaves))
     moved = [p * (1 + 0.3 * jax.random.normal(k, p.shape))
@@ -57,6 +56,23 @@ def params():
              or "ln" in jax.tree_util.keystr(path) else p
              for (path, p), k in zip(leaves, keys)]
     return jax.tree.unflatten(tree, moved)
+
+
+@pytest.fixture(scope="module")
+def params():
+    return _seeded(TINY)
+
+
+def _of_depth(n):
+    """``TINY`` and its reference's sizes at ``n`` layers."""
+    return (dataclasses.replace(TINY, n_layers=n,
+                                layer_kinds=(SHORTCUT,) * n),
+            {**TINY_DIMS, "n_layers": n})
+
+
+def _one_layer(blocks, l):
+    """Layer ``l`` cut out of a stacked tree, as a stack of one."""
+    return jax.tree.map(lambda p: p[l:l + 1], blocks)
 
 
 def _last_logits(params, tokens, last, cfg):
@@ -147,6 +163,11 @@ def _every_expert_on_every_token(u, router, experts, cfg):
     return out
 
 
+def _alone(experts):
+    """One layer's leaves [count, ...] as a stack of one (its layer: 0)."""
+    return jax.tree.map(lambda p: p[None], experts)
+
+
 @pytest.mark.parametrize("first,count,rows", [(0, 16, 1024), (4, 4, 16),
                                               (12, 4, 7)])
 def test_held_experts_part_is_the_plain_sum(monkeypatch, first, count, rows):
@@ -156,7 +177,7 @@ def test_held_experts_part_is_the_plain_sum(monkeypatch, first, count, rows):
     cfg = dataclasses.replace(EXPERTS, held=(first, count))
     router, experts = _mixture_weights(1, cfg=cfg)
     u = jax.random.normal(jax.random.PRNGKey(2), (50, 64))
-    got, load = held_experts_apply(u, router, experts, cfg)
+    got, load = held_experts_apply(u, router, _alone(experts), cfg, 0)
     want = _every_expert_on_every_token(u, router, experts, cfg)
     np.testing.assert_allclose(got, want, atol=2e-5)
     idx, _ = expert.route(u, router, cfg)
@@ -187,7 +208,7 @@ def test_dropless_when_every_token_goes_to_one_held_expert(monkeypatch,
     cfg = dataclasses.replace(EXPERTS, held=(4, 4))
     router, u = _forced_router((5, 0, 1, 2))
     _, experts = _mixture_weights(4, cfg=cfg)
-    got, load = held_experts_apply(u, router, experts, cfg)
+    got, load = held_experts_apply(u, router, _alone(experts), cfg, 0)
     np.testing.assert_allclose(
         got, _every_expert_on_every_token(u, router, experts, cfg),
         rtol=1e-5, atol=1e-5)
@@ -199,7 +220,7 @@ def test_no_token_for_any_held_expert_gives_the_zero_compute_part_alone():
     cfg = dataclasses.replace(EXPERTS, held=(4, 4))
     router, u = _forced_router((0, 1, 2, 17))
     _, experts = _mixture_weights(5, cfg=cfg)
-    got, load = held_experts_apply(u, router, experts, cfg)
+    got, load = held_experts_apply(u, router, _alone(experts), cfg, 0)
     _, w = expert.route(u, router, cfg)
     # index 17 has the largest logit: top_k lists it first
     np.testing.assert_allclose(got, w[:, :1] * u, rtol=1e-6)
@@ -209,7 +230,7 @@ def test_no_token_for_any_held_expert_gives_the_zero_compute_part_alone():
 def test_a_router_forced_onto_zero_compute_indices_returns_sum_w_times_u():
     router, u = _forced_router((16, 18, 20, 23))
     _, experts = _mixture_weights(6)
-    got, load = held_experts_apply(u, router, experts, EXPERTS)
+    got, load = held_experts_apply(u, router, _alone(experts), EXPERTS, 0)
     _, w = expert.route(u, router, EXPERTS)
     np.testing.assert_allclose(got, jnp.sum(w, -1, keepdims=True) * u,
                                rtol=1e-6)
@@ -253,6 +274,89 @@ def test_the_layers_counters_are_in_the_registry_after_a_forward(params):
     assert 0 < held_pairs() - before <= 2 * 48 * 4
 
 
+# -- the experts read as groups of the stacked leaf ------------------------------------
+
+
+def _stacked_mixture(n=3, cfg=EXPERTS):
+    """``n`` layers' routers and held experts, the leaves stacked [n, count,
+    ...]; every router shuns held expert 2, so no token chooses it."""
+    layers = [_mixture_weights(20 + l, cfg=cfg) for l in range(n)]
+    routers = jnp.stack([r for r, _ in layers]).at[:, :, cfg.held[0] + 2].set(
+        0.0).at[:, 0, cfg.held[0] + 2].set(-1e4)
+    experts = jax.tree.map(lambda *ps: jnp.stack(ps),
+                           *(e for _, e in layers))
+    u = jax.random.normal(jax.random.PRNGKey(21), (50, 64)).at[:, 0].set(1.0)
+    return routers, experts, u
+
+
+@pytest.mark.parametrize("layer", [0, 1, 2])
+@pytest.mark.parametrize("first,count,rows", [(0, 16, 1024), (4, 4, 7)])
+def test_a_layers_experts_in_the_stack_are_its_own_leaves(
+        monkeypatch, first, count, rows, layer):
+    """The stacked leaves with ``layer=l`` give what layer l's own leaves
+    give, sum and load: with an expert no token chose (an empty group
+    inside the layer's), with chunks that end inside a group (7 rows a
+    step) and under ``jit`` with the index traced."""
+    monkeypatch.setattr(expert, "CHUNK_ROWS", rows)
+    cfg = dataclasses.replace(EXPERTS, held=(first, count))
+    routers, experts, u = _stacked_mixture(cfg=cfg)
+    mine = jax.tree.map(lambda p: p[layer], experts)
+    want, want_load = held_experts_apply(u, routers[layer], _alone(mine),
+                                         cfg, 0)
+    got, load = jax.jit(lambda l: held_experts_apply(
+        u, routers[layer], experts, cfg, layer=l))(layer)
+    np.testing.assert_allclose(got, want, atol=2e-5)
+    assert load.tolist() == want_load.tolist()
+    idx, _ = expert.route(u, routers[layer], cfg)
+    bounds = np.cumsum([0] + [int(jnp.sum(idx == first + e))
+                             for e in range(count)])
+    assert bounds[2] == bounds[3] and bounds[-1] == load[0]  # an empty group
+    assert rows == 1024 or any(                 # a step ends inside a group
+        lo < step < hi for step in range(rows, bounds[-1], rows)
+        for lo, hi in zip(bounds, bounds[1:]))
+    np.testing.assert_allclose(
+        want, _every_expert_on_every_token(u, routers[layer], mine, cfg),
+        atol=2e-5)
+
+
+@pytest.mark.parametrize("layer", [0, 1, 2])
+def test_only_its_own_layers_experts_reach_a_layers_sum(layer):
+    """Every other layer's experts zeroed: the same sum to the bit (their
+    groups have no rows); this layer's zeroed: the zero-compute part
+    alone."""
+    routers, experts, u = _stacked_mixture()
+    keep = (jnp.arange(3) == layer)[:, None, None, None]
+    got, load = held_experts_apply(u, routers[layer], experts, EXPERTS,
+                                   layer=layer)
+    others, _ = held_experts_apply(
+        u, routers[layer], jax.tree.map(lambda p: jnp.where(keep, p, 0),
+                                        experts), EXPERTS, layer=layer)
+    np.testing.assert_array_equal(got, others)
+    own, own_load = held_experts_apply(
+        u, routers[layer], jax.tree.map(lambda p: jnp.where(keep, 0, p),
+                                        experts), EXPERTS, layer=layer)
+    idx, w = expert.route(u, routers[layer], EXPERTS)
+    zero = jnp.sum(jnp.where(idx >= 16, w, 0), -1, keepdims=True) * u
+    np.testing.assert_allclose(own, zero, rtol=1e-6, atol=1e-6)
+    assert own_load.tolist() == load.tolist()
+    assert float(jnp.abs(got - own).max()) > 0.1
+
+
+@pytest.mark.parametrize("fault", ["float32 under bfloat16 tokens",
+                                   "one layer's own leaves"])
+def test_leaves_that_would_have_to_be_copied_are_refused_by_name(fault):
+    """The mixture converts and cuts nothing: leaves in another dtype than
+    the tokens', or not stacked [n, count, ...], raise before any product."""
+    routers, experts, u = _stacked_mixture()
+    if fault.startswith("float32"):
+        u = u.astype(jnp.bfloat16)
+    else:
+        experts = jax.tree.map(lambda p: p[1], experts)
+    with pytest.raises(ValueError, match=r"experts\['w[igo]'\] is .*stacked "
+                                         r"leaves are \[\d+, 16, \.\.\.\]"):
+        held_experts_apply(u, routers[1], experts, EXPERTS, 1)
+
+
 # -- the layer against the reference ---------------------------------------------------
 
 
@@ -275,6 +379,45 @@ def test_prefill_at_two_lengths_with_right_padding_agrees_with_the_reference(
         np.testing.assert_allclose(row, want, atol=1e-4)
 
 
+@pytest.mark.parametrize("n_layers", [1, 2, 3])
+def test_the_stacked_forward_agrees_with_the_reference(n_layers):
+    """One, two and three layers read out of the stacked tree by one scan
+    over their indices: the last position's logits are the reference's."""
+    cfg, dims = _of_depth(n_layers)
+    params = _seeded(cfg)
+    tokens = jax.random.randint(jax.random.PRNGKey(14), (2, 20), 0, 96)
+    got = _last_logits(params, tokens, jnp.array([19, 19]), cfg)
+    want = longcat_reference.tree_last_logits(params, tokens, dims)
+    np.testing.assert_allclose(got, want, atol=1e-4)
+
+
+@pytest.mark.parametrize("n_layers", [1, 2, 3])
+def test_the_stacked_forward_is_the_block_on_each_layer_cut_out(n_layers):
+    """The scan over the stack against ``_shortcut_block`` applied layer by
+    layer to that layer's weights alone (a stack of one, read at 0): the
+    same states to float32's last digits, and the same loads."""
+    cfg, _ = _of_depth(n_layers)
+    blocks = _seeded(cfg)["blocks"][SHORTCUT]
+    x = jax.random.normal(jax.random.PRNGKey(15), (2, 20, 64))
+    positions = jnp.broadcast_to(jnp.arange(20)[None], (2, 20))
+    loads = []
+    got = jax.jit(lambda b, x: transformer._apply_shortcut(
+        b, x, positions, cfg))(blocks, x)
+    want = x
+    for l in range(n_layers):
+        want, load = transformer._shortcut_block(
+            _one_layer(blocks, l), 0, want, positions, cfg)
+        loads.append(load)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+    # the wrong layer's weights would show: the layers differ
+    if n_layers > 1:
+        swapped, _ = transformer._shortcut_block(
+            _one_layer(blocks, 1), 0, x, positions, cfg)
+        first, _ = transformer._shortcut_block(blocks, 0, x, positions, cfg)
+        assert float(jnp.abs(swapped - first).max()) > 0.1
+    assert all(int(load[0]) > 0 for load in loads)
+
+
 def test_the_shares_add_up_to_the_uncut_layer(params):
     """Four shares of four experts each, the zero-compute part counted
     once, are the uncut reference's mixture; and so for the whole layer:
@@ -289,7 +432,8 @@ def test_the_shares_add_up_to_the_uncut_layer(params):
         cfg = dataclasses.replace(EXPERTS, held=(4 * share, 4))
         mine = jax.tree.map(lambda p: p[4 * share:4 * share + 4],
                             layer["experts"])
-        part, _ = held_experts_apply(u, layer["router"], mine, cfg)
+        part, _ = held_experts_apply(u, layer["router"], _alone(mine), cfg,
+                                     0)
         total = total + (part - zero)
     with jax.default_matmul_precision("highest"):
         want = longcat_reference.experts_part(
@@ -298,7 +442,8 @@ def test_the_shares_add_up_to_the_uncut_layer(params):
 
     def layer_out(cfg, tree, x):
         positions = jnp.arange(x.shape[1])[None]
-        return transformer._shortcut_block(tree, x, positions, cfg)[0]
+        stack = jax.tree.map(lambda p: p[None], tree)   # a stack of one
+        return transformer._shortcut_block(stack, 0, x, positions, cfg)[0]
 
     x = jax.random.normal(jax.random.PRNGKey(12), (1, 20, 64))
     uncut = layer_out(TINY, layer, x)
