@@ -9,12 +9,14 @@ routes every token over *all* the experts at the published width and
 experts-per-token (``route``: softmax or sigmoid scores, the choice on the
 scores or on scores plus a bias, the weights as they are or normalised over
 the chosen: ``ExpertConfig``'s fields), computes its own experts' part of the
-result for the tokens routed to them, dropless, and adds what a zero-compute
-expert returns (weight x token, computed where the token lives). What the
-absent experts would add is left out: on one device the layer runs without
-its exchange, and the partial sum is what goes on. The model's stack hands
-it the experts of all its layers and the layer's index: they are read as
-groups of the stacked leaves, not cut out.
+result for the tokens routed to them, dropless (the pairs routed to held
+experts are listed by expert once a layer call, each placed by one counting
+pass, and a loop takes the list ``CHUNK_ROWS`` rows a step), and adds what a
+zero-compute expert returns (weight x token, computed where the token
+lives). What the absent experts would add is left out: on one device the
+layer runs without its exchange, and the partial sum is what goes on. The
+model's stack hands it the experts of all its layers and the layer's index:
+they are read as groups of the stacked leaves, not cut out.
 
 ``moe_apply`` is the older switch layer: top-1 routing with a capacity
 limit (dropped tokens pass through the residual path), experts sharded over
@@ -112,37 +114,37 @@ def route(u: jax.Array, router: jax.Array, cfg: ExpertConfig,
         return idx, cfg.scale * w
 
 
-def _held_rows(idx, weights, cfg: ExpertConfig):
-    """Which tokens chose which held expert (a token picks an expert once at
-    most): ``(weight [T, count], running count [count, T], bounds
-    [count + 1])``. Held expert e's pairs, in token order, are rows
-    ``bounds[e]:bounds[e + 1]`` of the sorted list that ``_rows_tokens``
-    reads; ``bounds[count]`` pairs are held in all. Counting, not a sort: a
-    sort of a call's pairs takes the TPU compiler half a minute a shape."""
+def _held_rows(idx, weights, cfg: ExpertConfig, length: int):
+    """The call's pairs routed to held experts, listed by expert and within
+    an expert by token: ``(row_tok [length], row_w [length], bounds [count +
+    1])``, ``length`` the ``T x k`` pairs or more. Held expert c's pairs are
+    rows ``bounds[c]:bounds[c + 1]`` of the list; row s holds its pair's
+    token and weight; ``bounds[count]`` pairs are held in all, and the rows
+    past them hold the token ``T`` (no token) at weight 0. Counting, not a
+    sort (a sort of a call's pairs takes the TPU compiler half a minute a
+    shape): pair (t, j) on held expert c is the ``running[t, c]``-th of c's,
+    so its row is ``bounds[c] + running[t, c] - 1``, and a scatter of the
+    ``T x k`` pairs' tokens and one of their weights write the list (a token
+    picks an expert once at most: a held pair has one row and a row one
+    pair). A pair that is not held is placed past the list's end, which the
+    scatters drop."""
     first, count = cfg.held
-    chosen = idx[:, :, None] == first + jnp.arange(count)       # [T, k, count]
-    weight = jnp.sum(jnp.where(chosen, weights[:, :, None], 0.0), axis=1)
+    T, k = idx.shape
+    chosen = (idx - first)[:, :, None] == jnp.arange(count)     # [T, k, count]
     running = jnp.cumsum(jnp.any(chosen, axis=1).astype(jnp.int32), axis=0)
     bounds = jnp.concatenate([jnp.zeros((1,), jnp.int32),
                               jnp.cumsum(running[-1])])
-    return weight, running.T, bounds
-
-
-def _rows_tokens(rows, running, bounds):
-    """The (token, held expert) of rows ``rows`` of the sorted list: row s
-    is the ``s - bounds[e] + 1``-th token that chose expert e, found by a
-    binary search of e's running count. Rows past ``bounds[-1]`` give
-    whatever; the caller masks them."""
-    count, T = running.shape
-    e = jnp.minimum(jnp.searchsorted(bounds[1:], rows, side="right"),
-                    count - 1)
-    nth = rows - bounds[e] + 1
-    lo, hi = jnp.zeros_like(rows), jnp.full_like(rows, T - 1)
-    for _ in range(max(1, (T - 1).bit_length())):
-        mid = (lo + hi) // 2
-        reached = running[e, mid] >= nth
-        lo, hi = jnp.where(reached, lo, mid + 1), jnp.where(reached, mid, hi)
-    return jnp.minimum(lo, T - 1), e
+    # the pair's row, read off its expert's column by the one-hot (a gather
+    # of T x k elements costs the TPU what a scatter of them does)
+    place = jnp.sum(jnp.where(
+        chosen, (bounds[:-1] + running - 1)[:, None, :], 0), axis=-1)
+    place = jnp.where(jnp.any(chosen, axis=-1), place, length).reshape(-1)
+    tok = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32)[:, None], (T, k))
+    row_tok = jnp.full((length,), T, jnp.int32).at[place].set(
+        tok.reshape(-1), mode="drop")
+    row_w = jnp.zeros((length,), jnp.float32).at[place].set(
+        weights.reshape(-1), mode="drop")
+    return row_tok, row_w, bounds
 
 
 def held_experts_apply(u: jax.Array, router: jax.Array,
@@ -166,13 +168,16 @@ def held_experts_apply(u: jax.Array, router: jax.Array,
     match); a group of no rows is not read.
 
     Dropless: no capacity. The pairs routed to held experts are listed by
-    expert and taken ``CHUNK_ROWS`` at a time, for as many steps as they
-    fill: gather the rows' tokens, three grouped products over the held
-    experts (``lax.ragged_dot``, which the TPU compiles to one Mosaic call
-    over ragged groups), weigh, scatter-add. So the products' work grows
-    with the routed pairs, not with T x experts (only the list's
-    bookkeeping, a [T, count] running count of integers, does), and every
-    token sent to one expert or none is exact alike.
+    expert once a call, outside the loop (``_held_rows``: counting, not a
+    sort; each pair is placed where its expert's running count says), and
+    taken ``CHUNK_ROWS`` at a time, for as many steps as they fill: slice
+    the step's tokens and weights off the list, gather the tokens' rows,
+    three grouped products over the held experts (``lax.ragged_dot``, which
+    the TPU compiles to one Mosaic call over ragged groups), weigh,
+    scatter-add. So the products' work grows with the routed pairs, not with
+    T x experts (only the list's making, a [T, count] running count of
+    integers and one placement of the T x k pairs, does), and every token
+    sent to one expert or none is exact alike.
 
     Returns the partial sum [T, d] in ``u``'s dtype and the layer's load,
     int32 [4]: pairs routed to held, absent and zero-compute experts, and
@@ -195,9 +200,11 @@ def held_experts_apply(u: jax.Array, router: jax.Array,
         out = (jnp.sum(jnp.where(zero, weights, 0.0), axis=-1, keepdims=True)
                * u.astype(jnp.float32))
 
-        weight, running, bounds = _held_rows(idx, weights, cfg)
-        n_held = bounds[count]
         rows = min(CHUNK_ROWS, T * cfg.top_k)
+        # whole steps of rows, so that the last step's slice is its own
+        row_tok, row_w, bounds = _held_rows(idx, weights, cfg,
+                                            -(-idx.size // rows) * rows)
+        n_held = bounds[count]
         w = {name: p.reshape(n * count, *p.shape[2:])
              for name, p in experts.items()}
         product = functools.partial(jax.lax.ragged_dot,
@@ -205,22 +212,21 @@ def held_experts_apply(u: jax.Array, router: jax.Array,
 
         def step(i, out):
             start = i * rows
-            mine = start + jnp.arange(rows, dtype=jnp.int32)
-            valid = mine < n_held
-            tok, e = _rows_tokens(mine, running, bounds)
+            tok = jax.lax.dynamic_slice(row_tok, (start,), (rows,))
+            wt = jax.lax.dynamic_slice(row_w, (start,), (rows,))
             sizes = jax.lax.dynamic_update_slice(
                 jnp.zeros((n * count,), jnp.int32),
                 jnp.clip(bounds[1:] - start, 0, rows)
                 - jnp.clip(bounds[:-1] - start, 0, rows), (layer * count,))
-            x = u[tok]
+            # rows past the held pairs hold token T: the gather clamps it,
+            # the rows belong to no group, and whatever the product left
+            # there the scatter-add drops
+            x = u.at[tok].get(mode="clip")
             hidden = (jax.nn.silu(product(x, w["wi"], sizes))
                       * product(x, w["wg"], sizes))
             y = product(hidden, w["wo"], sizes,
                         preferred_element_type=jnp.float32)
-            # rows past the held pairs belong to no group: whatever the product
-            # left there is dropped
-            y = jnp.where(valid[:, None], y * weight[tok, e][:, None], 0.0)
-            return out.at[jnp.where(valid, tok, T)].add(y, mode="drop")
+            return out.at[tok].add(y * wt[:, None], mode="drop")
 
         with jax.named_scope("experts"):
             out = jax.lax.fori_loop(0, (n_held + rows - 1) // rows, step, out)
@@ -265,9 +271,12 @@ def _record(count: int, loads) -> None:
         pairs.inc(n, tags={"dest": dest})
     load_max.inc(most)
     calls.inc(len(loads))
+    # ``placed``: the pairs the layer calls' one counting pass wrote into
+    # their lists, every held pair once (a program that searched for its
+    # rows a step has no such attribute)
     with observability.span("moe.route", held=held, absent=absent, zero=zero,
                             load_max=most, layers=len(loads), experts=count,
-                            steps=steps):
+                            steps=steps, placed=held):
         pass
 
 

@@ -500,6 +500,13 @@ def _loops(body):
             for line in body if " while(" in line]
 
 
+def _step_bodies(bodies):
+    """The ``while`` bodies that hold a grouped product (``ragged-dot``): the
+    dropless loop's steps, one computation for each place it is traced."""
+    return sorted({body for lines in bodies.values() for body in _loops(lines)
+                   if any("ragged-dot" in line for line in bodies[body])})
+
+
 def _result_bytes(shape):
     """Bytes of an instruction's result, a tuple's elements summed."""
     return sum(math.prod(int(n) for n in dims.split(",") if n)
@@ -666,6 +673,9 @@ def test_flash_kernel_compiles_at_64_wide_grouped_heads(topo, mosaic, length):
 
 # what ``memory_analysis()`` reads of the served program at [1, 8192], as the
 # configuration's ``reduced["serve.1"]["why"]`` states it (GB)
+# (0.273 GB of temporaries while a step of the dropless loop searched for its
+# rows; 0.270 since a layer call lists them once, PR 47: the list is two
+# vectors of 32,768 elements)
 LFM2_ARGUMENT_GB, LFM2_TEMP_GB = 10.356, 0.273
 
 
@@ -712,6 +722,24 @@ def test_the_expert_load_cells_forward_compiles_and_copies_no_weight(
     print(f"weight copies in the layers' loops: {len(copies)}, "
           f"{sum(size for _, size, _ in copies) / 1e9:.3f} GB")
     assert copies == []
+    # a step slices the list of its pairs and searches for nothing: the
+    # loops that hold the grouped products, one a mixture layer's trace,
+    # nest no loop and are handed no [64, T] count to gather from (the
+    # parent's carried ``s32[64, T]`` and ran a ``searchsorted`` loop and
+    # 13 gathers a step); the module's sorts are the router's top-k and
+    # the compiler's own of a step's 1,024 scatter-add indices, as in the
+    # parent: the call's pairs are placed by counting, no sort of them
+    steps = _step_bodies(bodies)
+    assert len(steps) == 4          # two runs' and the two attention layers'
+    for body in steps:
+        assert _loops(bodies[body]) == []
+        assert not any(f"s32[{shape}]" in line for line in bodies[body]
+                       for shape in (f"64,{length}", f"{length},64"))
+    sorts = [line for line in text.splitlines() if " sort(" in line]
+    assert sorts and all(
+        re.search(r'op_name="[^"]*/(router/top_k|experts/while/body/'
+                  r'scatter-add)"', line) for line in sorts)
+    assert not any(f"[{4 * length}]" in line for line in sorts)
     memory = compiled.memory_analysis()
     print(f"[1, {length}]: arguments {memory.argument_size_in_bytes / 1e9:.3f}"
           f" GB, temporaries {memory.temp_size_in_bytes / 1e9:.3f} GB")

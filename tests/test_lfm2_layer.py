@@ -355,10 +355,45 @@ def test_the_route_span_carries_the_loops_steps(monkeypatch):
     # ceil(200 / 64) + ceil(64 / 64) + 0 + ceil(65 / 64)
     assert name == "moe.route" and attrs["steps"] == 4 + 1 + 0 + 2
     assert (attrs["held"], attrs["layers"], attrs["experts"]) == (329, 4, 16)
+    assert attrs["placed"] == 329
     # a call of fewer pairs than a step's rows takes them in one step
     seen.clear()
     expert._record(16, np.array([[20, 4, 0, 3]]))
     assert seen[0][1]["steps"] == 1
+
+
+def test_the_route_span_says_the_pairs_were_placed(monkeypatch):
+    """``placed``: the pairs the layer calls' counting pass wrote into their
+    lists, through a jitted forward's own call-back: every held pair once,
+    so ``placed == held`` (a share held: fewer than the routed pairs), and a
+    reader can tell a program that lists once from one that searches."""
+    from ray_tpu import observability
+    seen = []
+    monkeypatch.setattr(
+        observability, "span",
+        lambda name, **attrs: seen.append((name, attrs)) or _Null())
+    cfg = dataclasses.replace(EXPERTS, held=(4, 8))
+    layer = _mixture_layer(22)
+    mine = jax.tree.map(lambda p: p[None, 4:12], layer["experts"])
+    u = jax.random.normal(jax.random.PRNGKey(23), (2, 50, 64))
+
+    @jax.jit
+    def forward(u):
+        outs, loads = zip(*(held_experts_apply(
+            x, layer["router"], mine, cfg, 0, bias=layer["router_bias"])
+            for x in u))
+        expert.record_load(jnp.stack(loads), cfg)
+        return jnp.stack(outs), jnp.stack(loads)
+
+    _, loads = forward(u)
+    jax.effects_barrier()
+    (name, attrs), = seen
+    held = int(loads[:, 0].sum())
+    assert name == "moe.route" and attrs["placed"] == attrs["held"] == held
+    assert 0 < held < 2 * 200 and attrs["layers"] == 2
+    idx = [expert.route(x, layer["router"], cfg, layer["router_bias"])[0]
+           for x in u]
+    assert held == sum(int(jnp.sum((i >= 4) & (i < 12))) for i in idx)
 
 
 class _Null:
@@ -561,7 +596,7 @@ def test_the_conv_mixer_runs_under_a_scope_of_its_own_at_the_layers_top():
 
 @pytest.mark.parametrize("name,cfg,want", [
     ("sala", MIXED, ("b02615aa0512187f", "8ca80f33e12a0f43")),
-    ("longcat", TINY_LONGCAT, ("08181005a897495d", "c1bc369a39beb7b8")),
+    ("longcat", TINY_LONGCAT, ("08181005a897495d", "3a226fff0b2fd67e")),
 ])
 def test_the_mixed_and_the_shortcut_programs_trace_what_the_parent_traced(
         name, cfg, want):
@@ -569,7 +604,11 @@ def test_the_mixed_and_the_shortcut_programs_trace_what_the_parent_traced(
     sizes: ``init_params`` and ``backbone`` + ``head`` are the jaxprs of
     the commit before this file, to the letter (read off it by this
     function): the router's new fields default to LongCat's router, and the
-    ``sparse`` and ``linear`` runs still scan their own slices."""
+    ``sparse`` and ``linear`` runs still scan their own slices. LongCat's
+    second digest is read off the commit that lists a call's held pairs once
+    (``expert._held_rows``' placement, one path for a share held and for all:
+    it was ``c1bc369a39beb7b8`` while a step searched for its rows); its
+    ``init_params`` and the mixed stack's pair are the older commit's."""
     key = jax.random.PRNGKey(0)
     params = jax.eval_shape(lambda k: transformer.init_params(k, cfg), key)
     tokens = jax.ShapeDtypeStruct((2, 48), jnp.int32)

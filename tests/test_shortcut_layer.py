@@ -357,6 +357,138 @@ def test_leaves_that_would_have_to_be_copied_are_refused_by_name(fault):
         held_experts_apply(u, routers[1], experts, EXPERTS, 1)
 
 
+# -- the list of held pairs: placed once a layer call, sliced a step -------------------
+
+
+def _picks(case, T=40, k=4):
+    """A call's choices [T, k] among 24 experts (a token picks an expert
+    once at most) and the share held, for a named case."""
+    rng = np.random.default_rng(7)
+    held = (4, 8)
+    if case == "all the experts held":
+        held = (0, 24)
+    if case == "every token on one expert":
+        idx = np.tile(np.array([9, 0, 1, 2]), (T, 1))
+    elif case == "the held pairs fill their steps exactly":
+        idx = np.tile(np.array([0, 1, 2, 3]), (T, 1))
+        idx[:32, :2] = [5, 10]                  # 64 held pairs, 16 a step
+    else:
+        idx = np.stack([rng.permutation(24)[:k] for _ in range(T)])
+    if case == "an expert with no token":
+        idx[idx == 6] = 23                      # held expert 6 is nobody's,
+        idx[(idx == 23).sum(axis=1) > 1] = [0, 1, 2, 23]    # 23 picked once
+    return jnp.asarray(idx, jnp.int32), held
+
+
+CASES = ["all the experts held", "a share held", "an expert with no token",
+         "every token on one expert",
+         "the held pairs fill their steps exactly"]
+
+
+@pytest.mark.parametrize("case", CASES)
+def test_the_list_is_the_stable_sort_of_the_held_pairs_by_expert(case):
+    """``_held_rows`` against ``numpy.argsort(kind="stable")`` of the held
+    pairs' experts, the pairs in token order: the same tokens and weights
+    in the same rows, the groups' bounds, and past the held pairs the token
+    ``T`` at weight 0 to the list's end (whole steps of 16 rows)."""
+    idx, (first, count) = _picks(case)
+    T, k = idx.shape
+    cfg = dataclasses.replace(EXPERTS, n_routed=24, n_zero=0,
+                              held=(first, count))
+    weights = jax.random.uniform(jax.random.PRNGKey(31), (T, k)) + 0.1
+    length = -(-T * k // 16) * 16
+    row_tok, row_w, bounds = jax.jit(
+        lambda i, w: expert._held_rows(i, w, cfg, length))(idx, weights)
+    c = np.asarray(idx).reshape(-1) - first
+    mine = np.flatnonzero((c >= 0) & (c < count))       # pairs, token order
+    order = mine[np.argsort(c[mine], kind="stable")]
+    n_held = len(order)
+    assert row_tok.shape == row_w.shape == (length,)
+    np.testing.assert_array_equal(row_tok[:n_held], order // k)
+    np.testing.assert_array_equal(row_w[:n_held],
+                                  np.asarray(weights).reshape(-1)[order])
+    np.testing.assert_array_equal(row_tok[n_held:], T)
+    np.testing.assert_array_equal(row_w[n_held:], 0.0)
+    np.testing.assert_array_equal(
+        bounds, np.concatenate([[0], np.cumsum(np.bincount(
+            c[mine], minlength=count))]))
+    if case == "an expert with no token":
+        assert bounds[2] == bounds[3] and 0 < n_held < T * k
+    if case == "every token on one expert":
+        assert n_held == T and bounds[5] == 0 and bounds[6] == T
+    if case == "the held pairs fill their steps exactly":
+        assert n_held == 64
+
+
+@pytest.mark.parametrize("rows,n_held", [(16, 40), (16, 64), (1024, 40)])
+def test_rows_past_the_held_pairs_add_nothing_whatever_the_product_left(
+        monkeypatch, rows, n_held):
+    """The grouped product's result poisoned (NaN) in every row that belongs
+    to no group: the sum is the same to the bit, since those rows hold the
+    token ``T`` and the scatter-add drops them. 40 held pairs end inside a
+    step, 64 fill four steps of 16 exactly."""
+    monkeypatch.setattr(expert, "CHUNK_ROWS", rows)
+    cfg = dataclasses.replace(EXPERTS, held=(4, 4))
+    router, u = _forced_router((0, 1, 2, 5))
+    if n_held == 64:        # 24 of the 40 tokens choose held expert 6 too
+        u = u.at[:, 1].set(0.0).at[:24, 1].set(1.0)
+        router = router.at[1, 6].set(60.0)
+    _, experts = _mixture_weights(4, cfg=cfg)
+    want, load = held_experts_apply(u, router, _alone(experts), cfg, 0)
+    assert int(load[0]) == n_held
+    plain, poisoned = jax.lax.ragged_dot, []
+
+    def poison(lhs, rhs, sizes, **kwargs):
+        y = plain(lhs, rhs, sizes, **kwargs)
+        in_a_group = jnp.arange(y.shape[0]) < jnp.sum(sizes)
+        poisoned.append(y.shape[0])
+        return jnp.where(in_a_group[:, None], y, jnp.nan)
+
+    monkeypatch.setattr(jax.lax, "ragged_dot", poison)
+    got, _ = held_experts_apply(u, router, _alone(experts), cfg, 0)
+    assert poisoned == [min(rows, 160)] * 3
+    np.testing.assert_array_equal(got, want)
+    assert bool(jnp.all(jnp.isfinite(got))) and float(jnp.abs(got).max()) > 0
+
+
+def _eqns(jaxpr):
+    """A jaxpr's equations and those of every jaxpr nested in them."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
+
+
+@pytest.mark.parametrize("first,count", [(0, 16), (4, 4)])
+def test_a_step_of_the_loop_searches_for_nothing(first, count):
+    """Read off ``jax.make_jaxpr``: the layer call holds one ``while`` (the
+    dropless loop), whose body has no loop, sort or search of its own,
+    gathers only the rows' tokens from ``u`` (the one gather), and is handed
+    no ``[T, count]`` count: it slices the list with two ``dynamic_slice``s.
+    The list's two scatters are outside it."""
+    cfg = dataclasses.replace(EXPERTS, held=(first, count))
+    router, experts = _mixture_weights(1, cfg=cfg)
+    u = jax.random.normal(jax.random.PRNGKey(2), (50, 64))
+    jaxpr = jax.make_jaxpr(lambda u: held_experts_apply(
+        u, router, _alone(experts), cfg, 0))(u).jaxpr
+    loops = [e for e in _eqns(jaxpr) if e.primitive.name == "while"]
+    assert len(loops) == 1
+    body = loops[0].params["body_jaxpr"].jaxpr
+    inside = [e.primitive.name for e in _eqns(body)]
+    assert not {"while", "sort", "scan", "cond", "scatter"} & set(inside)
+    assert "searchsorted" not in str(body)
+    assert inside.count("gather") == 1 and inside.count("scatter-add") == 1
+    assert inside.count("dynamic_slice") == 2
+    assert inside.count("ragged_dot_general") == 3
+    gather, = (e for e in _eqns(body) if e.primitive.name == "gather")
+    assert gather.invars[0].aval.shape == u.shape
+    shapes = {v.aval.shape for v in body.invars}
+    assert not shapes & {(50, count), (count, 50)}
+    assert (200,) in shapes             # the list: 200 pairs, one step's rows
+    everywhere = [e.primitive.name for e in _eqns(jaxpr)]
+    assert everywhere.count("scatter") == 2 and "sort" not in everywhere
+
+
 # -- the layer against the reference ---------------------------------------------------
 
 
