@@ -32,8 +32,9 @@ parameter tree and the compiled programs are the plain decoder's.
 A stack of several kinds of block (MiniCPM-SALA: ``layer_kinds``) holds one
 stacked parameter tree a kind, ``blocks[kind]``, and scans each run of
 neighbouring layers of one kind, in the published order. Such a stack is
-made of two kinds, both forward only (``dense``, the block above, stands in
-a stack of its own kind alone): ``sparse``
+made of two kinds, both forward only (``train.step.FORWARD_ONLY``;
+``dense``, the block above, stands in a stack of its own kind alone):
+``sparse``
 (per-head RMSNorm of q and k, no positions, causal flash attention up to
 ``sparse.dense_len`` tokens and block-sparse attention with a learned
 selection past it, a sigmoid output gate) and ``linear`` (the same norms,
@@ -43,7 +44,8 @@ and ``logit_scale`` are the muP factors of such a model; at 1 they emit
 nothing.
 
 A third kind, ``shortcut`` (LongCat-Flash), stands in a stack of its own
-kind and is forward only too: one layer holds two latent-attention blocks
+kind and is forward only too (its mixture and its attention are
+differentiable; the layer's late sum has no training path yet): one layer holds two latent-attention blocks
 (MLA: low-rank q and k/v projections with their norms and scalings, heads of
 ``nope_dim + rope_dim`` for q and k beside ``v_dim`` for v, interleaved
 rotary positions on the ``rope_dim`` part, which all heads share for k), two
@@ -52,11 +54,16 @@ one sub-layer late (``_shortcut_block``; sizes in ``LatentConfig`` and
 ``parallel.expert.ExpertConfig``). The mixture is this device's share of an
 expert-parallel layer: ``parallel.expert.held_experts_apply``.
 
-Three more kinds are *a token mixer and an FFN chosen apart* (LFM2:
-``PARTS``), forward only, and stand among each other in any order: ``conv``
-(a gated short convolution and a dense SwiGLU FFN), ``conv_moe`` (the same
-mixer and a routed mixture) and ``attn_moe`` (the dense block's grouped-query
-attention and the mixture). The short-convolution mixer is ``[B | C | z] = u
+Five more kinds are *a token mixer and an FFN chosen apart* (LFM2, Kanana-2:
+``PARTS``), served and trained on one device, and stand among each other in
+any order: ``conv`` (a gated short convolution and a dense SwiGLU FFN),
+``conv_moe`` (the same mixer and a routed mixture), ``attn_moe`` (the dense
+block's grouped-query attention and the mixture), ``latent`` (latent
+attention, one block of the shortcut layer's, without a q bottleneck where
+``LatentConfig.q_rank`` is None, and a dense FFN) and ``latent_moe`` (latent
+attention and the mixture). A mixture layer may add *shared experts*
+(``ExpertConfig.shared_width``: one dense SwiGLU that every token takes, on
+every device alike). The short-convolution mixer is ``[B | C | z] = u
 W_in``, ``g = B * z``, a causal depthwise convolution of ``conv_width`` taps
 over ``g`` and ``(C * c) W_out`` (``_shortconv_mixer``: shifted
 multiply-adds, no kernel); the mixture is ``held_experts_apply`` with the
@@ -68,6 +75,15 @@ experts' leaves go to the mixture whole with the layer's index. ``qk_norm``
 (per-head RMSNorm of q and k before the rotation) and ``tie_embeddings`` (no
 ``lm_head`` leaf: the head reads the embedding) are fields of the dense
 attention path and of the head, not of a kind.
+
+Training a stack of ``PARTS``' kinds (``loss_and_metrics`` ->
+``_parts_states``) scans each run over its stacked leaves, so that a layer's
+weight gradient is written once; the mixture and the flash kernel at two head
+widths have backward passes of their own (``parallel.expert._held_sum``,
+``ops.flash_attention``), the router's gradient flows through its weights
+and not through its choice, and the mixture layers' loads come back beside
+the loss (``moe_load``, ``moe_counts``) for the step that moves the router's
+bias by them (``train.step``).
 """
 
 from __future__ import annotations
@@ -93,22 +109,27 @@ from ray_tpu.parallel.sharding import ShardingRules
 
 
 # a layer's kind
-DENSE, SPARSE, LINEAR, SHORTCUT, CONV, CONV_MOE, ATTN_MOE = KINDS = (
-    "dense", "sparse", "linear", "shortcut", "conv", "conv_moe", "attn_moe")
+(DENSE, SPARSE, LINEAR, SHORTCUT, CONV, CONV_MOE, ATTN_MOE, LATENT,
+ LATENT_MOE) = KINDS = (
+    "dense", "sparse", "linear", "shortcut", "conv", "conv_moe", "attn_moe",
+    "latent", "latent_moe")
 # the kinds made of a token mixer and an FFN chosen apart: (mixer, FFN), each
-# the name of the layer's sub-tree and, ``attn`` | ``shortconv`` and ``mlp`` |
-# ``moe``, of its device scope
+# the name of the layer's sub-tree; the FFN's name, ``mlp`` | ``moe``, is its
+# device scope too, and the mixer's scope is ``shortconv`` or, for either
+# attention (grouped-query ``attn``, latent ``latent``), ``attn``
 PARTS = {CONV: ("shortconv", "mlp"), CONV_MOE: ("shortconv", "moe"),
-         ATTN_MOE: ("attn", "moe")}
+         ATTN_MOE: ("attn", "moe"), LATENT: ("latent", "mlp"),
+         LATENT_MOE: ("latent", "moe")}
 
 
 @dataclass(frozen=True)
 class LatentConfig:
     """Latent attention's (MLA's) sizes: the ranks of the q and the k/v
-    bottlenecks, and a head's widths: q and k are ``nope_dim + rope_dim``
-    wide (the ``rope_dim`` part rotated, interleaved pairs, and for k shared
-    by every head), v is ``v_dim`` wide."""
-    q_rank: int
+    bottlenecks (``q_rank`` None: q has none, one projection ``wq`` and no
+    norm), and a head's widths: q and k are ``nope_dim + rope_dim`` wide (the
+    ``rope_dim`` part rotated, interleaved pairs, and for k shared by every
+    head), v is ``v_dim`` wide."""
+    q_rank: Optional[int]
     kv_rank: int
     nope_dim: int
     rope_dim: int
@@ -154,8 +175,9 @@ class TransformerConfig:
     residual_scale: float = 1.0
     logit_scale: float = 1.0
     sparse: SparseConfig = SparseConfig()
-    # A ``SHORTCUT`` layer's attention; its mixture of experts, and a
-    # ``CONV_MOE`` or ``ATTN_MOE`` layer's.
+    # A ``SHORTCUT``, ``LATENT`` or ``LATENT_MOE`` layer's attention; a
+    # ``SHORTCUT`` layer's mixture of experts, and that of ``PARTS``' kinds
+    # whose FFN is one.
     latent: Optional[LatentConfig] = None
     experts: Optional[ExpertConfig] = None
     # Per-head RMSNorm of q and k (weights of ``head_dim``) before the rotary
@@ -166,6 +188,12 @@ class TransformerConfig:
     conv_width: int = 3
     # The head reads the embedding, transposed: the tree has no ``lm_head``.
     tie_embeddings: bool = False
+    # Training: steps over which ``train.step``'s default optimizer raises its
+    # learning rate linearly to its full value (0: constant from the first
+    # step, as the dense cells train). A router trained from seeded weights
+    # at the full rate collapses onto one choice for every token within
+    # twenty steps (PERF.md section 6, PR 48).
+    warmup_steps: int = 0
 
     def __post_init__(self):
         kinds = self.layer_kinds
@@ -191,6 +219,11 @@ class TransformerConfig:
             raise ValueError(
                 f"layer_kinds {kinds}: a {SHORTCUT!r} layer needs latent= "
                 "(LatentConfig) and experts= (ExpertConfig)")
+        if self.latent is None and any(
+                PARTS.get(kind, ("", ""))[0] == "latent" for kind in kinds):
+            raise ValueError(
+                f"layer_kinds {kinds}: latent attention needs latent= "
+                "(LatentConfig)")
         if self.experts is None and any(
                 PARTS.get(kind, ("", ""))[1] == "moe" for kind in kinds):
             raise ValueError(
@@ -230,35 +263,38 @@ def init_params(key: jax.Array, cfg: TransformerConfig) -> Dict[str, Any]:
     def dense(k, shape, fan_in):
         return jax.random.normal(k, shape, jnp.float32) / math.sqrt(fan_in)
 
+    def latent_attention(k):
+        """MLA's weights. The up-projections draw at 1 / sqrt(d), not 1 /
+        sqrt(rank): the forward multiplies what they read by sqrt(d / rank),
+        so q, k and v come out at unit variance and the scores at order 1,
+        as the dense block's do (at 1 / sqrt(rank) the scores' spread is
+        5.7, attention all but picks one key, and a rounding of 2^-8 grows
+        2.5 times a layer). Without a q bottleneck (``q_rank`` None) q is
+        one projection, ``wq``, drawn from the first of the five keys."""
+        a = cfg.latent
+        qk = a.nope_dim + a.rope_dim
+        ks = jax.random.split(k, 5)
+        q = ({"wq": dense(ks[0], (d, h, qk), d)} if a.q_rank is None else
+             {"wq_a": dense(ks[0], (d, a.q_rank), d),
+              "q_norm": jnp.ones((a.q_rank,), jnp.float32),
+              "wq_b": dense(ks[1], (a.q_rank, h, qk), d)})
+        return {**q,
+                "wkv_a": dense(ks[2], (d, a.kv_rank + a.rope_dim), d),
+                "kv_norm": jnp.ones((a.kv_rank,), jnp.float32),
+                "wkv_b": dense(ks[3], (a.kv_rank, h, a.nope_dim + a.v_dim),
+                               d),
+                "wo": dense(ks[4], (h, a.v_dim, d), h * a.v_dim)}
+
     def shortcut_layer(k):
         """Two latent-attention blocks and two FFNs (leaves stacked [2, ...]
         in the order they run), the router, and the held experts, each
         drawn from a key folded with its *published* index: the shares of
         different devices draw disjoint, consistent experts."""
-        a, e = cfg.latent, cfg.experts
-        qk = a.nope_dim + a.rope_dim
-
-        def attention(k):
-            # the up-projections draw at 1 / sqrt(d), not 1 / sqrt(rank):
-            # the forward multiplies what they read by sqrt(d / rank), so q,
-            # k and v come out at unit variance and the scores at order 1,
-            # as the dense block's do (at 1 / sqrt(rank) the scores' spread
-            # is 5.7, attention all but picks one key, and a rounding of
-            # 2^-8 grows 2.5 times a layer)
-            ks = jax.random.split(k, 5)
-            return {"wq_a": dense(ks[0], (d, a.q_rank), d),
-                    "q_norm": jnp.ones((a.q_rank,), jnp.float32),
-                    "wq_b": dense(ks[1], (a.q_rank, h, qk), d),
-                    "wkv_a": dense(ks[2], (d, a.kv_rank + a.rope_dim), d),
-                    "kv_norm": jnp.ones((a.kv_rank,), jnp.float32),
-                    "wkv_b": dense(ks[3], (a.kv_rank, h,
-                                           a.nope_dim + a.v_dim), d),
-                    "wo": dense(ks[4], (h, a.v_dim, d), h * a.v_dim)}
-
+        e = cfg.experts
         ks = jax.random.split(k, 4)
         first, count = e.held
         return {
-            "attn": jax.vmap(attention)(jax.random.split(ks[0], 2)),
+            "attn": jax.vmap(latent_attention)(jax.random.split(ks[0], 2)),
             "mlp": jax.vmap(functools.partial(ffn, width=f))(
                 jax.random.split(ks[1], 2)),
             "router": dense(ks[2], (d, e.n_outputs), d),
@@ -286,10 +322,14 @@ def init_params(key: jax.Array, cfg: TransformerConfig) -> Dict[str, Any]:
         (a trained buffer in the published model; zeros would leave its path
         unread) at ``expert.BIAS_SCALE`` on bfloat16's grid, so that a cast of
         the tree to the serving dtype leaves it as it is. An expert draws
-        from a key folded with its published index, as a shortcut layer's."""
+        from a key folded with its published index, as a shortcut layer's;
+        the shared experts (``experts.shared_width``) from the FFN's key
+        folded with 3, beside the three it is split into."""
         mixer, feed = PARTS[kind]
         k_mixer, k_ffn = jax.random.split(k)
-        if mixer == "attn":
+        if mixer == "latent":
+            mixed = latent_attention(k_mixer)
+        elif mixer == "attn":
             ks = jax.random.split(k_mixer, 4)
             mixed = {"wq": dense(ks[0], (d, h, hd), d),
                      "wk": dense(ks[1], (d, kvh, hd), d),
@@ -317,6 +357,9 @@ def init_params(key: jax.Array, cfg: TransformerConfig) -> Dict[str, Any]:
                     expert.BIAS_SCALE * jax.random.normal(
                         ks[1], (e.n_outputs,), jnp.float32)
                 ).astype(jnp.bfloat16).astype(jnp.float32)
+            if e.shared_width:
+                fed["shared"] = ffn(jax.random.fold_in(k_ffn, 3),
+                                    e.shared_width)
         return {mixer: mixed, **fed,
                 "ln1": jnp.ones((d,), jnp.float32),
                 "ln2": jnp.ones((d,), jnp.float32)}
@@ -416,10 +459,22 @@ def logical_axes(cfg: TransformerConfig) -> Dict[str, Any]:
                    "wg": ("layers", None, "embed", "mlp"),
                    "wo": ("layers", None, "mlp", "embed")}
 
+        def latent_axes():
+            # a ``PARTS`` layer's latent attention: one block a layer
+            a = {"wkv_a": ("layers", "embed", None),
+                 "kv_norm": ("layers", None),
+                 "wkv_b": ("layers", None, "heads", "kv"),
+                 "wo": ("layers", "heads", "kv", "embed")}
+            if cfg.latent.q_rank is None:
+                return {"wq": ("layers", "embed", "heads", "kv"), **a}
+            return {"wq_a": ("layers", "embed", None),
+                    "q_norm": ("layers", None),
+                    "wq_b": ("layers", None, "heads", "kv"), **a}
+
         def of_kind(kind):
             if kind == SHORTCUT:
-                # forward only and on one device: only the layers' axis is
-                # named (the two sub-blocks' axis and the experts' are not)
+                # on one device: only the layers' axis is named (the two
+                # sub-blocks' axis and the experts' are not)
                 return {
                     "attn": {"wq_a": ("layers", None, "embed", None),
                              "q_norm": ("layers", None, None),
@@ -437,11 +492,12 @@ def logical_axes(cfg: TransformerConfig) -> Dict[str, Any]:
                     "ln_mlp": ("layers", None, None),
                 }
             if kind in PARTS:
-                # forward only and on one device, as the shortcut kind
+                # on one device, as the shortcut kind
                 mixer, feed = PARTS[kind]
                 norms = ({"q_norm": ("layers", None),
                           "k_norm": ("layers", None)} if cfg.qk_norm else {})
-                mixed = ({**blk["attn"], **norms} if mixer == "attn" else
+                mixed = (latent_axes() if mixer == "latent" else
+                         {**blk["attn"], **norms} if mixer == "attn" else
                          {"w_in": ("layers", "embed", None),
                           "conv": ("layers", None, None),
                           "w_out": ("layers", None, "embed")})
@@ -452,6 +508,8 @@ def logical_axes(cfg: TransformerConfig) -> Dict[str, Any]:
                            "experts": experts}
                     if cfg.experts.choice_bias:
                         fed["router_bias"] = ("layers", None)
+                    if cfg.experts.shared_width:
+                        fed["shared"] = blk["mlp"]
                 return {mixer: mixed, **fed, "ln1": ("layers", None),
                         "ln2": ("layers", None)}
             extra = {"wg": ("layers", "embed", "heads", "kv"),
@@ -659,7 +717,8 @@ def _mixed_block(layer, x, positions, cfg: TransformerConfig, kind: str):
 
 def _latent_attention(params, h, positions, cfg: TransformerConfig):
     """MLA on the normed states ``h`` [B, L, d]: ``c_q = N(h W_qa)``, ``q =
-    c_q W_qb * sqrt(d / q_rank)`` as heads of ``nope_dim + rope_dim``;
+    c_q W_qb * sqrt(d / q_rank)`` as heads of ``nope_dim + rope_dim`` (or,
+    without a q bottleneck, ``q = h W_q``: no norm, no scaling);
     ``[c_kv | k_r] = h W_kva``, ``c_kv = N(c_kv) * sqrt(d / kv_rank)``,
     ``[k_n | v] = c_kv W_kvb`` as heads of ``nope_dim | v_dim``; rotary
     positions on q's last ``rope_dim`` and on ``k_r``, which every head
@@ -686,9 +745,12 @@ def _latent_attention(params, h, positions, cfg: TransformerConfig):
         return x.reshape(B, L, *x.shape[2:])
 
     h = rows(h)
-    c_q = norm(jnp.einsum("bld,dr->blr", h, w["wq_a"]), w["q_norm"])
-    q = seqs(jnp.einsum("blr,rhk->blhk", c_q, w["wq_b"])) \
-        * math.sqrt(cfg.d_model / a.q_rank)
+    if a.q_rank is None:
+        q = seqs(jnp.einsum("bld,dhk->blhk", h, w["wq"]))
+    else:
+        c_q = norm(jnp.einsum("bld,dr->blr", h, w["wq_a"]), w["q_norm"])
+        q = seqs(jnp.einsum("blr,rhk->blhk", c_q, w["wq_b"])) \
+            * math.sqrt(cfg.d_model / a.q_rank)
     kv = jnp.einsum("bld,dr->blr", h, w["wkv_a"])
     c_kv = norm(kv[..., :a.kv_rank], w["kv_norm"]) \
         * math.sqrt(cfg.d_model / a.kv_rank)
@@ -778,11 +840,14 @@ def _apply_shortcut(blocks, x, positions, cfg: TransformerConfig):
 def _parts_block(stack, l, x, positions, cfg: TransformerConfig, kind: str):
     """Layer ``l`` of the stacked tree ``stack`` of one of ``PARTS``' kinds:
     ``h = x + Mixer(N(x))``; ``y = h + FFN(N(h))``, the mixer a short
-    convolution or attention, the FFN dense or the routed mixture. Each
-    weight is read from the stack at ``l`` where it is used (``_at``) and
-    the experts' leaves go to the mixture whole, with ``l``, as
-    ``_shortcut_block`` does. Returns the states and the mixture's load
-    (``None`` for a dense FFN)."""
+    convolution, grouped-query or latent attention, the FFN dense or the
+    routed mixture, to which the shared experts (``experts.shared_width``:
+    one dense SwiGLU on every token) are added. Each weight is read from the
+    stack at ``l`` where it is used (``_at``) and the experts' leaves go to
+    the mixture whole, with ``l``, as ``_shortcut_block`` does. Returns the
+    states and, of a mixture, its load (``expert.held_experts_apply``) and
+    how often each routed expert was chosen (``expert.choice_counts``);
+    ``None`` for a dense FFN."""
     mixer, feed = PARTS[kind]
     B, L, d = x.shape
     norm = functools.partial(_rmsnorm, eps=cfg.norm_eps)
@@ -791,22 +856,44 @@ def _parts_block(stack, l, x, positions, cfg: TransformerConfig, kind: str):
         return jax.tree.map(lambda p: _at(p, l), stack[name])
 
     # a scope's name is a literal (the registry's test reads the source)
-    with (jax.named_scope("attn") if mixer == "attn"
-          else jax.named_scope("shortconv")):
+    with (jax.named_scope("shortconv") if mixer == "shortconv"
+          else jax.named_scope("attn")):
         h = norm(x, part("ln1"))
-        x = x + (_attention_mixer(part("attn"), h, positions, cfg, None)
-                 if mixer == "attn" else
-                 _shortconv_mixer(part("shortconv"), h))
+        if mixer == "latent":
+            x = x + _latent_attention(part("latent"), h, positions, cfg)
+        elif mixer == "attn":
+            x = x + _attention_mixer(part("attn"), h, positions, cfg, None)
+        else:
+            x = x + _shortconv_mixer(part("shortconv"), h)
     if feed == "mlp":
         with jax.named_scope("mlp"):
             return x + _mlp(part("mlp"), norm(x, part("ln2"))), None
+    e = cfg.experts
     with jax.named_scope("moe"):
         u = norm(x, part("ln2")).reshape(B * L, d)
-        bias = part("router_bias") if cfg.experts.choice_bias else None
-        s, load = expert.held_experts_apply(
-            u, part("router"), stack["experts"], cfg.experts, layer=l,
-            bias=bias)
-        return x + s.reshape(B, L, d), load
+        # a router without a bias is called as it always was (callers that
+        # stand a router of their own in ``route``'s place take three)
+        idx, weights = (
+            expert.route(u, part("router"), e, part("router_bias"))
+            if e.choice_bias else expert.route(u, part("router"), e))
+        s, load = expert.held_pairs_apply(u, idx, weights, stack["experts"],
+                                          e, l)
+        counts = expert.choice_counts(idx, e)
+    if e.shared_width:
+        with jax.named_scope("mlp"):    # the tokens as rows, like the mixture
+            s = s + _mlp(part("shared"), u[None])[0]
+    with jax.named_scope("moe"):
+        return x + s.reshape(B, L, d), (load, counts)
+
+
+def _parts_runs(cfg: TransformerConfig):
+    """A stack of ``PARTS``' kinds as its runs of neighbouring layers of one
+    kind, in order: ``(kind, first index in blocks[kind], layers)``."""
+    taken = dict.fromkeys(cfg.kinds, 0)
+    for kind, run in itertools.groupby(cfg.kinds):
+        n = len(list(run))
+        yield kind, taken[kind], n
+        taken[kind] += n
 
 
 def _apply_parts(blocks, x, positions, cfg: TransformerConfig):
@@ -815,23 +902,72 @@ def _apply_parts(blocks, x, positions, cfg: TransformerConfig):
     the stacked tree closed over, so that no run's layers are cut out of it
     (a slice of a run of three mixture layers would copy 3.6 GB at LFM2's
     widths). The mixtures' loads go to the program's counters in one
-    call-back a forward."""
-    taken = dict.fromkeys(cfg.kinds, 0)
+    call-back a forward. (Differentiated, every step of such a scan adds a
+    stack-sized, mostly zero gradient to its carry: the training loss takes
+    ``_parts_states``.)"""
     loads = []
-    for kind, run in itertools.groupby(cfg.kinds):
-        n = len(list(run))
+    for kind, start, n in _parts_runs(cfg):
         fn = functools.partial(_parts_block, cfg=cfg, kind=kind)
         if cfg.remat:
             fn = jax.checkpoint(fn)
-        x, load = jax.lax.scan(
-            lambda x, l, fn=fn, kind=kind: fn(blocks[kind], l, x, positions),
-            x, taken[kind] + jnp.arange(n))
+
+        def layer(x, l, fn=fn, kind=kind):
+            x, load = fn(blocks[kind], l, x, positions)
+            return x, None if load is None else load[0]     # not the counts
+
+        x, load = jax.lax.scan(layer, x, start + jnp.arange(n))
         if load is not None:
             loads.append(load)
-        taken[kind] += n
     if loads:
         expert.record_load(jnp.concatenate(loads), cfg.experts)
     return x
+
+
+def _parts_states(blocks, x: jax.Array, cfg: TransformerConfig):
+    """``_apply_parts`` for the training loss: each run is a ``lax.scan``
+    over the run's *stacked leaves* (``xs``), a layer's experts converted
+    to the compute dtype where the scan hands them over (the grouped product
+    takes its weights in the tokens' dtype; the other weights are converted
+    where they are used) and the layer's block reading it as a stack of one. Differentiated, the scan's transpose writes layer l's weight
+    gradient once, as slice l of the stacked gradient; the serving scan
+    over indices, with the tree closed over, would add a whole stacked
+    gradient to its carry every step. A run that is part of its kind's
+    stack is cut out of it first (a copy: LFM2's kinds, which no cell
+    trains). No call-back: the loads come back, ``(loads [n_moe, 4], counts
+    [n_moe, n_routed])`` over the mixture layers in order (``None`` without
+    one), and leave the step in its metrics."""
+    B, L, _ = x.shape
+    positions = jnp.broadcast_to(jnp.arange(L)[None], (B, L))
+    loads = []
+    for kind, start, n in _parts_runs(cfg):
+        def layer(x, params, kind=kind):
+            # the conversion is inside what ``remat`` recomputes: the scan
+            # keeps the layer's float32 slice, not a second copy of it (the
+            # TPU compiler still converts every stacked leaf whole before
+            # the loop, 2 B a parameter held through the step, with or
+            # without a barrier here: PERF.md section 4)
+            if "experts" in params:
+                params = {**params, "experts": jax.tree.map(
+                    lambda p: p.astype(cfg.dtype), params["experts"])}
+            return _parts_block(jax.tree.map(lambda p: p[None], params), 0,
+                                x, positions, cfg, kind)
+
+        if cfg.remat:
+            layer = jax.checkpoint(layer)
+        run = jax.tree.map(lambda p: p[start:start + n], blocks[kind])
+        x, load = jax.lax.scan(layer, x, run)
+        if load is not None:
+            loads.append(load)
+    if not loads:
+        return x, None
+    return x, jax.tree.map(lambda *a: jnp.concatenate(a), *loads)
+
+
+def _one_device(cfg: TransformerConfig, mesh) -> None:
+    if mesh is not None and mesh.devices.size > 1:
+        raise ValueError(f"layer kinds {sorted(set(cfg.kinds))} run on one "
+                         "device only: no mesh of more (their kernels have "
+                         "no shard_map wrapper)")
 
 
 def _apply_mixed(blocks, x, positions, cfg: TransformerConfig, mesh):
@@ -839,9 +975,7 @@ def _apply_mixed(blocks, x, positions, cfg: TransformerConfig, mesh):
     neighbouring layers of one kind is one scan, the runs in the published
     order. A ``SPARSE`` or ``LINEAR`` run scans its own slice of the stack
     (the long-document cell's program, left as it was measured)."""
-    if mesh is not None:
-        raise ValueError(f"layer kinds {sorted(set(cfg.kinds))} run on one "
-                         "device only: no mesh")
+    _one_device(cfg, mesh)
     if SHORTCUT in cfg.kinds:       # a stack of its own kind
         return _apply_shortcut(blocks[SHORTCUT], x, positions, cfg)
     if set(cfg.kinds) <= set(PARTS):
@@ -1018,13 +1152,18 @@ def _looped_states_summing(blocks, ln_f, x: jax.Array,
     return states_of(blocks, ln_f, x)
 
 
-def _pass_states(params, tokens, cfg: TransformerConfig, mesh, rules,
-                 looped) -> jax.Array:
-    """``pass_states``, several passes being ``looped``'s to run."""
+def _embed(params, tokens, cfg: TransformerConfig) -> jax.Array:
     with jax.named_scope("embed"):
         x = params["embed"].astype(cfg.dtype)[tokens]
         if cfg.embed_scale != 1.0:
             x = x * cfg.embed_scale
+        return x
+
+
+def _pass_states(params, tokens, cfg: TransformerConfig, mesh, rules,
+                 looped) -> jax.Array:
+    """``pass_states``, several passes being ``looped``'s to run."""
+    x = _embed(params, tokens, cfg)
     if cfg.n_passes == 1:
         return apply_layers(params["blocks"], x, cfg, mesh, rules)[None]
     return looped(params["blocks"], params["ln_f"], x, cfg, mesh, rules)
@@ -1235,7 +1374,19 @@ def loss_and_metrics(params, tokens, cfg: TransformerConfig,
                      rules: Optional[ShardingRules] = None
                      ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """The training loss of ``tokens`` (which serve as their own labels) and
-    its metrics, as ``loss_from_states`` gives them."""
+    its metrics, as ``loss_from_states`` gives them. A stack of ``PARTS``'
+    kinds with a mixture adds the mixture layers' loads: ``moe_load`` [n_moe,
+    4] (``expert.held_experts_apply``'s) and ``moe_counts`` [n_moe,
+    n_routed], how often the step's tokens chose each routed expert."""
+    if cfg.n_passes == 1 and set(cfg.kinds) <= set(PARTS):
+        _one_device(cfg, mesh)
+        x, loads = _parts_states(params["blocks"],
+                                 _embed(params, tokens[:, :-1], cfg), cfg)
+        loss, metrics = loss_from_states(params, x[None], tokens[:, 1:], cfg)
+        if loads is not None:
+            metrics = {**metrics, "moe_load": loads[0],
+                       "moe_counts": loads[1]}
+        return loss, metrics
     states = _pass_states(params, tokens[:, :-1], cfg, mesh, rules,
                           _looped_states_summing)
     return loss_from_states(params, states, tokens[:, 1:], cfg)

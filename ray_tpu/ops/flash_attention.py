@@ -34,9 +34,12 @@ GQA. K and V keep their own head count: query head ``h`` reads K/V head
 K/V heads, accumulating the group's query heads in scratch before one write.
 
 Head widths. q and k share one width and v (and with it o) may have another
-(latent attention: q and k of 192, v of 128), forward only: v is never padded
-to q's width in HBM, the accumulator and the output tile are v's width, and
-the backward refuses unequal widths by name.
+(latent attention: q and k of 192, v of 128), forward and backward: v is
+never padded to q's width in HBM. The forward's accumulator and output tile
+are v's width; in the backward q, k, dq and dk (and their scratch) are q's
+width and v, o, dO and dv v's, so the two products against v run at v's
+width. The tiles and the VMEM asked for are reckoned at q's width, the wider
+(``_tiles``): with equal widths nothing differs from a call of one width.
 
 Runs in interpreter mode only where the backend is ``cpu`` (the CPU test
 mesh exercises the same code path); on any other backend the Mosaic kernel
@@ -404,11 +407,7 @@ def _flash_bwd(scale, causal, blocks, interpret, residuals, g):
     q, k, v, out, lse = residuals
     do = g
     BH, Lq, D = q.shape
-    if v.shape[-1] != D:
-        raise NotImplementedError(
-            f"flash_attention has no backward pass for unequal head widths "
-            f"(q and k of {D}, v of {v.shape[-1]}): such attention is "
-            "forward only")
+    Dv = v.shape[-1]               # v's, o's, dO's and dv's width
     BKV, Lk, _ = k.shape
     group = BH // BKV
     itemsize = q.dtype.itemsize
@@ -416,14 +415,14 @@ def _flash_bwd(scale, causal, blocks, interpret, residuals, g):
                     axis=-1)                                   # [BH, Lq]
 
     args, vmem, grid, q_spec, kv_spec = _query_stationary(blocks, q, k, causal)
-    q_spec, kv_spec = q_spec(D), kv_spec(D)
     row_spec = pl.BlockSpec((1, args["block_q"], 1),
                             lambda b, i, j: (b, i, 0))
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, scale=scale, **args),
         grid=grid,
-        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
-        out_specs=q_spec,
+        in_specs=[q_spec(D), kv_spec(D), kv_spec(Dv), q_spec(Dv), row_spec,
+                  row_spec],
+        out_specs=q_spec(D),
         out_shape=jax.ShapeDtypeStruct((BH, Lq, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((args["block_q"], D), jnp.float32)],
         compiler_params=_compiler_params(vmem, 3),
@@ -444,10 +443,15 @@ def _flash_bwd(scale, causal, blocks, interpret, residuals, g):
         x = jnp.pad(x, ((0, 0), (0, nq * major - Lq)))
         return x.reshape(BH, nq * tiles, 1, block_q)
 
-    q_spec = pl.BlockSpec(
-        (1, major, D),
-        lambda b, j, g, i: (b * group + g, jnp.maximum(i, first(j)), 0))
-    kv_spec = pl.BlockSpec((1, block_k, D), lambda b, j, g, i: (b, j, 0))
+    def q_spec(width):
+        return pl.BlockSpec(
+            (1, major, width),
+            lambda b, j, g, i: (b * group + g, jnp.maximum(i, first(j)), 0))
+
+    def kv_spec(width):
+        return pl.BlockSpec((1, block_k, width),
+                            lambda b, j, g, i: (b, j, 0))
+
     row_spec = pl.BlockSpec(
         (1, tiles, 1, block_q),
         lambda b, j, g, i: (b * group + g, jnp.maximum(i, first(j)), 0, 0))
@@ -456,15 +460,16 @@ def _flash_bwd(scale, causal, blocks, interpret, residuals, g):
                           block_q=block_q, block_k=block_k, tiles=tiles,
                           seq_k=Lk, seq_q=Lq),
         grid=(BKV, nk, group, nq),
-        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
-        out_specs=[kv_spec, kv_spec],
+        in_specs=[q_spec(D), kv_spec(D), kv_spec(Dv), q_spec(Dv), row_spec,
+                  row_spec],
+        out_specs=[kv_spec(D), kv_spec(Dv)],
         out_shape=[
             jax.ShapeDtypeStruct((BKV, Lk, D), k.dtype),
-            jax.ShapeDtypeStruct((BKV, Lk, D), v.dtype),
+            jax.ShapeDtypeStruct((BKV, Lk, Dv), v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, D), jnp.float32),
-            pltpu.VMEM((block_k, D), jnp.float32),
+            pltpu.VMEM((block_k, Dv), jnp.float32),
         ],
         compiler_params=_compiler_params(vmem, 4),
         interpret=interpret,
@@ -503,8 +508,8 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     v_dim] with ``heads`` a multiple of ``kv_heads`` (query head h attends
     K/V head ``h // (heads // kv_heads)``).
 
-    Returns [batch, seqlen, heads, v_dim]. Differentiable (custom VJP) where
-    ``v_dim`` is ``head_dim``; any other ``v_dim`` is forward only.
+    Returns [batch, seqlen, heads, v_dim]. Differentiable (custom VJP),
+    whatever ``v_dim`` is.
     ``block_q x block_k`` is the score tile; ``block_major`` is how many rows
     of the streamed side (K/V in the forward and dq, q/dO in dk/dv) a grid
     step holds in VMEM. ``None`` = chosen from the shape.

@@ -18,6 +18,15 @@ layer runs without its exchange, and the partial sum is what goes on. The
 model's stack hands it the experts of all its layers and the layer's index:
 they are read as groups of the stacked leaves, not cut out.
 
+It trains too: the dropless loop has a backward pass of its own
+(``_held_sum``, a ``custom_vjp``: the loop's trip count is traced) that
+walks the same list a chunk at a time, and the router differentiates as
+plain JAX, through its weights and not through its choice. A router's
+``choice_bias`` is no parameter: ``choice_counts`` counts a call's choices
+and ``moved_bias`` moves the bias by them, outside the gradient
+(``train.step`` calls it after the optimizer's update). A train step's loads
+reach the counters below without a call-back (``record_load_when_ready``).
+
 ``moe_apply`` is the older switch layer: top-1 routing with a capacity
 limit (dropped tokens pass through the residual path), experts sharded over
 the ``expert`` mesh axis and tokens sent to their expert's device by one
@@ -29,6 +38,7 @@ exchange is what ``held_experts_apply`` across devices will build on.
 
 from __future__ import annotations
 
+import collections
 import functools
 from dataclasses import dataclass
 from typing import Any, Callable, Dict, Optional, Tuple
@@ -49,6 +59,9 @@ NORM_EPS = 1e-6
 # median, and a bias of this spread moves the choice of 60% of the tokens of
 # seeded weights (tests/test_lfm2_layer.py measures the share).
 BIAS_SCALE = 0.02
+# Training: what a step adds to or takes from a ``choice_bias`` by the step's
+# load (``moved_bias``; DeepSeek-V3's bias update speed).
+BIAS_RATE = 1e-3
 
 
 @dataclass(frozen=True)
@@ -67,6 +80,10 @@ class ExpertConfig:
     choice_bias: bool = False
     # the chosen weights are divided by their sum + NORM_EPS (before scale)
     normalize: bool = False
+    # Shared experts: one dense SwiGLU of this width beside the routed ones
+    # (the layer's ``shared`` leaf), which every token takes on every device
+    # alike; 0: none. The model computes it (``models/transformer.py``).
+    shared_width: int = 0
 
     def __post_init__(self):
         if self.score not in ("softmax", "sigmoid"):
@@ -147,6 +164,189 @@ def _held_rows(idx, weights, cfg: ExpertConfig, length: int):
     return row_tok, row_w, bounds
 
 
+def _transposed(w):
+    """The grouped weights with their last two axes swapped, materialised: a
+    grouped product that contracts its right side's last axis is expanded by
+    the TPU compiler into a dense product over every group (PERF.md section
+    6, PR 48), so the backward's products against ``W^T`` are given ``W^T``."""
+    return {name: jnp.swapaxes(p, 1, 2) for name, p in w.items()}
+
+
+def _chunks(rows, count, n_groups, layer, row_tok, row_w, bounds):
+    """``chunk(i) -> (tok, wt, sizes)`` of the dropless loop's step ``i``: the
+    step's tokens and weights sliced off the list, and its rows' counts by
+    group (zero outside ``layer``'s ``count`` groups)."""
+    def chunk(i):
+        start = i * rows
+        tok = jax.lax.dynamic_slice(row_tok, (start,), (rows,))
+        wt = jax.lax.dynamic_slice(row_w, (start,), (rows,))
+        sizes = jax.lax.dynamic_update_slice(
+            jnp.zeros((n_groups,), jnp.int32),
+            jnp.clip(bounds[1:] - start, 0, rows)
+            - jnp.clip(bounds[:-1] - start, 0, rows), (layer * count,))
+        return tok, wt, sizes
+    return chunk
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _held_sum(rows, out, u, row_w, w, row_tok, bounds, layer):
+    """``out + sum_s row_w[s] Expert_{e(s)}(u[row_tok[s]])`` over the listed
+    pairs (``_held_rows``), ``rows`` of the list a step, for as many steps as
+    the held pairs fill: the dropless loop. ``w``: the grouped weights
+    ``wi``, ``wg`` [groups, d, width] and ``wo`` [groups, width, d], of which
+    ``layer``'s ``count = len(bounds) - 1`` groups are meant. float32 [T, d].
+
+    The loop's trip count is traced, so autodiff cannot reverse it: the
+    backward (``_held_sum_bwd``) walks the same list the same ``rows`` at a
+    time."""
+    count = bounds.shape[0] - 1
+    product = functools.partial(jax.lax.ragged_dot,
+                                precision=_precision(u.dtype))
+    chunk = _chunks(rows, count, w["wi"].shape[0], layer, row_tok, row_w,
+                    bounds)
+
+    def step(i, out):
+        tok, wt, sizes = chunk(i)
+        # rows past the held pairs hold token T: the gather clamps it,
+        # the rows belong to no group, and whatever the product left
+        # there the scatter-add drops
+        x = u.at[tok].get(mode="clip")
+        hidden = (jax.nn.silu(product(x, w["wi"], sizes))
+                  * product(x, w["wg"], sizes))
+        y = product(hidden, w["wo"], sizes,
+                    preferred_element_type=jnp.float32)
+        return out.at[tok].add(y * wt[:, None], mode="drop")
+
+    return jax.lax.fori_loop(0, (bounds[count] + rows - 1) // rows, step, out)
+
+
+def _held_sum_fwd(rows, out, u, row_w, w, row_tok, bounds, layer):
+    return (_held_sum(rows, out, u, row_w, w, row_tok, bounds, layer),
+            (u, row_w, w, row_tok, bounds, layer))
+
+
+def _held_sum_bwd(rows, kept, g):
+    """The dropless loop backwards, a step of the same list at a time: the
+    step's rows gathered again and the two first products made again; ``g``'s
+    rows against ``wo^T`` (``d hidden`` before the pair's weight, whose
+    product with ``hidden`` along the width is ``d row_w``); ``d x`` against
+    ``wi^T`` and ``wg^T``, scatter-added to ``d u``. Each step leaves its
+    rows of the five operands of the weight gradients (``x``, ``d a``, ``d
+    b``, ``hidden``, the weighted ``d y``) in lists as long as the pairs'
+    own, and after the loop three grouped products whose *contracted*
+    dimension is the ragged one (the rows) make the weight gradients from
+    the whole lists at once: summed a step at a time, every step read and
+    wrote three float32 accumulators of the layer's held weights (2.4 ms a
+    step of 1,024 rows at 16 experts of 2048 x 768, sixteen times the
+    products' own time: PERF.md section 6, PR 48). No pair is dropped and no
+    group capped, as in the forward."""
+    u, row_w, w, row_tok, bounds, layer = kept
+    count = bounds.shape[0] - 1
+    n_groups, n_held = w["wi"].shape[0], bounds[count]
+    precision = _precision(u.dtype)
+    product = functools.partial(jax.lax.ragged_dot, precision=precision,
+                                preferred_element_type=jnp.float32)
+    by_rows = functools.partial(
+        jax.lax.ragged_dot_general, precision=precision,
+        preferred_element_type=jnp.float32,
+        ragged_dot_dimension_numbers=jax.lax.RaggedDotDimensionNumbers(
+            dot_dimension_numbers=(((0,), (0,)), ((), ())),
+            lhs_ragged_dimensions=[0], rhs_group_dimensions=[]))
+    w_t = _transposed(w)
+    chunk = _chunks(rows, count, n_groups, layer, row_tok, row_w, bounds)
+    length, d, width = row_tok.shape[0], u.shape[1], w["wi"].shape[2]
+
+    def step(i, carry):
+        d_u, d_row_w, lists = carry
+        tok, wt, sizes = chunk(i)
+        # a row past the held pairs belongs to no group: what the products
+        # left there is masked before it meets a sum
+        held = (i * rows + jnp.arange(rows) < n_held)[:, None]
+        x = u.at[tok].get(mode="clip")
+        a = jnp.where(held, product(x, w["wi"], sizes), 0.0)
+        b = jnp.where(held, product(x, w["wg"], sizes), 0.0)
+        gate = jax.nn.sigmoid(a)
+        hidden = a * gate * b
+        d_y = jnp.where(held, g.at[tok].get(mode="clip"), 0.0)
+        # d hidden for a pair of weight 1
+        d_h = jnp.where(held, product(d_y.astype(u.dtype), w_t["wo"], sizes),
+                        0.0)
+        d_wt = jnp.sum(d_h * hidden, axis=-1)
+        d_h = d_h * wt[:, None]
+        d_a = (d_h * b * gate * (1.0 + a * (1.0 - gate))).astype(u.dtype)
+        d_b = (d_h * a * gate).astype(u.dtype)
+        d_x = (product(d_a, w_t["wi"], sizes)
+               + product(d_b, w_t["wg"], sizes))
+        d_u = d_u.at[tok].add(jnp.where(held, d_x, 0.0), mode="drop")
+        at = (i * rows, 0)
+        put = jax.lax.dynamic_update_slice
+        lists = {"x": put(lists["x"], x, at),
+                 "d_a": put(lists["d_a"], d_a, at),
+                 "d_b": put(lists["d_b"], d_b, at),
+                 "hidden": put(lists["hidden"], hidden.astype(u.dtype), at),
+                 "d_y": put(lists["d_y"],
+                            (d_y * wt[:, None]).astype(u.dtype), at)}
+        return d_u, put(d_row_w, d_wt, at[:1]), lists
+
+    # traced where the loss is transposed, outside the scopes the forward
+    # entered
+    with jax.named_scope("moe"), jax.named_scope("experts"):
+        d_u, d_row_w, lists = jax.lax.fori_loop(
+            0, (n_held + rows - 1) // rows, step,
+            (jnp.zeros(u.shape, jnp.float32), jnp.zeros_like(row_w),
+             {name: jnp.zeros((length, wide), u.dtype) for name, wide in
+              (("x", d), ("d_a", width), ("d_b", width), ("hidden", width),
+               ("d_y", d))}))
+        # the whole list's rows by group: the steps' rows past the held pairs
+        # hold zeros and belong to no group
+        sizes = jax.lax.dynamic_update_slice(
+            jnp.zeros((n_groups,), jnp.int32), bounds[1:] - bounds[:-1],
+            (layer * count,))
+        d_w = {"wi": by_rows(lists["x"], lists["d_a"], sizes),
+               "wg": by_rows(lists["x"], lists["d_b"], sizes),
+               "wo": by_rows(lists["hidden"], lists["d_y"], sizes)}
+        return (g, d_u.astype(u.dtype), d_row_w,
+                {name: p.astype(w[name].dtype) for name, p in d_w.items()},
+                None, None, None)
+
+
+_held_sum.defvjp(_held_sum_fwd, _held_sum_bwd)
+
+
+def held_pairs_apply(u: jax.Array, idx: jax.Array, weights: jax.Array,
+                     experts: Dict[str, jax.Array], cfg: ExpertConfig, layer
+                     ) -> Tuple[jax.Array, jax.Array]:
+    """``held_experts_apply`` from the router's choice on: ``idx`` and
+    ``weights`` [T, k] as ``route`` gives them."""
+    with jax.named_scope("moe"):
+        T, d = u.shape
+        count = cfg.held[1]
+        n = experts["wi"].shape[0]
+        for name, p in experts.items():
+            if p.dtype != u.dtype or p.shape[:2] != (n, count):
+                raise ValueError(
+                    f"experts[{name!r}] is {p.dtype}{list(p.shape)}: the "
+                    f"stacked leaves are [{n}, {count}, ...] in the tokens' "
+                    f"{u.dtype}")
+        zero = idx >= cfg.n_routed
+        out = (jnp.sum(jnp.where(zero, weights, 0.0), axis=-1, keepdims=True)
+               * u.astype(jnp.float32))
+
+        rows = min(CHUNK_ROWS, T * cfg.top_k)
+        # whole steps of rows, so that the last step's slice is its own
+        row_tok, row_w, bounds = _held_rows(idx, weights, cfg,
+                                            -(-idx.size // rows) * rows)
+        n_held = bounds[count]
+        w = {name: p.reshape(n * count, *p.shape[2:])
+             for name, p in experts.items()}
+        with jax.named_scope("experts"):
+            out = _held_sum(rows, out, u, row_w, w, row_tok, bounds, layer)
+        n_zero = jnp.sum(zero, dtype=jnp.int32)
+        load = jnp.stack([n_held, idx.size - n_held - n_zero, n_zero,
+                          jnp.max(bounds[1:] - bounds[:-1])])
+        return out.astype(u.dtype), load
+
+
 def held_experts_apply(u: jax.Array, router: jax.Array,
                        experts: Dict[str, jax.Array], cfg: ExpertConfig,
                        layer, bias: Optional[jax.Array] = None
@@ -179,61 +379,42 @@ def held_experts_apply(u: jax.Array, router: jax.Array,
     integers and one placement of the T x k pairs, does), and every token
     sent to one expert or none is exact alike.
 
+    Differentiable in ``u``, ``router`` and ``experts``: the router's scores
+    and weights and the list's making are plain JAX (the gradient flows
+    through the weights, not through the choice, and ``bias`` gets none),
+    and the loop has a backward pass of its own (``_held_sum``).
+
     Returns the partial sum [T, d] in ``u``'s dtype and the layer's load,
     int32 [4]: pairs routed to held, absent and zero-compute experts, and
     the most-loaded held expert's pairs (``record_load``)."""
     with jax.named_scope("moe"):
-        T, d = u.shape
-        count = cfg.held[1]
-        n = experts["wi"].shape[0]
-        for name, p in experts.items():
-            if p.dtype != u.dtype or p.shape[:2] != (n, count):
-                raise ValueError(
-                    f"experts[{name!r}] is {p.dtype}{list(p.shape)}: the "
-                    f"stacked leaves are [{n}, {count}, ...] in the tokens' "
-                    f"{u.dtype}")
         # a router without a bias is called as it always was (callers that
         # stand a router of their own in ``route``'s place take three)
         idx, weights = (route(u, router, cfg) if bias is None
                         else route(u, router, cfg, bias))
-        zero = idx >= cfg.n_routed
-        out = (jnp.sum(jnp.where(zero, weights, 0.0), axis=-1, keepdims=True)
-               * u.astype(jnp.float32))
+        return held_pairs_apply(u, idx, weights, experts, cfg, layer)
 
-        rows = min(CHUNK_ROWS, T * cfg.top_k)
-        # whole steps of rows, so that the last step's slice is its own
-        row_tok, row_w, bounds = _held_rows(idx, weights, cfg,
-                                            -(-idx.size // rows) * rows)
-        n_held = bounds[count]
-        w = {name: p.reshape(n * count, *p.shape[2:])
-             for name, p in experts.items()}
-        product = functools.partial(jax.lax.ragged_dot,
-                                    precision=_precision(u.dtype))
 
-        def step(i, out):
-            start = i * rows
-            tok = jax.lax.dynamic_slice(row_tok, (start,), (rows,))
-            wt = jax.lax.dynamic_slice(row_w, (start,), (rows,))
-            sizes = jax.lax.dynamic_update_slice(
-                jnp.zeros((n * count,), jnp.int32),
-                jnp.clip(bounds[1:] - start, 0, rows)
-                - jnp.clip(bounds[:-1] - start, 0, rows), (layer * count,))
-            # rows past the held pairs hold token T: the gather clamps it,
-            # the rows belong to no group, and whatever the product left
-            # there the scatter-add drops
-            x = u.at[tok].get(mode="clip")
-            hidden = (jax.nn.silu(product(x, w["wi"], sizes))
-                      * product(x, w["wg"], sizes))
-            y = product(hidden, w["wo"], sizes,
-                        preferred_element_type=jnp.float32)
-            return out.at[tok].add(y * wt[:, None], mode="drop")
+def choice_counts(idx: jax.Array, cfg: ExpertConfig) -> jax.Array:
+    """How many of the call's tokens chose each routed expert, int32
+    [n_routed]: the load that moves a ``choice_bias`` (``moved_bias``)."""
+    with jax.named_scope("moe"), jax.named_scope("router"):
+        return jnp.sum(idx[:, :, None] == jnp.arange(cfg.n_routed),
+                       axis=(0, 1), dtype=jnp.int32)
 
-        with jax.named_scope("experts"):
-            out = jax.lax.fori_loop(0, (n_held + rows - 1) // rows, step, out)
-        n_zero = jnp.sum(zero, dtype=jnp.int32)
-        load = jnp.stack([n_held, idx.size - n_held - n_zero, n_zero,
-                          jnp.max(bounds[1:] - bounds[:-1])])
-        return out.astype(u.dtype), load
+
+def moved_bias(bias: jax.Array, counts: jax.Array, cfg: ExpertConfig
+               ) -> jax.Array:
+    """A router's ``choice_bias`` after a step whose tokens chose the routed
+    experts ``counts`` [..., n_routed] times: ``b_e + BIAS_RATE * sign(mean
+    load - load_e)`` (DeepSeek-V3's auxiliary-loss-free balancing,
+    arXiv:2412.19437 section 2.1.2): an expert chosen less than the mean is
+    chosen more readily by the next step. Outside the gradient and outside
+    the optimizer. A zero-compute index's bias stays."""
+    counts = counts.astype(jnp.float32)
+    move = BIAS_RATE * jnp.sign(
+        jnp.mean(counts, axis=-1, keepdims=True) - counts)
+    return bias.at[..., :cfg.n_routed].add(move.astype(bias.dtype))
 
 
 # -- the program's counters ----------------------------------------------------
@@ -286,6 +467,28 @@ def record_load(loads: jax.Array, cfg: ExpertConfig) -> None:
     inside a jitted program: one call-back a forward."""
     with jax.named_scope("moe"):
         jax.debug.callback(functools.partial(_record, cfg.held[1]), loads)
+
+
+# loads a train step left on the device, oldest first, with their experts' count
+_PENDING: collections.deque = collections.deque()
+
+
+def record_load_when_ready(loads: jax.Array, cfg: ExpertConfig) -> None:
+    """``record_load`` from outside a jitted program, for ``loads`` that a
+    step just dispatched may still be computing: queued, and fed to the
+    counters (oldest first, by this call or a later one) once the device has
+    them, so that the caller never waits for a step. ``flush_loads`` waits
+    for what is left."""
+    _PENDING.append((loads, cfg.held[1]))
+    flush_loads(wait=False)
+
+
+def flush_loads(wait: bool = True) -> None:
+    """Feed the queued loads to the counters, oldest first: all of them,
+    waiting for the device, or with ``wait`` false those it already has."""
+    while _PENDING and (wait or _PENDING[0][0].is_ready()):
+        ready, count = _PENDING.popleft()
+        _record(count, ready)
 
 
 @functools.lru_cache(maxsize=128)

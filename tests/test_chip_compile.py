@@ -753,3 +753,100 @@ def test_the_expert_load_cells_forward_compiles_and_copies_no_weight(
             "reduced"]["serve.1"]["why"]
         assert f"{LFM2_ARGUMENT_GB:.2f} GB" in why
         assert f"{LFM2_TEMP_GB:.2f} GB" in why
+
+
+# -- the latent mixer among the mixer-and-FFN kinds (Kanana-2): the trained step ----
+
+
+@pytest.mark.parametrize("batch,length,heads", [(1, 8192, 32), (4, 512, 32)])
+def test_flash_backward_compiles_at_unequal_head_widths(topo, mosaic, batch,
+                                                        length, heads):
+    """q and k heads of 192 beside v heads of 128, forward and backward: the
+    training cell's calls (32 heads at 8,192 tokens) and its comparison's (4
+    x 512). dq and dk come at 192, dv at 128: v is not padded."""
+    from ray_tpu.ops import flash_attention
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    qk = jax.ShapeDtypeStruct((batch, length, heads, 192), jnp.bfloat16,
+                              sharding=one_chip)
+    v = jax.ShapeDtypeStruct((batch, length, heads, 128), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v).astype(jnp.float32).sum()
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        qk, qk, v).compile()
+    text = compiled.as_text()
+    for call in ("flash_fwd", "flash_dq", "flash_dkv"):
+        assert call in text
+    widths = [leaf.shape[-1] for leaf in jax.tree.leaves(
+        compiled.output_shardings and jax.eval_shape(
+            jax.grad(loss, argnums=(0, 1, 2)), qk, qk, v))]
+    assert widths == [192, 192, 128]
+
+
+# what ``memory_analysis()`` reads of the cell's step at 1 x 8,192 tokens
+# (arguments + temporaries + outputs - aliased, GB), as the configuration's
+# ``reduced["train.1"]["why"]`` states it: the issue's rule takes 5 mixture
+# layers at 15.0 GB or less, else 4 (5 read 15.68)
+KANANA2_STEP_GB = 13.60
+
+
+def test_the_kanana2_cells_step_fits_and_writes_a_layers_gradient_once(
+        topo, mosaic):
+    """``kanana2-train-8k``'s step at its own sizes: the three flash kernels
+    and the grouped products are in it; the compiler's account of its memory
+    is what the rule that fixed the depth read; and in the layers' backward
+    loop a stacked float32 gradient is only ever written by a
+    ``dynamic-update-slice`` of the layer's slice: no operation of a loop
+    adds a whole stacked leaf to another (what a scan over the layers'
+    indices with the tree closed over did at every step)."""
+    from benchmark import manifest
+    lowered = _lower_cell_step(topo, "kanana2-train-8k")
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    for call in ("flash_fwd", "flash_dq", "flash_dkv", "ragged-dot"):
+        assert call in text
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+             + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    print(f"kanana2-train-8k: arguments {mem.argument_size_in_bytes / 1e9:.3f}"
+          f" + temporaries {mem.temp_size_in_bytes / 1e9:.3f} GB = "
+          f"{total / 1e9:.3f} GB")
+    assert total <= 15.0e9
+    assert total / 1e9 == pytest.approx(KANANA2_STEP_GB, abs=0.15)
+    cell = manifest.Manifest().cell("kanana2-train-8k")
+    assert f"{KANANA2_STEP_GB:.2f} GB" in cell.config["reduced"]["train.1"][
+        "why"]
+    # the stacked float32 leaves of the mixture layers, large ones
+    adapter = manifest.adapter(cell.config)
+    cfg = adapter.program_config(
+        adapter.dims(cell.config, cell.job, cell.chips), 8192,
+        cell.deploy["model"])
+    blocks = jax.eval_shape(lambda: transformer.init_params(
+        jax.random.PRNGKey(0), cfg))["blocks"][transformer.LATENT_MOE]
+    stacked = {"f32[" + ",".join(map(str, p.shape)) + "]"
+               for p in jax.tree.leaves(blocks)
+               if 4 * math.prod(p.shape[1:]) >= 2 ** 20}
+    assert "f32[4,16,2048,768]" in stacked
+    bodies, entry = _computations(text)
+    inside, todo = [], _loops(bodies[entry])
+    while todo:
+        inside.append(todo.pop())
+        todo += _loops(bodies[inside[-1]])
+    written, other = set(), []
+    for name in inside:
+        for line in bodies[name]:
+            m = _ASSIGNED.match(line)
+            if not m or m.group(2).split("{")[0] not in stacked:
+                continue
+            result, shape, kind = m.groups()
+            if kind in ("parameter", "get-tuple-element", "bitcast"):
+                continue
+            if "dynamic-update-slice" in result or kind == \
+                    "dynamic-update-slice":
+                written.add(shape.split("{")[0])
+            else:
+                other.append((name, result, kind))
+    assert other == []
+    assert written == stacked

@@ -529,11 +529,13 @@ def test_the_tied_head_is_the_embedding_transposed():
 # -- the configuration's guards ------------------------------------------------------
 
 
-def test_the_kinds_are_seven_and_the_new_ones_are_a_mixer_and_an_ffn():
-    assert len(KINDS) == 7 and set(PARTS) == {CONV, CONV_MOE, ATTN_MOE}
+def test_the_kinds_are_nine_and_the_new_ones_are_a_mixer_and_an_ffn():
+    assert len(KINDS) == 9 and set(PARTS) == {
+        CONV, CONV_MOE, ATTN_MOE, transformer.LATENT, transformer.LATENT_MOE}
     assert {PARTS[k] for k in PARTS} == {("shortconv", "mlp"),
                                          ("shortconv", "moe"),
-                                         ("attn", "moe")}
+                                         ("attn", "moe"), ("latent", "mlp"),
+                                         ("latent", "moe")}
     with pytest.raises(ValueError, match="among each other only"):
         dataclasses.replace(TINY, n_layers=2,
                             layer_kinds=(CONV, transformer.LINEAR),
@@ -549,15 +551,55 @@ def test_the_kinds_are_seven_and_the_new_ones_are_a_mixer_and_an_ffn():
                         layer_ids=None, experts=None)
 
 
+# a published layer of each kind: (kind, layer_types' value, published index)
+A_LAYER = {CONV: ("conv", 0), CONV_MOE: ("conv", 2),
+           ATTN_MOE: ("full_attention", 5)}
+
+
 @pytest.mark.parametrize("kinds", [(CONV,), (CONV_MOE,), (ATTN_MOE,),
-                                   TINY.layer_kinds])
-def test_train_step_refuses_the_new_kinds_by_name(kinds):
-    cfg = dataclasses.replace(TINY, n_layers=len(kinds), layer_kinds=kinds,
-                              layer_ids=None)
-    with pytest.raises(ValueError) as e:
-        make_lm_train_step(cfg, mesh=None)
-    assert "no backward pass" in str(e.value)
-    assert all(repr(kind) in str(e.value) for kind in set(kinds))
+                                   TINY.layer_kinds],
+                         ids=["conv", "conv_moe", "attn_moe", "the_cut"])
+def test_a_train_step_of_the_kinds_is_the_references_autodiff(kinds):
+    """Every ``PARTS`` kind trains: one step of ``make_lm_train_step`` on a
+    mesh of one device reads the loss and the gradient norm that autodiff of
+    the plain reference reads on the same float32 weights, and a second step
+    reads a smaller loss. The router's bias is moved by load, never by the
+    optimizer, which holds no state for it."""
+    from ray_tpu.parallel import MeshConfig, ShardingRules, build_mesh
+    if kinds == TINY.layer_kinds:
+        cfg, dims = TINY, TINY_DIMS
+    else:
+        types, ids = zip(*(A_LAYER[kind] for kind in kinds))
+        cfg = dataclasses.replace(TINY, n_layers=len(kinds),
+                                  layer_kinds=kinds, layer_ids=ids)
+        dims = {**TINY_DIMS, "n_layers": len(kinds),
+                "layer_types": list(types), "layer_ids": list(ids)}
+    mesh = build_mesh(MeshConfig(data=1), jax.devices()[:1])
+    init_fn, step_fn, shard = make_lm_train_step(cfg, mesh, ShardingRules())
+    key = jax.random.PRNGKey(61)
+    state = init_fn(key)
+    params = transformer.init_params(key, cfg)
+    tokens = np.asarray(jax.random.randint(jax.random.PRNGKey(62), (2, 25),
+                                           0, cfg.vocab_size))
+    want_loss, want_norm = lfm2_reference.loss_and_grad_norm(
+        params, jnp.asarray(tokens), dims)
+    moments = jax.tree_util.tree_flatten_with_path(state[1])[0]
+    assert not any("router_bias" in jax.tree_util.keystr(path)
+                   for path, _ in moments)
+    state, first = step_fn(state, shard(tokens))
+    np.testing.assert_allclose(first["loss"], want_loss, rtol=2e-5)
+    np.testing.assert_allclose(first["grad_norm"], want_norm, rtol=2e-4)
+    state, second = step_fn(state, shard(tokens))
+    assert float(second["loss"]) < float(first["loss"])
+    expert.flush_loads()
+    for kind in set(kinds) - {CONV}:
+        moved = (state[0]["blocks"][kind]["router_bias"]
+                 - params["blocks"][kind]["router_bias"])
+        steps = np.round(np.asarray(moved) / expert.BIAS_RATE)
+        np.testing.assert_allclose(np.asarray(moved),
+                                   steps * expert.BIAS_RATE, atol=1e-7)
+        assert set(np.unique(steps)) <= {-2.0, -1.0, 0.0, 1.0, 2.0}
+        assert np.any(steps != 0)
 
 
 def test_logical_axes_mirror_the_tree():
@@ -596,7 +638,7 @@ def test_the_conv_mixer_runs_under_a_scope_of_its_own_at_the_layers_top():
 
 @pytest.mark.parametrize("name,cfg,want", [
     ("sala", MIXED, ("b02615aa0512187f", "8ca80f33e12a0f43")),
-    ("longcat", TINY_LONGCAT, ("08181005a897495d", "3a226fff0b2fd67e")),
+    ("longcat", TINY_LONGCAT, ("08181005a897495d", "74d336bce51ce986")),
 ])
 def test_the_mixed_and_the_shortcut_programs_trace_what_the_parent_traced(
         name, cfg, want):
@@ -607,8 +649,13 @@ def test_the_mixed_and_the_shortcut_programs_trace_what_the_parent_traced(
     ``sparse`` and ``linear`` runs still scan their own slices. LongCat's
     second digest is read off the commit that lists a call's held pairs once
     (``expert._held_rows``' placement, one path for a share held and for all:
-    it was ``c1bc369a39beb7b8`` while a step searched for its rows); its
-    ``init_params`` and the mixed stack's pair are the older commit's."""
+    it was ``c1bc369a39beb7b8`` while a step searched for its rows) and
+    then off the commit that gave the dropless loop a backward pass (PR 48:
+    the same equations inside one ``custom_vjp_call``; it was
+    ``3a226fff0b2fd67e`` before, and the program compiled from it is the
+    parent's to the letter but for the numbering of its instructions:
+    PERF.md section 6); its ``init_params`` and the mixed stack's pair are
+    the older commit's."""
     key = jax.random.PRNGKey(0)
     params = jax.eval_shape(lambda k: transformer.init_params(k, cfg), key)
     tokens = jax.ShapeDtypeStruct((2, 48), jnp.int32)

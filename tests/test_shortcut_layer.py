@@ -106,11 +106,32 @@ def test_flash_kernel_at_24_16_head_widths_against_an_einsum(length,
     np.testing.assert_allclose(got, want, atol=2e-5)
 
 
-def test_flash_backward_refuses_unequal_widths_by_name():
-    q = jnp.ones((1, 16, 2, 24))
-    v = jnp.ones((1, 16, 2, 16))
-    with pytest.raises(NotImplementedError, match="unequal head widths"):
-        jax.grad(lambda q: flash_attention(q, q, v).sum())(q)
+@pytest.mark.parametrize("length,kv_heads", [(16, 2), (200, 2), (200, 1)])
+def test_flash_backward_at_unequal_widths_is_plain_attentions(length,
+                                                              kv_heads):
+    """q and k of 24 beside v of 16 (interpret mode): dq and dk come at q's
+    width, dv at v's, each the gradient of plain attention, with a ragged
+    last tile and grouped K/V heads too."""
+    ks = jax.random.split(jax.random.PRNGKey(5), 4)
+    q = jax.random.normal(ks[0], (2, length, 2, 24))
+    k = jax.random.normal(ks[1], (2, length, kv_heads, 24))
+    v = jax.random.normal(ks[2], (2, length, kv_heads, 16))
+    g = jax.random.normal(ks[3], (2, length, 2, 16))
+    rep = 2 // kv_heads
+
+    def plain(q, k, v):
+        return jnp.sum(g * _einsum_attention(q, jnp.repeat(k, rep, 2),
+                                             jnp.repeat(v, rep, 2)))
+
+    def kernel(q, k, v):
+        return jnp.sum(g * flash_attention(q, k, v, block_q=128,
+                                           block_k=128))
+
+    got = jax.grad(kernel, argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(plain, argnums=(0, 1, 2))(q, k, v)
+    assert [a.shape[-1] for a in got] == [24, 24, 16]
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a, b, atol=5e-5)
 
 
 def test_flash_shape_error_names_the_widths_it_takes():
@@ -614,7 +635,7 @@ def test_layer_kinds_message_lists_the_kinds_from_one_tuple():
     with pytest.raises(ValueError) as e:
         TransformerConfig(n_layers=1, layer_kinds=("window",))
     assert all(repr(kind) in str(e.value) for kind in KINDS)
-    assert len(KINDS) == 7 and SHORTCUT in KINDS
+    assert len(KINDS) == 9 and SHORTCUT in KINDS
 
 
 def test_a_shortcut_layer_stands_among_its_own_kind_and_needs_its_sizes():
@@ -624,9 +645,20 @@ def test_a_shortcut_layer_stands_among_its_own_kind_and_needs_its_sizes():
         dataclasses.replace(TINY, latent=None)
 
 
-def test_train_step_refuses_the_shortcut_kind_by_name():
-    with pytest.raises(ValueError, match="shortcut.*no backward pass"):
-        make_lm_train_step(TINY, mesh=None)
+def _forward_only(kind):
+    """The smallest stack of a kind that has no backward pass."""
+    if kind == SHORTCUT:
+        return TINY
+    from test_mixed_stack import MIXED
+    return dataclasses.replace(MIXED, n_layers=1, layer_kinds=(kind,),
+                               layer_ids=None)
+
+
+@pytest.mark.parametrize("kind", [SHORTCUT, "sparse", "linear"])
+def test_train_step_refuses_the_shortcut_kind_by_name(kind):
+    """The kinds that still have no backward pass, each refused by name."""
+    with pytest.raises(ValueError, match=f"{kind}.*no backward pass"):
+        make_lm_train_step(_forward_only(kind), mesh=None)
 
 
 def test_logical_axes_mirror_the_shortcut_tree(params):
