@@ -100,6 +100,7 @@ from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec
 
 from ray_tpu.ops import flash_attention, linear_attention, sparse_attention
+from ray_tpu.ops.flash_attention import KEPT as FLASH_KEPT
 from ray_tpu.ops.linear_attention import decay_rates
 from ray_tpu.ops.sparse_attention import SparseConfig
 from ray_tpu.parallel import expert
@@ -935,7 +936,19 @@ def _parts_states(blocks, x: jax.Array, cfg: TransformerConfig):
     stack is cut out of it first (a copy: LFM2's kinds, which no cell
     trains). No call-back: the loads come back, ``(loads [n_moe, 4], counts
     [n_moe, n_routed])`` over the mixture layers in order (``None`` without
-    one), and leave the step in its metrics."""
+    one), and leave the step in its metrics.
+
+    Under ``remat`` a layer's checkpoint keeps, beside the block's input,
+    the flash kernel's output and log-sum-exp (``flash_attention.KEPT``: o
+    ``[B x heads, L, v_dim]`` in the compute dtype and a float32 row a
+    head, 68 MB a layer at Kanana-2's 32 heads of 128 over 8,192 tokens):
+    the backward recomputes the projections, norms, rotary, q, k, v and the
+    mixture as before, but not ``flash_fwd``, whose recomputed call has no
+    reader and is dropped, so the forward kernel runs once a layer and
+    ``flash_dq`` / ``flash_dkv`` read the arrays the first call made (the
+    same bits). This checkpoint alone has that policy: the dense and the
+    looped stacks' own checkpoints keep nothing of the kernel (ROADMAP
+    S1b)."""
     B, L, _ = x.shape
     positions = jnp.broadcast_to(jnp.arange(L)[None], (B, L))
     loads = []
@@ -953,7 +966,9 @@ def _parts_states(blocks, x: jax.Array, cfg: TransformerConfig):
                                 x, positions, cfg, kind)
 
         if cfg.remat:
-            layer = jax.checkpoint(layer)
+            layer = jax.checkpoint(
+                layer, policy=jax.checkpoint_policies.save_only_these_names(
+                    *FLASH_KEPT))
         run = jax.tree.map(lambda p: p[start:start + n], blocks[kind])
         x, load = jax.lax.scan(layer, x, run)
         if load is not None:

@@ -41,6 +41,24 @@ width and v, o, dO and dv v's, so the two products against v run at v's
 width. The tiles and the VMEM asked for are reckoned at q's width, the wider
 (``_tiles``): with equal widths nothing differs from a call of one width.
 
+What a checkpoint may keep. The backward needs q, k, v, the output and
+the log-sum-exp. Under a plain ``jax.checkpoint`` all five are made again:
+the block is recomputed, ``flash_fwd`` with it, so the forward kernel runs
+twice a layer. The forward rule names the last two (``KEPT``: ``flash_out``
+and ``flash_lse``, by ``jax.ad_checkpoint.checkpoint_name``) and hands the
+*named* output on both as the result and among the residuals, so a
+checkpoint whose policy is
+``jax.checkpoint_policies.save_only_these_names(*KEPT)`` keeps those two
+arrays (o in the operands' dtype and a float32 row a head: at 32 heads of
+128 over 8,192 tokens 67.1 MB + 1.0 MB a layer), the recomputed block's
+second ``flash_fwd`` has no reader and partial evaluation drops it, and
+``flash_dq`` / ``flash_dkv`` read the arrays the first call made: the same
+bits, one call fewer. q, k and v are still recomputed with the projections
+that make them. ``models.transformer._parts_states`` is the one checkpoint
+with that policy; without one the names are identities and lower to
+nothing, and every other differentiated program compiles to what it
+compiled to.
+
 Runs in interpreter mode only where the backend is ``cpu`` (the CPU test
 mesh exercises the same code path); on any other backend the Mosaic kernel
 compiles or the call fails. A Mosaic kernel cannot be partitioned by XLA:
@@ -55,10 +73,15 @@ from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+# The names of the forward's output and log-sum-exp (``checkpoint_name``):
+# what a ``jax.checkpoint`` policy may keep so that its backward does not run
+# ``flash_fwd`` again (the module's docstring, "What a checkpoint may keep").
+KEPT = ("flash_out", "flash_lse")
 _LANES = 128  # row statistics are kept replicated over a full lane dim
 
 _NT = (((1,), (1,)), ((), ()))   # a @ b.T
@@ -490,7 +513,10 @@ def _flash_attention_bhld(q, k, v, scale, causal, blocks, interpret):
 
 
 def _fwd_rule(q, k, v, scale, causal, blocks, interpret):
-    out, lse = _flash_fwd(q, k, v, scale, causal, blocks, interpret)
+    # the named arrays are both the primal and the residuals: once a policy
+    # keeps them, nothing reads a recomputed call's results
+    out, lse = map(checkpoint_name,
+                   _flash_fwd(q, k, v, scale, causal, blocks, interpret), KEPT)
     return out, (q, k, v, out, lse)
 
 

@@ -338,6 +338,111 @@ def test_flash_backward_at_two_widths_is_plain_attentions(shape):
         np.testing.assert_allclose(a, b, atol=1e-4)
 
 
+# -- what the training stack's checkpoint keeps of the kernel ---------------------------
+
+
+def _kernels(jaxpr):
+    """The names of the ``pallas_call``s anywhere inside a jaxpr."""
+    names = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            names.append(eqn.params["name"])
+        for inner in jax.core.jaxprs_in_params(eqn.params):
+            names += _kernels(inner)
+    return sorted(names)
+
+
+def _scans_kernels(jaxpr):
+    """``(reverse, kernels in the body)`` of every ``scan`` that holds a
+    kernel, outermost scans only, in the order the jaxpr has them."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scan":
+            held = _kernels(eqn.params["jaxpr"].jaxpr)
+            if held:
+                found.append((eqn.params["reverse"], held))
+            continue
+        for inner in jax.core.jaxprs_in_params(eqn.params):
+            found += _scans_kernels(inner)
+    return found
+
+
+FLASH = dataclasses.replace(TINY, use_flash=True, remat=True)
+
+
+def _loss_and_grads(cfg, params, tokens):
+    return jax.value_and_grad(
+        lambda p: transformer.loss_and_metrics(p, tokens, cfg)[0])(params)
+
+
+def test_keeping_the_kernels_output_changes_no_bit_of_the_gradient(
+        monkeypatch):
+    """``_parts_states``' checkpoint keeps the flash forward's output and
+    log-sum-exp (``flash_attention.KEPT``): the loss and every leaf's
+    gradient are, bit for bit, those of the same stack under a plain
+    ``jax.checkpoint`` (the policy patched away: the kept arrays are the
+    ones the recomputation would make again), and the file's tolerances away
+    from the stack without ``remat``."""
+    params, tokens = _seeded(FLASH), _tokens(5)
+    loss, grads = _loss_and_grads(FLASH, params, tokens)
+    free_loss, free_grads = _loss_and_grads(
+        dataclasses.replace(FLASH, remat=False), params, tokens)
+    with monkeypatch.context() as patched:
+        patched.setattr(jax.checkpoint_policies, "save_only_these_names",
+                        lambda *names: None)
+        plain_loss, plain_grads = _loss_and_grads(FLASH, params, tokens)
+    assert float(loss) == float(plain_loss)
+    plain = dict(jax.tree_util.tree_flatten_with_path(plain_grads)[0])
+    free = dict(jax.tree_util.tree_flatten_with_path(free_grads)[0])
+    flat = jax.tree_util.tree_flatten_with_path(grads)[0]
+    assert len(flat) == len(plain) == 28
+    for path, got in flat:
+        name = jax.tree_util.keystr(path)
+        np.testing.assert_array_equal(got, plain[path], err_msg=name)
+        scale = float(jnp.max(jnp.abs(free[path])))
+        np.testing.assert_allclose(got, free[path], err_msg=name,
+                                   atol=1e-5 * scale + 1e-9)
+    np.testing.assert_allclose(loss, free_loss, rtol=1e-6)
+
+
+def test_the_training_stacks_backward_runs_no_flash_forward(monkeypatch):
+    """In the jaxpr of the differentiated loss each run's forward scan holds
+    one ``flash_fwd`` and its backward scan ``flash_dq`` and ``flash_dkv``
+    and no ``flash_fwd``: the recomputed block's second call has no reader
+    once the first call's results are kept. Under a plain ``jax.checkpoint``
+    (the parent's program) the backward scan held it."""
+    params, tokens = _seeded(FLASH), _tokens(6)
+
+    def scans():
+        return _scans_kernels(jax.make_jaxpr(jax.grad(
+            lambda p: transformer.loss_and_metrics(p, tokens, FLASH)[0]))(
+                params).jaxpr)
+
+    # a run of one dense-FFN layer, a run of two mixture layers
+    assert scans() == 2 * [(False, ["flash_fwd"])] + 2 * [
+        (True, ["flash_dkv", "flash_dq"])]
+    monkeypatch.setattr(jax.checkpoint_policies, "save_only_these_names",
+                        lambda *names: None)
+    assert scans() == 2 * [(False, ["flash_fwd"])] + 2 * [
+        (True, ["flash_dkv", "flash_dq", "flash_fwd"])]
+
+
+def test_the_dense_stacks_backward_still_runs_the_flash_forward():
+    """``apply_layers``' checkpoint has no policy (its cells' roofline
+    readers count two forwards by rule: ROADMAP S1b): the names in the
+    kernel's forward rule keep nothing there and the backward scan's body
+    recomputes ``flash_fwd``."""
+    dense = TransformerConfig(
+        vocab_size=96, d_model=32, n_layers=2, n_heads=4, d_ff=48,
+        max_seq_len=64, dtype=jnp.float32, use_flash=True, remat=True)
+    params = transformer.init_params(jax.random.PRNGKey(7), dense)
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda p: transformer.loss_and_metrics(p, _tokens(7), dense)[0]))(
+            params).jaxpr
+    assert _scans_kernels(jaxpr) == [
+        (False, ["flash_fwd"]), (True, ["flash_dkv", "flash_dq", "flash_fwd"])]
+
+
 # -- the step: the bias, the optimizer, the loss ---------------------------------------
 
 

@@ -6,6 +6,7 @@ against ``benchmark/longcat_reference.py`` and plain einsums."""
 
 import dataclasses
 import math
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -142,10 +143,15 @@ def test_flash_shape_error_names_the_widths_it_takes():
         flash_attention(q, q, jnp.ones((1, 8, 2, 16)))
 
 
-def test_flash_at_equal_head_widths_traces_what_the_parent_commit_traced():
+def test_flash_at_equal_head_widths_traces_what_the_parent_commit_traced(
+        monkeypatch):
     """Every accepted cell calls the kernel with v as wide as q and k: the
     forward's and the backward's jaxprs are the parent commit's, to the
-    letter (read off commit cb00583 by this function)."""
+    letter (read off commit cb00583 by this function). Since PR 49 the
+    gradient's jaxpr holds two ``name`` equations more (the forward rule
+    names its output and log-sum-exp for a checkpoint's policy), which lower
+    to nothing: that is its only difference from the parent's, and with
+    ``checkpoint_name`` an identity it reads the parent's digest."""
     q = jax.ShapeDtypeStruct((2, 200, 4, 16), jnp.bfloat16)
     k = jax.ShapeDtypeStruct((2, 200, 2, 16), jnp.bfloat16)
 
@@ -157,7 +163,11 @@ def test_flash_at_equal_head_widths_traces_what_the_parent_commit_traced():
 
     assert (_digest(attend, q, k, k),
             _digest(jax.grad(loss, argnums=(0, 1, 2)), q, k, k)) == (
-        "56ae453cd98670d3", "46ccfd123954c22c")
+        "56ae453cd98670d3", "1ce9693422dd360d")
+    monkeypatch.setattr(sys.modules["ray_tpu.ops.flash_attention"],
+                        "checkpoint_name", lambda x, name: x)
+    assert _digest(jax.grad(loss, argnums=(0, 1, 2)), q, k,
+                   k) == "46ccfd123954c22c"
 
 
 # -- the expert layer ----------------------------------------------------------------
