@@ -321,6 +321,7 @@ class DistributedRuntime(Runtime):
         # heartbeat-ack signal, preemption watcher) and is idempotent.
         self._drain_lock = threading.Lock()
         self._drain_started = False
+        self._drain_stamped = False     # the state service has "drained: .."
         self._drain_progress: Dict[str, Any] = {}
         self._node_state_gauge = _metrics.Gauge(
             "node_state",
@@ -919,6 +920,7 @@ class DistributedRuntime(Runtime):
             self.state.mark_node_dead(self.local_node.node_id.binary(),
                                       f"drained: {reason}" if reason
                                       else "drained")
+            self._drain_stamped = True
         except Exception as e:
             logger.debug("drained mark_node_dead failed: %s", e)
         self._node_state_gauge.set(2)
@@ -1258,11 +1260,12 @@ class DistributedRuntime(Runtime):
                     state="FINISHED"))
             except Exception as e:
                 logger.debug("job FINISHED publish failed: %s", e)
-        try:
-            self.state.mark_node_dead(self.local_node.node_id.binary(),
-                                      "graceful shutdown")
-        except Exception as e:
-            logger.debug("mark_node_dead failed: %s", e)
+        if not self._drain_stamped:     # a drained node keeps its reason
+            try:
+                self.state.mark_node_dead(self.local_node.node_id.binary(),
+                                          "graceful shutdown")
+            except Exception as e:
+                logger.debug("mark_node_dead failed: %s", e)
         super().shutdown()
         with self._push_flush_cv:
             self._push_flush_cv.notify_all()  # release the linger flusher

@@ -177,6 +177,11 @@ def init_tensor_plane(group_name: str, world_size: int, rank: int,
                 f"rank {rank}: no coordinator for {group_name}@{epoch} "
                 f"within {timeout_s}s")
 
+    # JAX's preemption service takes the process's SIGTERM for itself (its
+    # notifier replaces the handler), and nothing here reads its sync point:
+    # a daemon that had joined a plane then outlived SIGTERM until it was
+    # killed. The daemon's own watcher (host_daemon) handles a preemption.
+    jax.config.update("jax_enable_preemption_service", False)
     jax.distributed.initialize(coordinator_address=coord,
                                num_processes=world_size, process_id=rank,
                                initialization_timeout=int(timeout_s))

@@ -6,6 +6,7 @@ reference's in-process multi-raylet ``Cluster`` (``cluster_utils.py:99``).
 """
 
 import os
+import sys
 
 os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (
@@ -55,3 +56,55 @@ def eight_device_mesh():
     devices = jax.devices("cpu")
     assert len(devices) >= 8, f"need 8 virtual devices, got {len(devices)}"
     yield devices[:8]
+
+
+# -- compiles for a described chip (tests/test_chip_compile*.py) ---------------
+
+
+@pytest.fixture(scope="module")
+def topo():
+    """A TPU v5e 2x2 host, described and not attached: the TPU compiler
+    compiles for its devices, and nothing runs on them."""
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - whatever libtpu raises here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def on_chip(topo):
+    """``on_chip(shape, dtype)``: an array's shape and type on the first
+    described chip, which can hold no array."""
+    from jax.sharding import SingleDeviceSharding
+    first = SingleDeviceSharding(topo.devices[0])
+    return lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                     sharding=first)
+
+
+@pytest.fixture(scope="module")
+def no_compile_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without a chip; keep these tests out of it."""
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def mosaic(topo, no_compile_cache):
+    """The process's backend is the CPU, where the kernels would take
+    interpret mode; a module that asks for this compiles for the described
+    chip (module-scoped, so that a module-scoped fixture can compile)."""
+    import ray_tpu.ops  # noqa: F401  (the three kernel modules)
+    with pytest.MonkeyPatch.context() as patch:
+        for module in ("flash_attention", "linear_attention",
+                       "sparse_attention"):
+            patch.setattr(sys.modules[f"ray_tpu.ops.{module}"],
+                          "_backend_is_cpu", lambda: False)
+        yield

@@ -1,0 +1,245 @@
+"""Compiles for a described TPU v5e 2x2 host: the served forwards.
+
+The serve forward at its batch buckets, and the served programs of the
+long-document, prefill and expert-load cells at their published widths, with
+what their layers' loops may not copy. See ``test_chip_compile.py``.
+"""
+
+import math
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from chip_programs import (CFG, KERNEL, SEQ, cell_dims, computations,
+                           first_token, loops, param_shapes, step_bodies,
+                           weight_copies)
+from ray_tpu.models import transformer
+
+
+@pytest.mark.parametrize("bucket", [2, 4, 8])
+def test_serve_forward_compiles_at_bucket(on_chip, mosaic, bucket):
+    params = param_shapes(on_chip, CFG)
+    tokens = on_chip((bucket, SEQ), jnp.int32)
+    text = jax.jit(lambda p, t: transformer.apply(p, t, CFG)).lower(
+        params, tokens).compile().as_text()
+    assert KERNEL in text
+
+
+@pytest.mark.parametrize("length,calls", [(8192, ["flash_fwd"]),
+                                          (16384, ["sparse_attn_fwd"])])
+def test_mixed_stack_serve_forward_compiles(on_chip, mosaic, length, calls):
+    """The long-document cell's forward at its published widths, one period
+    of the stack (a sparse layer and three linear ones): up to ``dense_len``
+    the sparse layer is the flash kernel, past it the selection."""
+    cell, adapter, dims = cell_dims("minicpm-sala-serve-longdoc")
+    dims = {**dims, "n_layers": 4, "mixer_types": dims["mixer_types"][:4],
+            "layer_ids": dims["layer_ids"][:4]}
+    cfg = adapter.program_config(dims, length, cell.deploy["model"])
+    params = param_shapes(on_chip, cfg, cfg.dtype)
+    tokens = on_chip((1, length), jnp.int32)
+    compiled = jax.jit(lambda p, t: transformer.head(
+        p, transformer.backbone(p, t, cfg)[:, -1:], cfg)).lower(
+            params, tokens).compile()
+    text = compiled.as_text()
+    for call in calls + ["linear_attn_fwd"]:
+        assert call in text
+    mem = compiled.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            < 15 * 10 ** 9)
+
+
+def test_shortcut_layer_serve_forward_compiles(on_chip, mosaic):
+    """The prefill cell's forward at its published widths, one layer of the
+    four: both kernels are in it, the flash call and the ragged product the
+    compiler makes of ``lax.ragged_dot``."""
+    cell, adapter, dims = cell_dims("longcat-flash-serve-prefill")
+    dims = {**dims, "n_layers": 1}
+    cfg = adapter.program_config(dims, 2048, cell.deploy["model"])
+    params = param_shapes(on_chip, cfg, cfg.dtype)
+    tokens = on_chip((1, 2048), jnp.int32)
+    text = jax.jit(lambda p, t: transformer.apply(p, t, cfg)).lower(
+        params, tokens).compile().as_text()
+    assert "flash_fwd" in text and "ragged-dot" in text
+
+
+# -- the shortcut stack reads its weights where they lie ---------------------
+
+_PLANTED = """\
+%steps (arg.1: (s32[], bf16[8,512,256])) -> (s32[], bf16[8,512,256]) {
+  %arg.1 = (s32[], bf16[8,512,256]{2,1,0}) parameter(0)
+  %groups = bf16[8,512,256]{2,1,0:T(8,128)(2,1)} get-tuple-element(%arg.1), index=1
+  %cut.1 = bf16[4,512,256]{2,1,0} fusion(%groups, %i), kind=kLoop, calls=%sliced, metadata={op_name="jit(f)/while/body/moe/experts/dynamic_slice"}
+  %small = bf16[1,512,256]{2,1,0} fusion(%groups, %i), kind=kLoop, calls=%sliced
+  %ragged-dot = bf16[64,256]{1,0} custom-call(%x, %cut.1), custom_call_target="ragged"
+  ROOT %tuple.1 = (s32[], bf16[8,512,256]{2,1,0}) tuple(%i, %groups)
+}
+
+%layers (arg.2: (s32[], bf16[2,4,512,256])) -> (s32[], bf16[2,4,512,256]) {
+  %arg.2 = (s32[], bf16[2,4,512,256]{3,2,1,0}) parameter(0)
+  %leaf = bf16[2,4,512,256]{3,2,1,0} get-tuple-element(%arg.2), index=1
+  %bitcast.1 = bf16[8,512,256]{2,1,0} bitcast(%leaf)
+  %copy.1 = bf16[8,512,256]{2,1,0} copy(%bitcast.1), metadata={op_name="jit(f)/while/body/moe/reshape"}
+  %used = bf16[64,256]{1,0} fusion(%x, %leaf), kind=kOutput, calls=%product
+  %tuple.2 = (s32[], bf16[8,512,256]{2,1,0}) tuple(%i, %copy.1)
+  %while.2 = (s32[], bf16[8,512,256]{2,1,0}) while(%tuple.2), condition=%cond, body=%steps
+  ROOT %tuple.3 = (s32[], bf16[2,4,512,256]{3,2,1,0}) tuple(%i, %leaf)
+}
+
+%sliced (p.0: bf16[8,512,256], p.1: s32[]) -> bf16[4,512,256] {
+  ROOT %dynamic-slice.1 = bf16[4,512,256]{2,1,0} dynamic-slice(%p.0, %p.1, %c, %c)
+}
+
+%product (p.2: bf16[64,512], p.3: bf16[2,4,512,256]) -> bf16[64,256] {
+  ROOT %convolution.1 = bf16[64,256]{1,0} convolution(%p.2, %slice.1), dim_labels=bf_io->bf
+}
+
+ENTRY %main (w: bf16[2,4,512,256]) -> bf16[2,4,512,256] {
+  %w = bf16[2,4,512,256]{3,2,1,0} parameter(0)
+  %outside = bf16[2,4,512,256]{3,2,1,0} copy(%w)
+  %tuple.4 = (s32[], bf16[2,4,512,256]{3,2,1,0}) tuple(%c, %outside)
+  %while.1 = (s32[], bf16[2,4,512,256]{3,2,1,0}) while(%tuple.4), condition=%cond, body=%layers
+  ROOT %out = bf16[2,4,512,256]{3,2,1,0} get-tuple-element(%while.1), index=1
+}
+"""
+
+
+def test_the_helper_follows_a_weight_through_a_bitcast_and_into_the_steps():
+    """``weight_copies`` on a module written by hand: a copy of the stack
+    behind its ``bitcast`` to groups, in the layers' loop, and a cut of one
+    layer's groups in the loop nested in it are both found, with their
+    bytes and op_names; a copy outside the loops, a cut under the least
+    size and a product's own read of the leaf are not."""
+    weights = {"bf16[2,4,512,256]", "bf16[8,512,256]"}
+    found = weight_copies(_PLANTED, weights, least=2 ** 20)
+    assert sorted(found) == [
+        ("copy.1", 2 * 8 * 512 * 256, "jit(f)/while/body/moe/reshape"),
+        ("cut.1", 2 * 4 * 512 * 256,
+         "jit(f)/while/body/moe/experts/dynamic_slice")]
+    # unseeded with the groups' shape, the nested loop's cut goes unseen
+    assert [name for name, _, _ in weight_copies(
+        _PLANTED, {"bf16[2,4,512,256]"}, least=2 ** 20)] == ["copy.1"]
+
+
+def test_the_prefill_cells_layers_loop_copies_no_weight(on_chip, mosaic):
+    """The prefill cell's served program, four layers at ``[1, 2048]`` and
+    the published widths: the layers are one ``while`` with the dropless
+    steps' ``while`` nested in it (``longcat_counts.expert_ops`` tells the
+    mixture's operations by that), both kernels are in the text, and the
+    loop writes no copy of a weight: each product reads its slice of the
+    stacked leaf, the grouped product its groups. A scan over the stacked
+    tree wrote 2.50 GB of such copies a layer (eleven of 32 MB or more) and
+    held 1.35 GB of temporaries."""
+    lowered, params = first_token(on_chip, "longcat-flash-serve-prefill",
+                                   batch=1, length=2048)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert "flash_fwd" in text and "ragged-dot" in text
+    bodies, entry = computations(text)
+    layers = loops(bodies[entry])
+    assert len(layers) == 1
+    steps = loops(bodies[layers[0]])
+    assert len(steps) == 1 and any(
+        "ragged-dot" in line for line in bodies[steps[0]])
+    # the leaves of which one layer's slice is large enough to count (a norm's
+    # weight rides the fusion that applies it)
+    def named(*shape):
+        return "bf16[" + ",".join(map(str, shape)) + "]"
+
+    blocks = params["blocks"][transformer.SHORTCUT]
+    stacked = {named(*p.shape) for p in jax.tree.leaves(blocks)
+               if 2 * math.prod(p.shape[1:]) >= 32 * 2 ** 20}
+    # and the experts' as the dropless loop is handed them: n x count groups
+    stacked |= {named(p.shape[0] * p.shape[1], *p.shape[2:])
+                for p in blocks["experts"].values()}
+    assert any(f" {shape}" in line for shape in stacked
+               for line in bodies[steps[0]])
+    copies = weight_copies(text, stacked)
+    print(f"weight copies in the layers' loop: {len(copies)}, "
+          f"{sum(size for _, size, _ in copies) / 1e9:.3f} GB a layer")
+    assert copies == []
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.8e9
+
+
+# what ``memory_analysis()`` reads of the served program at [1, 8192], as the
+# configuration's ``reduced["serve.1"]["why"]`` states it (GB)
+# (0.273 GB of temporaries while a step of the dropless loop searched for its
+# rows; 0.270 since a layer call lists them once, PR 47: the list is two
+# vectors of 32,768 elements)
+LFM2_ARGUMENT_GB, LFM2_TEMP_GB = 10.356, 0.273
+
+
+@pytest.mark.parametrize("length", [2048, 4096, 8192])
+def test_the_expert_load_cells_forward_compiles_and_copies_no_weight(
+        on_chip, mosaic, length):
+    """The served program of ``lfm2-24b-serve-prefill`` at its published
+    widths and its three shapes: both kernels are in the text; the runs of
+    mixture layers are ``while`` loops with the dropless steps' loop nested
+    in them, and no loop writes a copy of a weight (the helper above,
+    seeded with this tree's leaf shapes, the experts' ``[n x 64, ...]``
+    groups among them): each product reads its slice of the stacked leaf.
+    At ``[1, 8192]`` the compiler's own account of the memory is what the
+    configuration's file says, which bounds a copy made outside the loops
+    too (one layer's experts are 0.6 GB)."""
+    lowered, params = first_token(on_chip, "lfm2-24b-serve-prefill", batch=1,
+                                   length=length)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert "flash_fwd" in text and "ragged-dot" in text
+    bodies, entry = computations(text)
+    runs = [body for body in loops(bodies[entry])
+            if any(any("ragged-dot" in line for line in bodies[steps])
+                   for steps in loops(bodies[body]))]
+    assert len(runs) == 2           # the two runs of three conv_moe layers
+
+    def named(*shape):
+        return "bf16[" + ",".join(map(str, shape)) + "]"
+
+    stacked = set()
+    for blocks in params["blocks"].values():
+        stacked |= {named(*p.shape) for p in jax.tree.leaves(blocks)
+                    if 2 * math.prod(p.shape[1:]) >= 2 ** 20}
+        stacked |= {named(p.shape[0] * p.shape[1], *p.shape[2:])
+                    for p in blocks.get("experts", {}).values()}
+    assert named(6 * 64, 2048, 1536) in stacked
+    # the helper knows a weight by its shape: at 2,048 tokens the states
+    # [1, L, d] have the one convolution layer's ``w_out``'s [1, d, d]
+    stacked.discard(named(1, length, 2048))
+    assert any(f" {shape}" in line for shape in stacked for run in runs
+               for steps in loops(bodies[run]) for line in bodies[steps])
+    copies = weight_copies(text, stacked, least=2 ** 20)
+    print(f"weight copies in the layers' loops: {len(copies)}, "
+          f"{sum(size for _, size, _ in copies) / 1e9:.3f} GB")
+    assert copies == []
+    # a step slices the list of its pairs and searches for nothing: the
+    # loops that hold the grouped products, one a mixture layer's trace,
+    # nest no loop and are handed no [64, T] count to gather from (the
+    # parent's carried ``s32[64, T]`` and ran a ``searchsorted`` loop and
+    # 13 gathers a step); the module's sorts are the router's top-k and
+    # the compiler's own of a step's 1,024 scatter-add indices, as in the
+    # parent: the call's pairs are placed by counting, no sort of them
+    steps = step_bodies(bodies)
+    assert len(steps) == 4          # two runs' and the two attention layers'
+    for body in steps:
+        assert loops(bodies[body]) == []
+        assert not any(f"s32[{shape}]" in line for line in bodies[body]
+                       for shape in (f"64,{length}", f"{length},64"))
+    sorts = [line for line in text.splitlines() if " sort(" in line]
+    assert sorts and all(
+        re.search(r'op_name="[^"]*/(router/top_k|experts/while/body/'
+                  r'scatter-add)"', line) for line in sorts)
+    assert not any(f"[{4 * length}]" in line for line in sorts)
+    memory = compiled.memory_analysis()
+    print(f"[1, {length}]: arguments {memory.argument_size_in_bytes / 1e9:.3f}"
+          f" GB, temporaries {memory.temp_size_in_bytes / 1e9:.3f} GB")
+    assert memory.temp_size_in_bytes < 0.4e9
+    if length == 8192:
+        assert memory.argument_size_in_bytes / 1e9 == pytest.approx(
+            LFM2_ARGUMENT_GB, abs=2e-3)
+        assert memory.temp_size_in_bytes / 1e9 == pytest.approx(
+            LFM2_TEMP_GB, abs=0.03)
+        why = cell_dims("lfm2-24b-serve-prefill")[0].config[
+            "reduced"]["serve.1"]["why"]
+        assert f"{LFM2_ARGUMENT_GB:.2f} GB" in why
+        assert f"{LFM2_TEMP_GB:.2f} GB" in why

@@ -272,6 +272,42 @@ def test_arena_survives_repeat_fetches_and_eviction(cluster):
         assert float(v[0, 0]) == float(i)
 
 
+def test_a_cluster_whose_daemons_joined_a_tensor_plane_is_down_in_five_seconds():
+    """``ProcessCluster.shutdown`` on two daemons that hold a tensor plane
+    (``jax.distributed.initialize`` in each): both leave on SIGTERM, through
+    their own handler and with exit code 0, and the state service after
+    them, within 5 s and with no process left. JAX's preemption service took
+    the signal for itself once a plane was up, and each daemon then waited
+    out ``shutdown``'s 10 s and was killed."""
+    from ray_tpu.collective import create_collective_group
+
+    @ray_tpu.remote(num_cpus=2)  # fills a daemon: one rank per process
+    class Rank:
+        def sum(self, group_name):
+            from ray_tpu import collective as col
+            return np.asarray(col.allreduce(np.arange(4.0),
+                                            group_name=group_name))
+
+    ray_tpu.shutdown()
+    c = ProcessCluster(num_daemons=2, num_cpus=2, tp_cpu_devices=2)
+    try:
+        ray_tpu.init(address=c.address)
+        ranks = [Rank.remote() for _ in range(2)]
+        create_collective_group(ranks, 2, [0, 1], backend="xla",
+                                group_name="down")
+        for out in ray_tpu.get([r.sum.remote("down") for r in ranks],
+                               timeout=120):
+            np.testing.assert_allclose(out, 2 * np.arange(4.0))
+    finally:
+        ray_tpu.shutdown()
+        began = time.monotonic()
+        c.shutdown()
+        took = time.monotonic() - began
+    assert [d["proc"].poll() for d in c.daemons] == [0, 0]
+    assert c.state_proc.poll() is not None
+    assert took < 5.0, f"shutdown took {took:.1f} s"
+
+
 def test_push_path_streams_object_to_peer():
     """With the arena off, large task args are proactively pushed to the
     executing daemon with windowed backpressure (push_manager.h role)."""

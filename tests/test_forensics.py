@@ -321,15 +321,17 @@ def test_cluster_sigkill_daemon_doctor_reconstructs(tmp_path):
         "RAY_TPU_FLIGHT_RECORDER_DIR": str(tmp_path),
         "RAY_TPU_FLIGHT_RECORDER_SPOOL_MS": "50",
         # hold task.execute for 30s so the kill lands mid-task
-        "RAY_TPU_CHAOS": "5:task.execute[key=*slow_task*]@1=delay(30000)",
+        "RAY_TPU_CHAOS": "5:task.execute[name=*slow_task*]@1=delay(30000)",
     }
     c = ProcessCluster(num_daemons=1, num_cpus=2)
     try:
-        c.add_daemon(num_cpus=2, env=flight_env)
+        # the task is held to the daemon that carries the schedule and the
+        # flight directory: on the other it would simply return
+        c.add_daemon(num_cpus=2, env=flight_env, resources={"doomed": 1.0})
         observability.enable()
         ray_tpu.init(address=c.address)
 
-        @ray_tpu.remote
+        @ray_tpu.remote(resources={"doomed": 1.0})
         def slow_task():
             return 1
 
@@ -375,14 +377,14 @@ def test_cluster_chaos_exit_daemon_seals_itself(tmp_path):
     flight_env = {
         "RAY_TPU_FLIGHT_RECORDER_DIR": str(tmp_path),
         "RAY_TPU_FLIGHT_RECORDER_SPOOL_MS": "50",
-        "RAY_TPU_CHAOS": "5:task.execute[key=*dying_task*]@1=exit(19)",
+        "RAY_TPU_CHAOS": "5:task.execute[name=*dying_task*]@1=exit(19)",
     }
     c = ProcessCluster(num_daemons=1, num_cpus=2)
     try:
-        c.add_daemon(num_cpus=2, env=flight_env)
+        c.add_daemon(num_cpus=2, env=flight_env, resources={"doomed": 1.0})
         ray_tpu.init(address=c.address)
 
-        @ray_tpu.remote
+        @ray_tpu.remote(resources={"doomed": 1.0})  # as the drill above
         def dying_task():
             return 1
 

@@ -432,20 +432,25 @@ def test_cluster_goodput_preemption_drill():
     from ray_tpu.cluster_utils import ProcessCluster
     from ray_tpu.dashboard.head import DashboardHead
     from ray_tpu import doctor
+    from ray_tpu.util.scheduling_strategies import \
+        NodeAffinitySchedulingStrategy
     from tests.test_drain import Keeper, _actor_call_with_retry
     _require_state_service()
     ray_tpu.shutdown()
     c = ProcessCluster(num_daemons=2, num_cpus=2)
     # the chaos daemon's 6th watcher poll (~3s) returns the eviction
-    # notice; the pin resource forces the actor onto it
-    c.add_daemon(resources={"pin": 1.0},
-                 env={"RAY_TPU_CHAOS": "7:node.preempt@6=drop",
-                      "RAY_TPU_PREEMPT_LEAD_S": "20"})
+    # notice; a soft affinity puts the actor on it and lets a survivor
+    # take it afterwards (a resource that only the victim has would not)
+    chaos_addr = c.add_daemon(env={"RAY_TPU_CHAOS": "7:node.preempt@6=drop",
+                                   "RAY_TPU_PREEMPT_LEAD_S": "20"})
     try:
         ray_tpu.init(address=c.address)
         rt = ray_tpu._private.worker.global_worker().runtime
 
-        k = Keeper.options(resources={"pin": 1.0}).remote()
+        chaos_node = next(n.node_id.hex() for n in rt.state.list_nodes()
+                          if n.address == chaos_addr)
+        k = Keeper.options(scheduling_strategy=NodeAffinitySchedulingStrategy(
+            chaos_node, soft=True)).remote()
         assert ray_tpu.get(k.inc.remote(), timeout=60) == 1
         victim_node, _pid = ray_tpu.get(k.where.remote(), timeout=30)
 

@@ -21,6 +21,7 @@ from ray_tpu.ops import flash_attention
 from ray_tpu.parallel import MeshConfig, ShardingRules, build_mesh, expert
 from ray_tpu.parallel.expert import ExpertConfig, held_experts_apply
 from ray_tpu.train.step import make_lm_train_step
+from test_shortcut_layer import _einsum_attention, _init, _seeded, held
 
 # d 32, 4 heads of 16 + 8 / 16, kv rank 16, 16 routed experts of 24, top-3,
 # shared experts of 2 x 24, a dense layer and two mixture layers
@@ -41,24 +42,6 @@ TINY_DIMS = {
     "shared_width": 48, "held": [0, 4], "bias_rate": 1e-3}
 
 
-def held(cfg, first, count):
-    return dataclasses.replace(cfg, experts=dataclasses.replace(
-        cfg.experts, held=(first, count)))
-
-
-def _seeded(cfg, seed=71):
-    """Seeded weights, every norm weight moved off its initial 1 so that a
-    norm left out, or its weight, shows."""
-    params = transformer.init_params(jax.random.PRNGKey(seed), cfg)
-    leaves, tree = jax.tree_util.tree_flatten_with_path(params)
-    keys = jax.random.split(jax.random.PRNGKey(seed + 1), len(leaves))
-    moved = [p * (1 + 0.3 * jax.random.normal(k, p.shape))
-             if "norm" in jax.tree_util.keystr(path)
-             or "ln" in jax.tree_util.keystr(path) else p
-             for (path, p), k in zip(leaves, keys)]
-    return jax.tree.unflatten(tree, moved)
-
-
 def _tokens(seed, batch=2, length=41):
     return jax.random.randint(jax.random.PRNGKey(seed), (batch, length), 0,
                               TINY.vocab_size)
@@ -68,11 +51,24 @@ def _one_device():
     return build_mesh(MeshConfig(data=1), jax.devices()[:1])
 
 
+@pytest.fixture(scope="module")
+def params():
+    """``TINY``'s seeded weights: ``use_flash`` and ``remat`` change no leaf,
+    so every test of the whole model reads these."""
+    return _seeded(TINY, 71)
+
+
+@pytest.fixture(scope="module")
+def tiny_step():
+    """``make_lm_train_step`` of ``TINY`` with the default optimizer, compiled
+    by the first test that steps it."""
+    return make_lm_train_step(TINY, _one_device(), ShardingRules())
+
+
 # -- the tree --------------------------------------------------------------------
 
 
-def test_the_tree_has_no_q_bottleneck_and_the_shared_experts():
-    params = transformer.init_params(jax.random.PRNGKey(0), TINY)
+def test_the_tree_has_no_q_bottleneck_and_the_shared_experts(params):
     assert set(params["blocks"]) == {LATENT, LATENT_MOE}
     latent = params["blocks"][LATENT_MOE]["latent"]
     assert set(latent) == {"wq", "wkv_a", "kv_norm", "wkv_b", "wo"}
@@ -96,8 +92,8 @@ def test_a_share_draws_its_own_published_experts():
     """Expert e of a share that holds it is expert e of the share that holds
     all: the shares of different chips are disjoint and consistent, and
     what every chip computes alike (the shared experts, MLA) is the same."""
-    whole = transformer.init_params(jax.random.PRNGKey(3), held(TINY, 0, 16))
-    share = transformer.init_params(jax.random.PRNGKey(3), held(TINY, 8, 4))
+    whole = _init(jax.random.PRNGKey(3), held(TINY, 0, 16))
+    share = _init(jax.random.PRNGKey(3), held(TINY, 8, 4))
     for name in ("wi", "wg", "wo"):
         np.testing.assert_array_equal(
             share["blocks"][LATENT_MOE]["experts"][name],
@@ -118,30 +114,40 @@ def test_a_latent_layer_without_its_sizes_is_refused_by_name():
 # -- the program against the reference, float32 ---------------------------------------
 
 
+@pytest.fixture(scope="module")
+def reference_logits(params):
+    tokens = _tokens(1)[:, :-1]
+    return tokens, jax.jit(jax.vmap(lambda row: kanana2_reference.logits(
+        params, row, TINY_DIMS)))(tokens)
+
+
 @pytest.mark.parametrize("use_flash", [False, True], ids=["einsum", "flash"])
-def test_logits_are_the_references(use_flash):
+def test_logits_are_the_references(params, reference_logits, use_flash):
     cfg = dataclasses.replace(TINY, use_flash=use_flash)
-    params, tokens = _seeded(cfg), _tokens(1)[:, :-1]
-    want = jax.vmap(lambda row: kanana2_reference.logits(
-        params, row, TINY_DIMS))(tokens)
-    got = transformer.apply(params, tokens, cfg)
+    tokens, want = reference_logits
+    got = jax.jit(lambda p, t: transformer.apply(p, t, cfg))(params, tokens)
     np.testing.assert_allclose(got, want, atol=2e-5)
+
+
+@pytest.fixture(scope="module")
+def reference_gradients(params):
+    tokens = _tokens(2)
+    return tokens, jax.jit(lambda p, t: kanana2_reference.loss_and_grads(
+        p, t, TINY_DIMS))(params, tokens)
 
 
 @pytest.mark.parametrize("use_flash,remat", [(False, False), (True, True)],
                          ids=["einsum", "flash+remat"])
-def test_the_loss_and_every_leafs_gradient_are_the_references(use_flash,
-                                                              remat):
+def test_the_loss_and_every_leafs_gradient_are_the_references(
+        params, reference_gradients, use_flash, remat):
     """``loss_and_metrics`` (the training path: a scan over the stacked
     leaves, the mixture's and the kernel's own backward passes) against
     autodiff of the plain reference, leaf by leaf; the bias gets none."""
     cfg = dataclasses.replace(TINY, use_flash=use_flash, remat=remat)
-    params, tokens = _seeded(cfg), _tokens(2)
-    (loss, metrics), grads = jax.value_and_grad(
+    tokens, (want, want_grads) = reference_gradients
+    (loss, metrics), grads = jax.jit(jax.value_and_grad(
         lambda p: transformer.loss_and_metrics(p, tokens, cfg),
-        has_aux=True)(params)
-    want, want_grads = kanana2_reference.loss_and_grads(params, tokens,
-                                                        TINY_DIMS)
+        has_aux=True))(params)
     np.testing.assert_allclose(loss, want, rtol=1e-6)
     flat = jax.tree_util.tree_flatten_with_path(grads)[0]
     wanted = dict(jax.tree_util.tree_flatten_with_path(want_grads)[0])
@@ -163,26 +169,28 @@ def test_the_loss_and_every_leafs_gradient_are_the_references(use_flash,
                                   metrics["moe_load"][:, 0])
 
 
-def test_serving_and_training_read_the_same_states():
+def test_serving_and_training_read_the_same_states(params):
     """The serving scan (over indices, the tree closed over) and the training
     scan (over the stacked leaves) are one layer function."""
-    params, tokens = _seeded(TINY), _tokens(3)
-    served = transformer.token_nll(
-        params, transformer.backbone(params, tokens[:, :-1], TINY),
-        tokens[:, 1:], TINY)
-    trained, _ = transformer.loss_and_metrics(params, tokens, TINY)
+    tokens = _tokens(3)
+    served = jax.jit(lambda p, t: transformer.token_nll(
+        p, transformer.backbone(p, t[:, :-1], TINY), t[:, 1:], TINY))(
+            params, tokens)
+    trained, _ = jax.jit(lambda p, t: transformer.loss_and_metrics(
+        p, t, TINY))(params, tokens)
     np.testing.assert_allclose(jnp.mean(served), trained, rtol=1e-6)
 
 
-def test_a_mesh_of_more_than_one_device_is_refused_by_name():
+def test_a_mesh_of_more_than_one_device_is_refused_by_name(params):
     mesh = build_mesh(MeshConfig(data=2), jax.devices()[:2])
-    params, tokens = _seeded(TINY), _tokens(4)
+    tokens = _tokens(4)
     with pytest.raises(ValueError, match="one device only"):
         transformer.loss_and_metrics(params, tokens, TINY, mesh)
     with pytest.raises(ValueError, match="one device only"):
         transformer.apply(params, tokens, TINY, mesh)
     # a mesh of one is the cell's
-    transformer.apply(params, tokens, TINY, _one_device())
+    jax.jit(lambda p, t: transformer.apply(p, t, TINY, _one_device()))(
+        params, tokens)
 
 
 # -- the share ties to the model ------------------------------------------------------
@@ -192,28 +200,30 @@ def test_eight_shares_and_the_shared_experts_once_are_the_uncut_layer():
     """The routed parts that the shares of all devices compute, plus what
     every device computes alike (the shared experts) counted once, are the
     uncut reference's mixture FFN, all 16 experts held."""
-    whole = held(TINY, 0, 16)
-    params = _seeded(whole)
+    whole, dims = held(TINY, 0, 16), {**TINY_DIMS, "held": [0, 16]}
+    params = _seeded(whole, 71)
     layer = jax.tree.map(lambda p: p[1], params["blocks"][LATENT_MOE])
     u = jax.random.normal(jax.random.PRNGKey(5), (50, 32))
-    want = kanana2_reference.moe_ffn(u, layer, {**TINY_DIMS, "held": [0, 16]})
-    total = kanana2_reference.ffn(layer["shared"], u)
+    want = jax.jit(lambda u, l: kanana2_reference.moe_ffn(u, l, dims))(
+        u, layer)
+    total = jax.jit(kanana2_reference.ffn)(layer["shared"], u)
     for first in range(0, 16, 2):           # eight shares of two experts
         cfg = dataclasses.replace(EXPERTS, held=(first, 2))
         mine = jax.tree.map(lambda p: p[None, first:first + 2],
                             layer["experts"])
-        part, load = held_experts_apply(u, layer["router"], mine, cfg, 0,
-                                        bias=layer["router_bias"])
+        part, load = jax.jit(lambda u, l, mine: held_experts_apply(
+            u, l["router"], mine, cfg, 0, bias=l["router_bias"]))(
+                u, layer, mine)
         assert int(load[0]) + int(load[1]) == 50 * 3
         total = total + part
     np.testing.assert_allclose(total, want, atol=2e-5)
     # and the program's own layer with every expert held is that sum
     x = jax.random.normal(jax.random.PRNGKey(6), (1, 50, 32))
     stack = jax.tree.map(lambda p: p[1:2], params["blocks"][LATENT_MOE])
-    got, _ = transformer._parts_block(
-        stack, 0, x, jnp.arange(50)[None], whole, LATENT_MOE)
-    want_block = kanana2_reference.block(layer, x[0], 1,
-                                         {**TINY_DIMS, "held": [0, 16]})
+    got, _ = jax.jit(lambda stack, x: transformer._parts_block(
+        stack, 0, x, jnp.arange(50)[None], whole, LATENT_MOE))(stack, x)
+    want_block = jax.jit(lambda l, x: kanana2_reference.block(
+        l, x, 1, dims))(layer, x[0])
     np.testing.assert_allclose(got[0], want_block, atol=2e-5)
 
 
@@ -271,10 +281,10 @@ def test_the_mixtures_backward_is_autodiff_of_a_dense_masked_mixture(
     def dense(u, router, bias, experts):
         return jnp.sum(_dense_mixture(u, router, bias, experts, cfg) * g)
 
-    (value, load), got = jax.value_and_grad(
-        program, argnums=(0, 1, 2, 3), has_aux=True)(u, router, bias, experts)
-    want_value, want = jax.value_and_grad(dense, argnums=(0, 1, 2, 3))(
-        u, router, bias, experts)
+    (value, load), got = jax.jit(jax.value_and_grad(
+        program, argnums=(0, 1, 2, 3), has_aux=True))(u, router, bias, experts)
+    want_value, want = jax.jit(jax.value_and_grad(
+        dense, argnums=(0, 1, 2, 3)))(u, router, bias, experts)
     held_pairs = {"spread": None, "one": 70, "none": 0}[which]
     if held_pairs is not None:
         assert int(load[0]) == held_pairs
@@ -300,22 +310,15 @@ def test_the_mixtures_backward_reads_the_stack_at_the_layers_index():
         out, _ = held_experts_apply(u, router, stacked, cfg, layer, bias=bias)
         return jnp.sum(out * g)
 
-    got = jax.jit(jax.grad(program), static_argnums=())(stacked, 1)
-    alone = jax.grad(lambda e: program(
-        jax.tree.map(lambda p: p[None], e), 0))(experts)
+    got = jax.jit(jax.grad(program))(stacked, 1)
+    alone = jax.jit(jax.grad(lambda e: program(
+        jax.tree.map(lambda p: p[None], e), 0)))(experts)
     for name in ("wi", "wg", "wo"):
         assert not np.any(np.asarray(got[name][0]))
         np.testing.assert_allclose(got[name][1], alone[name], atol=1e-6)
 
 
 # -- the flash backward at two widths -------------------------------------------------
-
-
-def _plain_attention(q, k, v):
-    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(q.shape[-1])
-    mask = jnp.tril(jnp.ones((q.shape[1], k.shape[1]), bool))
-    prob = jax.nn.softmax(jnp.where(mask, scores, -jnp.inf), axis=-1)
-    return jnp.einsum("bhqk,bkhd->bqhd", prob, v)
 
 
 @pytest.mark.parametrize("shape", [(1, 256, 2, 192, 128), (2, 72, 4, 24, 16)],
@@ -329,10 +332,10 @@ def test_flash_backward_at_two_widths_is_plain_attentions(shape):
     k = jax.random.normal(ks[1], (B, L, H, D))
     v = jax.random.normal(ks[2], (B, L, H, Dv))
     g = jax.random.normal(ks[3], (B, L, H, Dv))
-    got = jax.grad(lambda *a: jnp.sum(g * flash_attention(
-        *a, block_q=128, block_k=128)), argnums=(0, 1, 2))(q, k, v)
-    want = jax.grad(lambda *a: jnp.sum(g * _plain_attention(*a)),
-                    argnums=(0, 1, 2))(q, k, v)
+    got = jax.jit(jax.grad(lambda *a: jnp.sum(g * flash_attention(
+        *a, block_q=128, block_k=128)), argnums=(0, 1, 2)))(q, k, v)
+    want = jax.jit(jax.grad(lambda *a: jnp.sum(g * _einsum_attention(*a)),
+                            argnums=(0, 1, 2)))(q, k, v)
     assert [a.shape for a in got] == [q.shape, k.shape, v.shape]
     for a, b in zip(got, want):
         np.testing.assert_allclose(a, b, atol=1e-4)
@@ -371,19 +374,21 @@ FLASH = dataclasses.replace(TINY, use_flash=True, remat=True)
 
 
 def _loss_and_grads(cfg, params, tokens):
-    return jax.value_and_grad(
-        lambda p: transformer.loss_and_metrics(p, tokens, cfg)[0])(params)
+    """Traced anew at every call (the ``jit`` is of a new function), so a
+    patch of what the trace reads takes effect."""
+    return jax.jit(jax.value_and_grad(
+        lambda p: transformer.loss_and_metrics(p, tokens, cfg)[0]))(params)
 
 
 def test_keeping_the_kernels_output_changes_no_bit_of_the_gradient(
-        monkeypatch):
+        monkeypatch, params):
     """``_parts_states``' checkpoint keeps the flash forward's output and
     log-sum-exp (``flash_attention.KEPT``): the loss and every leaf's
     gradient are, bit for bit, those of the same stack under a plain
     ``jax.checkpoint`` (the policy patched away: the kept arrays are the
     ones the recomputation would make again), and the file's tolerances away
     from the stack without ``remat``."""
-    params, tokens = _seeded(FLASH), _tokens(5)
+    tokens = _tokens(5)
     loss, grads = _loss_and_grads(FLASH, params, tokens)
     free_loss, free_grads = _loss_and_grads(
         dataclasses.replace(FLASH, remat=False), params, tokens)
@@ -405,13 +410,14 @@ def test_keeping_the_kernels_output_changes_no_bit_of_the_gradient(
     np.testing.assert_allclose(loss, free_loss, rtol=1e-6)
 
 
-def test_the_training_stacks_backward_runs_no_flash_forward(monkeypatch):
+def test_the_training_stacks_backward_runs_no_flash_forward(monkeypatch,
+                                                            params):
     """In the jaxpr of the differentiated loss each run's forward scan holds
     one ``flash_fwd`` and its backward scan ``flash_dq`` and ``flash_dkv``
     and no ``flash_fwd``: the recomputed block's second call has no reader
     once the first call's results are kept. Under a plain ``jax.checkpoint``
     (the parent's program) the backward scan held it."""
-    params, tokens = _seeded(FLASH), _tokens(6)
+    tokens = _tokens(6)
 
     def scans():
         return _scans_kernels(jax.make_jaxpr(jax.grad(
@@ -435,7 +441,7 @@ def test_the_dense_stacks_backward_still_runs_the_flash_forward():
     dense = TransformerConfig(
         vocab_size=96, d_model=32, n_layers=2, n_heads=4, d_ff=48,
         max_seq_len=64, dtype=jnp.float32, use_flash=True, remat=True)
-    params = transformer.init_params(jax.random.PRNGKey(7), dense)
+    params = jax.eval_shape(_init, jax.random.PRNGKey(7), dense)
     jaxpr = jax.make_jaxpr(jax.grad(
         lambda p: transformer.loss_and_metrics(p, _tokens(7), dense)[0]))(
             params).jaxpr
@@ -457,7 +463,7 @@ def test_the_bias_moves_by_the_load_and_is_no_parameter():
         optimizer=optax.adamw(1e-2, weight_decay=0.5))
     key = jax.random.PRNGKey(31)
     state = init_fn(key)
-    params = transformer.init_params(key, cfg)
+    params = _init(key, cfg)
     moments = jax.tree_util.tree_flatten_with_path(state[1])[0]
     assert moments and not any("router_bias" in jax.tree_util.keystr(path)
                                for path, _ in moments)
@@ -498,12 +504,11 @@ def test_a_steps_loss_falls_over_twenty_steps_on_a_repeated_batch():
     expert.flush_loads()
 
 
-def test_the_steps_loads_reach_the_counters_without_a_call_back():
+def test_the_steps_loads_reach_the_counters_without_a_call_back(tiny_step):
     """No ``debug_callback`` in the step's jaxpr (its program keeps its key
     in the compile cache); the counters are fed from the step's ``moe_load``
     once the device has it."""
-    init_fn, step_fn, shard = make_lm_train_step(TINY, _one_device(),
-                                                 ShardingRules())
+    init_fn, step_fn, shard = tiny_step
     state = init_fn(jax.random.PRNGKey(51))
     tokens = shard(np.asarray(_tokens(9)))
     text = str(jax.make_jaxpr(step_fn.__wrapped__)(state, tokens))
@@ -526,7 +531,8 @@ def test_the_steps_loads_reach_the_counters_without_a_call_back():
     np.testing.assert_array_equal(seen[0][1], metrics["moe_load"])
 
 
-def test_the_default_optimizers_rate_warms_up_where_the_configuration_asks():
+def test_the_default_optimizers_rate_warms_up_where_the_configuration_asks(
+        tiny_step):
     """``warmup_steps`` raises the default optimizer's rate linearly from
     ``3e-4 / steps``: the first update of a weight is that much, not 3e-4
     (Adam's first step moves every weight by its rate); without the field
@@ -534,10 +540,11 @@ def test_the_default_optimizers_rate_warms_up_where_the_configuration_asks():
     from ray_tpu.train.step import _default_optimizer
     tokens = np.asarray(_tokens(10))
     moved = {}
+    assert TINY.warmup_steps == 0
     for steps in (0, 10):
-        cfg = dataclasses.replace(TINY, warmup_steps=steps)
-        init_fn, step_fn, shard = make_lm_train_step(cfg, _one_device(),
-                                                     ShardingRules())
+        init_fn, step_fn, shard = tiny_step if steps == 0 else (
+            make_lm_train_step(dataclasses.replace(TINY, warmup_steps=steps),
+                               _one_device(), ShardingRules()))
         state = init_fn(jax.random.PRNGKey(3))
         before = np.asarray(state[0]["lm_head"])
         state, _ = step_fn(state, shard(tokens))

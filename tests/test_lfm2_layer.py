@@ -5,6 +5,7 @@ router's variants: at tiny sizes on the CPU against
 ``benchmark/lfm2_reference.py``, ``jnp.convolve`` and plain sums."""
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -21,6 +22,7 @@ from ray_tpu.parallel.expert import ExpertConfig, held_experts_apply
 from ray_tpu.train.step import make_lm_train_step
 from test_mixed_stack import MIXED, _digest
 from test_shortcut_layer import TINY as TINY_LONGCAT
+from test_shortcut_layer import _init, _last_logits, _seeded
 
 # d 64, 4 query heads over 2 K/V heads of 16, 16 experts of 32, top-4, the
 # published pattern of the cut: layer 0 and two periods of layers 2-9
@@ -57,23 +59,12 @@ def _cut(first, n):
     return cfg, dims
 
 
-def _seeded(cfg, seed=51):
-    """Seeded weights, every norm weight moved off its initial 1 so that a
-    norm left out, or its weight, shows."""
-    params = transformer.init_params(jax.random.PRNGKey(seed), cfg)
-    leaves, tree = jax.tree_util.tree_flatten_with_path(params)
-    keys = jax.random.split(jax.random.PRNGKey(seed + 1), len(leaves))
-    moved = [p * (1 + 0.3 * jax.random.normal(k, p.shape))
-             if "norm" in jax.tree_util.keystr(path)
-             or "ln" in jax.tree_util.keystr(path) else p
-             for (path, p), k in zip(leaves, keys)]
-    return jax.tree.unflatten(tree, moved)
 
 
-def _last_logits(params, tokens, last, cfg):
-    x = transformer.backbone(params, tokens, cfg)
-    x = jnp.take_along_axis(x, last[:, None, None], axis=1)
-    return transformer.head(params, x, cfg)[:, 0]
+@pytest.fixture(scope="module")
+def params():
+    """``TINY``'s seeded weights, drawn once for the file."""
+    return _seeded(TINY, 51)
 
 
 # -- the short convolution -----------------------------------------------------------
@@ -223,7 +214,7 @@ def test_the_drawn_bias_moves_the_choice_of_most_tokens_and_is_on_bf16s_grid():
     is."""
     cfg = dataclasses.replace(TINY, experts=dataclasses.replace(
         EXPERTS, n_routed=64, held=(0, 64)))
-    params = transformer.init_params(jax.random.PRNGKey(8), cfg)
+    params = _init(jax.random.PRNGKey(8), cfg)
     layer = jax.tree.map(lambda p: p[0], params["blocks"][CONV_MOE])
     bias = layer["router_bias"]
     assert bias.shape == (64,) and bias.dtype == jnp.float32
@@ -232,9 +223,9 @@ def test_the_drawn_bias_moves_the_choice_of_most_tokens_and_is_on_bf16s_grid():
     assert 0.5 * expert.BIAS_SCALE < float(jnp.std(bias)) \
         < 1.5 * expert.BIAS_SCALE
     u = jax.random.normal(jax.random.PRNGKey(9), (4096, 64))
-    with_bias, _ = expert.route(u, layer["router"], cfg.experts, bias)
-    without, _ = expert.route(u, layer["router"], cfg.experts,
-                              jnp.zeros_like(bias))
+    route = jax.jit(lambda u, r, b: expert.route(u, r, cfg.experts, b))
+    with_bias, _ = route(u, layer["router"], bias)
+    without, _ = route(u, layer["router"], jnp.zeros_like(bias))
     moved = float(jnp.mean(jnp.any(jnp.sort(with_bias) != jnp.sort(without),
                                    axis=-1)))
     print(f"the bias moves the choice of {100 * moved:.1f}% of the tokens")
@@ -250,6 +241,7 @@ def _mixture_layer(seed=10):
     return jax.tree.map(lambda p: p[0], params["blocks"][CONV_MOE])
 
 
+@functools.partial(jax.jit, static_argnums=2)
 def _reference_mixture(u, layer, held=(0, 16)):
     with jax.default_matmul_precision("highest"):
         return lfm2_reference.mixture(
@@ -268,9 +260,9 @@ def test_all_the_experts_held_and_no_zero_expert_is_the_references_mixture(
     monkeypatch.setattr(expert, "CHUNK_ROWS", rows)
     layer = _mixture_layer()
     u = jax.random.normal(jax.random.PRNGKey(11), (50, 64))
-    got, load = held_experts_apply(
-        u, layer["router"], jax.tree.map(lambda p: p[None], layer["experts"]),
-        EXPERTS, 0, bias=layer["router_bias"])
+    got, load = jax.jit(lambda u, l: held_experts_apply(
+        u, l["router"], jax.tree.map(lambda p: p[None], l["experts"]),
+        EXPERTS, 0, bias=l["router_bias"]))(u, layer)
     np.testing.assert_allclose(got, _reference_mixture(u, layer), atol=2e-5)
     assert load.tolist()[:3] == [200, 0, 0]
 
@@ -288,8 +280,9 @@ def test_the_shares_add_up_to_the_uncut_references_layer(count):
         cfg = dataclasses.replace(EXPERTS, held=(first, count))
         mine = jax.tree.map(lambda p: p[None, first:first + count],
                             layer["experts"])
-        part, load = held_experts_apply(u, layer["router"], mine, cfg, 0,
-                                        bias=layer["router_bias"])
+        part, load = jax.jit(lambda u, l, mine: held_experts_apply(
+            u, l["router"], mine, cfg, 0, bias=l["router_bias"]))(
+                u, layer, mine)
         assert int(load[0]) + int(load[1]) == 160 and int(load[2]) == 0
         total = total + part
     np.testing.assert_allclose(total, _reference_mixture(u, layer),
@@ -302,33 +295,33 @@ def test_the_shares_add_up_to_the_uncut_references_layer(count):
         stack = jax.tree.map(lambda p: p[None], {
             **layer, "experts": jax.tree.map(
                 lambda p: p[first:first + count], layer["experts"])})
-        return transformer._parts_block(stack, 0, x, positions, cfg,
-                                        CONV_MOE)[0]
+        return block(stack, cfg)
 
     x = jax.random.normal(jax.random.PRNGKey(14), (1, 20, 64))
     positions = jnp.arange(20)[None]
+    block = jax.jit(lambda stack, cfg: transformer._parts_block(
+        stack, 0, x, positions, cfg, CONV_MOE)[0], static_argnums=1)
     uncut = layer_out(0, 16)
     # a share's layer less the layer without any expert is its experts' part
     cfg, dims = _cut(2, 1)
     zeroed = jax.tree.map(lambda p: p[None], {
         **layer, "experts": jax.tree.map(jnp.zeros_like, layer["experts"])})
-    none = transformer._parts_block(zeroed, 0, x, positions, cfg,
-                                    CONV_MOE)[0]
+    none = block(zeroed, cfg)
     parts = sum(layer_out(first, count) - none
                 for first in range(0, 16, count))
     np.testing.assert_allclose(none + parts, uncut, atol=5e-5)
     with jax.default_matmul_precision("highest"):
-        want = lfm2_reference.block(
+        want = jax.jit(lambda layer, x: lfm2_reference.block(
             lfm2_reference.from_tree(
                 {"blocks": {CONV_MOE: jax.tree.map(lambda p: p[None],
                                                    layer)}}, 0, dims),
-            x[0], 0, dims)
+            x, 0, dims))(layer, x[0])
     np.testing.assert_allclose(uncut[0], want, atol=5e-5)
 
 
 def test_shares_of_different_devices_draw_consistent_experts():
-    whole = transformer.init_params(jax.random.PRNGKey(15), TINY)
-    share = transformer.init_params(jax.random.PRNGKey(15), dataclasses.replace(
+    whole = _init(jax.random.PRNGKey(15), TINY)
+    share = _init(jax.random.PRNGKey(15), dataclasses.replace(
         TINY, experts=dataclasses.replace(EXPERTS, held=(4, 8))))
     for kind in (ATTN_MOE, CONV_MOE):
         for name in ("wi", "wg", "wo"):
@@ -419,17 +412,19 @@ def test_each_layer_shape_agrees_with_the_reference(first, kind, use_flash):
     params = _seeded(cfg, 16 + first)
     assert set(params["blocks"]) == {kind}
     tokens = jax.random.randint(jax.random.PRNGKey(17), (2, 20), 0, 96)
-    got = _last_logits(params, tokens, jnp.array([19, 19]), cfg)
-    want = lfm2_reference.tree_last_logits(params, tokens, dims)
+    got = jax.jit(lambda p, t, l: _last_logits(p, t, l, cfg))(
+        params, tokens, jnp.array([19, 19]))
+    want = jax.jit(lambda p, t: lfm2_reference.tree_last_logits(p, t, dims))(
+        params, tokens)
     np.testing.assert_allclose(got, want, atol=1e-4)
     assert float(jnp.abs(want).max()) > 0.05
 
 
-def test_the_nine_layer_stack_agrees_with_the_reference_under_jit_and_padding():
+def test_the_nine_layer_stack_agrees_with_the_reference_under_jit_and_padding(
+        params):
     """The cut's own pattern, prompts of 9 and 24 tokens in one padded batch
     of 32: each prompt's logits at its last real position are the
     reference's on the prompt alone."""
-    params = _seeded(TINY, 18)
     assert {k: p["ln1"].shape[0] for k, p in params["blocks"].items()} == {
         CONV: 1, ATTN_MOE: 2, CONV_MOE: 6}
     key = jax.random.PRNGKey(19)
@@ -440,35 +435,34 @@ def test_the_nine_layer_stack_agrees_with_the_reference_under_jit_and_padding():
     got = jax.jit(lambda p, t, l: _last_logits(p, t, l, TINY))(params, tokens,
                                                                last)
     for row, prompt in zip(got, prompts):
-        want = lfm2_reference.tree_last_logits(params, prompt[None],
-                                               TINY_DIMS)[0]
+        want = jax.jit(lambda p, t: lfm2_reference.tree_last_logits(
+            p, t, TINY_DIMS))(params, prompt[None])[0]
         np.testing.assert_allclose(row, want, atol=2e-4)
 
 
-def test_the_runs_scan_is_the_block_on_each_layer_in_the_published_order():
+def test_the_runs_scan_is_the_block_on_each_layer_in_the_published_order(
+        params):
     """``_apply_parts`` against ``_parts_block`` applied layer by layer, each
     on its own kind's stack at its own index: the same states, and a layer
     of the wrong index would show."""
-    params = _seeded(TINY, 20)
     x = jax.random.normal(jax.random.PRNGKey(21), (2, 20, 64))
     positions = jnp.broadcast_to(jnp.arange(20)[None], (2, 20))
     got = jax.jit(lambda b, x: transformer._apply_parts(
         b, x, positions, TINY))(params["blocks"], x)
+    block = jax.jit(lambda stack, index, x, kind: transformer._parts_block(
+        stack, index, x, positions, TINY, kind), static_argnums=(1, 3))
     want, taken = x, dict.fromkeys(PARTS, 0)
     for kind in TINY.layer_kinds:
-        want, load = transformer._parts_block(
-            params["blocks"][kind], taken[kind], want, positions, TINY, kind)
+        want, load = block(params["blocks"][kind], taken[kind], want, kind)
         assert (load is None) == (kind == CONV)
         taken[kind] += 1
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
-    swapped, _ = transformer._parts_block(params["blocks"][CONV_MOE], 1, x,
-                                          positions, TINY, CONV_MOE)
-    first, _ = transformer._parts_block(params["blocks"][CONV_MOE], 0, x,
-                                        positions, TINY, CONV_MOE)
+    swapped, _ = block(params["blocks"][CONV_MOE], 1, x, CONV_MOE)
+    first, _ = block(params["blocks"][CONV_MOE], 0, x, CONV_MOE)
     assert float(jnp.abs(swapped - first).max()) > 0.1
 
 
-def test_a_forwards_loads_reach_the_counters_in_one_call_back():
+def test_a_forwards_loads_reach_the_counters_in_one_call_back(params):
     from ray_tpu.util import metrics
 
     def calls():
@@ -476,7 +470,6 @@ def test_a_forwards_loads_reach_the_counters_in_one_call_back():
                    if f["name"] == "moe_layer_calls_total"
                    for _, _, v in f["samples"])
 
-    params = _seeded(TINY, 22)
     before = calls()
     tokens = jax.random.randint(jax.random.PRNGKey(23), (1, 16), 0, 96)
     jaxpr = str(jax.make_jaxpr(
@@ -501,19 +494,21 @@ def test_the_q_and_k_norms_come_before_the_rotation_and_only_by_the_field():
     assert attn["q_norm"].shape == attn["k_norm"].shape == (16,)
     h = jax.random.normal(jax.random.PRNGKey(25), (1, 12, 64))
     positions = jnp.arange(12)[None]
-    got = transformer._attention_mixer(attn, h, positions, cfg, None)[0]
+    mixer = jax.jit(lambda a, h, cfg: transformer._attention_mixer(
+        a, h, positions, cfg, None)[0], static_argnums=2)
+    got = mixer(attn, h, cfg)
     with jax.default_matmul_precision("highest"):
-        want = lfm2_reference.attention(attn, h[0], {**TINY_DIMS})
+        want = jax.jit(lambda a, h: lfm2_reference.attention(
+            a, h, {**TINY_DIMS}))(attn, h[0])
     np.testing.assert_allclose(got, want, atol=2e-5)
     plain = dataclasses.replace(cfg, qk_norm=False)
-    assert "q_norm" not in transformer.init_params(
-        jax.random.PRNGKey(0), plain)["blocks"]["attn"]
-    off = transformer._attention_mixer(attn, h, positions, plain, None)[0]
+    assert "q_norm" not in jax.eval_shape(
+        _init, jax.random.PRNGKey(0), plain)["blocks"]["attn"]
+    off = mixer(attn, h, plain)
     assert float(jnp.abs(off - got).max()) > 1e-2
 
 
-def test_the_tied_head_is_the_embedding_transposed():
-    params = _seeded(TINY, 26)
+def test_the_tied_head_is_the_embedding_transposed(params):
     assert "lm_head" not in params
     x = jax.random.normal(jax.random.PRNGKey(27), (2, 3, 64))
     got = transformer.head(params, x, TINY)
@@ -523,7 +518,7 @@ def test_the_tied_head_is_the_embedding_transposed():
     np.testing.assert_allclose(got, want, rtol=1e-6)
     np.testing.assert_array_equal(transformer._head_weight(params, TINY),
                                   params["embed"].T)
-    assert "lm_head" in transformer.init_params(jax.random.PRNGKey(0), untied)
+    assert "lm_head" in jax.eval_shape(_init, jax.random.PRNGKey(0), untied)
 
 
 # -- the configuration's guards ------------------------------------------------------
@@ -578,11 +573,12 @@ def test_a_train_step_of_the_kinds_is_the_references_autodiff(kinds):
     init_fn, step_fn, shard = make_lm_train_step(cfg, mesh, ShardingRules())
     key = jax.random.PRNGKey(61)
     state = init_fn(key)
-    params = transformer.init_params(key, cfg)
+    params = _init(key, cfg)
     tokens = np.asarray(jax.random.randint(jax.random.PRNGKey(62), (2, 25),
                                            0, cfg.vocab_size))
-    want_loss, want_norm = lfm2_reference.loss_and_grad_norm(
-        params, jnp.asarray(tokens), dims)
+    want_loss, want_norm = jax.jit(
+        lambda p, t: lfm2_reference.loss_and_grad_norm(p, t, dims))(
+            params, jnp.asarray(tokens))
     moments = jax.tree_util.tree_flatten_with_path(state[1])[0]
     assert not any("router_bias" in jax.tree_util.keystr(path)
                    for path, _ in moments)
@@ -602,8 +598,7 @@ def test_a_train_step_of_the_kinds_is_the_references_autodiff(kinds):
         assert np.any(steps != 0)
 
 
-def test_logical_axes_mirror_the_tree():
-    params = _seeded(TINY, 28)
+def test_logical_axes_mirror_the_tree(params):
     axes = transformer.logical_axes(TINY)
     is_axes = lambda a: isinstance(a, tuple)   # noqa: E731
     flat_axes = jax.tree_util.tree_flatten_with_path(axes, is_leaf=is_axes)[0]
@@ -612,13 +607,13 @@ def test_logical_axes_mirror_the_tree():
     assert all(a.ndim == len(b) for (_, a), (_, b) in zip(flat, flat_axes))
 
 
-def test_the_conv_mixer_runs_under_a_scope_of_its_own_at_the_layers_top():
+def test_the_conv_mixer_runs_under_a_scope_of_its_own_at_the_layers_top(
+        params):
     """``shortconv`` is the first scope on the path of the mixer's
     operations (not inside ``attn``), it is declared, and no accepted scope
     was renamed for it."""
     assert metric_names.LATER_DEVICE_SCOPES == {"shortconv"}
     assert not metric_names.LATER_DEVICE_SCOPES & metric_names.DEVICE_SCOPES
-    params = _seeded(TINY, 29)
     tokens = jnp.zeros((1, 16), jnp.int32)
     text = jax.jit(lambda p, t: transformer.backbone(p, t, TINY)).lower(
         params, tokens).compile().as_text()

@@ -18,10 +18,18 @@ from ray_tpu.parallel import (MeshConfig, ShardingRules, batch_sharding,
 
 TINY = TransformerConfig(vocab_size=256, d_model=64, n_layers=2, n_heads=4,
                          max_seq_len=128, dtype=jnp.float32, use_flash=False)
+# one compiled program a configuration: drawn eagerly, a tree costs some
+# hundred one-primitive compiles
+_init = jax.jit(transformer.init_params, static_argnums=1)
 
 
-def test_transformer_forward_shapes():
-    params = transformer.init_params(jax.random.PRNGKey(0), TINY)
+@pytest.fixture(scope="module")
+def tiny_params():
+    return _init(jax.random.PRNGKey(0), TINY)
+
+
+def test_transformer_forward_shapes(tiny_params):
+    params = tiny_params
     tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 16), 0, 256)
     logits = transformer.apply(params, tokens, TINY)
     assert logits.shape == (2, 16, 256)
@@ -30,7 +38,7 @@ def test_transformer_forward_shapes():
 
 def test_transformer_loss_decreases():
     cfg = TINY
-    params = transformer.init_params(jax.random.PRNGKey(0), cfg)
+    params = _init(jax.random.PRNGKey(0), cfg)
     tokens = jax.random.randint(jax.random.PRNGKey(1), (4, 32), 0, 256)
     opt = optax.adam(1e-3)
     opt_state = opt.init(params)
@@ -49,13 +57,13 @@ def test_transformer_loss_decreases():
     assert losses[-1] < losses[0] - 0.5, losses
 
 
-def test_transformer_causality():
+def test_transformer_causality(tiny_params):
     """Changing a future token must not affect earlier logits."""
-    params = transformer.init_params(jax.random.PRNGKey(0), TINY)
+    apply = jax.jit(lambda p, t: transformer.apply(p, t, TINY))
     tokens = jax.random.randint(jax.random.PRNGKey(1), (1, 16), 0, 256)
-    logits1 = transformer.apply(params, tokens, TINY)
+    logits1 = apply(tiny_params, tokens)
     tokens2 = tokens.at[0, -1].set((tokens[0, -1] + 1) % 256)
-    logits2 = transformer.apply(params, tokens2, TINY)
+    logits2 = apply(tiny_params, tokens2)
     np.testing.assert_allclose(np.asarray(logits1[0, :-1]),
                                np.asarray(logits2[0, :-1]),
                                rtol=1e-4, atol=1e-4)
@@ -64,7 +72,7 @@ def test_transformer_causality():
 def test_transformer_flash_matches_dense():
     cfg_dense = TINY
     cfg_flash = TransformerConfig(**{**cfg_dense.__dict__, "use_flash": True})
-    params = transformer.init_params(jax.random.PRNGKey(0), cfg_dense)
+    params = _init(jax.random.PRNGKey(0), cfg_dense)
     tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 128), 0, 256)
     l_dense = transformer.apply(params, tokens, cfg_dense)
     l_flash = transformer.apply(params, tokens, cfg_flash)
@@ -91,7 +99,7 @@ def _assert_same_loss_and_grads(got, want, tol):
 def test_transformer_gqa_flash_matches_dense():
     """The kernel reads K/V heads by index; the einsum path repeats them.
     Loss and every gradient (wk and wv sum their group's query heads)."""
-    params = transformer.init_params(jax.random.PRNGKey(0), GQA)
+    params = _init(jax.random.PRNGKey(0), GQA)
     assert params["blocks"]["attn"]["wk"].shape[2] == 2
     tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 129), 0, 256)
     _assert_same_loss_and_grads(_loss_and_grads(GQA_FLASH, params, tokens),
@@ -105,7 +113,7 @@ def test_transformer_gqa_flash_under_a_mesh(eight_device_mesh, axes):
     """Under a mesh K and V go into the kernel's shard_map with their own
     head count split like q's: each device's query heads find their K/V
     heads on it, and the result is the single-device one."""
-    params = transformer.init_params(jax.random.PRNGKey(0), GQA)
+    params = _init(jax.random.PRNGKey(0), GQA)
     tokens = jax.random.randint(jax.random.PRNGKey(1), (8, 65), 0, 256)
     want = _loss_and_grads(GQA, params, tokens)
     mesh = build_mesh(MeshConfig(**axes), eight_device_mesh)
@@ -119,7 +127,7 @@ def test_transformer_gqa_flash_under_a_mesh(eight_device_mesh, axes):
 def test_transformer_gqa_flash_refuses_heads_that_do_not_split(
         eight_device_mesh):
     cfg = TransformerConfig(**{**GQA_FLASH.__dict__, "n_kv_heads": 1})
-    params = transformer.init_params(jax.random.PRNGKey(0), cfg)
+    params = _init(jax.random.PRNGKey(0), cfg)
     tokens = jax.random.randint(jax.random.PRNGKey(1), (8, 33), 0, 256)
     mesh = build_mesh(MeshConfig(data=4, tensor=2), eight_device_mesh)
     with pytest.raises(ValueError, match="n_kv_heads=1"):
@@ -128,7 +136,7 @@ def test_transformer_gqa_flash_refuses_heads_that_do_not_split(
 
 def test_transformer_gqa_ring_attention_matches(eight_device_mesh):
     """Ring attention still gets K and V repeated to q's heads."""
-    params = transformer.init_params(jax.random.PRNGKey(0), GQA)
+    params = _init(jax.random.PRNGKey(0), GQA)
     tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 64), 0, 256)
     ref = transformer.apply(params, tokens, GQA, mesh=None)
     mesh = build_mesh(MeshConfig(data=2, seq=4), eight_device_mesh)
@@ -143,7 +151,7 @@ def test_transformer_sharded_train_step(eight_device_mesh):
                       eight_device_mesh)
     cfg = TINY
     rules = ShardingRules()
-    params = transformer.init_params(jax.random.PRNGKey(0), cfg)
+    params = _init(jax.random.PRNGKey(0), cfg)
     axes = transformer.logical_axes(cfg)
     params = shard_pytree(params, axes, mesh, rules)
     tokens = jax.random.randint(jax.random.PRNGKey(1), (8, 32), 0, 256)
@@ -166,7 +174,7 @@ def test_transformer_sharded_train_step(eight_device_mesh):
 def test_transformer_seq_parallel_matches(eight_device_mesh):
     """Ring-attention path (seq axis > 1) matches single-device output."""
     cfg = TINY
-    params = transformer.init_params(jax.random.PRNGKey(0), cfg)
+    params = _init(jax.random.PRNGKey(0), cfg)
     tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 64), 0, 256)
     ref = transformer.apply(params, tokens, cfg, mesh=None)
     mesh = build_mesh(MeshConfig(data=2, seq=4), eight_device_mesh)
@@ -177,13 +185,14 @@ def test_transformer_seq_parallel_matches(eight_device_mesh):
 
 def test_resnet_forward_and_grad():
     cfg = resnet.resnet18(num_classes=10)
-    params = resnet.init_params(jax.random.PRNGKey(0), cfg)
+    params = jax.jit(resnet.init_params, static_argnums=1)(
+        jax.random.PRNGKey(0), cfg)
     images = jax.random.normal(jax.random.PRNGKey(1), (2, 32, 32, 3))
-    logits = resnet.apply(params, images, cfg)
+    logits = jax.jit(resnet.apply, static_argnums=2)(params, images, cfg)
     assert logits.shape == (2, 10)
     labels = jnp.array([1, 2])
-    loss, grads = jax.value_and_grad(resnet.loss_fn)(params, images, labels,
-                                                     cfg)
+    loss, grads = jax.jit(jax.value_and_grad(resnet.loss_fn),
+                          static_argnums=3)(params, images, labels, cfg)
     assert np.isfinite(float(loss))
     gw = grads["head"]["w"]
     assert np.isfinite(np.asarray(gw)).all()
@@ -191,7 +200,8 @@ def test_resnet_forward_and_grad():
 
 def test_resnet50_params_count():
     cfg = resnet.resnet50()
-    params = resnet.init_params(jax.random.PRNGKey(0), cfg)
+    params = jax.eval_shape(
+        lambda: resnet.init_params(jax.random.PRNGKey(0), cfg))
     n = transformer.num_params(params)
     # torchvision resnet50 has ~25.6M params
     assert 20e6 < n < 30e6, n
@@ -211,26 +221,30 @@ LOOPED_DIMS = {"n_heads": 4, "n_kv_heads": 4, "rope_theta": 1e6,
 def looped():
     """Seeded weights (norm weights and the gate's bias moved off their
     initial 1 and 0, so that every leaf matters) and tokens [2, 17]."""
-    params = transformer.init_params(jax.random.PRNGKey(30), LOOPED)
-    leaves, tree = jax.tree_util.tree_flatten_with_path(params)
-    keys = jax.random.split(jax.random.PRNGKey(31), len(leaves))
-    moved = [p + 0.1 * jax.random.normal(k, p.shape)
-             if "ln" in jax.tree_util.keystr(path)
-             or "exit_gate" in jax.tree_util.keystr(path) else p
-             for (path, p), k in zip(leaves, keys)]
+    @jax.jit
+    def seeded():
+        params = transformer.init_params(jax.random.PRNGKey(30), LOOPED)
+        leaves, tree = jax.tree_util.tree_flatten_with_path(params)
+        keys = jax.random.split(jax.random.PRNGKey(31), len(leaves))
+        moved = [p + 0.1 * jax.random.normal(k, p.shape)
+                 if "ln" in jax.tree_util.keystr(path)
+                 or "exit_gate" in jax.tree_util.keystr(path) else p
+                 for (path, p), k in zip(leaves, keys)]
+        return jax.tree.unflatten(tree, moved)
+
     tokens = jax.random.randint(jax.random.PRNGKey(32), (2, 17), 0, 96)
-    return jax.tree.unflatten(tree, moved), tokens
+    return seeded(), tokens
 
 
-def test_default_fields_keep_todays_parameter_tree():
-    params = transformer.init_params(jax.random.PRNGKey(0), TINY)
+def test_default_fields_keep_todays_parameter_tree(tiny_params):
+    params = tiny_params
     assert sorted(params) == ["blocks", "embed", "lm_head", "ln_f"]
     assert sorted(params["blocks"]) == ["attn", "ln1", "ln2", "mlp"]
     axes = transformer.logical_axes(TINY)
     assert jax.tree.structure(params) == jax.tree.structure(
         axes, is_leaf=lambda a: isinstance(a, tuple))
     # the looped configuration grows two norms a block and the gate
-    grown = transformer.init_params(jax.random.PRNGKey(0), LOOPED)
+    grown = _init(jax.random.PRNGKey(0), LOOPED)
     assert sorted(grown) == ["blocks", "embed", "exit_gate", "lm_head",
                              "ln_f"]
     assert sorted(grown["blocks"]) == ["attn", "ln1", "ln1_post", "ln2",
@@ -241,7 +255,7 @@ def test_default_fields_keep_todays_parameter_tree():
         transformer.logical_axes(LOOPED),
         is_leaf=lambda a: isinstance(a, tuple))
     # the gate's key is beside the others: the weights both have are equal
-    plain = transformer.init_params(jax.random.PRNGKey(0), dataclasses.replace(
+    plain = _init(jax.random.PRNGKey(0), dataclasses.replace(
         LOOPED, n_passes=1, post_norm=False, exit_beta=None))
     np.testing.assert_array_equal(plain["lm_head"], grown["lm_head"])
     np.testing.assert_array_equal(plain["blocks"]["mlp"]["wi"],
@@ -279,12 +293,12 @@ def test_looped_loss_metrics_and_every_gradient_leaf_match_the_reference(
     params, tokens = looped
     (loss, metrics), grads = jax.value_and_grad(
         transformer.loss_and_metrics, has_aux=True)(params, tokens, LOOPED)
-    want, want_grads = looped_reference.loss_and_grads(params, tokens,
-                                                       LOOPED_DIMS)
+    want, want_grads = jax.jit(lambda p, t: looped_reference.loss_and_grads(
+        p, t, LOOPED_DIMS))(params, tokens)
     assert float(loss) == pytest.approx(float(want), rel=1e-5)
     assert float(transformer.loss_fn(params, tokens, LOOPED)) == float(loss)
-    _, exit_p, entropy = looped_reference.loss_and_exits(params, tokens,
-                                                         LOOPED_DIMS)
+    _, exit_p, entropy = jax.jit(lambda p, t: looped_reference.loss_and_exits(
+        p, t, LOOPED_DIMS))(params, tokens)
     np.testing.assert_allclose(metrics["exit_p"], exit_p, rtol=1e-4)
     assert float(metrics["exit_entropy"]) == pytest.approx(float(entropy),
                                                            rel=1e-4)
@@ -318,8 +332,9 @@ def test_shared_blocks_gradient_is_the_sum_over_unshared_copies(looped):
 
     copies = jax.tree.map(lambda p: jnp.stack([p] * LOOPED.n_passes),
                           params["blocks"])
-    each = jax.grad(unshared)(copies)
-    shared = jax.grad(transformer.loss_fn)(params, tokens, LOOPED)["blocks"]
+    each = jax.jit(jax.grad(unshared))(copies)
+    shared = jax.jit(jax.grad(lambda p: transformer.loss_fn(
+        p, tokens, LOOPED)))(params)["blocks"]
     for got, parts in zip(jax.tree.leaves(shared), jax.tree.leaves(each)):
         assert float(jnp.max(jnp.abs(parts[0] - parts[1]))) > 0
         np.testing.assert_allclose(got, parts.sum(0), rtol=1e-3,
@@ -393,18 +408,23 @@ def _control(name, params, tokens):
     return unnormed
 
 
+@pytest.fixture(scope="module")
+def reference_loss_and_norm(looped):
+    from benchmark import looped_reference
+    return jax.jit(lambda p, t: looped_reference.loss_and_grad_norm(
+        p, t, LOOPED_DIMS))(*looped)
+
+
 @pytest.mark.parametrize("name", [
     "sound", "one_pass_fewer", "exit_dropped", "no_inter_pass_norm",
     "no_post_norms", "eight_bit_rounding"])
 def test_a_part_left_out_fails_by_the_looped_adapters_own_tolerances(
-        looped, name):
-    import optax
-    from benchmark import looped_reference
+        looped, reference_loss_and_norm, name):
     from benchmark.adapters import looped_decoder
     params, tokens = looped
-    value, grads = jax.value_and_grad(_control(name, params, tokens))(params)
-    want, want_norm = looped_reference.loss_and_grad_norm(params, tokens,
-                                                          LOOPED_DIMS)
+    value, grads = jax.jit(jax.value_and_grad(
+        _control(name, params, tokens)))(params)
+    want, want_norm = reference_loss_and_norm
     tol = looped_decoder.TOLERANCES
     inside = (abs(float(value) - float(want))
               <= tol["loss_rtol"] * abs(float(want))
@@ -476,10 +496,12 @@ def _vocab_matmuls(fn, *args, vocab=VOCAB_APART) -> int:
 @pytest.mark.parametrize("name", ["plain", "looped"])
 def test_loss_head_matches_autodiff_in_float32(looped, name):
     params, tokens, cfg = _head_case(looped, name)
-    (loss, metrics), grads = jax.value_and_grad(
-        transformer.loss_and_metrics, has_aux=True)(params, tokens, cfg)
-    (want, want_metrics), want_grads = jax.value_and_grad(
-        _autodiff_loss_and_metrics, has_aux=True)(params, tokens, cfg)
+    (loss, metrics), grads = jax.jit(jax.value_and_grad(
+        lambda p: transformer.loss_and_metrics(p, tokens, cfg),
+        has_aux=True))(params)
+    (want, want_metrics), want_grads = jax.jit(jax.value_and_grad(
+        lambda p: _autodiff_loss_and_metrics(p, tokens, cfg),
+        has_aux=True))(params)
     assert float(loss) == pytest.approx(float(want), rel=1e-5)
     assert sorted(metrics) == sorted(want_metrics)
     for key in metrics:
@@ -500,9 +522,10 @@ def test_loss_head_matches_autodiff_in_bfloat16(looped, name):
     pass through a bfloat16 array): each leaf to the reference tests'
     tolerances, taken over the leaf."""
     params, tokens, cfg = _head_case(looped, name, jnp.bfloat16)
-    loss, grads = jax.value_and_grad(transformer.loss_fn)(params, tokens, cfg)
-    want, want_grads = jax.value_and_grad(
-        lambda p: _autodiff_loss_and_metrics(p, tokens, cfg)[0])(params)
+    loss, grads = jax.jit(jax.value_and_grad(
+        lambda p: transformer.loss_fn(p, tokens, cfg)))(params)
+    want, want_grads = jax.jit(jax.value_and_grad(
+        lambda p: _autodiff_loss_and_metrics(p, tokens, cfg)[0]))(params)
     assert float(loss) == pytest.approx(float(want), rel=2e-3)
     paths = jax.tree_util.tree_flatten_with_path(grads)[0]
     for (path, got), ref in zip(paths, jax.tree.leaves(want_grads)):
@@ -516,9 +539,10 @@ def test_loss_head_matches_autodiff_in_bfloat16(looped, name):
 @pytest.mark.parametrize("name", ["plain", "looped"])
 def test_loss_head_scales_with_a_cotangent_that_is_not_one(looped, name):
     params, tokens, cfg = _head_case(looped, name)
-    grads = jax.grad(transformer.loss_fn)(params, tokens, cfg)
-    tripled = jax.grad(
-        lambda p: 3.0 * transformer.loss_fn(p, tokens, cfg))(params)
+    grads = jax.jit(jax.grad(
+        lambda p: transformer.loss_fn(p, tokens, cfg)))(params)
+    tripled = jax.jit(jax.grad(
+        lambda p: 3.0 * transformer.loss_fn(p, tokens, cfg)))(params)
     for got, ref in zip(jax.tree.leaves(tripled), jax.tree.leaves(grads)):
         np.testing.assert_allclose(got, 3.0 * ref, rtol=1e-5,
                                    atol=1e-5 * float(jnp.max(jnp.abs(ref))))
@@ -533,7 +557,7 @@ def test_loss_head_vocabulary_matmuls_are_three_an_exit(looped, name):
     more, and gives the same loss."""
     # a vocabulary of its own: LOOPED's 96 is also its d_ff
     cfg = dataclasses.replace(HEAD_CASES[name], vocab_size=VOCAB_APART)
-    params = transformer.init_params(jax.random.PRNGKey(34), cfg)
+    params = _init(jax.random.PRNGKey(34), cfg)
     tokens = looped[1]
     exits = cfg.n_passes if cfg.exit_beta is not None else 1
     assert _vocab_matmuls(
@@ -544,11 +568,12 @@ def test_loss_head_vocabulary_matmuls_are_three_an_exit(looped, name):
     assert _vocab_matmuls(
         jax.grad(lambda p: _autodiff_loss_and_metrics(p, tokens, cfg)[0]),
         params) == 3 * exits        # the counter, on the unrolled oracle
-    loss = transformer.loss_fn(params, tokens, cfg)
-    assert float(loss) == pytest.approx(float(jax.value_and_grad(
-        transformer.loss_fn)(params, tokens, cfg)[0]), rel=1e-6)
-    assert float(loss) == pytest.approx(
-        float(_autodiff_loss_and_metrics(params, tokens, cfg)[0]), rel=1e-5)
+    loss = jax.jit(lambda p: transformer.loss_fn(p, tokens, cfg))(params)
+    assert float(loss) == pytest.approx(float(jax.jit(jax.value_and_grad(
+        lambda p: transformer.loss_fn(p, tokens, cfg)))(params)[0]), rel=1e-6)
+    assert float(loss) == pytest.approx(float(jax.jit(
+        lambda p: _autodiff_loss_and_metrics(p, tokens, cfg)[0])(params)),
+        rel=1e-5)
 
 
 @pytest.mark.parametrize("name", ["plain", "looped"])
@@ -674,7 +699,7 @@ def test_looped_backward_adds_no_stacked_tree_and_carries_one_accumulator(
     _, tokens = looped
     # 4 passes round 3 layers: no shape of the passes' is a stacked leaf's
     cfg = dataclasses.replace(LOOPED, n_passes=4)
-    params = transformer.init_params(jax.random.PRNGKey(43), cfg)
+    params = _init(jax.random.PRNGKey(43), cfg)
     shapes = _stacked_shapes(cfg)
     assert sum(shapes.values()) == len(jax.tree.leaves(params["blocks"]))
 

@@ -396,7 +396,11 @@ def test_cluster_top_json_straggler_and_profile():
         head = DashboardHead(c.address)
         try:
             prof = head._profile()
-            daemon_hosts = [h for h in prof["hosts"] if h != "head"]
+            # this process is the head and, as the driver, a node as well:
+            # its sampler is federated under both labels
+            driver = ray_tpu._private.worker.global_worker().runtime
+            mine = ("head", f"node:{driver.local_node.node_id.hex()[:8]}")
+            daemon_hosts = [h for h in prof["hosts"] if h not in mine]
             assert len(daemon_hosts) == 3  # every daemon's sampler federated
             assert prof["merged"]["ticks"] > 0
             assert prof["collapsed"]

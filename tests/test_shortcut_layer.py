@@ -5,6 +5,7 @@ experts it holds: at tiny sizes on the CPU, the kernel in interpreter mode,
 against ``benchmark/longcat_reference.py`` and plain einsums."""
 
 import dataclasses
+import functools
 import math
 import sys
 
@@ -46,12 +47,19 @@ def held(cfg, first, count):
         cfg.experts, held=(first, count)))
 
 
-def _seeded(cfg):
+# one compiled program a configuration: drawn eagerly, a tree costs some
+# hundred one-primitive compiles
+_init = jax.jit(transformer.init_params, static_argnums=1)
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def _seeded(cfg, seed=41):
     """Seeded weights, every norm weight moved off its initial 1 so that a
-    norm left out, or its weight, shows."""
-    params = transformer.init_params(jax.random.PRNGKey(41), cfg)
+    norm left out, or its weight, shows (``test_lfm2_layer.py`` and
+    ``test_kanana2_layer.py`` draw theirs here too)."""
+    params = transformer.init_params(jax.random.PRNGKey(seed), cfg)
     leaves, tree = jax.tree_util.tree_flatten_with_path(params)
-    keys = jax.random.split(jax.random.PRNGKey(42), len(leaves))
+    keys = jax.random.split(jax.random.PRNGKey(seed + 1), len(leaves))
     moved = [p * (1 + 0.3 * jax.random.normal(k, p.shape))
              if "norm" in jax.tree_util.keystr(path)
              or "ln" in jax.tree_util.keystr(path) else p
@@ -74,6 +82,11 @@ def _of_depth(n):
 def _one_layer(blocks, l):
     """Layer ``l`` cut out of a stacked tree, as a stack of one."""
     return jax.tree.map(lambda p: p[l:l + 1], blocks)
+
+
+def _reference_logits(params, tokens, dims):
+    return jax.jit(lambda p, t: longcat_reference.tree_last_logits(
+        p, t, dims))(params, tokens)
 
 
 def _last_logits(params, tokens, last, cfg):
@@ -128,8 +141,8 @@ def test_flash_backward_at_unequal_widths_is_plain_attentions(length,
         return jnp.sum(g * flash_attention(q, k, v, block_q=128,
                                            block_k=128))
 
-    got = jax.grad(kernel, argnums=(0, 1, 2))(q, k, v)
-    want = jax.grad(plain, argnums=(0, 1, 2))(q, k, v)
+    got = jax.jit(jax.grad(kernel, argnums=(0, 1, 2)))(q, k, v)
+    want = jax.jit(jax.grad(plain, argnums=(0, 1, 2)))(q, k, v)
     assert [a.shape[-1] for a in got] == [24, 24, 16]
     for a, b in zip(got, want):
         np.testing.assert_allclose(a, b, atol=5e-5)
@@ -535,10 +548,10 @@ def test_prefill_at_two_lengths_with_right_padding_agrees_with_the_reference(
                for k, n in zip(jax.random.split(key, 2), (9, 24))]
     tokens = jnp.stack([jnp.pad(p, (0, 32 - len(p))) for p in prompts])
     last = jnp.array([len(p) - 1 for p in prompts])
-    got = _last_logits(params, tokens, last, cfg)
+    got = jax.jit(lambda p, t, l: _last_logits(p, t, l, cfg))(params, tokens,
+                                                              last)
     for row, prompt in zip(got, prompts):
-        want = longcat_reference.tree_last_logits(params, prompt[None],
-                                                  TINY_DIMS)[0]
+        want = _reference_logits(params, prompt[None], TINY_DIMS)[0]
         np.testing.assert_allclose(row, want, atol=1e-4)
 
 
@@ -549,8 +562,9 @@ def test_the_stacked_forward_agrees_with_the_reference(n_layers):
     cfg, dims = _of_depth(n_layers)
     params = _seeded(cfg)
     tokens = jax.random.randint(jax.random.PRNGKey(14), (2, 20), 0, 96)
-    got = _last_logits(params, tokens, jnp.array([19, 19]), cfg)
-    want = longcat_reference.tree_last_logits(params, tokens, dims)
+    got = jax.jit(lambda p, t, l: _last_logits(p, t, l, cfg))(
+        params, tokens, jnp.array([19, 19]))
+    want = _reference_logits(params, tokens, dims)
     np.testing.assert_allclose(got, want, atol=1e-4)
 
 
@@ -566,17 +580,17 @@ def test_the_stacked_forward_is_the_block_on_each_layer_cut_out(n_layers):
     loads = []
     got = jax.jit(lambda b, x: transformer._apply_shortcut(
         b, x, positions, cfg))(blocks, x)
+    block = jax.jit(lambda stack, x: transformer._shortcut_block(
+        stack, 0, x, positions, cfg))
     want = x
     for l in range(n_layers):
-        want, load = transformer._shortcut_block(
-            _one_layer(blocks, l), 0, want, positions, cfg)
+        want, load = block(_one_layer(blocks, l), want)
         loads.append(load)
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
     # the wrong layer's weights would show: the layers differ
     if n_layers > 1:
-        swapped, _ = transformer._shortcut_block(
-            _one_layer(blocks, 1), 0, x, positions, cfg)
-        first, _ = transformer._shortcut_block(blocks, 0, x, positions, cfg)
+        swapped, _ = block(_one_layer(blocks, 1), x)
+        first, _ = block(blocks, x)
         assert float(jnp.abs(swapped - first).max()) > 0.1
     assert all(int(load[0]) > 0 for load in loads)
 
@@ -587,22 +601,23 @@ def test_the_shares_add_up_to_the_uncut_layer(params):
     the layer is linear in the mixture's sum."""
     layer = jax.tree.map(lambda p: p[0], params["blocks"][SHORTCUT])
     u = jax.random.normal(jax.random.PRNGKey(11), (40, 64))
-    _, w = expert.route(u, layer["router"], EXPERTS)
-    idx, _ = expert.route(u, layer["router"], EXPERTS)
+    idx, w = jax.jit(lambda u, r: expert.route(u, r, EXPERTS))(
+        u, layer["router"])
     zero = jnp.sum(jnp.where(idx >= 16, w, 0), -1, keepdims=True) * u
     total = zero
     for share in range(4):
         cfg = dataclasses.replace(EXPERTS, held=(4 * share, 4))
         mine = jax.tree.map(lambda p: p[4 * share:4 * share + 4],
                             layer["experts"])
-        part, _ = held_experts_apply(u, layer["router"], _alone(mine), cfg,
-                                     0)
+        part, _ = jax.jit(lambda u, r, mine: held_experts_apply(
+            u, r, _alone(mine), cfg, 0))(u, layer["router"], mine)
         total = total + (part - zero)
     with jax.default_matmul_precision("highest"):
-        want = longcat_reference.experts_part(
-            u, longcat_reference.from_tree(layer), TINY_DIMS)
+        want = jax.jit(lambda u, l: longcat_reference.experts_part(
+            u, longcat_reference.from_tree(l), TINY_DIMS))(u, layer)
     np.testing.assert_allclose(total, want, atol=2e-5)
 
+    @functools.partial(jax.jit, static_argnums=0)
     def layer_out(cfg, tree, x):
         positions = jnp.arange(x.shape[1])[None]
         stack = jax.tree.map(lambda p: p[None], tree)   # a stack of one
@@ -619,17 +634,16 @@ def test_the_shares_add_up_to_the_uncut_layer(params):
     np.testing.assert_allclose(none + parts, uncut, atol=5e-5)
     with jax.default_matmul_precision("highest"):
         np.testing.assert_allclose(
-            uncut[0], longcat_reference.block(
-                longcat_reference.from_tree(layer), x[0], TINY_DIMS),
+            uncut[0], jax.jit(lambda l, x: longcat_reference.block(
+                longcat_reference.from_tree(l), x, TINY_DIMS))(layer, x[0]),
             atol=5e-5)
 
 
 def test_shares_of_different_devices_draw_consistent_experts():
     """An expert's weights come from a key folded with its published index:
     the share (4, 4) holds what experts 4 to 7 of the whole are."""
-    whole = transformer.init_params(jax.random.PRNGKey(13), TINY)
-    share = transformer.init_params(jax.random.PRNGKey(13),
-                                    held(TINY, 4, 4))
+    whole = _init(jax.random.PRNGKey(13), TINY)
+    share = _init(jax.random.PRNGKey(13), held(TINY, 4, 4))
     for name in ("wi", "wg", "wo"):
         np.testing.assert_array_equal(
             share["blocks"][SHORTCUT]["experts"][name],

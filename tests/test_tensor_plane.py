@@ -169,6 +169,7 @@ def _make_dp_loop():
         import jax
         import jax.numpy as jnp
         import numpy as np
+        import os
         import time
         from jax.sharding import NamedSharding, PartitionSpec as P
         from ray_tpu.air.checkpoint import Checkpoint
@@ -199,6 +200,10 @@ def _make_dp_loop():
 
         for s in range(start, config["steps"]):
             w_dev, loss = step(w_dev, X, y)
+            if s == config.get("kill_step") and rank == 0 and ckpt is None:
+                # the test kills this daemon now, at this step
+                with open(config["kill_file"], "w") as f:
+                    f.write(str(os.getpid()))
             if config.get("step_sleep"):
                 time.sleep(config["step_sleep"])
             ck = None
@@ -247,36 +252,44 @@ def tp_cluster4():
     c.shutdown()
 
 
-def test_trainer_resumes_across_daemon_kill(tp_cluster4):
+def test_trainer_resumes_across_daemon_kill(tp_cluster4, tmp_path):
     """SIGKILL one worker's daemon mid-training: the JAX coordination
     service fails the whole plane (its peers abort — device-owner
     processes are expendable), and the trainer restarts the group on the
     spare daemons FROM THE CHECKPOINT (reference contract:
-    backend_executor.py:461-531 elastic restart)."""
+    backend_executor.py:461-531 elastic restart). The kill is tied to a
+    step, not to a clock: rank 0 names its daemon when it has taken step 2
+    of the first attempt, and the test kills that daemon then."""
     import threading
     from ray_tpu.air.config import FailureConfig, RunConfig, ScalingConfig
     from ray_tpu.train import JaxTrainer
 
     killed = threading.Event()
+    kill_file = tmp_path / "kill"
 
     trainer = JaxTrainer(
         _make_dp_loop(),
-        train_loop_config={"steps": 8, "step_sleep": 0.4},
+        train_loop_config={"steps": 8, "step_sleep": 0.4, "kill_step": 2,
+                           "kill_file": str(kill_file)},
         scaling_config=ScalingConfig(
             num_workers=2, resources_per_worker={"CPU": 2},
             placement_strategy="STRICT_SPREAD"),
         run_config=RunConfig(failure_config=FailureConfig(max_failures=2)),
         collective_backend="xla")
 
-    def kill_after_delay():
-        time.sleep(6)  # group up + a few steps in
+    def kill_at_the_step():
+        deadline = time.monotonic() + 120
+        while not kill_file.exists() or not kill_file.read_text():
+            if time.monotonic() > deadline:
+                return
+            time.sleep(0.05)
+        pid = int(kill_file.read_text())
         for i, d in enumerate(tp_cluster4.daemons):
-            if d["proc"].poll() is None:
+            if d["proc"].pid == pid:
                 tp_cluster4.kill_daemon(i)
                 killed.set()
-                return
 
-    t = threading.Thread(target=kill_after_delay, daemon=True)
+    t = threading.Thread(target=kill_at_the_step, daemon=True)
     t.start()
     res = trainer.fit()
     assert killed.is_set(), "chaos never fired"
