@@ -109,7 +109,7 @@ class DeploymentState:
         opts.setdefault("max_concurrency",
                         max(2, self.config.max_concurrent_queries))
         batch_cfg = None
-        if getattr(self.config, "batched", False):
+        if self.config.batched:
             batch_cfg = {
                 "max_batch_size": self.config.max_batch_size,
                 "batch_wait_timeout_s": self.config.batch_wait_timeout_s,

@@ -9,7 +9,17 @@ user_config pushed to live replicas.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, Optional, Sequence, Tuple
+
+
+def batched(max_batch_size: int, pad_batch_to: Optional[Sequence[int]]
+            ) -> bool:
+    """Whether a replica runs the request batcher and hands its callable a
+    LIST: ``max_batch_size`` above 1, or ``pad_batch_to`` given. A
+    deployment that names the batch sizes it compiled for is a batched
+    one at a cap of 1 too (one request a call, as a list of one): the
+    cap a live retune may already leave a batcher at."""
+    return int(max_batch_size) > 1 or bool(pad_batch_to)
 
 
 @dataclasses.dataclass
@@ -90,12 +100,8 @@ class DeploymentConfig:
 
     @property
     def batched(self) -> bool:
-        """Whether a replica runs the micro-batcher and hands its callable
-        a LIST: ``max_batch_size`` above 1, or ``pad_batch_to`` given. A
-        deployment that names the batch sizes it compiled for is a batched
-        one at a cap of 1 too (one request a call, as a list of one): the
-        cap a live retune may already leave a batcher at."""
-        return self.max_batch_size > 1 or bool(self.pad_batch_to)
+        """:func:`batched` of this deployment's shape."""
+        return batched(self.max_batch_size, self.pad_batch_to)
 
     def effective_target_latency_ms(self) -> float:
         if self.target_latency_ms > 0:

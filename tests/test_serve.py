@@ -201,6 +201,31 @@ def test_batching_error_propagates(ray_start_regular):
         bad(1)
 
 
+def test_one_class_owns_a_request_queue_and_its_flusher():
+    """``@serve.batch`` and a batched replica run one state machine: the
+    two modules define one class with a ``_flush_loop``, and a decorated
+    function's queue is one of it."""
+    import inspect
+
+    from ray_tpu.serve import batching
+    from ray_tpu.serve._private import replica
+
+    flushers = {cls for module in (batching, replica)
+                for cls in vars(module).values()
+                if inspect.isclass(cls) and cls.__module__ == module.__name__
+                and hasattr(cls, "_flush_loop")}
+    assert len(flushers) == 1, flushers
+
+    @serve.batch(max_batch_size=2, batch_wait_timeout_s=0.0)
+    def double(items):
+        return [2 * x for x in items]
+
+    assert double(21) == 42
+    queue = getattr(double, "__batch_queue_double")
+    assert type(queue) in flushers
+    queue.shutdown()
+
+
 def test_http_proxy(serve_instance):
     serve.run(Echo.options(name="http_echo").bind(), route_prefix="/api")
     url = serve.start_http_proxy()

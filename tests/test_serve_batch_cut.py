@@ -1,29 +1,31 @@
-"""The replica batcher's cut: which queued requests share a call, and when
+"""The request batcher's cut: which queued requests share a call, and when
 the batcher stops waiting for more.
 
 Which: the rule is a pure function (``serve/batching.py`` ``cut_by_size``)
-and is tested as one, with no clock; then through a real ``Replica`` whose
-first call is held open while the queue is filled in a known order, so that
-what each later cut takes, leaves and reports is counted and not timed.
+and is tested as one, with no clock; then through the batcher's two entry
+points, a real ``Replica`` and a ``@serve.batch`` function, whose first call
+is held open while the queue is filled in a known order, so that what each
+later cut takes, leaves and reports is counted and not timed.
 
 When: the four reasons to cut (``cut`` on ``serve.batch.linger``: ``full``,
 ``waited``, ``passed``, ``not_due``).  The last compares two estimates the
-replica keeps of its own traffic; the tests put them into the batcher's own
+batcher keeps of its own traffic; the tests put them into the batcher's own
 fields, so that which reason fires does not hang on how fast the test's
 threads run.
 """
 
 import contextlib
 import threading
+from unittest import mock
 
 import pytest
 
-from ray_tpu import observability
+from ray_tpu import observability, serve
 from ray_tpu._private.config import _config
 from ray_tpu._private.profiling import get_profiler
 from ray_tpu.observability import metric_names
 from ray_tpu.serve._private.replica import Replica
-from ray_tpu.serve.batching import cut_by_size, item_size
+from ray_tpu.serve.batching import _Batcher, cut_by_size, item_size
 
 B248 = (2, 4, 8)
 MIXED = [100, 2000, 120, 1900, 90, 2040, 300, 310]
@@ -101,15 +103,75 @@ def test_cut_by_size(case):
         assert taken == list(range(min(cap, len(sizes))))
 
 
-# -- through a real replica --------------------------------------------------
+# -- through the two entry points --------------------------------------------
 
 WAIT_S = 10.0       # a join or a poll that takes this long has failed
 
 
-def held_deployment():
-    """A batched function deployment whose first call stays open until
-    released: every reply is ``(len(item), len(items))``.  A function, so
+def estimates(batcher, gap_s, call_ms):
+    """Put the two estimates the fourth reason compares into the fields
+    the batcher keeps them in (``None`` / 0.0: none yet, as in a new
+    batcher).  The first admission after this has no admission before it,
+    so it moves neither."""
+    with batcher._lock:
+        batcher._gap_ewma_s = gap_s
+    with batcher._estimate._lock:
+        batcher._estimate._ms = call_ms
+
+
+class ThroughReplica:
+    """``fn`` as a batched function deployment's replica.  A function, so
     that the replica sets no init gauge for other files' tests to find."""
+
+    def __init__(self, fn, cap, bound_s, buckets, gap_s, call_ms):
+        self.replica = Replica("held", "held#1", fn, (), {}, batch_config={
+            "max_batch_size": cap, "batch_wait_timeout_s": bound_s,
+            "pad_batch_to": buckets, "target_latency_ms": 1e9})
+        self.batcher = self.replica._batcher
+        estimates(self.batcher, gap_s, call_ms)
+
+    def submit(self, item):
+        return self.replica.handle_request("__call__", (item,), {})
+
+    def set_bound(self, bound_s):
+        self.replica.set_batch_config({"batch_wait_timeout_s": bound_s})
+
+    def counts(self):
+        return self.replica.get_metrics()
+
+    def close(self):
+        self.replica.prepare_for_shutdown(timeout_s=WAIT_S)
+
+
+class ThroughDecorator:
+    """``fn`` under ``@serve.batch``."""
+
+    def __init__(self, fn, cap, bound_s, buckets, gap_s, call_ms):
+        self.submit = serve.batch(
+            max_batch_size=cap, batch_wait_timeout_s=bound_s,
+            pad_batch_to=buckets)(fn)
+        # the decorator builds the function's batcher at its first call:
+        # one call that admits nothing and hands the batcher back
+        with mock.patch.object(_Batcher, "submit", lambda batcher, _: batcher):
+            self.batcher = self.submit(None)
+        estimates(self.batcher, gap_s, call_ms)
+
+    def set_bound(self, bound_s):
+        self.batcher.retune({"batch_wait_timeout_s": bound_s})
+
+    def counts(self):
+        return self.batcher.counts()
+
+    def close(self):
+        self.batcher.shutdown()
+
+
+ENTRIES = {"replica": ThroughReplica, "decorator": ThroughDecorator}
+
+
+def held_deployment():
+    """A batched function whose first call stays open until released:
+    every reply is ``(len(item), len(items))``."""
     calls, started, release = [], threading.Event(), threading.Event()
 
     def held(items):
@@ -143,83 +205,71 @@ def spans_on():
         get_profiler().clear()
 
 
-def _seen(replica):
-    """The replica's metrics and its batcher's spans so far."""
-    return {"metrics": replica.get_metrics(),
+def _seen(entry):
+    """The batcher's counts and its spans so far."""
+    return {"metrics": entry.counts(),
             "linger": _spans("serve.batch.linger"),
             "execute": _spans("serve.batch.execute")}
 
 
-def estimates(replica, gap_s, call_ms):
-    """Put the two estimates the fourth reason compares into the fields
-    the batcher keeps them in (``None`` / 0.0: none yet, as in a new
-    replica).  The first admission after this has no admission before it,
-    so it moves neither."""
-    with replica._batcher._lock:
-        replica._batcher._gap_ewma_s = gap_s
-    with replica._lock:
-        replica._ewma_item_ms = call_ms
-
-
 @spans_on()
-def run_held(requests, cap, buckets, gap_s=None, call_ms=0.0, bound_s=600.0,
-             aged_s=0.0, release_bound_s=None):
+def run_held(through, requests, cap, buckets, gap_s=None, call_ms=0.0,
+             bound_s=600.0, aged_s=0.0, release_bound_s=None):
     """Fill the queue with ``requests`` in order behind a held first call,
     with a linger of ``bound_s`` (by default one no request could sit out
     twice), then let the flusher cut: the replies in the order of
-    ``requests``, the deployment, the replica's metrics and the batcher's
-    spans (the ring is on).  ``gap_s`` and ``call_ms`` are the replica's
-    estimates before its first request; ``aged_s`` puts the queued
-    requests' admission that far back, as a call the host held would;
-    ``release_bound_s`` is a linger retuned just before the release."""
+    ``requests``, the calls, the batcher's counts and its spans (the ring
+    is on).  ``through`` is one of ``ENTRIES``; ``gap_s`` and ``call_ms`` are
+    the batcher's estimates before its first request; ``aged_s`` puts the
+    queued requests' admission that far back, as a call the host held
+    would; ``release_bound_s`` is a linger retuned just before the
+    release."""
     held = held_deployment()
-    replica = Replica("held", "held#1", held, (), {}, batch_config={
-        "max_batch_size": cap, "batch_wait_timeout_s": 0.0,
-        "pad_batch_to": buckets, "target_latency_ms": 1e9})
-    estimates(replica, gap_s, call_ms)
+    entry = ENTRIES[through](held, cap, 0.0, buckets, gap_s, call_ms)
+    batcher = entry.batcher
     replies = {}
 
     def call(key, item):
-        replies[key] = replica.handle_request("__call__", (item,), {})
+        replies[key] = entry.submit(item)
 
     threads = [threading.Thread(target=call, args=("held", "g"))]
     try:
         threads[0].start()
         assert held.started.wait(WAIT_S)
         # from here on a fresh request would linger for minutes
-        replica.set_batch_config({"batch_wait_timeout_s": bound_s})
+        entry.set_bound(bound_s)
         for k, item in enumerate(requests):
             threads.append(threading.Thread(target=call, args=(k, item)))
             threads[-1].start()
             poll = threading.Event()
             for _ in range(int(WAIT_S / 0.002)):
-                if replica._batcher.depth() == k + 1:
+                if batcher.depth() == k + 1:
                     break
                 poll.wait(0.002)
-            assert replica._batcher.depth() == k + 1
+            assert batcher.depth() == k + 1
         if aged_s:
-            with replica._batcher._lock:
-                for slot in replica._batcher._queue:
+            with batcher._lock:
+                for slot in batcher._queue:
                     slot.t_enqueue -= aged_s
         if release_bound_s is not None:
-            replica.set_batch_config(
-                {"batch_wait_timeout_s": release_bound_s})
+            entry.set_bound(release_bound_s)
         held.release.set()
         for t in threads:
             t.join(WAIT_S)
         assert not any(t.is_alive() for t in threads)
-        seen = _seen(replica)
+        seen = _seen(entry)
     finally:
         held.release.set()
-        replica.prepare_for_shutdown(timeout_s=WAIT_S)
+        entry.close()
     return {"replies": [replies[k] for k in range(len(requests))],
             "calls": held.calls, **seen}
 
 
-@pytest.fixture(scope="module")
-def reordered():
+@pytest.fixture(scope="module", params=ENTRIES)
+def reordered(request):
     """100, 2,000, 120 and 1,900 tokens queued in that order, cap 4."""
-    return run_held([[0] * 100, [0] * 2000, [0] * 120, [0] * 1900],
+    return run_held(request.param,
+                    [[0] * 100, [0] * 2000, [0] * 120, [0] * 1900],
                     cap=4, buckets=(2, 4))
 
 
@@ -251,8 +301,9 @@ def test_the_fill_is_on_the_spans_and_in_get_metrics(reordered):
         s["padded_n"] * s["size_max"] for s in execute) == 4242
 
 
-def test_scalars_and_dicts_are_cut_in_arrival_order():
-    got = run_held([1, {"x": 2}, 3, {"y": [0] * 500}, 5, 6], cap=4,
+@pytest.mark.parametrize("entry", ENTRIES)
+def test_scalars_and_dicts_are_cut_in_arrival_order(entry):
+    got = run_held(entry, [1, {"x": 2}, 3, {"y": [0] * 500}, 5, 6], cap=4,
                    buckets=(2, 4))
     assert got["calls"] == [[1, 1], [1, 1, 1, 1], [1, 1]]
     assert got["replies"] == [(1, 4)] * 4 + [(1, 2)] * 2
@@ -267,10 +318,10 @@ SHORT = [0] * 100
 
 
 @spans_on()
-def run_alone(requests, bound_s, cap=4, buckets=(2, 4), gap_s=None,
+def run_alone(through, requests, bound_s, cap=4, buckets=(2, 4), gap_s=None,
               call_ms=0.0):
     """``requests`` one after the other, each answered before the next is
-    sent, through a replica with the given estimates and a linger of
+    sent, through a batcher with the given estimates and a linger of
     ``bound_s``: what ``run_held`` returns."""
     calls = []
 
@@ -278,16 +329,12 @@ def run_alone(requests, bound_s, cap=4, buckets=(2, 4), gap_s=None,
         calls.append([item_size(x) for x in items])
         return [(item_size(x), len(items)) for x in items]
 
-    replica = Replica("alone", "alone#1", echo, (), {}, batch_config={
-        "max_batch_size": cap, "batch_wait_timeout_s": bound_s,
-        "pad_batch_to": buckets, "target_latency_ms": 1e9})
-    estimates(replica, gap_s, call_ms)
+    entry = ENTRIES[through](echo, cap, bound_s, buckets, gap_s, call_ms)
     try:
-        replies = [replica.handle_request("__call__", (item,), {})
-                   for item in requests]
-        seen = _seen(replica)
+        replies = [entry.submit(item) for item in requests]
+        seen = _seen(entry)
     finally:
-        replica.prepare_for_shutdown(timeout_s=WAIT_S)
+        entry.close()
     return {"replies": replies, "calls": calls, **seen}
 
 
@@ -309,7 +356,7 @@ CUTS = {
         ((run_held, [SHORT] * 2, dict(cap=4, buckets=(2, 4),
                                       release_bound_s=0.0, **CLUMP)),
          ["waited", "waited"], [1, 2], [[1, 1], [100] * 2], None),
-    "a replica with no estimate yet holds for the configured bound":
+    "a batcher with no estimate yet holds for the configured bound":
         ((run_alone, [SHORT], dict(bound_s=0.15)),
          ["waited"], [1], [[100] * 2], 0.15),
     "with a gap estimate and no call yet the bound holds":
@@ -338,17 +385,19 @@ CUTS = {
 }
 
 
+@pytest.mark.parametrize("entry", ENTRIES)
 @pytest.mark.parametrize("case", CUTS)
-def test_why_the_batcher_cut(case):
+def test_why_the_batcher_cut(case, entry):
     (run, requests, more), reasons, depths, calls, waited_s = CUTS[case]
-    got = run(requests, **more)
+    got = run(entry, requests, **more)
     linger = got["linger"]
     assert [s["cut"] for s in linger] == reasons
     assert [s["depth"] for s in linger] == depths
     assert got["calls"] == calls
     # every request was answered, and nothing expired in the queue
     assert [r[0] for r in got["replies"]] == [len(x) for x in requests]
-    # the two counts in get_metrics() are the spans' reasons, counted
+    # the batcher's two counts (a replica's get_metrics() carries them) are
+    # the spans' reasons, counted
     assert got["metrics"][metric_names.REPLICA_BATCH_CUTS] == len(linger)
     assert got["metrics"][metric_names.REPLICA_BATCH_CUTS_NOT_DUE] == \
         reasons.count("not_due")
@@ -362,3 +411,48 @@ def test_why_the_batcher_cut(case):
         # for a machine busy with other tests
         assert waited_s * 1e6 <= linger[-1]["oldest_wait_us"] \
             < (waited_s + WAIT_S) * 1e6
+
+
+# -- what a @serve.batch user sees of the one machine -------------------------
+
+
+def test_a_decorated_function_cuts_a_burst_by_size():
+    # a long and three short sequences, four to a call, admitted in any
+    # order: the cut is by size, so the short ones share a call and the
+    # long one pays for no row but its own
+    calls = []
+
+    @serve.batch(max_batch_size=4, batch_wait_timeout_s=WAIT_S)
+    def sizes(items):
+        calls.append(sorted(len(x) for x in items))
+        return [len(x) for x in items]
+
+    burst = [[0] * 2000, SHORT, SHORT, SHORT]
+    replies = [None] * len(burst)
+    threads = [threading.Thread(
+        target=lambda k=k: replies.__setitem__(k, sizes(burst[k])))
+        for k in range(len(burst))]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(WAIT_S)
+    assert replies == [2000, 100, 100, 100]
+    assert sorted(calls) == [[100, 100, 100], [2000]]
+
+
+@spans_on()
+def test_a_decorated_functions_lone_request_is_let_go_when_none_is_due():
+    @serve.batch(max_batch_size=4, batch_wait_timeout_s=0.25)
+    def echo(items):
+        threading.Event().wait(0.001)     # a call that takes some time
+        return items
+
+    # the first has no estimate to go by and is held for the bound; the
+    # second comes a bound and more after it, a hundred calls' time, so
+    # no neighbour is due and it is not held ("waited" is tried first: a
+    # request cut not_due was let go inside the bound)
+    assert [echo(1), echo(2)] == [1, 2]
+    linger = _spans("serve.batch.linger")
+    assert [s["cut"] for s in linger] == ["waited", "not_due"]
+    assert linger[0]["oldest_wait_us"] >= 250_000
+    assert linger[1]["gap_est_us"] >= 250_000 > linger[1]["call_est_us"] > 0
