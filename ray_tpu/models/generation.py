@@ -1,0 +1,122 @@
+"""The transformer as a *slot model* of the generation engine
+(``ray_tpu/serve/generation.py``): the weights and ``S`` sequences' state on
+one device behind ``admit``, ``step`` and ``read``, over this package's
+``transformer.prefill``, ``insert_state`` and ``decode_step``."""
+
+from __future__ import annotations
+
+from typing import List, Sequence
+
+import numpy as np
+
+
+class TransformerGenerator:
+    """The slot model over ``models.transformer``: the weights, a
+    ``DecodeState`` of ``slots`` sequences with room for ``cache_len``
+    positions and the slots' next input tokens, all on ``device``, and three
+    jitted programs a reader of a trace finds by name: ``jit_prefill`` (one
+    right-padded prompt ``[1, bucket]`` -> its first token, that token's logit
+    and what the sequence keeps), ``jit_insert`` (that into a slot, the state
+    donated) and ``jit_decode_step`` (one token for every slot, the state
+    donated, each slot's largest logit its next input). A
+    deployment's class subclasses it or holds one; ``warm_up()`` runs every
+    shape once."""
+
+    def __init__(self, cfg, params, *, slots: int, cache_len: int,
+                 length_buckets: Sequence[int], device=None):
+        import jax
+        import jax.numpy as jnp
+
+        from ray_tpu.models import transformer
+
+        self.cfg, self.params = cfg, params
+        self.slots, self.cache_len = int(slots), int(cache_len)
+        self.length_buckets = tuple(sorted(int(b) for b in length_buckets))
+        self.device = device if device is not None else jax.devices()[0]
+        if self.length_buckets[-1] > self.cache_len:
+            raise ValueError(
+                f"the longest prompt bucket ({self.length_buckets[-1]}) does "
+                f"not fit a slot's {self.cache_len} positions")
+        with jax.default_device(self.device):
+            state = jax.jit(lambda: transformer.init_decode_state(
+                cfg, self.slots, self.cache_len))()
+        # committed to the device, as every later state and token array is (a
+        # program's results are): one compilation a shape, not a second one
+        # for the first call's uncommitted arguments
+        self.state = jax.device_put(state, self.device)
+        self.tokens = self._put(np.zeros((self.slots,), np.int32))
+
+        def best(logits):
+            return (jnp.argmax(logits, axis=-1).astype(jnp.int32),
+                    jnp.max(logits, axis=-1))
+
+        def prefill(params, prompt, length):
+            last, piece = transformer.prefill(params, prompt, length, cfg)
+            token, logit = best(transformer.head(params, last[:, None],
+                                                 cfg)[:, 0])
+            return token, logit, piece
+
+        def insert(state, tokens, piece, token, slot):
+            return (transformer.insert_state(state, piece, slot),
+                    jax.lax.dynamic_update_slice(tokens, token, (slot,)))
+
+        def decode_step(params, tokens, state, active):
+            logits, state = transformer.decode_step(params, tokens, state,
+                                                    cfg, active)
+            token, logit = best(logits)
+            return token, logit, state
+
+        # the state alone is donated: a step's tokens are the next step's
+        # input and also what the host reads a step later
+        self._prefill = jax.jit(prefill)
+        self._insert = jax.jit(insert, donate_argnums=(0,))
+        self._decode_step = jax.jit(decode_step, donate_argnums=(2,))
+
+    def bucket(self, length: int) -> int:
+        for b in self.length_buckets:
+            if b >= length:
+                return b
+        raise ValueError(f"a prompt of {length} tokens is longer than the "
+                         f"last bucket ({self.length_buckets[-1]})")
+
+    def check(self, prompt: List[int], n: int) -> None:
+        self.bucket(len(prompt))
+        if len(prompt) + n > self.cache_len:
+            raise ValueError(
+                f"a prompt of {len(prompt)} tokens and {n} new ones do not "
+                f"fit a slot's {self.cache_len} positions")
+
+    def _put(self, array):
+        import jax
+        return jax.device_put(array, self.device)
+
+    def admit(self, prompt: List[int], slot: int):
+        bucket = self.bucket(len(prompt))
+        row = np.zeros((1, bucket), np.int32)
+        row[0, :len(prompt)] = prompt
+        length = self._put(np.array([len(prompt)], np.int32))
+        token, logit, piece = self._prefill(self.params, self._put(row),
+                                            length)
+        self.state, self.tokens = self._insert(
+            self.state, self.tokens, piece, token,
+            self._put(np.int32(slot)))
+        return (token, logit), bucket
+
+    def step(self, active: np.ndarray):
+        token, logit, self.state = self._decode_step(
+            self.params, self.tokens, self.state, self._put(active))
+        self.tokens = token
+        return token, logit
+
+    def read(self, handle):
+        import jax
+        return jax.device_get(handle)
+
+    def warm_up(self) -> None:
+        """Every shape the engine can ask for, once: a prompt of each bucket
+        into slot 0, then a step. The slots are left as they are found
+        (empty ones hold what the warm-up wrote: an insert overwrites it)."""
+        import jax
+        for bucket in self.length_buckets:
+            self.admit([0] * bucket, 0)
+        jax.block_until_ready(self.step(np.zeros((self.slots,), bool)))

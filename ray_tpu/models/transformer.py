@@ -92,7 +92,7 @@ import functools
 import itertools
 import math
 from dataclasses import dataclass, field
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -100,6 +100,7 @@ from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec
 
 from ray_tpu.ops import flash_attention, linear_attention, sparse_attention
+from ray_tpu.ops import ssd
 from ray_tpu.ops.flash_attention import KEPT as FLASH_KEPT
 from ray_tpu.ops.linear_attention import decay_rates
 from ray_tpu.ops.sparse_attention import SparseConfig
@@ -111,16 +112,19 @@ from ray_tpu.parallel.sharding import ShardingRules
 
 # a layer's kind
 (DENSE, SPARSE, LINEAR, SHORTCUT, CONV, CONV_MOE, ATTN_MOE, LATENT,
- LATENT_MOE) = KINDS = (
+ LATENT_MOE, MAMBA, ATTN) = KINDS = (
     "dense", "sparse", "linear", "shortcut", "conv", "conv_moe", "attn_moe",
-    "latent", "latent_moe")
+    "latent", "latent_moe", "mamba", "attn")
 # the kinds made of a token mixer and an FFN chosen apart: (mixer, FFN), each
 # the name of the layer's sub-tree; the FFN's name, ``mlp`` | ``moe``, is its
-# device scope too, and the mixer's scope is ``shortconv`` or, for either
-# attention (grouped-query ``attn``, latent ``latent``), ``attn``
+# device scope too, and the mixer's scope is ``shortconv``, ``mamba`` or, for
+# either attention (grouped-query ``attn``, latent ``latent``), ``attn``
 PARTS = {CONV: ("shortconv", "mlp"), CONV_MOE: ("shortconv", "moe"),
          ATTN_MOE: ("attn", "moe"), LATENT: ("latent", "mlp"),
-         LATENT_MOE: ("latent", "moe")}
+         LATENT_MOE: ("latent", "moe"), MAMBA: ("mamba", "mlp"),
+         ATTN: ("attn", "mlp")}
+# the kinds that keep a state across calls (``prefill``, ``decode_step``)
+DECODABLE = (MAMBA, ATTN)
 
 
 @dataclass(frozen=True)
@@ -135,6 +139,29 @@ class LatentConfig:
     nope_dim: int
     rope_dim: int
     v_dim: int
+
+
+@dataclass(frozen=True)
+class MambaConfig:
+    """A Mamba-2 mixer's sizes: ``n_heads`` heads of ``head_dim`` values
+    against a state ``d_state`` wide that every head's B and C share (one
+    group), a causal depthwise convolution of ``conv_width`` taps over ``[x |
+    B | C]`` and the scan's chunk (published ``mamba_n_heads``,
+    ``mamba_d_head``, ``mamba_d_state``, ``mamba_d_conv``,
+    ``mamba_chunk_size``)."""
+    n_heads: int
+    head_dim: int
+    d_state: int
+    conv_width: int = 4
+    chunk: int = ssd.CHUNK
+
+    @property
+    def d_inner(self) -> int:
+        return self.n_heads * self.head_dim
+
+    @property
+    def conv_dim(self) -> int:
+        return self.d_inner + 2 * self.d_state
 
 
 @dataclass(frozen=True)
@@ -189,6 +216,13 @@ class TransformerConfig:
     conv_width: int = 3
     # The head reads the embedding, transposed: the tree has no ``lm_head``.
     tie_embeddings: bool = False
+    # Grouped-query attention without positions (published
+    # ``position_embedding_type`` ``nope``) where False, and its softmax scale
+    # where that is not ``head_dim ** -0.5`` (``attention_multiplier``).
+    rope: bool = True
+    attn_scale: Optional[float] = None
+    # A ``MAMBA`` layer's mixer.
+    mamba: Optional[MambaConfig] = None
     # Training: steps over which ``train.step``'s default optimizer raises its
     # learning rate linearly to its full value (0: constant from the first
     # step, as the dense cells train). A router trained from seeded weights
@@ -214,7 +248,12 @@ class TransformerConfig:
         if set(kinds) & set(PARTS) and not set(kinds) <= set(PARTS):
             raise ValueError(
                 f"layer_kinds {kinds}: the kinds {sorted(PARTS)} stand "
-                "among each other only (their block has no residual_scale)")
+                "among each other only (their runs scan a stack they close "
+                "over)")
+        if MAMBA in kinds and self.mamba is None:
+            raise ValueError(
+                f"layer_kinds {kinds}: a {MAMBA!r} layer needs mamba= "
+                "(MambaConfig)")
         if SHORTCUT in kinds and (self.latent is None
                                   or self.experts is None):
             raise ValueError(
@@ -286,6 +325,37 @@ def init_params(key: jax.Array, cfg: TransformerConfig) -> Dict[str, Any]:
                                d),
                 "wo": dense(ks[4], (h, a.v_dim, d), h * a.v_dim)}
 
+    def mamba_mixer(k):
+        """A Mamba-2 mixer's weights. ``A = -exp(a_log)`` is drawn uniform in
+        -1..-16 and ``dt_bias`` so that ``softplus(dt_bias)`` is log-uniform
+        in 0.001..0.1, as Mamba-2 initialises them (a state then neither dies
+        within a chunk nor never decays); ``d_skip`` is ones, as there; the
+        convolution's bias is drawn at 0.02. The four small leaves are drawn
+        on bfloat16's grid, so that a cast of the tree to the serving dtype
+        leaves them as they are."""
+        m = cfg.mamba
+        ks = jax.random.split(k, 6)
+
+        def on_grid(v):
+            return v.astype(jnp.bfloat16).astype(jnp.float32)
+
+        step = jnp.exp(jax.random.uniform(
+            ks[4], (m.n_heads,), jnp.float32, math.log(1e-3), math.log(1e-1)))
+        return {
+            # [z | x B C | dt]
+            "w_in": dense(ks[0], (d, 2 * m.d_inner + 2 * m.d_state
+                                  + m.n_heads), d),
+            "conv": dense(ks[1], (m.conv_dim, m.conv_width), m.conv_width),
+            "conv_bias": on_grid(0.02 * jax.random.normal(
+                ks[2], (m.conv_dim,), jnp.float32)),
+            "a_log": on_grid(jnp.log(jax.random.uniform(
+                ks[3], (m.n_heads,), jnp.float32, 1.0, 16.0))),
+            # softplus's inverse of the step
+            "dt_bias": on_grid(step + jnp.log(-jnp.expm1(-step))),
+            "d_skip": jnp.ones((m.n_heads,), jnp.float32),
+            "norm": jnp.ones((m.d_inner,), jnp.float32),
+            "w_out": dense(ks[5], (m.d_inner, d), m.d_inner)}
+
     def shortcut_layer(k):
         """Two latent-attention blocks and two FFNs (leaves stacked [2, ...]
         in the order they run), the router, and the held experts, each
@@ -330,6 +400,8 @@ def init_params(key: jax.Array, cfg: TransformerConfig) -> Dict[str, Any]:
         k_mixer, k_ffn = jax.random.split(k)
         if mixer == "latent":
             mixed = latent_attention(k_mixer)
+        elif mixer == "mamba":
+            mixed = mamba_mixer(k_mixer)
         elif mixer == "attn":
             ks = jax.random.split(k_mixer, 4)
             mixed = {"wq": dense(ks[0], (d, h, hd), d),
@@ -501,6 +573,15 @@ def logical_axes(cfg: TransformerConfig) -> Dict[str, Any]:
                          {**blk["attn"], **norms} if mixer == "attn" else
                          {"w_in": ("layers", "embed", None),
                           "conv": ("layers", None, None),
+                          "conv_bias": ("layers", None),
+                          "a_log": ("layers", None),
+                          "dt_bias": ("layers", None),
+                          "d_skip": ("layers", None),
+                          "norm": ("layers", None),
+                          "w_out": ("layers", None, "embed")}
+                         if mixer == "mamba" else
+                         {"w_in": ("layers", "embed", None),
+                          "conv": ("layers", None, None),
                           "w_out": ("layers", None, "embed")})
                 if feed == "mlp":
                     fed = {"mlp": blk["mlp"]}
@@ -620,23 +701,174 @@ def _attention(q, k, v, cfg: TransformerConfig, mesh: Optional[Mesh],
     return jnp.einsum("bhqk,bkhd->bqhd", p, v)
 
 
-def _attention_mixer(attn, h, positions, cfg: TransformerConfig, mesh,
-                     rules=None):
-    """Grouped-query attention on the normed states ``h`` [B, L, d]: the
-    projections, with ``cfg.qk_norm`` an RMSNorm of each head of q and k,
-    rotary positions (by halves), causal softmax attention (``core``) and
-    ``W_o``. The caller enters the ``attn`` scope."""
+def _attention_qkv(attn, h, positions, cfg: TransformerConfig):
+    """q, k and v of grouped-query attention from the normed states ``h`` [B,
+    L, d]: the projections, with ``cfg.qk_norm`` an RMSNorm of each head of q
+    and k, rotary positions (by halves; none where ``cfg.rope`` is False), and
+    with ``cfg.attn_scale`` q times ``attn_scale * sqrt(head_dim)``, so that
+    the attention's own ``head_dim ** -0.5`` leaves the published scale."""
     q = jnp.einsum("bld,dhk->blhk", h, attn["wq"].astype(h.dtype))
     k = jnp.einsum("bld,dhk->blhk", h, attn["wk"].astype(h.dtype))
     v = jnp.einsum("bld,dhk->blhk", h, attn["wv"].astype(h.dtype))
     if cfg.qk_norm:
         q = _rmsnorm(q, attn["q_norm"], cfg.norm_eps)
         k = _rmsnorm(k, attn["k_norm"], cfg.norm_eps)
-    q = _rope(q, cfg.rope_theta, positions)
-    k = _rope(k, cfg.rope_theta, positions)
+    if cfg.rope:
+        q = _rope(q, cfg.rope_theta, positions)
+        k = _rope(k, cfg.rope_theta, positions)
+    if cfg.attn_scale is not None:
+        q = q * (cfg.attn_scale * math.sqrt(cfg.head_dim))
+    return q, k, v
+
+
+def _attention_mixer(attn, h, positions, cfg: TransformerConfig, mesh,
+                     rules=None, kept: Optional[list] = None):
+    """Grouped-query attention on the normed states ``h`` [B, L, d]:
+    ``_attention_qkv``, causal softmax attention (``core``) and ``W_o``.
+    ``kept``, a list, is handed k and v (what a decode loop keeps). The
+    caller enters the ``attn`` scope."""
+    q, k, v = _attention_qkv(attn, h, positions, cfg)
+    if kept is not None:
+        kept.extend((k, v))
     with jax.named_scope("core"):
         o = _attention(q, k, v, cfg, mesh, rules)
     return jnp.einsum("blhk,hkd->bld", o, attn["wo"].astype(h.dtype))
+
+
+def _attention_step(attn, h, cfg: TransformerConfig, k_cache, v_cache, l,
+                    lengths):
+    """One token a slot in attention layer ``l`` of the stacked caches: ``h``
+    [S, d] normed states, every layer's K and V [n, S, T, kv_heads x head_dim]
+    (a position's heads side by side: one row) and how many positions each
+    slot holds. The token's k and v are written as a row at ``(l, slot,
+    position)`` of the stack itself (a scatter of S rows, in place: written
+    into the layer's slice and the slice written back, the compiler moved the
+    whole slice three times) and the query reads the slot's cache up to and
+    with it, as a masked product over all T (no kernel: the cache is a small
+    part of a step's bytes). Each query head is laid out over all the K/V
+    heads' lanes with zeros outside its own group's, so that both products
+    read the cache as it lies (a product batched over the K/V heads had the
+    TPU compiler transpose the whole cache every step, and back for the
+    write): 8 times the scores' FLOPs, which are nothing beside the cache's
+    bytes. Returns the mixer's output and the two stacks."""
+    _, S, T, _ = k_cache.shape
+    H, G, D = cfg.n_heads, cfg.kv_heads, cfg.head_dim
+    q, k, v = _attention_qkv(attn, h[:, None], lengths[:, None], cfg)
+    at = jnp.minimum(lengths, T - 1)
+    slot = jnp.arange(S)
+    k_cache = k_cache.at[l, slot, at].set(
+        k[:, 0].reshape(S, G * D).astype(k_cache.dtype))
+    v_cache = v_cache.at[l, slot, at].set(
+        v[:, 0].reshape(S, G * D).astype(v_cache.dtype))
+    with jax.named_scope("core"):
+        # own[h, g]: query head h reads K/V head g
+        own = (jnp.arange(H)[:, None] // (H // G)
+               == jnp.arange(G)[None])[None, :, :, None]
+        spread = jnp.where(own, q[:, 0, :, None, :], 0).reshape(S, H, G * D)
+        s = jnp.einsum("shc,stc->sht", spread,
+                       jax.lax.dynamic_index_in_dim(k_cache, l, 0, False),
+                       preferred_element_type=jnp.float32)
+        s = s / math.sqrt(D)
+        seen = jnp.arange(T)[None] <= at[:, None]               # [S, T]
+        p = jax.nn.softmax(jnp.where(seen[:, None], s, -1e30), axis=-1)
+        o = jnp.einsum("sht,stc->shc", p.astype(h.dtype),
+                       jax.lax.dynamic_index_in_dim(v_cache, l, 0, False))
+        o = jnp.sum(jnp.where(own, o.reshape(S, H, G, D), 0), axis=2)
+    return (jnp.einsum("shk,hkd->sd", o, attn["wo"].astype(h.dtype)),
+            k_cache, v_cache)
+
+
+def _mamba_inputs(p, h, cfg: TransformerConfig):
+    """``[z | xBC | dt] = h W_in`` for normed states ``h`` [..., d]."""
+    m = cfg.mamba
+    u = jnp.einsum("...d,de->...e", h, p["w_in"].astype(h.dtype))
+    return (u[..., :m.d_inner], u[..., m.d_inner:m.d_inner + m.conv_dim],
+            u[..., m.d_inner + m.conv_dim:])
+
+
+def _mamba_scan_inputs(p, window, dt, cfg: TransformerConfig):
+    """From the convolution's window ``window`` [..., taps, conv_dim] (the
+    position's own row last) and the raw step ``dt`` [..., heads]: ``[x | B |
+    C] = silu(conv + bias)`` in the compute dtype, ``softplus(dt + dt_bias)``
+    and ``A = -exp(a_log)``, float32."""
+    m = cfg.mamba
+    taps = p["conv"].astype(jnp.float32).T                      # [taps, C]
+    c = jnp.sum(window.astype(jnp.float32) * taps, axis=-2) \
+        + p["conv_bias"].astype(jnp.float32)
+    xbc = jax.nn.silu(c).astype(window.dtype)
+    x = xbc[..., :m.d_inner].reshape(*xbc.shape[:-1], m.n_heads, m.head_dim)
+    b = xbc[..., m.d_inner:m.d_inner + m.d_state]
+    c = xbc[..., m.d_inner + m.d_state:]
+    dt = jax.nn.softplus(dt.astype(jnp.float32)
+                         + p["dt_bias"].astype(jnp.float32))
+    return x, b, c, dt, -jnp.exp(p["a_log"].astype(jnp.float32))
+
+
+def _mamba_output(p, y, x, z, cfg: TransformerConfig):
+    """``(RMSNorm(y' * silu(z)) * w) W_out`` with ``y' = y + D x``: ``y`` and
+    ``x`` [..., heads, head_dim], ``z`` [..., d_inner]; the norm over all
+    ``d_inner`` channels (one group)."""
+    y = y.astype(jnp.float32) + x.astype(jnp.float32) \
+        * p["d_skip"].astype(jnp.float32)[:, None]
+    g = y.reshape(z.shape) * jax.nn.silu(z.astype(jnp.float32))
+    g = _rmsnorm(g.astype(z.dtype), p["norm"], cfg.norm_eps)
+    return jnp.einsum("...e,ed->...d", g, p["w_out"].astype(z.dtype))
+
+
+def _mamba_mixer(p, h, cfg: TransformerConfig, lengths=None,
+                 kept: Optional[list] = None):
+    """A Mamba-2 mixer on the normed states ``h`` [B, L, d]: ``[z | xBC | dt]
+    = h W_in``; ``[x | B | C] = silu(conv(xBC) + b)``, depthwise and causal;
+    ``dt = softplus(dt + dt_bias)``; a head's ``S_t = exp(dt_t A) S_{t-1} +
+    dt_t x_t B_t^T``, ``y_t = S_t C_t + D x_t`` (``ops.ssd``: ``core``); the
+    gated norm; ``W_out``. With ``lengths`` [B] a position past a sequence's
+    length steps by 0, so the state passes through it unchanged. ``kept``, a
+    list, is handed what a decode loop keeps: the state after the last real
+    position [B, heads, head_dim, d_state] float32 and the convolution's tail
+    there, the last ``conv_width - 1`` rows of ``xBC``. The caller enters the
+    ``mamba`` scope."""
+    m = cfg.mamba
+    B, L, _ = h.shape
+    z, xbc, dt = _mamba_inputs(p, h, cfg)
+    back = m.conv_width - 1
+    padded = jnp.pad(xbc, ((0, 0), (back, 0), (0, 0)))
+    window = jnp.stack([padded[:, j:j + L] for j in range(m.conv_width)],
+                       axis=2)                              # [B, L, taps, C]
+    x, b, c, dt, a = _mamba_scan_inputs(p, window, dt, cfg)
+    if lengths is not None:
+        dt = jnp.where((jnp.arange(L)[None] < lengths[:, None])[..., None],
+                       dt, 0.0)
+    with jax.named_scope("core"):
+        y, state = ssd.ssd_fwd(x, dt, a, b, c, chunk=m.chunk,
+                               use_kernel=cfg.use_flash)
+    if kept is not None:
+        ends = jnp.full((B,), L) if lengths is None else lengths
+        tail = jax.vmap(lambda rows, n: jax.lax.dynamic_slice_in_dim(
+            rows, n, back))(padded, ends)                   # [B, back, C]
+        kept.extend((state, tail))
+    return _mamba_output(p, y, x, z, cfg)
+
+
+def _mamba_step(p, h, cfg: TransformerConfig, ssm, conv, l):
+    """One token a slot in Mamba layer ``l`` of the stacked state: ``h`` [S,
+    d] normed states, every layer's state ``ssm`` [n, S, heads, head_dim,
+    d_state] float32 and convolution tail ``conv`` [n, S, conv_width - 1,
+    conv_dim]. ``_mamba_mixer``'s arithmetic with the recurrence itself for
+    the scan (``ssd.ssd_step``); the layer's state is read from the stack,
+    stepped and written back where it was read inside ``core``, one pass over
+    it. Returns the output and the two stacks."""
+    z, xbc, dt = _mamba_inputs(p, h, cfg)
+    tail = jax.lax.dynamic_index_in_dim(conv, l, 0, keepdims=False)
+    window = jnp.concatenate([tail.astype(xbc.dtype), xbc[:, None]], axis=1)
+    x, b, c, dt, a = _mamba_scan_inputs(p, window, dt, cfg)
+    with jax.named_scope("core"):
+        y, state = ssd.ssd_step(
+            x, dt, a, b, c,
+            jax.lax.dynamic_index_in_dim(ssm, l, 0, keepdims=False))
+        ssm = jax.lax.dynamic_update_index_in_dim(ssm, state, l, 0)
+    conv = jax.lax.dynamic_update_index_in_dim(
+        conv, window[:, 1:].astype(conv.dtype), l, 0)
+    return _mamba_output(p, y.astype(h.dtype), x, z, cfg), ssm, conv
 
 
 def _shortconv_mixer(conv, h):
@@ -838,12 +1070,17 @@ def _apply_shortcut(blocks, x, positions, cfg: TransformerConfig):
     return x
 
 
-def _parts_block(stack, l, x, positions, cfg: TransformerConfig, kind: str):
+def _parts_block(stack, l, x, positions, cfg: TransformerConfig, kind: str,
+                 lengths=None, kept: Optional[list] = None):
     """Layer ``l`` of the stacked tree ``stack`` of one of ``PARTS``' kinds:
-    ``h = x + Mixer(N(x))``; ``y = h + FFN(N(h))``, the mixer a short
-    convolution, grouped-query or latent attention, the FFN dense or the
-    routed mixture, to which the shared experts (``experts.shared_width``:
-    one dense SwiGLU on every token) are added. Each weight is read from the
+    ``h = x + r Mixer(N(x))``; ``y = h + r FFN(N(h))`` (``r`` =
+    ``cfg.residual_scale``; at 1 nothing is emitted), the mixer a short
+    convolution, a Mamba-2 scan, grouped-query or latent attention, the FFN
+    dense or the routed mixture, to which the shared experts
+    (``experts.shared_width``: one dense SwiGLU on every token) are added.
+    ``lengths`` [B] reach the Mamba mixer (a right-padded prompt leaves the
+    state of its last real position) and ``kept``, a list, is handed what a
+    decode loop keeps of a ``DECODABLE`` kind's mixer (``prefill``). Each weight is read from the
     stack at ``l`` where it is used (``_at``) and the experts' leaves go to
     the mixture whole, with ``l``, as ``_shortcut_block`` does. Returns the
     states and, of a mixture, its load (``expert.held_experts_apply``) and
@@ -856,19 +1093,27 @@ def _parts_block(stack, l, x, positions, cfg: TransformerConfig, kind: str):
     def part(name):
         return jax.tree.map(lambda p: _at(p, l), stack[name])
 
+    def scaled(out):        # muP's residual factor; at 1 the old program
+        return out if cfg.residual_scale == 1.0 else cfg.residual_scale * out
+
     # a scope's name is a literal (the registry's test reads the source)
     with (jax.named_scope("shortconv") if mixer == "shortconv"
+          else jax.named_scope("mamba") if mixer == "mamba"
           else jax.named_scope("attn")):
         h = norm(x, part("ln1"))
         if mixer == "latent":
-            x = x + _latent_attention(part("latent"), h, positions, cfg)
+            x = x + scaled(_latent_attention(part("latent"), h, positions,
+                                             cfg))
         elif mixer == "attn":
-            x = x + _attention_mixer(part("attn"), h, positions, cfg, None)
+            x = x + scaled(_attention_mixer(part("attn"), h, positions, cfg,
+                                            None, kept=kept))
+        elif mixer == "mamba":
+            x = x + scaled(_mamba_mixer(part("mamba"), h, cfg, lengths, kept))
         else:
-            x = x + _shortconv_mixer(part("shortconv"), h)
+            x = x + scaled(_shortconv_mixer(part("shortconv"), h))
     if feed == "mlp":
         with jax.named_scope("mlp"):
-            return x + _mlp(part("mlp"), norm(x, part("ln2"))), None
+            return x + scaled(_mlp(part("mlp"), norm(x, part("ln2")))), None
     e = cfg.experts
     with jax.named_scope("moe"):
         u = norm(x, part("ln2")).reshape(B * L, d)
@@ -884,7 +1129,7 @@ def _parts_block(stack, l, x, positions, cfg: TransformerConfig, kind: str):
         with jax.named_scope("mlp"):    # the tokens as rows, like the mixture
             s = s + _mlp(part("shared"), u[None])[0]
     with jax.named_scope("moe"):
-        return x + s.reshape(B, L, d), (load, counts)
+        return x + scaled(s.reshape(B, L, d)), (load, counts)
 
 
 def _parts_runs(cfg: TransformerConfig):
@@ -1234,6 +1479,166 @@ def apply(params: Dict[str, Any], tokens: jax.Array,
     """tokens: [B, L] int32 -> logits [B, L, vocab] (float32)."""
     x = backbone(params, tokens, cfg, mesh, rules)
     return head(params, x, cfg)
+
+
+# -- state that outlives a call: prefill, then a token a step ------------------------
+
+
+class DecodeState(NamedTuple):
+    """What ``S`` sequences keep between tokens, stacked over the layers of a
+    kind in their order in the stack: the Mamba layers' state ``ssm`` [n_mamba,
+    S, heads, head_dim, d_state] float32 and convolution tail ``conv``
+    [n_mamba, S, conv_width - 1, conv_dim], the attention layers' ``k`` and
+    ``v`` [n_attn, S, T, kv_heads x head_dim] (a position's heads side by
+    side, one row of the cache), both in the compute dtype, and
+    ``lengths`` [S], the positions each sequence holds."""
+    ssm: jax.Array
+    conv: jax.Array
+    k: jax.Array
+    v: jax.Array
+    lengths: jax.Array
+
+
+def _decodable(cfg: TransformerConfig) -> None:
+    if not set(cfg.kinds) <= set(DECODABLE) or cfg.n_passes != 1:
+        raise ValueError(
+            f"layer kinds {sorted(set(cfg.kinds))}: only a stack of "
+            f"{DECODABLE} keeps a state across calls")
+
+
+def init_decode_state(cfg: TransformerConfig, slots: int,
+                      cache_len: int) -> DecodeState:
+    """``slots`` empty sequences with room for ``cache_len`` positions."""
+    _decodable(cfg)
+    n_mamba, n_attn = cfg.kinds.count(MAMBA), cfg.kinds.count(ATTN)
+    m = cfg.mamba or MambaConfig(0, 0, 0)
+    kv = (n_attn, slots, cache_len, cfg.kv_heads * cfg.head_dim)
+    return DecodeState(
+        ssm=jnp.zeros((n_mamba, slots, m.n_heads, m.head_dim, m.d_state),
+                      jnp.float32),
+        conv=jnp.zeros((n_mamba, slots, m.conv_width - 1, m.conv_dim),
+                       cfg.dtype),
+        k=jnp.zeros(kv, cfg.dtype), v=jnp.zeros(kv, cfg.dtype),
+        lengths=jnp.zeros((slots,), jnp.int32))
+
+
+def prefill(params: Dict[str, Any], tokens: jax.Array, lengths: jax.Array,
+            cfg: TransformerConfig) -> Tuple[jax.Array, DecodeState]:
+    """The right-padded prompts ``tokens`` [B, L] of ``lengths`` [B] through
+    the stack: each prompt's pre-final-norm state at its last real position
+    [B, d] (``head`` makes the first token's logits of it) and what the B
+    sequences keep, a ``DecodeState`` of B slots whose K and V hold L
+    positions. Each run of layers is a scan over its indices that hands out
+    what the layers keep (one prompt's is small: 2 MB a Mamba layer)."""
+    _decodable(cfg)
+    B, L = tokens.shape
+    x = _embed(params, tokens, cfg)
+    positions = jnp.broadcast_to(jnp.arange(L)[None], (B, L))
+    blocks = params["blocks"]
+    kept_by_kind: Dict[str, list] = {MAMBA: [], ATTN: []}
+    for kind, start, n in _parts_runs(cfg):
+        def layer(x, l, kind=kind):
+            kept: list = []
+            x, _ = _parts_block(blocks[kind], l, x, positions, cfg, kind,
+                                lengths, kept)
+            return x, tuple(kept)
+
+        x, kept = jax.lax.scan(layer, x, start + jnp.arange(n))
+        kept_by_kind[kind].append(kept)
+    empty = init_decode_state(cfg, B, L)
+
+    def joined(kind, i, otherwise):
+        runs = kept_by_kind[kind]
+        if not runs:
+            return otherwise
+        whole = jnp.concatenate([run[i] for run in runs])
+        if kind == ATTN:    # [n, B, L, kv_heads, head_dim]: a row a position
+            whole = whole.reshape(*whole.shape[:3], -1)
+        return whole.astype(otherwise.dtype)
+
+    last = jnp.take_along_axis(x, (lengths - 1)[:, None, None], axis=1)[:, 0]
+    return last, DecodeState(
+        ssm=joined(MAMBA, 0, empty.ssm), conv=joined(MAMBA, 1, empty.conv),
+        k=joined(ATTN, 0, empty.k), v=joined(ATTN, 1, empty.v),
+        lengths=lengths.astype(jnp.int32))
+
+
+def insert_state(state: DecodeState, piece: DecodeState, slot
+                 ) -> DecodeState:
+    """``piece`` (a ``prefill`` of one or more sequences, its K and V no
+    longer than the slots') written into ``state`` from slot ``slot`` on: a
+    ``dynamic_update_slice`` a leaf, in place where ``state`` is donated.
+    Every other slot keeps its bits."""
+    def put(whole, part):
+        at = (0, slot) + (0,) * (whole.ndim - 2)
+        return jax.lax.dynamic_update_slice(whole, part.astype(whole.dtype),
+                                            at)
+
+    return DecodeState(
+        ssm=put(state.ssm, piece.ssm), conv=put(state.conv, piece.conv),
+        k=put(state.k, piece.k), v=put(state.v, piece.v),
+        lengths=jax.lax.dynamic_update_slice(state.lengths, piece.lengths,
+                                             (slot,)))
+
+
+def decode_step(params: Dict[str, Any], tokens: jax.Array,
+                state: DecodeState, cfg: TransformerConfig,
+                active: Optional[jax.Array] = None
+                ) -> Tuple[jax.Array, DecodeState]:
+    """One token for every slot: ``tokens`` [S] -> float32 logits [S, V] of
+    the next, and the state with the token taken in. ``active`` [S] (all, if
+    None) says which slots hold a sequence: only their ``lengths`` advance
+    (an empty slot computes on whatever it holds, so the program has one
+    shape). Each run of layers is a loop over its indices with the state as
+    the carry: a layer's slice is read where it is used and written back
+    where it was read (``_mamba_step``, ``_attention_step``), so a donated
+    state is updated in place (as ``xs`` and ``ys`` of a scan it would be held
+    twice)."""
+    _decodable(cfg)
+    norm = functools.partial(_rmsnorm, eps=cfg.norm_eps)
+    blocks = params["blocks"]
+    r = cfg.residual_scale
+    x = _embed(params, tokens[:, None], cfg)[:, 0]              # [S, d]
+    ssm, conv, k_cache, v_cache, lengths = state
+
+    for kind, start, n in _parts_runs(cfg):
+        def part(name, l, kind=kind):
+            return jax.tree.map(lambda p: _at(p, l), blocks[kind][name])
+
+        def ffn(x, l, part=part):
+            with jax.named_scope("mlp"):
+                return x + r * _mlp(part("mlp", l),
+                                    norm(x, part("ln2", l))[None])[0]
+
+        def mamba_layer(i, carry, start=start, part=part, ffn=ffn):
+            x, ssm, conv = carry
+            l = start + i
+            with jax.named_scope("mamba"):
+                out, ssm, conv = _mamba_step(
+                    part("mamba", l), norm(x, part("ln1", l)), cfg, ssm,
+                    conv, l)
+                x = x + r * out
+            return ffn(x, l), ssm, conv
+
+        def attn_layer(i, carry, start=start, part=part, ffn=ffn):
+            x, k_cache, v_cache = carry
+            l = start + i
+            with jax.named_scope("attn"):
+                out, k_cache, v_cache = _attention_step(
+                    part("attn", l), norm(x, part("ln1", l)), cfg, k_cache,
+                    v_cache, l, lengths)
+                x = x + r * out
+            return ffn(x, l), k_cache, v_cache
+
+        if kind == MAMBA:
+            x, ssm, conv = jax.lax.fori_loop(0, n, mamba_layer,
+                                             (x, ssm, conv))
+        else:
+            x, k_cache, v_cache = jax.lax.fori_loop(
+                0, n, attn_layer, (x, k_cache, v_cache))
+    step = 1 if active is None else active.astype(lengths.dtype)
+    logits = head(params, x[:, None], cfg)[:, 0]
+    return logits, DecodeState(ssm, conv, k_cache, v_cache, lengths + step)
 
 
 def _exit_nll(x, w_head, targets):

@@ -99,6 +99,15 @@ SPANS = frozenset({
     # the padded rectangle's fill is size_sum / (padded_n x size_max)
     "serve.batch.execute",
     "serve.batch.call",     # the user's callable alone
+    # serve: the generation engine's thread (serve/generation.py)
+    # a request admitted: its prompt prefilled alone and inserted into a
+    # free slot; len, bucket (what the model padded it to), slot, waited_us
+    # (from the request's arrival at the replica to its admission)
+    "serve.generate.prefill",
+    # one token for every occupied slot: active (slots that took the step),
+    # finished (of them, those whose answer the step completed)
+    "serve.generate.step",
+    "serve.generate.reply",  # a finished answer handed to its caller; n_new
     # runtime
     "task.execute",         # one task on a worker thread
     "actor.call",           # one method call on an actor's thread
@@ -152,10 +161,24 @@ LATER_DEVICE_SCOPES = frozenset({
     # jaxpr primitive is named so (``conv`` would match
     # ``conv_general_dilated``'s path token)
     "shortconv",
+    # a layer's Mamba-2 mixer: norm, in-projection, the causal depthwise
+    # convolution, the selective scan (``core`` inside it: ``ops/ssd.py``'s
+    # chunked scan in prefill, its one-token step in decode), the gated norm,
+    # out-projection and the residual's add
+    "mamba",
 })
 
 # The gauge a replica sets once, when its constructor returns.
 REPLICA_INIT_GAUGE = "serve_replica_init_seconds"
+
+# The generation engine's counters (``serve/generation.py``; tagged by
+# deployment): tokens by ``phase`` (``prefill``: a prompt's real tokens at its
+# admission; ``decode``: one a step and occupied slot), steps, admissions, and
+# the gauge of occupied slots, set at every step.
+GENERATE_TOKENS = "serve_generate_tokens_total"
+GENERATE_STEPS = "serve_generate_steps_total"
+GENERATE_ADMITTED = "serve_generate_admitted_total"
+GENERATE_SLOTS_OCCUPIED = "serve_generate_slots_occupied"
 
 # ``Replica.get_metrics()``: the same two numbers summed over every batch a
 # replica has run (real sizes; rows x largest size), so that the fill is

@@ -109,7 +109,17 @@ class DeploymentState:
         opts.setdefault("max_concurrency",
                         max(2, self.config.max_concurrent_queries))
         batch_cfg = None
-        if self.config.batched:
+        slots = self.config.generation_slots
+        if slots:
+            # as many callers park on the replica's engine at once
+            if opts["max_concurrency"] < slots:
+                raise ValueError(
+                    f"deployment {self.name!r}: generation_slots={slots} "
+                    f"callers park on a replica at once, its actor's "
+                    f"max_concurrency is {opts['max_concurrency']}")
+            batch_cfg = {"generation_slots": slots,
+                         "target_latency_ms": self.config.target_latency_ms}
+        elif self.config.batched:
             batch_cfg = {
                 "max_batch_size": self.config.max_batch_size,
                 "batch_wait_timeout_s": self.config.batch_wait_timeout_s,

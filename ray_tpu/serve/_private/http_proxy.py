@@ -47,6 +47,15 @@ from ray_tpu.serve.handle import DeploymentHandle
 logger = logging.getLogger("ray_tpu")
 
 
+class _Server(ThreadingHTTPServer):
+    """The proxy's listening socket. ``socketserver``'s queue of connections
+    the kernel holds until ``accept`` takes them is 5: of 64 callers that
+    connect at one instant most find it full, their SYNs are dropped and
+    sent again after 1, 3, 7 and 15 s (a closed loop of 64 took 16 s to fill
+    64 slots; PERF.md section 6, PR 52)."""
+    request_queue_size = 128
+
+
 class HTTPProxy:
     def __init__(self, controller_handle, host: str = "127.0.0.1",
                  port: int = 0, max_concurrent_requests: int = 200,
@@ -221,7 +230,7 @@ class HTTPProxy:
                 length = int(self.headers.get("Content-Length", 0))
                 self._dispatch(self.rfile.read(length) if length else None)
 
-        self._server = ThreadingHTTPServer((host, port), Handler)
+        self._server = _Server((host, port), Handler)
         self.host, self.port = self._server.server_address[:2]
         self._thread = threading.Thread(
             target=self._server.serve_forever, daemon=True,

@@ -18,6 +18,13 @@ the state machine), which this replica builds and hands its callable
 call estimate; the callable then takes a LIST of requests and returns a
 list of equal length.
 
+A generating deployment (``DeploymentConfig.generation_slots``): the callable
+is a slot model and ``__call__`` requests go through the generation engine of
+``serve/generation.py``, which this replica builds as it builds the batcher
+and hands its two sensors: ``queue_wait`` is then a request's wait for a
+slot, ``execute`` its time in one, and the per-item estimate that time over
+the slots (what a waiting request waits for the one ahead of it).
+
 Every request — batched or direct — feeds two replica-local
 :class:`~ray_tpu.observability.perf.PerfHistogram` instances
 (``queue_wait`` and ``execute``).  Their raw bucket counts ride
@@ -44,6 +51,7 @@ from ray_tpu.observability.metric_names import (REPLICA_BATCH_CUTS,
                                                  REPLICA_INIT_GAUGE)
 from ray_tpu.serve.batching import _Batcher, _ItemEstimate
 from ray_tpu.serve.config import batched
+from ray_tpu.serve.generation import GenerationEngine
 
 
 def _load_checkpoint(checkpoint: Any) -> Any:
@@ -142,12 +150,35 @@ class Replica:
         self._estimate = _ItemEstimate()
         self._batch_cfg = dict(batch_config) if batch_config else None
         self._batcher = self._build_batcher()
+        self._engine = self._build_engine()
         if user_config is not None:
             self.reconfigure(user_config)
 
+    def _build_engine(self) -> Optional[GenerationEngine]:
+        if not (self._batch_cfg or {}).get("generation_slots"):
+            return None
+        engine = GenerationEngine(
+            self._callable, self.deployment_name,
+            f"serve-replica-generate-{self.replica_tag}",
+            observe_queue_wait=self._observe_queue_wait,
+            observe_execute=self._observe_generation)
+        if engine.slots != self._batch_cfg["generation_slots"]:
+            raise ValueError(
+                f"deployment {self.deployment_name!r}: generation_slots="
+                f"{self._batch_cfg['generation_slots']}, its slot model "
+                f"holds {engine.slots}")
+        return engine
+
+    def _observe_generation(self, ms: float, n: int) -> None:
+        """One answer left its slot after ``ms``: the slots turn over one
+        request every ``ms / slots``, which is what the one queued behind it
+        waits for."""
+        self._observe_execute(ms, n)
+        self._estimate.observe(ms, self._engine.slots)
+
     def _build_batcher(self) -> Optional[_Batcher]:
         cfg = self._batch_cfg or {}
-        if not batched(cfg.get("max_batch_size", 1), cfg.get("pad_batch_to")):
+        if cfg.get("generation_slots") or not batched(cfg.get("max_batch_size", 1), cfg.get("pad_batch_to")):
             return None
         return _Batcher(
             self._invoke_batch,
@@ -219,6 +250,10 @@ class Replica:
             self._total += 1
         try:
             args = _resolve_arg_refs(args)
+            if (self._engine is not None and method_name == "__call__"
+                    and len(args) == 1 and not kwargs):
+                # the caller's actor thread parks on its request
+                return self._engine.submit(args[0])
             batcher = self._batcher
             if (batcher is not None and method_name == "__call__"
                     and len(args) == 1 and not kwargs):
@@ -251,13 +286,15 @@ class Replica:
         qw_counts, qw_sum = self._hist_queue_wait.merged()
         ex_counts, ex_sum = self._hist_execute.merged()
         batcher = self._batcher
-        depth = batcher.depth() if batcher is not None else 0
+        engine = self._engine
+        queue = engine if engine is not None else batcher
+        depth = queue.depth() if queue is not None else 0
         with self._lock:
             ongoing = self._ongoing
             total = self._total
         # Estimated time-to-drain of work already admitted here: the
         # router's shed signal and a tiebreaker for scoring.
-        pending = depth if batcher is not None else ongoing
+        pending = depth if queue is not None else ongoing
         ewma = self._estimate.ms()
         return {"replica_tag": self.replica_tag,
                 "num_ongoing_requests": ongoing,
@@ -269,6 +306,8 @@ class Replica:
                 # in (the batches' fill) and the cuts, of them those made
                 # because no neighbour was due: readable without a trace
                 **(batcher.counts() if batcher is not None else _NO_BATCHES),
+                # the generation engine's counts, where there is one
+                **(engine.counts() if engine is not None else {}),
                 "perf": {
                     "bounds": list(perf.bucket_bounds()),
                     "queue_wait": {"counts": qw_counts, "sum_ms": qw_sum},
@@ -296,6 +335,8 @@ class Replica:
             time.sleep(0.01)
         if self._batcher is not None:
             self._batcher.shutdown()
+        if self._engine is not None:
+            self._engine.shutdown()
         return drained
 
     # A node drain snapshots hosted actors with cloudpickle. The locks, the
@@ -308,6 +349,7 @@ class Replica:
             st = self.__dict__.copy()
         st.pop("_lock", None)
         st.pop("_batcher", None)
+        st.pop("_engine", None)
         st.pop("_estimate", None)
         st.pop("_hist_queue_wait", None)
         st.pop("_hist_execute", None)
@@ -322,3 +364,4 @@ class Replica:
         self._hist_execute = perf.PerfHistogram("execute")
         self._estimate = _ItemEstimate()
         self._batcher = self._build_batcher()
+        self._engine = self._build_engine()

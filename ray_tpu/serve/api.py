@@ -15,7 +15,8 @@ import threading
 from typing import Any, Callable, Dict, List, Optional
 
 import ray_tpu
-from ray_tpu.serve.config import AutoscalingConfig, DeploymentConfig
+from ray_tpu.serve.config import (AutoscalingConfig, DeploymentConfig,
+                                  batched)
 from ray_tpu.serve.controller import CONTROLLER_NAME, ServeController
 from ray_tpu.serve.handle import DeploymentHandle
 
@@ -121,7 +122,8 @@ def deployment(_func_or_class=None, *, name: Optional[str] = None,
                max_batch_size: int = 1,
                batch_wait_timeout_s: float = 0.005,
                pad_batch_to: Optional[Any] = None,
-               target_latency_ms: float = 0.0):
+               target_latency_ms: float = 0.0,
+               generation_slots: int = 0):
     """Decorator declaring a class or function as a Serve deployment.
 
     ``checkpoint`` accepts a ``ray_tpu.checkpoint.CheckpointRef`` (e.g.
@@ -139,9 +141,29 @@ def deployment(_func_or_class=None, *, name: Optional[str] = None,
     — with ``AutoscalingConfig.target_latency_ms`` — the SLO the
     autoscaler holds (0 falls back to the ``serve_target_latency_ms``
     knob).
+
+    ``generation_slots > 0`` asks for the generation engine
+    (``serve/generation.py``): the class is then a *slot model* (``admit``,
+    ``step``, ``read``; ``models.generation.TransformerGenerator`` is one)
+    holding that many sequences that decode side by side, and a request is
+    ``{"prompt": [token ids], "max_new_tokens": n}``, answered with
+    ``{"tokens": [...], "logits": [...]}``. As many callers park on a replica
+    at once, so ``max_concurrent_queries`` must not be below it.
     """
 
     def wrap(func_or_class):
+        if generation_slots:
+            if inspect.isfunction(func_or_class) or batched(max_batch_size,
+                                                            pad_batch_to):
+                raise ValueError(
+                    "@serve.deployment(generation_slots=...) takes a class "
+                    "(a slot model) and no batching options: the engine "
+                    "forms its own steps")
+            if generation_slots > max_concurrent_queries:
+                raise ValueError(
+                    f"generation_slots={generation_slots} callers park on a "
+                    f"replica at once: max_concurrent_queries="
+                    f"{max_concurrent_queries} is below that")
         if checkpoint is not None and inspect.isfunction(func_or_class):
             raise ValueError(
                 "@serve.deployment(checkpoint=...) requires a class: the "
@@ -163,7 +185,8 @@ def deployment(_func_or_class=None, *, name: Optional[str] = None,
             max_batch_size=max_batch_size,
             batch_wait_timeout_s=batch_wait_timeout_s,
             pad_batch_to=tuple(pad_batch_to) if pad_batch_to else None,
-            target_latency_ms=target_latency_ms)
+            target_latency_ms=target_latency_ms,
+            generation_slots=int(generation_slots))
         return Deployment(func_or_class,
                           name or func_or_class.__name__, cfg, route_prefix)
 

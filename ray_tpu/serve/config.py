@@ -97,6 +97,12 @@ class DeploymentConfig:
     # and the router sheds over; 0 falls back to the global
     # serve_target_latency_ms knob.
     target_latency_ms: float = 0.0
+    # Autoregressive generation (``serve/generation.py``): > 0 makes the
+    # replica's callable a *slot model* of that many sequences decoding side
+    # by side, behind a ``GenerationEngine``; a ``__call__`` request is then
+    # ``{"prompt": [...], "max_new_tokens": n}``. As many callers park on a
+    # replica at once, so it is held under ``max_concurrent_queries``.
+    generation_slots: int = 0
 
     @property
     def batched(self) -> bool:

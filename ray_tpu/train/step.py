@@ -25,7 +25,7 @@ from ray_tpu.parallel import (ShardingRules, batch_sharding, expert,
 
 # the kinds of layer whose operations have no backward pass
 FORWARD_ONLY = frozenset({transformer.SPARSE, transformer.LINEAR,
-                          transformer.SHORTCUT})
+                          transformer.SHORTCUT, transformer.MAMBA})
 BIAS = "router_bias"        # a mixture layer's leaf that load moves
 
 

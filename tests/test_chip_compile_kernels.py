@@ -143,3 +143,26 @@ def test_flash_backward_compiles_at_unequal_head_widths(on_chip, mosaic, batch,
         compiled.output_shardings and jax.eval_shape(
             jax.grad(loss, argnums=(0, 1, 2)), qk, qk, v))]
     assert widths == [192, 192, 128]
+
+
+# -- the chunked selective scan (ops/ssd.py) ---------------------------------------
+@pytest.mark.parametrize("length", [128, 256, 512, 1024])
+def test_the_chunked_scan_compiles_at_the_generating_cells_buckets(
+        on_chip, mosaic, length):
+    """``ssd_fwd`` at Granite 4.0-H's widths (64 heads of 64 against a state
+    of 128, chunks of 256) at the four prefill buckets of
+    ``granite4h-serve-chat``, one prompt a call, from an initial state: one
+    Mosaic call by its own name, and nothing beside it that a chunk's worth
+    of temporaries would not hold."""
+    from ray_tpu.ops.ssd import KERNEL_NAME, ssd_fwd
+    compiled = jax.jit(
+        lambda x, dt, a, b, c, s: ssd_fwd(x, dt, a, b, c, initial_state=s)
+    ).lower(on_chip((1, length, 64, 64), jnp.bfloat16),
+            on_chip((1, length, 64), jnp.float32),
+            on_chip((64,), jnp.float32),
+            on_chip((1, length, 128), jnp.bfloat16),
+            on_chip((1, length, 128), jnp.bfloat16),
+            on_chip((1, 64, 64, 128), jnp.float32)).compile()
+    text = compiled.as_text()
+    assert text.count(KERNEL) == 1 and KERNEL_NAME in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2 ** 20
