@@ -854,18 +854,26 @@ def _mamba_step(p, h, cfg: TransformerConfig, ssm, conv, l):
     d] normed states, every layer's state ``ssm`` [n, S, heads, head_dim,
     d_state] float32 and convolution tail ``conv`` [n, S, conv_width - 1,
     conv_dim]. ``_mamba_mixer``'s arithmetic with the recurrence itself for
-    the scan (``ssd.ssd_step``); the layer's state is read from the stack,
-    stepped and written back where it was read inside ``core``, one pass over
-    it. Returns the output and the two stacks."""
+    the scan, inside ``core``. With ``cfg.use_flash`` (which chooses the
+    scan's kernel in ``_mamba_mixer`` too) that is one Mosaic call on the
+    stack itself, ``ssd.ssd_step_stacked``: layer ``l``'s state comes into
+    VMEM a slot at a time, is stepped, summed against ``C`` and written back,
+    read once and written once. Without it, ``ssd.ssd_step`` on the layer's
+    state, read from the stack and written back where it was read: the update
+    in place too, but the sum over the new state is a fusion of its own and a
+    third pass. Returns the output and the two stacks."""
     z, xbc, dt = _mamba_inputs(p, h, cfg)
     tail = jax.lax.dynamic_index_in_dim(conv, l, 0, keepdims=False)
     window = jnp.concatenate([tail.astype(xbc.dtype), xbc[:, None]], axis=1)
     x, b, c, dt, a = _mamba_scan_inputs(p, window, dt, cfg)
     with jax.named_scope("core"):
-        y, state = ssd.ssd_step(
-            x, dt, a, b, c,
-            jax.lax.dynamic_index_in_dim(ssm, l, 0, keepdims=False))
-        ssm = jax.lax.dynamic_update_index_in_dim(ssm, state, l, 0)
+        if cfg.use_flash:
+            y, ssm = ssd.ssd_step_stacked(x, dt, a, b, c, ssm, l)
+        else:
+            y, state = ssd.ssd_step(
+                x, dt, a, b, c,
+                jax.lax.dynamic_index_in_dim(ssm, l, 0, keepdims=False))
+            ssm = jax.lax.dynamic_update_index_in_dim(ssm, state, l, 0)
     conv = jax.lax.dynamic_update_index_in_dim(
         conv, window[:, 1:].astype(conv.dtype), l, 0)
     return _mamba_output(p, y.astype(h.dtype), x, z, cfg), ssm, conv
@@ -1593,7 +1601,9 @@ def decode_step(params: Dict[str, Any], tokens: jax.Array,
     the carry: a layer's slice is read where it is used and written back
     where it was read (``_mamba_step``, ``_attention_step``), so a donated
     state is updated in place (as ``xs`` and ``ys`` of a scan it would be held
-    twice)."""
+    twice). With ``cfg.use_flash`` a Mamba layer's recurrence is a Mosaic call
+    that takes the whole stack of states and the layer's index and is its
+    own output: it moves that layer's bytes alone, once in and once out."""
     _decodable(cfg)
     norm = functools.partial(_rmsnorm, eps=cfg.norm_eps)
     blocks = params["blocks"]

@@ -23,15 +23,21 @@ a head that forgets quickly would overflow). ``C B^T`` and ``M``'s product with
 the cumulative sums, the state and every product that reads or writes it are
 float32.
 
-Three entry points. ``ssd_fwd`` with ``use_kernel=True`` is one Mosaic call:
+Four entry points. ``ssd_fwd`` with ``use_kernel=True`` is one Mosaic call:
 the grid runs over (batch, groups of heads, chunks), the state of a group of
 heads lives in VMEM scratch across the chunk axis, starts from an optional
 initial state and leaves as the final one; ``C B^T`` is made once a grid step
 for all its heads. ``use_kernel=False`` is the same arithmetic in
 ``jax.numpy`` under a ``lax.scan`` over chunks: what the CPU tests take and
 what the interpreted kernel is compared with. ``ssd_step`` is the recurrence
-itself for one token over a batch of slots, plain ``jax.numpy``: it is bound
-by the state's bytes. Forward only. It sits beside
+itself for one token over a batch of slots, plain ``jax.numpy``: what the
+CPU tests and a decode step without the kernels take, three passes over the
+state on the TPU. ``ssd_step_stacked`` is the same step as one Mosaic call
+(``ssd_step``) on one layer of a state stacked over layers, in place: a
+slot's state comes into VMEM once, is stepped, summed against ``C`` and
+written back to where it came from, two passes, and every other layer of
+the stack keeps its bits. Both are bound by the state's bytes. Forward only.
+It sits beside
 ``ops/linear_attention.py`` (the same chunk, the same float32 state across the
 chunk axis) and does not replace it: there the decay is a constant a head and
 a head's value is as wide as its state.
@@ -40,6 +46,7 @@ a head's value is as wide as its state.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional, Tuple
 
 import jax
@@ -52,6 +59,8 @@ from ray_tpu.ops.flash_attention import _NN, _backend_is_cpu, _dot
 CHUNK = 256            # tokens a chunk: M is [CHUNK, CHUNK]
 HEADS_PER_STEP = 8     # heads a grid step walks: they share one C B^T
 KERNEL_NAME = "ssd_fwd"
+STEP_KERNEL_NAME = "ssd_step"
+LANES = 128            # of a vector register: the step packs heads into rows of them
 
 
 def _chunk_sums(dt: jax.Array, a: jax.Array, chunk: int) -> jax.Array:
@@ -211,9 +220,10 @@ def ssd_step(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
     state: the recurrence as it is written, every term float32 (sums, not
     products on the MXU, which would round the state to bfloat16). On the TPU
     the update and the sum over the new state are two fusions, three passes
-    over the state where two would do; taken from the state before the step,
-    ``exp(dt A) (S C) + dt x (B . C)``, the sum was a third pass all the same
-    (PERF.md section 6, PR 52)."""
+    over the state (the compiler fuses no reduction over the array an
+    in-place update writes: PERF.md section 6, PR 52); a decode step with the
+    kernels takes ``ssd_step_stacked``, which makes two. This one is what that
+    is compared with, and what a state that is not stacked takes."""
     dt = dt.astype(jnp.float32)
     decay = jnp.exp(dt * a.astype(jnp.float32))                 # [S, H]
     push = (dt[..., None] * x.astype(jnp.float32))[..., None] \
@@ -221,3 +231,96 @@ def ssd_step(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
     state = decay[..., None, None] * state + push
     y = jnp.sum(state * c.astype(jnp.float32)[:, None, None, :], axis=-1)
     return y, state
+
+
+def _step_kernel(layer_ref, xdt_ref, decay_ref, b_ref, c_ref, s_ref, out_ref,
+                 y_ref, *, heads: int, pack: int):
+    """One slot of the layer the index maps picked, ``heads`` heads a loop
+    step. ``xdt_ref`` and ``y_ref`` [S, H / pack, pack x P] hold ``pack``
+    heads a row, ``P`` on the lanes; against the state [P, N] a head ``P``
+    lies on the sublanes. A row becomes columns, and columns a row again, by
+    a transpose of the whole tile: ``dt x`` laid ``N`` times over the
+    sublanes and transposed is the row's value on every lane of its own
+    sublane, and ``S' * C`` transposed sums over sublanes (adds of whole
+    registers) into a row of ``y``. A head's decay is a scalar, from SMEM."""
+    del layer_ref
+    P, N = s_ref.shape[3:]
+    slot = pl.program_id(0)
+    b = b_ref[pl.ds(slot, 1), :]                                # [1, N]
+    c = c_ref[pl.ds(slot, 1), :]
+    rows = heads // pack
+
+    def walk(g, carry):
+        for i in range(rows):
+            r = g * rows + i
+            xdt = xdt_ref[slot, pl.ds(r, 1), :]                 # [1, pack P]
+            push = jnp.broadcast_to(xdt, (N, pack * P)).T * b   # [pack P, N]
+            summed = []
+            for k in range(pack):
+                h = r * pack + k
+                new = decay_ref[slot, h] * s_ref[0, 0, h] \
+                    + push[k * P:(k + 1) * P]
+                out_ref[0, 0, h] = new
+                summed.append(new * c)
+            y_ref[slot, pl.ds(r, 1), :] = jnp.sum(
+                jnp.concatenate(summed).T, axis=0, keepdims=True)
+        return carry
+
+    jax.lax.fori_loop(0, s_ref.shape[2] // heads, walk, None)
+
+
+def ssd_step_stacked(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
+                     c: jax.Array, states: jax.Array, layer
+                     ) -> Tuple[jax.Array, jax.Array]:
+    """``ssd_step`` on layer ``layer`` (an int32 scalar, traced or not) of
+    ``states`` [n, S, heads, P, N] float32, as one Mosaic call on the stack
+    itself: ``x``, ``dt``, ``a``, ``b``, ``c`` as ``ssd_step`` takes them.
+    Returns ``y`` [S, heads, P] float32 and the stack with that layer stepped.
+    The stack is the call's own output (``input_output_aliases``) and the
+    layer's index a scalar-prefetch operand that the state's index map reads,
+    so only that layer's bytes move, each once in and once out, and a donated
+    stack is updated in place; a slice of the stack handed to a custom call
+    would be copied out and in. A grid step is one slot: its heads' state
+    ``[heads, P, N]`` (2 MB at Granite's sizes, double-buffered both ways)
+    while everything else (``dt x``, the decays, ``b``, ``c``, ``y``: under
+    2.2 MB for 64 slots) stays in VMEM for the whole call, fetched and written
+    once, because a small copy a grid step is waited for and a large one is
+    not. Every term is float32 and no product goes to the MXU. Interpreted on
+    a CPU backend."""
+    n, S, H, P, N = states.shape
+    f32 = jnp.float32
+    dt = dt.astype(f32)
+    pack = math.gcd(H, max(1, LANES // P))      # heads a row of lanes
+    heads = HEADS_PER_STEP if H % HEADS_PER_STEP == 0 \
+        and HEADS_PER_STEP % pack == 0 else H
+    xdt = (dt[..., None] * x.astype(f32)).reshape(S, H // pack, pack * P)
+
+    def resident(*shape):
+        return pl.BlockSpec(shape, lambda s, layer: (0,) * len(shape))
+
+    a_slot = pl.BlockSpec((1, 1, H, P, N),
+                          lambda s, layer: (layer[0], s, 0, 0, 0))
+    # bytes: a slot's state in and out, and dt x, y, b and c; two buffers
+    # each, and room for the loop's tiles
+    vmem = 2 * 4 * (2 * H * P * N + 2 * xdt.size + 2 * S * N) + 4 * 2 ** 20
+    # an index past the stack is held to it, as a dynamic slice holds it
+    layer = jnp.clip(jnp.asarray(layer, jnp.int32), 0, n - 1)
+    states, y = pl.pallas_call(
+        functools.partial(_step_kernel, heads=heads, pack=pack),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(S,),
+            in_specs=[resident(*xdt.shape),
+                      pl.BlockSpec(memory_space=pltpu.SMEM),
+                      resident(S, N), resident(S, N), a_slot],
+            out_specs=[a_slot, resident(*xdt.shape)]),
+        out_shape=[jax.ShapeDtypeStruct(states.shape, f32),
+                   jax.ShapeDtypeStruct(xdt.shape, f32)],
+        input_output_aliases={5: 0},    # the stack, counted from ``layer``
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),     # ``y`` stays across it
+            vmem_limit_bytes=vmem),
+        interpret=_backend_is_cpu(),
+        name=STEP_KERNEL_NAME,    # the XLA Ops line of a device trace carries it
+    )(layer.reshape(1), xdt, jnp.exp(dt * a.astype(f32)), b.astype(f32),
+      c.astype(f32), states)
+    return y.reshape(S, H, P), states

@@ -166,3 +166,25 @@ def test_the_chunked_scan_compiles_at_the_generating_cells_buckets(
     text = compiled.as_text()
     assert text.count(KERNEL) == 1 and KERNEL_NAME in text
     assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2 ** 20
+
+
+def test_the_stacked_step_compiles_at_the_generating_cells_slots(on_chip,
+                                                                 mosaic):
+    """``ssd_step_stacked`` at Granite 4.0-H's widths over 64 slots, on a
+    stack of three layers that is donated: one Mosaic call by its own name,
+    the stack its own output (aliased whole) and nothing beside it, no copy
+    of a layer in or out."""
+    from ray_tpu.ops.ssd import STEP_KERNEL_NAME, ssd_step_stacked
+    stack = on_chip((3, 64, 64, 64, 128), jnp.float32)
+    compiled = jax.jit(ssd_step_stacked, donate_argnums=(5,)).lower(
+        on_chip((64, 64, 64), jnp.bfloat16), on_chip((64, 64), jnp.float32),
+        on_chip((64,), jnp.float32), on_chip((64, 128), jnp.bfloat16),
+        on_chip((64, 128), jnp.bfloat16), stack,
+        on_chip((), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert text.count(KERNEL) == 1 and STEP_KERNEL_NAME in text
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes == 3 * 64 * 64 * 64 * 128 * 4
+    assert memory.temp_size_in_bytes < 4 * 2 ** 20
+    assert not any(" copy(" in line and "64,64,128]" in line
+                   for line in text.splitlines())

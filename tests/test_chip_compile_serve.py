@@ -5,6 +5,7 @@ long-document, prefill and expert-load cells at their published widths, with
 what their layers' loops may not copy. See ``test_chip_compile.py``.
 """
 
+import itertools
 import math
 import re
 
@@ -331,6 +332,38 @@ def test_a_decode_step_updates_the_slots_state_in_place(generating):
     # what the configuration's file says the rule read
     read = generating["cell"].config["reduced"]["generate.1"]["slots_read"]
     assert f"{_held_gb(compiled):.2f} GB" in read
+
+
+def test_a_decode_step_steps_the_state_in_one_call_on_the_stack(generating):
+    """The recurrence of a Mamba layer in the compiled step is the Mosaic
+    call ``ssd_step``, one for each run of Mamba layers' loop, handed the
+    whole stack of states: the stack is still aliased, the temporaries are
+    what they were, and no layer's ``[.., 64, 64, 128]`` float32 state is
+    copied out of the stack or into it."""
+    from ray_tpu.ops.ssd import STEP_KERNEL_NAME
+    compiled = generating["decode_step"]
+    lines = compiled.as_text().splitlines()
+    calls = [line for line in lines
+             if " custom-call(" in line and KERNEL in line
+             and re.search(rf"\s%?{STEP_KERNEL_NAME}[.\d]* = ", line)]
+    kinds = cell_dims("granite4h-serve-chat")[2]["layer_types"]
+    runs = [kind for kind, _ in itertools.groupby(kinds)]
+    assert len(calls) == runs.count("mamba") == 5
+    stack = f"f32[36,{generating['slots']},64,64,128]"
+    assert all(stack in line and "output_to_operand_aliasing" in line
+               for line in calls)
+    assert all("/mamba/core/" in line for line in calls)
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= generating["bytes"]["state"]
+    assert m.temp_size_in_bytes < 1e9
+    copies = [line.strip()[:160] for line in lines
+              if " copy(" in line and re.search(r"f32\[[\d,]*64,64,128\]",
+                                                line)]
+    assert copies == []
+    # the sum over the state is the call's: no fusion beside it reads a
+    # layer of the stack
+    assert not any("add_dynamic-update-slice_fusion" in line
+                   and stack in line for line in lines)
 
 
 def test_the_longest_prefill_fits_beside_the_resident_state(generating):

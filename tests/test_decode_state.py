@@ -110,6 +110,32 @@ def test_an_insert_leaves_the_other_slots_to_the_bit(run):
         after.lengths, state.lengths.at[1].set(LENGTHS[2]))
 
 
+def test_the_kernels_step_is_the_plain_step(run):
+    """``decode_step`` with ``use_flash`` (the recurrence a Mosaic call on the
+    stacked state, interpreted here) against without, from one ``prefill``
+    over ``NEW`` steps: every step's logits and all five leaves of the state
+    it leaves. Two runs of Mamba layers, so the layer's index is traced."""
+    cfg = tiny.config(use_flash=True)
+    assert cfg.use_flash and not run["cfg"].use_flash
+    kernels = jax.jit(lambda t, s, a: transformer.decode_step(
+        run["params"], t, s, cfg, a))
+    _, piece = _prefilled(run, range(3))
+    plain = with_kernels = run["insert"](run["empty"], piece, 1)
+    active = jnp.array([False, True, True, True, False])
+    for i in range(NEW):
+        feed = jnp.zeros((SLOTS,), jnp.int32).at[1:4].set(jnp.stack(
+            [run["tokens"][r, LENGTHS[r] + i] for r in range(3)]))
+        want, plain = run["step"](feed, plain, active)
+        got, with_kernels = kernels(feed, with_kernels, active)
+        np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
+    for name in ("ssm", "conv", "k", "v"):
+        np.testing.assert_allclose(
+            getattr(with_kernels, name), getattr(plain, name), rtol=1e-4,
+            atol=1e-5, err_msg=name)
+    np.testing.assert_array_equal(with_kernels.lengths, plain.lengths)
+    assert float(jnp.max(jnp.abs(plain.ssm[:, 1:4]))) > 1e-2
+
+
 def test_only_a_stack_of_the_two_kinds_keeps_a_state():
     dense = transformer.TransformerConfig(vocab_size=16, d_model=8,
                                           n_layers=1, n_heads=2)

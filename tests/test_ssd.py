@@ -1,13 +1,14 @@
 """``ops/ssd.py``: the chunked scan (the Mosaic kernel interpreted, and its
 ``jax.numpy`` fallback) and the one-token step against Mamba-2's recurrence
-written token by token."""
+written token by token; the step's Mosaic call on a stack of layers
+(interpreted) against the step as it is written."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.ops.ssd import ssd_fwd, ssd_step
+from ray_tpu.ops.ssd import ssd_fwd, ssd_step, ssd_step_stacked
 
 B, H, P, N, CHUNK = 2, 4, 8, 16, 16
 
@@ -117,3 +118,74 @@ def test_the_step_continues_the_scan(programs):
             x, dt, a, b, c, state)
     np.testing.assert_allclose(y_all[:, 48], y_next, rtol=2e-4, atol=2e-4)
     np.testing.assert_allclose(state_all, after, rtol=2e-4, atol=2e-4)
+
+
+# -- the step as one call on the stacked state ---------------------------------------
+
+LAYERS = 3
+# (slots, heads, P, N): the generating cell's widths, and a small shape whose
+# heads do not fill a row of lanes in pairs
+STACKS = {"cell": (2, 64, 64, 128), "small": (3, 4, 8, 16)}
+
+
+def _stack_inputs(shape, dtype, seed=0):
+    S, heads, P, N = shape
+    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+    x = jax.random.normal(ks[0], (S, heads, P), jnp.float32).astype(dtype)
+    dt = jax.nn.softplus(jax.random.normal(ks[1], (S, heads)) - 2.0)
+    a = -jnp.exp(jax.random.uniform(ks[2], (heads,), minval=0.0, maxval=2.7))
+    b = jax.random.normal(ks[3], (S, N), jnp.float32).astype(dtype)
+    c = jax.random.normal(ks[4], (S, N), jnp.float32).astype(dtype)
+    states = jax.random.normal(ks[5], (LAYERS, S, heads, P, N), jnp.float32)
+    return x, dt, a, b, c, states
+
+
+@pytest.fixture(scope="module")
+def stacked():
+    return jax.jit(ssd_step_stacked)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("shape", list(STACKS))
+def test_the_stacked_step_is_the_step_on_one_layer(stacked, shape, dtype):
+    """``y`` and the stepped layer to float32 rounding (the interpreter on a
+    CPU may fuse a multiply and an add that the step rounds apart); every
+    other layer of the stack to the bit; a slot that steps by 0 keeps its
+    state's bits."""
+    x, dt, a, b, c, states = _stack_inputs(STACKS[shape], dtype)
+    dt = dt.at[0].set(0.0)
+    y, out = stacked(x, dt, a, b, c, states, 1)
+    want_y, want = ssd_step(x, dt, a, b, c, states[1])
+    assert y.dtype == out.dtype == jnp.float32 and y.shape == x.shape
+    np.testing.assert_allclose(out[1], want, rtol=1e-6, atol=1e-6)
+    np.testing.assert_allclose(y, want_y, rtol=1e-5, atol=2e-5)
+    for other in (0, 2):
+        np.testing.assert_array_equal(out[other], states[other])
+    np.testing.assert_array_equal(out[1, 0], states[1, 0])
+    assert not np.array_equal(out[1, 1], states[1, 1])
+
+
+def test_the_stacked_step_takes_a_traced_layer(stacked):
+    """The layer's index as a loop's counter, the stack as its carry (what
+    ``decode_step`` builds), against a Python loop over the layers (two
+    compilations: float32 rounding apart)."""
+    x, dt, a, b, c, states = _stack_inputs(STACKS["small"], jnp.float32, 1)
+
+    @jax.jit
+    def looped(states):
+        def layer(l, carry):
+            states, ys = carry
+            y, states = ssd_step_stacked(x, dt, a, b, c, states, l)
+            return states, ys.at[l].set(y)
+        return jax.lax.fori_loop(
+            0, LAYERS, layer,
+            (states, jnp.zeros((LAYERS,) + x.shape, jnp.float32)))
+
+    got, got_ys = looped(states)
+    want = states
+    for l in range(LAYERS):
+        y, want = stacked(x, dt, a, b, c, want, l)
+        np.testing.assert_allclose(got_ys[l], y, rtol=1e-5, atol=2e-5)
+        assert not np.array_equal(want[l], states[l])
+    np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
