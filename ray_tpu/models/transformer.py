@@ -52,7 +52,11 @@ rotary positions on the ``rope_dim`` part, which all heads share for k), two
 dense SwiGLU FFNs and a routed mixture of experts whose sum joins the stream
 one sub-layer late (``_shortcut_block``; sizes in ``LatentConfig`` and
 ``parallel.expert.ExpertConfig``). The mixture is this device's share of an
-expert-parallel layer: ``parallel.expert.held_experts_apply``.
+expert-parallel layer: ``parallel.expert.held_experts_apply``, whose dropless
+loop (gather, three grouped products, weigh) scatter-adds a step's rows into
+the sum where the device holds a share of the experts, as here, and where it
+holds them all (LFM2 below) writes them into a list that one gather after the
+loop sums.
 
 Five more kinds are *a token mixer and an FFN chosen apart* (LFM2, Kanana-2:
 ``PARTS``), served and trained on one device, and stand among each other in
@@ -68,7 +72,9 @@ W_in``, ``g = B * z``, a causal depthwise convolution of ``conv_width`` taps
 over ``g`` and ``(C * c) W_out`` (``_shortconv_mixer``: shifted
 multiply-adds, no kernel); the mixture is ``held_experts_apply`` with the
 router ``ExpertConfig`` describes (here sigmoid scores, a bias for the choice
-alone, weights normalised over the chosen). A run of neighbouring layers of
+alone, weights normalised over the chosen), combined by gather where every
+expert of the layer is held (LFM2's served cut) and by a scatter-add a step
+where a share is (Kanana-2's trained one). A run of neighbouring layers of
 one such kind is a scan over the layers' indices with the stacked tree closed
 over: each weight is read from the stack where it is used (``_at``), the
 experts' leaves go to the mixture whole with the layer's index. ``qk_norm``

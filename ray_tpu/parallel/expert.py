@@ -11,16 +11,24 @@ scores or on scores plus a bias, the weights as they are or normalised over
 the chosen: ``ExpertConfig``'s fields), computes its own experts' part of the
 result for the tokens routed to them, dropless (the pairs routed to held
 experts are listed by expert once a layer call, each placed by one counting
-pass, and a loop takes the list ``CHUNK_ROWS`` rows a step), and adds what a
-zero-compute expert returns (weight x token, computed where the token
-lives). What the absent experts would add is left out: on one device the
-layer runs without its exchange, and the partial sum is what goes on. The
-model's stack hands it the experts of all its layers and the layer's index:
-they are read as groups of the stacked leaves, not cut out.
+pass, and a loop takes the list ``CHUNK_ROWS`` rows a step: gather the rows'
+tokens, three grouped products, weigh), and adds what a zero-compute expert
+returns (weight x token, computed where the token lives). How a step's
+weighed rows reach their tokens' sums depends on what the device holds
+(``ExpertConfig.all_held``): a device that holds a share of the experts
+scatter-adds them, a step at a time, into the sum the loop carries (few of a
+token's pairs have a row there); a device that holds every expert writes
+them into a float32 list where they lie, and one gather after the loop sums
+each token's ``k`` rows. What the absent experts would add is left out: on
+one device the layer runs without its exchange, and the partial sum is what
+goes on. The model's stack hands it the experts of all its layers and the
+layer's index: they are read as groups of the stacked leaves, not cut out.
 
 It trains too: the dropless loop has a backward pass of its own
 (``_held_sum``, a ``custom_vjp``: the loop's trip count is traced) that
-walks the same list a chunk at a time, and the router differentiates as
+walks the same list a chunk at a time from the kept operands, whichever way
+the forward combined (its ``d u`` is a scatter-add a step on every device: no
+cell trains a mixture held whole), and the router differentiates as
 plain JAX, through its weights and not through its choice. A router's
 ``choice_bias`` is no parameter: ``choice_counts`` counts a call's choices
 and ``moved_bias`` moves the bias by them, outside the gradient
@@ -93,6 +101,13 @@ class ExpertConfig:
     def n_outputs(self) -> int:
         return self.n_routed + self.n_zero
 
+    @property
+    def all_held(self) -> bool:
+        """This device holds the whole mixture: every routed pair has a row
+        in the call's list, which is what lets the dropless sum be combined
+        by one gather (``_held_sum``)."""
+        return self.held == (0, self.n_routed)
+
 
 def _precision(dtype) -> jax.lax.Precision:
     """float32 operands multiply as float32; below 32 bits a product is
@@ -134,12 +149,13 @@ def route(u: jax.Array, router: jax.Array, cfg: ExpertConfig,
 def _held_rows(idx, weights, cfg: ExpertConfig, length: int):
     """The call's pairs routed to held experts, listed by expert and within
     an expert by token: ``(row_tok [length], row_w [length], bounds [count +
-    1])``, ``length`` the ``T x k`` pairs or more. Held expert c's pairs are
-    rows ``bounds[c]:bounds[c + 1]`` of the list; row s holds its pair's
-    token and weight; ``bounds[count]`` pairs are held in all, and the rows
-    past them hold the token ``T`` (no token) at weight 0. Counting, not a
-    sort (a sort of a call's pairs takes the TPU compiler half a minute a
-    shape): pair (t, j) on held expert c is the ``running[t, c]``-th of c's,
+    1], place [T x k])``, ``length`` the ``T x k`` pairs or more. Held expert
+    c's pairs are rows ``bounds[c]:bounds[c + 1]`` of the list; row s holds
+    its pair's token and weight; ``bounds[count]`` pairs are held in all, and
+    the rows past them hold the token ``T`` (no token) at weight 0; pair (t,
+    j)'s row is ``place[t x k + j]``, ``length`` if it is not held. Counting,
+    not a sort (a sort of a call's pairs takes the TPU compiler half a minute
+    a shape): pair (t, j) on held expert c is the ``running[t, c]``-th of c's,
     so its row is ``bounds[c] + running[t, c] - 1``, and a scatter of the
     ``T x k`` pairs' tokens and one of their weights write the list (a token
     picks an expert once at most: a held pair has one row and a row one
@@ -161,7 +177,7 @@ def _held_rows(idx, weights, cfg: ExpertConfig, length: int):
         tok.reshape(-1), mode="drop")
     row_w = jnp.zeros((length,), jnp.float32).at[place].set(
         weights.reshape(-1), mode="drop")
-    return row_tok, row_w, bounds
+    return row_tok, row_w, bounds, place
 
 
 def _transposed(w):
@@ -189,39 +205,71 @@ def _chunks(rows, count, n_groups, layer, row_tok, row_w, bounds):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _held_sum(rows, out, u, row_w, w, row_tok, bounds, layer):
+def _held_sum(rows, out, u, row_w, w, row_tok, bounds, layer, place):
     """``out + sum_s row_w[s] Expert_{e(s)}(u[row_tok[s]])`` over the listed
     pairs (``_held_rows``), ``rows`` of the list a step, for as many steps as
     the held pairs fill: the dropless loop. ``w``: the grouped weights
     ``wi``, ``wg`` [groups, d, width] and ``wo`` [groups, width, d], of which
     ``layer``'s ``count = len(bounds) - 1`` groups are meant. float32 [T, d].
 
+    How a step's weighed products reach their tokens' sums is ``place``'s to
+    say. ``None`` (a device that holds a share of the experts: few of a
+    token's pairs have a row): each step scatter-adds its rows into the sum
+    the loop carries. The pairs' rows in the list [T x k] (a device that
+    holds every expert: the list is dense): a step writes its rows into a
+    float32 list [length, d] where they lie, and after the loop one gather
+    sums each token's ``k`` rows, in the order of its choice; a pair that is
+    not held (a zero-compute pick, placed at ``length``) reads as zeros,
+    whatever the list holds.
+
     The loop's trip count is traced, so autodiff cannot reverse it: the
     backward (``_held_sum_bwd``) walks the same list the same ``rows`` at a
-    time."""
+    time, from the operands alone, whichever way the forward combined."""
     count = bounds.shape[0] - 1
     product = functools.partial(jax.lax.ragged_dot,
                                 precision=_precision(u.dtype))
     chunk = _chunks(rows, count, w["wi"].shape[0], layer, row_tok, row_w,
                     bounds)
+    steps = (bounds[count] + rows - 1) // rows
 
-    def step(i, out):
+    def weighed(i):
         tok, wt, sizes = chunk(i)
         # rows past the held pairs hold token T: the gather clamps it,
         # the rows belong to no group, and whatever the product left
-        # there the scatter-add drops
+        # there no sum reads (the scatter-add drops it, the list's gather
+        # has no pair placed there)
         x = u.at[tok].get(mode="clip")
         hidden = (jax.nn.silu(product(x, w["wi"], sizes))
                   * product(x, w["wg"], sizes))
         y = product(hidden, w["wo"], sizes,
                     preferred_element_type=jnp.float32)
-        return out.at[tok].add(y * wt[:, None], mode="drop")
+        return tok, y * wt[:, None]
 
-    return jax.lax.fori_loop(0, (bounds[count] + rows - 1) // rows, step, out)
+    if place is None:
+        def step(i, out):
+            tok, y = weighed(i)
+            return out.at[tok].add(y, mode="drop")
+
+        return jax.lax.fori_loop(0, steps, step, out)
+
+    def step(i, listed):
+        return jax.lax.dynamic_update_slice(listed, weighed(i)[1],
+                                            (i * rows, 0))
+
+    T, d = out.shape
+    listed = jax.lax.fori_loop(
+        0, steps, step, jnp.zeros((row_tok.shape[0], d), jnp.float32))
+    # the tokens' first choices, then their second, ...: k slabs [T, d] to
+    # add (summed token by token, [T, k, d] over its middle axis, the same
+    # gather and sum took 1.1 ms more a layer call of 8,192 tokens: PERF.md
+    # section 6, PR 54)
+    by_choice = place.reshape(T, -1).T.reshape(-1)
+    picked = listed.at[by_choice].get(mode="fill", fill_value=0.0)
+    return out + jnp.sum(picked.reshape(-1, T, d), axis=0)
 
 
-def _held_sum_fwd(rows, out, u, row_w, w, row_tok, bounds, layer):
-    return (_held_sum(rows, out, u, row_w, w, row_tok, bounds, layer),
+def _held_sum_fwd(rows, out, u, row_w, w, row_tok, bounds, layer, place):
+    return (_held_sum(rows, out, u, row_w, w, row_tok, bounds, layer, place),
             (u, row_w, w, row_tok, bounds, layer))
 
 
@@ -307,7 +355,7 @@ def _held_sum_bwd(rows, kept, g):
                "wo": by_rows(lists["hidden"], lists["d_y"], sizes)}
         return (g, d_u.astype(u.dtype), d_row_w,
                 {name: p.astype(w[name].dtype) for name, p in d_w.items()},
-                None, None, None)
+                None, None, None, None)
 
 
 _held_sum.defvjp(_held_sum_fwd, _held_sum_bwd)
@@ -334,13 +382,14 @@ def held_pairs_apply(u: jax.Array, idx: jax.Array, weights: jax.Array,
 
         rows = min(CHUNK_ROWS, T * cfg.top_k)
         # whole steps of rows, so that the last step's slice is its own
-        row_tok, row_w, bounds = _held_rows(idx, weights, cfg,
-                                            -(-idx.size // rows) * rows)
+        row_tok, row_w, bounds, place = _held_rows(
+            idx, weights, cfg, -(-idx.size // rows) * rows)
         n_held = bounds[count]
         w = {name: p.reshape(n * count, *p.shape[2:])
              for name, p in experts.items()}
         with jax.named_scope("experts"):
-            out = _held_sum(rows, out, u, row_w, w, row_tok, bounds, layer)
+            out = _held_sum(rows, out, u, row_w, w, row_tok, bounds, layer,
+                            place if cfg.all_held else None)
         n_zero = jnp.sum(zero, dtype=jnp.int32)
         load = jnp.stack([n_held, idx.size - n_held - n_zero, n_zero,
                           jnp.max(bounds[1:] - bounds[:-1])])
@@ -373,11 +422,15 @@ def held_experts_apply(u: jax.Array, router: jax.Array,
     taken ``CHUNK_ROWS`` at a time, for as many steps as they fill: slice
     the step's tokens and weights off the list, gather the tokens' rows,
     three grouped products over the held experts (``lax.ragged_dot``, which
-    the TPU compiles to one Mosaic call over ragged groups), weigh,
-    scatter-add. So the products' work grows with the routed pairs, not with
-    T x experts (only the list's making, a [T, count] running count of
-    integers and one placement of the T x k pairs, does), and every token
-    sent to one expert or none is exact alike.
+    the TPU compiles to one Mosaic call over ragged groups), weigh, and
+    combine: where ``cfg.held`` is a share of the experts the step
+    scatter-adds its rows into the sum; where it is all of them
+    (``cfg.all_held``) the step writes its rows into a float32 list and one
+    gather after the loop sums each token's ``k`` rows, in the order of its
+    choice (``_held_sum``). So the products' work grows with the routed
+    pairs, not with T x experts (only the list's making, a [T, count]
+    running count of integers and one placement of the T x k pairs, does),
+    and every token sent to one expert or none is exact alike.
 
     Differentiable in ``u``, ``router`` and ``experts``: the router's scores
     and weights and the list's making are plain JAX (the gradient flows
@@ -433,10 +486,16 @@ def _counters():
             "moe_held_load_max_total",
             "pairs of the most-loaded held expert, summed over layer calls"),
         metrics.Counter(
-            "moe_layer_calls_total", "layer calls of held_experts_apply"))
+            "moe_layer_calls_total", "layer calls of held_experts_apply"),
+        metrics.Counter(
+            "moe_combined_pairs_total",
+            "held pairs whose weighed products reached their tokens' sums, "
+            "by how: gather (one after the loop, where the device holds "
+            "every expert), scatter_add (a step, where it holds a share)",
+            tag_keys=("by",)))
 
 
-def _record(count: int, loads) -> None:
+def _record(cfg: ExpertConfig, loads) -> None:
     """Host side of ``record_load``: the registry's counters, and one span
     (``moe.route``) that carries the same increments while a profiler
     session or the ring records, so that a reader finds a window's share."""
@@ -447,17 +506,25 @@ def _record(count: int, loads) -> None:
     rows = np.minimum(CHUNK_ROWS, loads[:, :3].sum(axis=1))
     steps = int((-(-loads[:, 0] // np.maximum(rows, 1))).sum())
     held, absent, zero, most = (int(n) for n in loads.sum(axis=0))
-    pairs, load_max, calls = _counters()
+    pairs, load_max, calls, combined = _counters()
     for dest, n in (("held", held), ("absent", absent), ("zero", zero)):
         pairs.inc(n, tags={"dest": dest})
     load_max.inc(most)
     calls.inc(len(loads))
+    # which way the program combines is in ``cfg``, as it was when the
+    # program was traced (``_held_sum``)
+    gathered = held if cfg.all_held else 0
+    combined.inc(gathered, tags={"by": "gather"})
+    combined.inc(held - gathered, tags={"by": "scatter_add"})
     # ``placed``: the pairs the layer calls' one counting pass wrote into
     # their lists, every held pair once (a program that searched for its
-    # rows a step has no such attribute)
+    # rows a step has no such attribute); ``gathered``: those of them that
+    # the gather after the loop combined (a program that scatter-added every
+    # step's rows has no such attribute, a device that holds a share says 0)
     with observability.span("moe.route", held=held, absent=absent, zero=zero,
-                            load_max=most, layers=len(loads), experts=count,
-                            steps=steps, placed=held):
+                            load_max=most, layers=len(loads),
+                            experts=cfg.held[1], steps=steps, placed=held,
+                            gathered=gathered):
         pass
 
 
@@ -466,10 +533,11 @@ def record_load(loads: jax.Array, cfg: ExpertConfig) -> None:
     second result, stacked [layers, 4]) to the program's counters, from
     inside a jitted program: one call-back a forward."""
     with jax.named_scope("moe"):
-        jax.debug.callback(functools.partial(_record, cfg.held[1]), loads)
+        jax.debug.callback(functools.partial(_record, cfg), loads)
 
 
-# loads a train step left on the device, oldest first, with their experts' count
+# loads a train step left on the device, oldest first, with their mixture's
+# sizes
 _PENDING: collections.deque = collections.deque()
 
 
@@ -479,7 +547,7 @@ def record_load_when_ready(loads: jax.Array, cfg: ExpertConfig) -> None:
     counters (oldest first, by this call or a later one) once the device has
     them, so that the caller never waits for a step. ``flush_loads`` waits
     for what is left."""
-    _PENDING.append((loads, cfg.held[1]))
+    _PENDING.append((loads, cfg))
     flush_loads(wait=False)
 
 
@@ -487,8 +555,8 @@ def flush_loads(wait: bool = True) -> None:
     """Feed the queued loads to the counters, oldest first: all of them,
     waiting for the device, or with ``wait`` false those it already has."""
     while _PENDING and (wait or _PENDING[0][0].is_ready()):
-        ready, count = _PENDING.popleft()
-        _record(count, ready)
+        ready, cfg = _PENDING.popleft()
+        _record(cfg, ready)
 
 
 @functools.lru_cache(maxsize=128)

@@ -241,10 +241,12 @@ def _dense_mixture(u, router, bias, experts, cfg):
     return jnp.einsum("tc,ctd->td", mine, each)
 
 
-def _mixture_case(which, T=70):
+def _mixture_case(which, T=70, count=4):
     """A router that sends every pair to one held expert, none to a held
-    one, or spreads them, with more pairs than one chunk takes."""
-    d, cfg = 32, EXPERTS
+    one, or spreads them, with more pairs than one chunk takes; the first
+    ``count`` of the 16 experts held (all 16: the whole mixture, whose
+    forward combines by gather)."""
+    d, cfg = 32, dataclasses.replace(EXPERTS, held=(0, count))
     ks = jax.random.split(jax.random.PRNGKey(11), 6)
     u = jax.random.normal(ks[0], (T, d))
     router = jax.random.normal(ks[1], (d, 16)) / np.sqrt(d)
@@ -253,24 +255,30 @@ def _mixture_case(which, T=70):
         bias = bias.at[jnp.array([2, 9, 13])].set(10.0)
     elif which == "none":
         bias = bias.at[jnp.array([8, 9, 13])].set(10.0)
-    experts = {"wi": jax.random.normal(ks[2], (4, d, 24)) / np.sqrt(d),
-               "wg": jax.random.normal(ks[3], (4, d, 24)) / np.sqrt(d),
-               "wo": jax.random.normal(ks[4], (4, 24, d)) / np.sqrt(24)}
+    experts = {"wi": jax.random.normal(ks[2], (count, d, 24)) / np.sqrt(d),
+               "wg": jax.random.normal(ks[3], (count, d, 24)) / np.sqrt(d),
+               "wo": jax.random.normal(ks[4], (count, 24, d)) / np.sqrt(24)}
     g = jax.random.normal(ks[5], (T, d))
     return u, router, bias, experts, g, cfg
 
 
-@pytest.mark.parametrize("which", ["spread", "one", "none"])
+@pytest.mark.parametrize("which,count", [
+    ("spread", 4), ("one", 4), ("none", 4), ("spread", 16), ("one", 16)])
 @pytest.mark.parametrize("chunk", [1024, 16])
 def test_the_mixtures_backward_is_autodiff_of_a_dense_masked_mixture(
-        monkeypatch, which, chunk):
+        monkeypatch, which, count, chunk):
     """``held_experts_apply``'s ``custom_vjp`` against autodiff of the dense
     masked mixture: ``d u``, the router's gradient (through the weights, not
     the choice), the three weight gradients; with every pair on one held
     expert, on none, and with a list longer than one chunk (16 rows a
-    step: 70 tokens' held pairs take several)."""
+    step: 70 tokens' held pairs take several). With ``count == n_routed``
+    the forward combines by gather and the backward is the one that walks
+    the list from the kept operands: still one function and its gradient
+    (every pair held; "one" puts them on three experts and leaves thirteen
+    without a row)."""
     monkeypatch.setattr(expert, "CHUNK_ROWS", chunk)
-    u, router, bias, experts, g, cfg = _mixture_case(which)
+    u, router, bias, experts, g, cfg = _mixture_case(which, count=count)
+    assert cfg.all_held == (count == 16)
 
     def program(u, router, bias, experts):
         out, load = held_experts_apply(
@@ -285,7 +293,8 @@ def test_the_mixtures_backward_is_autodiff_of_a_dense_masked_mixture(
         program, argnums=(0, 1, 2, 3), has_aux=True))(u, router, bias, experts)
     want_value, want = jax.jit(jax.value_and_grad(
         dense, argnums=(0, 1, 2, 3)))(u, router, bias, experts)
-    held_pairs = {"spread": None, "one": 70, "none": 0}[which]
+    held_pairs = ({"spread": None, "one": 70, "none": 0}[which]
+                  if count == 4 else 210)
     if held_pairs is not None:
         assert int(load[0]) == held_pairs
     else:
@@ -294,10 +303,12 @@ def test_the_mixtures_backward_is_autodiff_of_a_dense_masked_mixture(
     for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         np.testing.assert_allclose(a, b, atol=3e-5)
     assert not np.any(np.asarray(got[2]))           # the bias gets none
-    if which == "one":      # experts 0, 1 and 3 saw no row: no gradient
+    if which == "one":      # the other held experts saw no row: no gradient
+        chosen = [c for c in (2, 9, 13) if c < count]
+        idle = jnp.array([c for c in range(count) if c not in chosen])
         for name in ("wi", "wg", "wo"):
-            assert not np.any(np.asarray(got[3][name][jnp.array([0, 1, 3])]))
-            assert np.any(np.asarray(got[3][name][2]))
+            assert not np.any(np.asarray(got[3][name][idle]))
+            assert all(np.any(np.asarray(got[3][name][c])) for c in chosen)
 
 
 def test_the_mixtures_backward_reads_the_stack_at_the_layers_index():
@@ -517,9 +528,9 @@ def test_the_steps_loads_reach_the_counters_without_a_call_back(tiny_step):
     seen = []
     real = expert._record
 
-    def record(count, loads):
-        seen.append((count, np.asarray(loads)))
-        return real(count, loads)
+    def record(cfg, loads):
+        seen.append((cfg, np.asarray(loads)))
+        return real(cfg, loads)
 
     try:
         expert._record = record
@@ -527,7 +538,7 @@ def test_the_steps_loads_reach_the_counters_without_a_call_back(tiny_step):
         expert.flush_loads()
     finally:
         expert._record = real
-    assert len(seen) == 1 and seen[0][0] == 4
+    assert len(seen) == 1 and seen[0][0] == TINY.experts
     np.testing.assert_array_equal(seen[0][1], metrics["moe_load"])
 
 

@@ -22,7 +22,7 @@ from ray_tpu.parallel.expert import ExpertConfig, held_experts_apply
 from ray_tpu.train.step import make_lm_train_step
 from test_mixed_stack import MIXED, _digest
 from test_shortcut_layer import TINY as TINY_LONGCAT
-from test_shortcut_layer import _init, _last_logits, _seeded
+from test_shortcut_layer import _init, _last_logits, _seeded, held
 
 # d 64, 4 query heads over 2 K/V heads of 16, 16 experts of 32, top-4, the
 # published pattern of the cut: layer 0 and two periods of layers 2-9
@@ -252,19 +252,78 @@ def _reference_mixture(u, layer, held=(0, 16)):
             {**TINY_DIMS, "held": list(held)})
 
 
-@pytest.mark.parametrize("rows", [1024, 24])
+def _steps_of_each_token(u, layer, cfg, rows):
+    """The dropless loop's step that holds each of a token's ``k`` pairs,
+    [T, k]: the pair's row in the call's list over ``rows``."""
+    idx, weights = expert.route(u, layer["router"], cfg, layer["router_bias"])
+    length = -(-idx.size // rows) * rows
+    place = expert._held_rows(idx, weights, cfg, length)[3]
+    assert int(place.max()) < idx.size          # every pair has a row
+    return np.asarray(place).reshape(idx.shape) // rows
+
+
+# (rows a step, tokens, experts every token is forced onto): 200 pairs below
+# one step of 1,024 and in one step of exactly 200 (a token's four pairs fall
+# in one step); nine steps of 24, the last of 8 rows; 25 steps of 8 (a
+# token's four pairs fall in four steps); every token on the same four
+# experts, so that each of them holds whole steps and twelve hold no row
+COMBINES = [(1024, 50, None), (200, 50, None), (24, 50, None), (8, 50, None),
+            (16, 40, (5, 0, 9, 12))]
+
+
+@pytest.mark.parametrize("rows,T,forced", COMBINES)
 def test_all_the_experts_held_and_no_zero_expert_is_the_references_mixture(
-        monkeypatch, rows):
-    """``held = (0, n_routed)``, ``n_zero = 0``, several steps of the
-    dropless loop: every pair is held, none absent, none zero."""
+        monkeypatch, rows, T, forced):
+    """``held = (0, n_routed)``, ``n_zero = 0``: every pair is held, none
+    absent, none zero, and the device combines by gather: each step writes
+    its weighed rows into the list and one gather sums a token's four, to
+    float32 rounding of the float32 reference, wherever a token's pairs
+    fall among the steps."""
     monkeypatch.setattr(expert, "CHUNK_ROWS", rows)
     layer = _mixture_layer()
-    u = jax.random.normal(jax.random.PRNGKey(11), (50, 64))
+    if forced is not None:          # the choice is on score + bias
+        layer = {**layer, "router_bias": layer["router_bias"].at[
+            jnp.array(forced)].set(10.0)}
+    u = jax.random.normal(jax.random.PRNGKey(11), (T, 64))
     got, load = jax.jit(lambda u, l: held_experts_apply(
         u, l["router"], jax.tree.map(lambda p: p[None], l["experts"]),
         EXPERTS, 0, bias=l["router_bias"]))(u, layer)
     np.testing.assert_allclose(got, _reference_mixture(u, layer), atol=2e-5)
-    assert load.tolist()[:3] == [200, 0, 0]
+    assert load.tolist()[:3] == [4 * T, 0, 0]
+    assert not forced or load[3] == T
+    steps = _steps_of_each_token(u, layer, EXPERTS, min(rows, 4 * T))
+    apart = np.array([len(set(row)) for row in steps])
+    if rows >= 4 * T:
+        assert (apart == 1).all()
+    elif rows == 8 or forced:
+        assert (apart == 4).mean() > 0.9
+    else:
+        assert steps.max() == -(-4 * T // rows) - 1 and 4 * T % rows
+
+
+@pytest.mark.parametrize("rows", [1024, 16, 7])
+def test_all_held_with_zero_compute_outputs_the_masked_pairs_add_nothing(
+        monkeypatch, rows):
+    """A mixture held whole whose router also has zero-compute outputs (12
+    routed + 4): a zero-compute pick has no row in the list, the gather
+    reads it as zeros whatever the list holds, and its ``w u`` comes from
+    the part the sum starts from. Against the plain sum of every expert on
+    every token."""
+    from test_shortcut_layer import _every_expert_on_every_token
+    monkeypatch.setattr(expert, "CHUNK_ROWS", rows)
+    cfg = dataclasses.replace(EXPERTS, n_routed=12, n_zero=4, held=(0, 12),
+                              choice_bias=False)
+    assert cfg.all_held
+    layer = _mixture_layer(24)
+    mine = jax.tree.map(lambda p: p[:12], layer["experts"])
+    u = jax.random.normal(jax.random.PRNGKey(25), (50, 64))
+    got, load = jax.jit(lambda u, r, mine: held_experts_apply(
+        u, r, jax.tree.map(lambda p: p[None], mine), cfg, 0))(
+            u, layer["router"], mine)
+    want = _every_expert_on_every_token(u, layer["router"], mine, cfg)
+    np.testing.assert_allclose(got, want, atol=2e-5)
+    n_held, n_absent, n_zero, _ = load.tolist()
+    assert n_absent == 0 and n_held + n_zero == 200 and 20 < n_zero < 120
 
 
 @pytest.mark.parametrize("count", [8, 2])
@@ -342,32 +401,55 @@ def test_the_route_span_carries_the_loops_steps(monkeypatch):
         observability, "span",
         lambda name, **attrs: seen.append((name, attrs)) or _Null())
     monkeypatch.setattr(expert, "CHUNK_ROWS", 64)
-    expert._record(16, np.array([[200, 0, 0, 20], [64, 136, 0, 9],
-                                 [0, 30, 2, 0], [65, 5, 0, 65]]))
+    share = dataclasses.replace(EXPERTS, n_routed=64, held=(8, 16))
+    expert._record(share, np.array([[200, 0, 0, 20], [64, 136, 0, 9],
+                                    [0, 30, 2, 0], [65, 5, 0, 65]]))
     (name, attrs), = seen
     # ceil(200 / 64) + ceil(64 / 64) + 0 + ceil(65 / 64)
     assert name == "moe.route" and attrs["steps"] == 4 + 1 + 0 + 2
     assert (attrs["held"], attrs["layers"], attrs["experts"]) == (329, 4, 16)
-    assert attrs["placed"] == 329
+    assert attrs["placed"] == 329 and attrs["gathered"] == 0
     # a call of fewer pairs than a step's rows takes them in one step
     seen.clear()
-    expert._record(16, np.array([[20, 4, 0, 3]]))
+    expert._record(share, np.array([[20, 4, 0, 3]]))
     assert seen[0][1]["steps"] == 1
+    # a device that holds the whole mixture steps through its list alike,
+    # and says that its pairs were combined by the gather
+    seen.clear()
+    expert._record(EXPERTS, np.array([[200, 0, 0, 20], [65, 0, 0, 65]]))
+    assert seen[0][1]["steps"] == 4 + 2
+    assert seen[0][1]["gathered"] == seen[0][1]["held"] == 265
 
 
-def test_the_route_span_says_the_pairs_were_placed(monkeypatch):
+@pytest.mark.parametrize("first,count", [(4, 8), (0, 16)])
+def test_the_route_span_says_the_pairs_were_placed(monkeypatch, first, count):
     """``placed``: the pairs the layer calls' counting pass wrote into their
     lists, through a jitted forward's own call-back: every held pair once,
     so ``placed == held`` (a share held: fewer than the routed pairs), and a
-    reader can tell a program that lists once from one that searches."""
+    reader can tell a program that lists once from one that searches.
+    ``gathered``: the pairs the gather after the loop combined, all the held
+    ones where the device holds the whole mixture and none where it holds a
+    share; the registry's ``moe_combined_pairs_total`` splits the same way.
+    ``steps`` is what the program ran: counted here by a call-back in each
+    grouped product, three a step."""
     from ray_tpu import observability
-    seen = []
+    from ray_tpu.util import metrics
+    seen, ran = [], []
     monkeypatch.setattr(
         observability, "span",
         lambda name, **attrs: seen.append((name, attrs)) or _Null())
-    cfg = dataclasses.replace(EXPERTS, held=(4, 8))
+    monkeypatch.setattr(expert, "CHUNK_ROWS", 64)
+    plain = jax.lax.ragged_dot
+
+    def counted(*args, **kwargs):
+        jax.debug.callback(lambda: ran.append(1))
+        return plain(*args, **kwargs)
+
+    monkeypatch.setattr(jax.lax, "ragged_dot", counted)
+    cfg = dataclasses.replace(EXPERTS, held=(first, count))
     layer = _mixture_layer(22)
-    mine = jax.tree.map(lambda p: p[None, 4:12], layer["experts"])
+    mine = jax.tree.map(lambda p: p[None, first:first + count],
+                        layer["experts"])
     u = jax.random.normal(jax.random.PRNGKey(23), (2, 50, 64))
 
     @jax.jit
@@ -378,15 +460,32 @@ def test_the_route_span_says_the_pairs_were_placed(monkeypatch):
         expert.record_load(jnp.stack(loads), cfg)
         return jnp.stack(outs), jnp.stack(loads)
 
+    def combined():
+        return {dict(map(tuple, tags))["by"]: v
+                for f in metrics.snapshot()
+                if f["name"] == "moe_combined_pairs_total"
+                for _, tags, v in f["samples"]}
+
+    before = combined()
     _, loads = forward(u)
     jax.effects_barrier()
     (name, attrs), = seen
     held = int(loads[:, 0].sum())
     assert name == "moe.route" and attrs["placed"] == attrs["held"] == held
-    assert 0 < held < 2 * 200 and attrs["layers"] == 2
+    assert attrs["layers"] == 2
     idx = [expert.route(x, layer["router"], cfg, layer["router_bias"])[0]
            for x in u]
-    assert held == sum(int(jnp.sum((i >= 4) & (i < 12))) for i in idx)
+    assert held == sum(int(jnp.sum((i >= first) & (i < first + count)))
+                       for i in idx)
+    assert (0 < held < 2 * 200) if count == 8 else held == 2 * 200
+    assert attrs["gathered"] == (held if cfg.all_held else 0)
+    after = combined()
+    by = {how: after[how] - before.get(how, 0)
+          for how in ("gather", "scatter_add")}
+    assert by == {"gather": attrs["gathered"],
+                  "scatter_add": held - attrs["gathered"]}
+    assert attrs["steps"] == len(ran) / 3 == sum(
+        -(-int(n) // 64) for n in loads[:, 0])
 
 
 class _Null:
@@ -635,24 +734,28 @@ def test_the_conv_mixer_runs_under_a_scope_of_its_own_at_the_layers_top(
 
 @pytest.mark.parametrize("name,cfg,want", [
     ("sala", MIXED, ("b02615aa0512187f", "8ca80f33e12a0f43")),
-    ("longcat", TINY_LONGCAT, ("08181005a897495d", "74d336bce51ce986")),
+    ("longcat", held(TINY_LONGCAT, 0, 8),
+     ("83631133cd5a9c93", "4c9b2b767d4c83d3")),
 ])
 def test_the_mixed_and_the_shortcut_programs_trace_what_the_parent_traced(
         name, cfg, want):
     """The long-document and the prefill cell's programs at the tests' tiny
     sizes: ``init_params`` and ``backbone`` + ``head`` are the jaxprs of
-    the commit before this file, to the letter (read off it by this
-    function): the router's new fields default to LongCat's router, and the
-    ``sparse`` and ``linear`` runs still scan their own slices. LongCat's
-    second digest is read off the commit that lists a call's held pairs once
-    (``expert._held_rows``' placement, one path for a share held and for all:
-    it was ``c1bc369a39beb7b8`` while a step searched for its rows) and
-    then off the commit that gave the dropless loop a backward pass (PR 48:
-    the same equations inside one ``custom_vjp_call``; it was
-    ``3a226fff0b2fd67e`` before, and the program compiled from it is the
-    parent's to the letter but for the numbering of its instructions:
-    PERF.md section 6); its ``init_params`` and the mixed stack's pair are
-    the older commit's."""
+    the parent commit, to the letter (read off it by this function): the
+    router's new fields default to LongCat's router, and the ``sparse`` and
+    ``linear`` runs still scan their own slices. **LongCat's pair is read
+    with the tiny stack cut as the cell is, a share of the routed experts
+    held (8 of 16; the cell holds 16 of 512), off commit 6fef1d7, PR 54's
+    parent**: a device that holds a share scatter-adds a step's rows as it
+    did, to the letter. Until PR 54 this case read the tiny stack with all
+    16 held (``08181005a897495d``, ``74d336bce51ce986``, the second read off
+    the commit that lists a call's held pairs once, ``expert._held_rows``,
+    and then off the one that gave the dropless loop a backward pass, PR 48:
+    the same equations inside one ``custom_vjp_call``); a stack that holds
+    every routed expert now combines by gather (``ExpertConfig.all_held``)
+    and traces another program on purpose, which
+    ``test_shortcut_layer.py::test_a_step_of_the_loop_searches_for_nothing``
+    reads. The mixed stack's pair is the older commit's."""
     key = jax.random.PRNGKey(0)
     params = jax.eval_shape(lambda k: transformer.init_params(k, cfg), key)
     tokens = jax.ShapeDtypeStruct((2, 48), jnp.int32)

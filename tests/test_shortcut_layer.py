@@ -213,10 +213,12 @@ def _alone(experts):
 
 
 @pytest.mark.parametrize("first,count,rows", [(0, 16, 1024), (4, 4, 16),
-                                              (12, 4, 7)])
+                                              (12, 4, 7), (0, 16, 16),
+                                              (0, 16, 7)])
 def test_held_experts_part_is_the_plain_sum(monkeypatch, first, count, rows):
     """Whatever the share held and however many steps the dropless loop
-    takes (``rows`` pairs a step)."""
+    takes (``rows`` pairs a step); all 16 held combine by gather, and a
+    third of their picks are zero-compute ones, which have no row."""
     monkeypatch.setattr(expert, "CHUNK_ROWS", rows)
     cfg = dataclasses.replace(EXPERTS, held=(first, count))
     router, experts = _mixture_weights(1, cfg=cfg)
@@ -441,7 +443,7 @@ def test_the_list_is_the_stable_sort_of_the_held_pairs_by_expert(case):
                               held=(first, count))
     weights = jax.random.uniform(jax.random.PRNGKey(31), (T, k)) + 0.1
     length = -(-T * k // 16) * 16
-    row_tok, row_w, bounds = jax.jit(
+    row_tok, row_w, bounds, place = jax.jit(
         lambda i, w: expert._held_rows(i, w, cfg, length))(idx, weights)
     c = np.asarray(idx).reshape(-1) - first
     mine = np.flatnonzero((c >= 0) & (c < count))       # pairs, token order
@@ -453,6 +455,10 @@ def test_the_list_is_the_stable_sort_of_the_held_pairs_by_expert(case):
                                   np.asarray(weights).reshape(-1)[order])
     np.testing.assert_array_equal(row_tok[n_held:], T)
     np.testing.assert_array_equal(row_w[n_held:], 0.0)
+    # each pair's row: the inverse of the order, ``length`` if not held
+    want_place = np.full(T * k, length)
+    want_place[order] = np.arange(n_held)
+    np.testing.assert_array_equal(place, want_place)
     np.testing.assert_array_equal(
         bounds, np.concatenate([[0], np.cumsum(np.bincount(
             c[mine], minlength=count))]))
@@ -462,6 +468,21 @@ def test_the_list_is_the_stable_sort_of_the_held_pairs_by_expert(case):
         assert n_held == T and bounds[5] == 0 and bounds[6] == T
     if case == "the held pairs fill their steps exactly":
         assert n_held == 64
+
+
+def _poisoned_products(monkeypatch):
+    """``lax.ragged_dot`` with NaN in every row that belongs to no group;
+    the list it returns collects the rows of each call."""
+    plain, calls = jax.lax.ragged_dot, []
+
+    def poison(lhs, rhs, sizes, **kwargs):
+        y = plain(lhs, rhs, sizes, **kwargs)
+        in_a_group = jnp.arange(y.shape[0]) < jnp.sum(sizes)
+        calls.append(y.shape[0])
+        return jnp.where(in_a_group[:, None], y, jnp.nan)
+
+    monkeypatch.setattr(jax.lax, "ragged_dot", poison)
+    return calls
 
 
 @pytest.mark.parametrize("rows,n_held", [(16, 40), (16, 64), (1024, 40)])
@@ -480,16 +501,29 @@ def test_rows_past_the_held_pairs_add_nothing_whatever_the_product_left(
     _, experts = _mixture_weights(4, cfg=cfg)
     want, load = held_experts_apply(u, router, _alone(experts), cfg, 0)
     assert int(load[0]) == n_held
-    plain, poisoned = jax.lax.ragged_dot, []
-
-    def poison(lhs, rhs, sizes, **kwargs):
-        y = plain(lhs, rhs, sizes, **kwargs)
-        in_a_group = jnp.arange(y.shape[0]) < jnp.sum(sizes)
-        poisoned.append(y.shape[0])
-        return jnp.where(in_a_group[:, None], y, jnp.nan)
-
-    monkeypatch.setattr(jax.lax, "ragged_dot", poison)
+    poisoned = _poisoned_products(monkeypatch)
     got, _ = held_experts_apply(u, router, _alone(experts), cfg, 0)
+    assert poisoned == [min(rows, 160)] * 3
+    np.testing.assert_array_equal(got, want)
+    assert bool(jnp.all(jnp.isfinite(got))) and float(jnp.abs(got).max()) > 0
+
+
+@pytest.mark.parametrize("rows", [16, 1024])
+def test_all_held_the_gather_reads_no_row_past_the_held_pairs(monkeypatch,
+                                                              rows):
+    """All 16 held, one pick in four a zero-compute one: 120 held pairs end
+    inside a step of 16 and inside the one step of 160, and the rows past
+    them, NaN in the list the steps write, belong to no pair: a
+    zero-compute pick is placed past the list's end and reads as zeros. The
+    sum is the same to the bit as with a clean product."""
+    monkeypatch.setattr(expert, "CHUNK_ROWS", rows)
+    router, u = _forced_router((0, 1, 17, 5))
+    _, experts = _mixture_weights(4)
+    assert EXPERTS.all_held and EXPERTS.n_zero
+    want, load = held_experts_apply(u, router, _alone(experts), EXPERTS, 0)
+    assert load.tolist() == [120, 0, 40, 40]
+    poisoned = _poisoned_products(monkeypatch)
+    got, _ = held_experts_apply(u, router, _alone(experts), EXPERTS, 0)
     assert poisoned == [min(rows, 160)] * 3
     np.testing.assert_array_equal(got, want)
     assert bool(jnp.all(jnp.isfinite(got))) and float(jnp.abs(got).max()) > 0
@@ -509,19 +543,27 @@ def test_a_step_of_the_loop_searches_for_nothing(first, count):
     dropless loop), whose body has no loop, sort or search of its own,
     gathers only the rows' tokens from ``u`` (the one gather), and is handed
     no ``[T, count]`` count: it slices the list with two ``dynamic_slice``s.
-    The list's two scatters are outside it."""
+    The list's two scatters are outside it. **How the step's rows reach the
+    sum**: a share held scatter-adds them in the step, and its layer call
+    is the parent commit's jaxpr to the letter (read off commit 6fef1d7 by
+    ``_digest``); all 16 held write them into the float32 list where they
+    lie (a ``dynamic_update_slice``, no scatter-add anywhere) and one
+    gather from that list follows the loop."""
     cfg = dataclasses.replace(EXPERTS, held=(first, count))
     router, experts = _mixture_weights(1, cfg=cfg)
     u = jax.random.normal(jax.random.PRNGKey(2), (50, 64))
-    jaxpr = jax.make_jaxpr(lambda u: held_experts_apply(
-        u, router, _alone(experts), cfg, 0))(u).jaxpr
+
+    def call(u):
+        return held_experts_apply(u, router, _alone(experts), cfg, 0)
+
+    jaxpr = jax.make_jaxpr(call)(u).jaxpr
     loops = [e for e in _eqns(jaxpr) if e.primitive.name == "while"]
     assert len(loops) == 1
     body = loops[0].params["body_jaxpr"].jaxpr
     inside = [e.primitive.name for e in _eqns(body)]
     assert not {"while", "sort", "scan", "cond", "scatter"} & set(inside)
     assert "searchsorted" not in str(body)
-    assert inside.count("gather") == 1 and inside.count("scatter-add") == 1
+    assert inside.count("gather") == 1
     assert inside.count("dynamic_slice") == 2
     assert inside.count("ragged_dot_general") == 3
     gather, = (e for e in _eqns(body) if e.primitive.name == "gather")
@@ -531,6 +573,21 @@ def test_a_step_of_the_loop_searches_for_nothing(first, count):
     assert (200,) in shapes             # the list: 200 pairs, one step's rows
     everywhere = [e.primitive.name for e in _eqns(jaxpr)]
     assert everywhere.count("scatter") == 2 and "sort" not in everywhere
+    # the step's own sizes are one ``dynamic_update_slice`` on either path
+    if cfg.all_held:
+        assert "scatter-add" not in everywhere
+        assert inside.count("dynamic_update_slice") == 2
+        assert (200, 64) in shapes      # the carried list of weighed rows
+        after = [e for e in _eqns(jaxpr) if e.primitive.name == "gather"
+                 and e.invars[0].aval.shape == (200, 64)]
+        assert len(after) == 1 and after[0].outvars[0].aval.shape == (200, 64)
+        assert after[0].invars[1].aval.shape == (200, 1)    # every pair's row
+    else:
+        assert inside.count("scatter-add") == everywhere.count(
+            "scatter-add") == 1
+        assert inside.count("dynamic_update_slice") == 1
+        assert (200, 64) not in shapes
+        assert _digest(call, u) == "0109d6b0f3f67dbd"
 
 
 # -- the layer against the reference ---------------------------------------------------
