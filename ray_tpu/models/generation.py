@@ -50,21 +50,26 @@ class TransformerGenerator:
             return (jnp.argmax(logits, axis=-1).astype(jnp.int32),
                     jnp.max(logits, axis=-1))
 
+        # a stack with a mixture hands its layers' loads out of the program
+        # (the last result: None without one), and ``_count_loads`` feeds
+        # them to the counters once the device has them: no call-back that
+        # the device would wait for, and programs the compile cache keeps
         def prefill(params, prompt, length):
-            last, piece = transformer.prefill(params, prompt, length, cfg)
+            last, piece, loads = transformer.prefill(params, prompt, length,
+                                                     cfg)
             token, logit = best(transformer.head(params, last[:, None],
                                                  cfg)[:, 0])
-            return token, logit, piece
+            return token, logit, piece, loads
 
         def insert(state, tokens, piece, token, slot):
             return (transformer.insert_state(state, piece, slot),
                     jax.lax.dynamic_update_slice(tokens, token, (slot,)))
 
         def decode_step(params, tokens, state, active):
-            logits, state = transformer.decode_step(params, tokens, state,
-                                                    cfg, active)
+            logits, state, loads = transformer.decode_step(
+                params, tokens, state, cfg, active)
             token, logit = best(logits)
-            return token, logit, state
+            return token, logit, state, loads
 
         # the state alone is donated: a step's tokens are the next step's
         # input and also what the host reads a step later
@@ -86,6 +91,20 @@ class TransformerGenerator:
                 f"a prompt of {len(prompt)} tokens and {n} new ones do not "
                 f"fit a slot's {self.cache_len} positions")
 
+    def live_rows(self, lengths: Sequence[int]) -> int:
+        """The K/V rows that sequences of these lengths hold over the
+        attention layers: a window layer keeps the last ``window`` positions
+        of each, any other every position."""
+        (n_full, *_), (n_ring, _, ring, _) = (self.state.k.shape,
+                                              self.state.ring_k.shape)
+        return sum(n_full * int(n) + n_ring * min(int(n), ring)
+                   for n in lengths)
+
+    def _count_loads(self, loads) -> None:
+        if loads is not None:
+            from ray_tpu.parallel import expert
+            expert.record_load_when_ready(loads, self.cfg.experts)
+
     def _put(self, array):
         import jax
         return jax.device_put(array, self.device)
@@ -95,16 +114,18 @@ class TransformerGenerator:
         row = np.zeros((1, bucket), np.int32)
         row[0, :len(prompt)] = prompt
         length = self._put(np.array([len(prompt)], np.int32))
-        token, logit, piece = self._prefill(self.params, self._put(row),
-                                            length)
+        token, logit, piece, loads = self._prefill(
+            self.params, self._put(row), length)
+        self._count_loads(loads)
         self.state, self.tokens = self._insert(
             self.state, self.tokens, piece, token,
             self._put(np.int32(slot)))
         return (token, logit), bucket
 
     def step(self, active: np.ndarray):
-        token, logit, self.state = self._decode_step(
+        token, logit, self.state, loads = self._decode_step(
             self.params, self.tokens, self.state, self._put(active))
+        self._count_loads(loads)
         self.tokens = token
         return token, logit
 

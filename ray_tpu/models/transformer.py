@@ -58,7 +58,8 @@ the sum where the device holds a share of the experts, as here, and where it
 holds them all (LFM2 below) writes them into a list that one gather after the
 loop sums.
 
-Five more kinds are *a token mixer and an FFN chosen apart* (LFM2, Kanana-2:
+Five more kinds are *a token mixer and an FFN chosen apart* (LFM2, Kanana-2;
+Granite's ``mamba`` and ``attn`` and SmallThinker's two below are made so too:
 ``PARTS``), served and trained on one device, and stand among each other in
 any order: ``conv`` (a gated short convolution and a dense SwiGLU FFN),
 ``conv_moe`` (the same mixer and a routed mixture), ``attn_moe`` (the dense
@@ -82,6 +83,32 @@ experts' leaves go to the mixture whole with the layer's index. ``qk_norm``
 ``lm_head`` leaf: the head reads the embedding) are fields of the dense
 attention path and of the head, not of a kind.
 
+Two more of ``PARTS``' kinds are SmallThinker's blocks, grouped-query
+attention at a ``head_dim`` of its own (``head_width``: 28 heads of 128 on a
+stream of 2,560) with the mixture as the FFN: ``window_moe`` (rotary
+positions by halves; position ``t`` attends the keys ``t - window < j <= t``:
+the flash kernel with its ``window`` in prefill) and ``global_moe`` (no
+positions, every key), whatever ``cfg.rope`` says (``_kind_attention``). Both
+are ``EARLY_ROUTED``: **the router reads the block's input** ``x`` itself,
+before the block's first norm, and its choice is handed across the attention
+to the experts, which read ``N2`` of the state after it (``_parts_block``,
+``_mixture``); an expert is a ReGLU (``ExpertConfig.activation``). Where a
+device holds every expert and a call routes more than ``LIST_PAIRS`` pairs
+the tokens take the dropless loop in equal blocks.
+
+A stack of ``DECODABLE`` kinds (``mamba``, ``attn``, ``window_moe``,
+``global_moe``) keeps a state across calls (``DecodeState``; ``prefill``,
+``insert_state``, ``decode_step``): a Mamba layer its recurrent state and its
+convolution's tail, an attention layer K and V. **There are two K/V stacks of
+different length**: ``k`` / ``v`` with a row a position up to ``cache_len``
+for the layers that attend over everything, and ``ring_k`` / ``ring_v`` for
+the window layers, a ring of ``window`` rows in which position ``p`` lives in
+row ``p % window`` (k is kept rotated, so the rows' order does not matter to
+the softmax). ``prefill`` hands over a prompt's last ``window`` positions in
+ring order, ``decode_step`` writes the token at ``lengths % window``, rotates
+q and k at each slot's own position and masks a row until the sequence has
+reached it; the mixture runs in the step on the slots' ``[S, d]`` rows.
+
 Training a stack of ``PARTS``' kinds (``loss_and_metrics`` ->
 ``_parts_states``) scans each run over its stacked leaves, so that a layer's
 weight gradient is written once; the mixture and the flash kernel at two head
@@ -94,6 +121,8 @@ bias by them (``train.step``).
 
 from __future__ import annotations
 
+import collections
+import contextlib
 import functools
 import itertools
 import math
@@ -118,9 +147,9 @@ from ray_tpu.parallel.sharding import ShardingRules
 
 # a layer's kind
 (DENSE, SPARSE, LINEAR, SHORTCUT, CONV, CONV_MOE, ATTN_MOE, LATENT,
- LATENT_MOE, MAMBA, ATTN) = KINDS = (
+ LATENT_MOE, MAMBA, ATTN, WINDOW_MOE, GLOBAL_MOE) = KINDS = (
     "dense", "sparse", "linear", "shortcut", "conv", "conv_moe", "attn_moe",
-    "latent", "latent_moe", "mamba", "attn")
+    "latent", "latent_moe", "mamba", "attn", "window_moe", "global_moe")
 # the kinds made of a token mixer and an FFN chosen apart: (mixer, FFN), each
 # the name of the layer's sub-tree; the FFN's name, ``mlp`` | ``moe``, is its
 # device scope too, and the mixer's scope is ``shortconv``, ``mamba`` or, for
@@ -128,9 +157,13 @@ from ray_tpu.parallel.sharding import ShardingRules
 PARTS = {CONV: ("shortconv", "mlp"), CONV_MOE: ("shortconv", "moe"),
          ATTN_MOE: ("attn", "moe"), LATENT: ("latent", "mlp"),
          LATENT_MOE: ("latent", "moe"), MAMBA: ("mamba", "mlp"),
-         ATTN: ("attn", "mlp")}
+         ATTN: ("attn", "mlp"), WINDOW_MOE: ("attn", "moe"),
+         GLOBAL_MOE: ("attn", "moe")}
+# the kinds whose router reads the block's input, before the mixer's norm, and
+# hands its choice across the attention to the experts (SmallThinker)
+EARLY_ROUTED = (WINDOW_MOE, GLOBAL_MOE)
 # the kinds that keep a state across calls (``prefill``, ``decode_step``)
-DECODABLE = (MAMBA, ATTN)
+DECODABLE = (MAMBA, ATTN, WINDOW_MOE, GLOBAL_MOE)
 
 
 @dataclass(frozen=True)
@@ -227,6 +260,12 @@ class TransformerConfig:
     # where that is not ``head_dim ** -0.5`` (``attention_multiplier``).
     rope: bool = True
     attn_scale: Optional[float] = None
+    # A head's width where it is not ``d_model // n_heads`` (published
+    # ``head_dim``): ``cfg.head_dim`` reads it.
+    head_width: Optional[int] = None
+    # A ``WINDOW_MOE`` layer's reach: position ``t`` attends the keys ``t -
+    # window < j <= t`` (published ``sliding_window_size``).
+    window: Optional[int] = None
     # A ``MAMBA`` layer's mixer.
     mamba: Optional[MambaConfig] = None
     # Training: steps over which ``train.step``'s default optimizer raises its
@@ -256,6 +295,9 @@ class TransformerConfig:
                 f"layer_kinds {kinds}: the kinds {sorted(PARTS)} stand "
                 "among each other only (their runs scan a stack they close "
                 "over)")
+        if WINDOW_MOE in kinds and not self.window:
+            raise ValueError(
+                f"layer_kinds {kinds}: a {WINDOW_MOE!r} layer needs window=")
         if MAMBA in kinds and self.mamba is None:
             raise ValueError(
                 f"layer_kinds {kinds}: a {MAMBA!r} layer needs mamba= "
@@ -289,7 +331,7 @@ class TransformerConfig:
 
     @property
     def head_dim(self) -> int:
-        return self.d_model // self.n_heads
+        return self.head_width or self.d_model // self.n_heads
 
     @property
     def kv_heads(self) -> int:
@@ -678,14 +720,21 @@ def _repeat_kv(q, k, v):
 
 
 def _attention(q, k, v, cfg: TransformerConfig, mesh: Optional[Mesh],
-               rules: Optional[ShardingRules] = None):
+               rules: Optional[ShardingRules] = None,
+               window: Optional[int] = None):
     """``mesh`` is the mesh of the enclosing jit, or None when the caller
     already runs per device (one chip, or inside a ``shard_map``). ``k`` and
     ``v`` carry ``cfg.kv_heads`` heads: the flash kernel takes them so, the
-    other two paths repeat them first."""
+    other two paths repeat them first. ``window``: a position attends the
+    ``window`` last keys, itself among them (on one device only)."""
+    if window is not None and mesh is not None:
+        raise ValueError("attention through a window runs on one device "
+                         "(neither the ring nor the sharded kernel has one)")
     if mesh is not None and "seq" in mesh.axis_names and mesh.shape["seq"] > 1:
         return ring_attention(q, *_repeat_kv(q, k, v), mesh, causal=True)
     if cfg.use_flash:
+        if window is not None:
+            return flash_attention(q, k, v, causal=True, window=window)
         if mesh is None:
             return flash_attention(q, k, v, causal=True)
         # batch over the rules' batch axes, heads over tensor
@@ -702,15 +751,52 @@ def _attention(q, k, v, cfg: TransformerConfig, mesh: Optional[Mesh],
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) / math.sqrt(D)
     L, Lk = q.shape[1], k.shape[1]
     mask = jnp.tril(jnp.ones((L, Lk), bool))
+    if window is not None:
+        mask = mask & ~jnp.tril(jnp.ones((L, Lk), bool), -window)
     s = jnp.where(mask[None, None], s, -1e30)
     p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
     return jnp.einsum("bhqk,bkhd->bqhd", p, v)
 
 
-def _attention_qkv(attn, h, positions, cfg: TransformerConfig):
+def _kind_attention(cfg: TransformerConfig, kind: str
+                    ) -> Tuple[bool, Optional[int]]:
+    """``(rotary positions, window)`` of a kind's grouped-query attention: a
+    ``WINDOW_MOE`` layer rotates and reaches ``cfg.window`` keys back, a
+    ``GLOBAL_MOE`` layer does neither, and any other kind is as ``cfg.rope``
+    says, over everything."""
+    if kind == WINDOW_MOE:
+        return True, cfg.window
+    if kind == GLOBAL_MOE:
+        return False, None
+    return cfg.rope, None
+
+
+def _kind_scope(kind: str):
+    """The scope inside ``attn`` that tells a kind's attention from the
+    others': ``swa``, ``nope``, or none (the names are literals: the
+    registry's test reads the source)."""
+    if kind == WINDOW_MOE:
+        return jax.named_scope("swa")
+    if kind == GLOBAL_MOE:
+        return jax.named_scope("nope")
+    return contextlib.nullcontext()
+
+
+def _early_choice(kind: str, x, router, cfg: TransformerConfig):
+    """An ``EARLY_ROUTED`` kind's choice ``(idx, weights)`` from the block's
+    input rows ``x`` [T, d], before any norm; None for any other kind."""
+    if kind not in EARLY_ROUTED:
+        return None
+    with jax.named_scope("moe"):
+        return expert.route(x, router(), cfg.experts)
+
+
+def _attention_qkv(attn, h, positions, cfg: TransformerConfig,
+                   rope: Optional[bool] = None):
     """q, k and v of grouped-query attention from the normed states ``h`` [B,
     L, d]: the projections, with ``cfg.qk_norm`` an RMSNorm of each head of q
-    and k, rotary positions (by halves; none where ``cfg.rope`` is False), and
+    and k, rotary positions (by halves; none where ``rope``, by default
+    ``cfg.rope``, is False), and
     with ``cfg.attn_scale`` q times ``attn_scale * sqrt(head_dim)``, so that
     the attention's own ``head_dim ** -0.5`` leaves the published scale."""
     q = jnp.einsum("bld,dhk->blhk", h, attn["wq"].astype(h.dtype))
@@ -719,7 +805,7 @@ def _attention_qkv(attn, h, positions, cfg: TransformerConfig):
     if cfg.qk_norm:
         q = _rmsnorm(q, attn["q_norm"], cfg.norm_eps)
         k = _rmsnorm(k, attn["k_norm"], cfg.norm_eps)
-    if cfg.rope:
+    if cfg.rope if rope is None else rope:
         q = _rope(q, cfg.rope_theta, positions)
         k = _rope(k, cfg.rope_theta, positions)
     if cfg.attn_scale is not None:
@@ -728,25 +814,40 @@ def _attention_qkv(attn, h, positions, cfg: TransformerConfig):
 
 
 def _attention_mixer(attn, h, positions, cfg: TransformerConfig, mesh,
-                     rules=None, kept: Optional[list] = None):
+                     rules=None, kept: Optional[list] = None,
+                     rope: Optional[bool] = None,
+                     window: Optional[int] = None):
     """Grouped-query attention on the normed states ``h`` [B, L, d]:
-    ``_attention_qkv``, causal softmax attention (``core``) and ``W_o``.
-    ``kept``, a list, is handed k and v (what a decode loop keeps). The
-    caller enters the ``attn`` scope."""
-    q, k, v = _attention_qkv(attn, h, positions, cfg)
+    ``_attention_qkv``, causal softmax attention (``core``; through
+    ``window`` where given) and ``W_o``. ``kept``, a list, is handed k (as
+    rotated) and v (what a decode loop keeps). The caller enters the ``attn``
+    scope."""
+    q, k, v = _attention_qkv(attn, h, positions, cfg, rope)
     if kept is not None:
         kept.extend((k, v))
     with jax.named_scope("core"):
-        o = _attention(q, k, v, cfg, mesh, rules)
+        o = _attention(q, k, v, cfg, mesh, rules, window)
     return jnp.einsum("blhk,hkd->bld", o, attn["wo"].astype(h.dtype))
 
 
+def _ring_row(positions, rows: int):
+    """The row of a ring of ``rows`` rows that holds position ``p``: ``p %
+    rows``."""
+    return positions % rows
+
+
 def _attention_step(attn, h, cfg: TransformerConfig, k_cache, v_cache, l,
-                    lengths):
+                    lengths, rope: Optional[bool] = None, ring: bool = False):
     """One token a slot in attention layer ``l`` of the stacked caches: ``h``
     [S, d] normed states, every layer's K and V [n, S, T, kv_heads x head_dim]
     (a position's heads side by side: one row) and how many positions each
-    slot holds. The token's k and v are written as a row at ``(l, slot,
+    slot holds. q and k are rotated at each slot's own position (``rope``,
+    by default ``cfg.rope``). With ``ring`` the T rows are a window layer's
+    ring: position ``p`` lives in row ``p % T`` (k is kept rotated, so the
+    rows' order does not matter to the softmax), the token overwrites the
+    position ``T`` before it, and a row counts once the sequence has reached
+    it (every row from position ``T - 1`` on), which is the window ``t - T <
+    j <= t`` itself. The token's k and v are written as a row at ``(l, slot,
     position)`` of the stack itself (a scatter of S rows, in place: written
     into the layer's slice and the slice written back, the compiler moved the
     whole slice three times) and the query reads the slot's cache up to and
@@ -759,8 +860,9 @@ def _attention_step(attn, h, cfg: TransformerConfig, k_cache, v_cache, l,
     bytes. Returns the mixer's output and the two stacks."""
     _, S, T, _ = k_cache.shape
     H, G, D = cfg.n_heads, cfg.kv_heads, cfg.head_dim
-    q, k, v = _attention_qkv(attn, h[:, None], lengths[:, None], cfg)
-    at = jnp.minimum(lengths, T - 1)
+    q, k, v = _attention_qkv(attn, h[:, None], lengths[:, None], cfg, rope)
+    newest = jnp.minimum(lengths, T - 1)
+    at = _ring_row(lengths, T) if ring else newest
     slot = jnp.arange(S)
     k_cache = k_cache.at[l, slot, at].set(
         k[:, 0].reshape(S, G * D).astype(k_cache.dtype))
@@ -775,7 +877,7 @@ def _attention_step(attn, h, cfg: TransformerConfig, k_cache, v_cache, l,
                        jax.lax.dynamic_index_in_dim(k_cache, l, 0, False),
                        preferred_element_type=jnp.float32)
         s = s / math.sqrt(D)
-        seen = jnp.arange(T)[None] <= at[:, None]               # [S, T]
+        seen = jnp.arange(T)[None] <= newest[:, None]           # [S, T]
         p = jax.nn.softmax(jnp.where(seen[:, None], s, -1e30), axis=-1)
         o = jnp.einsum("sht,stc->shc", p.astype(h.dtype),
                        jax.lax.dynamic_index_in_dim(v_cache, l, 0, False))
@@ -1110,6 +1212,8 @@ def _parts_block(stack, l, x, positions, cfg: TransformerConfig, kind: str,
     def scaled(out):        # muP's residual factor; at 1 the old program
         return out if cfg.residual_scale == 1.0 else cfg.residual_scale * out
 
+    routed = _early_choice(kind, x.reshape(B * L, d),
+                           lambda: part("router"), cfg)
     # a scope's name is a literal (the registry's test reads the source)
     with (jax.named_scope("shortconv") if mixer == "shortconv"
           else jax.named_scope("mamba") if mixer == "mamba"
@@ -1119,8 +1223,11 @@ def _parts_block(stack, l, x, positions, cfg: TransformerConfig, kind: str,
             x = x + scaled(_latent_attention(part("latent"), h, positions,
                                              cfg))
         elif mixer == "attn":
-            x = x + scaled(_attention_mixer(part("attn"), h, positions, cfg,
-                                            None, kept=kept))
+            rope, window = _kind_attention(cfg, kind)
+            with _kind_scope(kind):
+                x = x + scaled(_attention_mixer(
+                    part("attn"), h, positions, cfg, None, kept=kept,
+                    rope=rope, window=window))
         elif mixer == "mamba":
             x = x + scaled(_mamba_mixer(part("mamba"), h, cfg, lengths, kept))
         else:
@@ -1128,22 +1235,65 @@ def _parts_block(stack, l, x, positions, cfg: TransformerConfig, kind: str,
     if feed == "mlp":
         with jax.named_scope("mlp"):
             return x + scaled(_mlp(part("mlp"), norm(x, part("ln2")))), None
-    e = cfg.experts
+    s, load, counts = _mixture(stack, l, x, routed, cfg)
     with jax.named_scope("moe"):
-        u = norm(x, part("ln2")).reshape(B * L, d)
+        return x + scaled(s.reshape(B, L, d)), (load, counts)
+
+
+# The most routed pairs one call of the dropless loop takes where the device
+# holds every expert: the call's weighed rows are a float32 list [pairs, d]
+# that one gather reads again (two arrays of 0.76 GB each at 12,288 tokens of
+# SmallThinker's 6 x 2,560). A longer call's tokens go through the loop in
+# equal blocks of at most so many pairs, one after another (LFM2's longest,
+# 8,192 x 4, is one block).
+LIST_PAIRS = 32768
+
+
+def _mixture(stack, l, x, routed, cfg: TransformerConfig):
+    """The routed mixture of layer ``l`` of ``stack`` on the states ``x`` [B,
+    L, d] that the FFN's norm reads, taken as ``T = B x L`` rows: ``sum_j w_j
+    Expert_j(N2(x))`` and the shared experts where the configuration has
+    them. ``routed`` is the choice ``(idx, weights)`` an ``EARLY_ROUTED``
+    kind made from the block's input, or None: the router then reads
+    ``N2(x)``. Returns the sum [T, d], the layer's load and how often each
+    routed expert was chosen."""
+    e = cfg.experts
+    B, L, d = x.shape
+
+    def part(name):
+        return jax.tree.map(lambda p: _at(p, l), stack[name])
+
+    with jax.named_scope("moe"):
+        u = _rmsnorm(x, part("ln2"), cfg.norm_eps).reshape(B * L, d)
         # a router without a bias is called as it always was (callers that
         # stand a router of their own in ``route``'s place take three)
-        idx, weights = (
+        idx, weights = routed if routed is not None else (
             expert.route(u, part("router"), e, part("router_bias"))
             if e.choice_bias else expert.route(u, part("router"), e))
-        s, load = expert.held_pairs_apply(u, idx, weights, stack["experts"],
-                                          e, l)
+        blocks = e.all_held and idx.size > LIST_PAIRS
+        if blocks:
+            T, k = idx.shape
+            n = next(n for n in range(-(-idx.size // LIST_PAIRS), T + 1)
+                     if T % n == 0)
+            s, loads = jax.lax.map(
+                lambda at: expert.held_pairs_apply(*at, stack["experts"], e,
+                                                   l),
+                (u.reshape(n, T // n, d), idx.reshape(n, T // n, k),
+                 weights.reshape(n, T // n, k)))
+            s = s.reshape(T, d)
+        else:
+            s, load = expert.held_pairs_apply(u, idx, weights,
+                                              stack["experts"], e, l)
         counts = expert.choice_counts(idx, e)
+        if blocks:
+            # every pair is held: the call's most-loaded expert is the one
+            # most chosen
+            load = jnp.concatenate([jnp.sum(loads[:, :3], axis=0),
+                                    jnp.max(counts)[None]])
     if e.shared_width:
         with jax.named_scope("mlp"):    # the tokens as rows, like the mixture
             s = s + _mlp(part("shared"), u[None])[0]
-    with jax.named_scope("moe"):
-        return x + scaled(s.reshape(B, L, d)), (load, counts)
+    return s, load, counts
 
 
 def _parts_runs(cfg: TransformerConfig):
@@ -1504,13 +1654,28 @@ class DecodeState(NamedTuple):
     S, heads, head_dim, d_state] float32 and convolution tail ``conv``
     [n_mamba, S, conv_width - 1, conv_dim], the attention layers' ``k`` and
     ``v`` [n_attn, S, T, kv_heads x head_dim] (a position's heads side by
-    side, one row of the cache), both in the compute dtype, and
-    ``lengths`` [S], the positions each sequence holds."""
+    side, one row of the cache), both in the compute dtype,
+    ``lengths`` [S], the positions each sequence holds, and the window
+    layers' ``ring_k`` and ``ring_v`` [n_window, S, W, kv_heads x head_dim]:
+    a ring of ``W = min(window, T)`` rows in which position ``p`` lives in row
+    ``p % W`` (``_attention_step``). ``k`` and ``v`` are the layers' that
+    attend over everything (``ATTN``, ``GLOBAL_MOE``). A stack without a kind
+    of layer holds an empty array in its place."""
     ssm: jax.Array
     conv: jax.Array
     k: jax.Array
     v: jax.Array
     lengths: jax.Array
+    ring_k: jax.Array
+    ring_v: jax.Array
+
+
+def _cache_of(kind: str) -> str:
+    """Which of ``DecodeState``'s stacks a ``DECODABLE`` kind's layer keeps
+    its share in: ``state`` (``ssm`` and ``conv``), ``full`` (``k`` and
+    ``v``) or ``ring`` (``ring_k`` and ``ring_v``)."""
+    return ("state" if kind == MAMBA else "ring" if kind == WINDOW_MOE
+            else "full")
 
 
 def _decodable(cfg: TransformerConfig) -> None:
@@ -1524,63 +1689,95 @@ def init_decode_state(cfg: TransformerConfig, slots: int,
                       cache_len: int) -> DecodeState:
     """``slots`` empty sequences with room for ``cache_len`` positions."""
     _decodable(cfg)
-    n_mamba, n_attn = cfg.kinds.count(MAMBA), cfg.kinds.count(ATTN)
+    n = collections.Counter(_cache_of(kind) for kind in cfg.kinds)
+    n_mamba = n["state"]
     m = cfg.mamba or MambaConfig(0, 0, 0)
-    kv = (n_attn, slots, cache_len, cfg.kv_heads * cfg.head_dim)
+    row = cfg.kv_heads * cfg.head_dim
+    kv = (n["full"], slots, cache_len, row)
+    ring = (n["ring"], slots, min(cfg.window or 0, cache_len), row)
     return DecodeState(
         ssm=jnp.zeros((n_mamba, slots, m.n_heads, m.head_dim, m.d_state),
                       jnp.float32),
         conv=jnp.zeros((n_mamba, slots, m.conv_width - 1, m.conv_dim),
                        cfg.dtype),
         k=jnp.zeros(kv, cfg.dtype), v=jnp.zeros(kv, cfg.dtype),
-        lengths=jnp.zeros((slots,), jnp.int32))
+        lengths=jnp.zeros((slots,), jnp.int32),
+        ring_k=jnp.zeros(ring, cfg.dtype), ring_v=jnp.zeros(ring, cfg.dtype))
+
+
+def _joined_loads(loads: list) -> Optional[jax.Array]:
+    """The mixture layers' loads of one program (a list of ``[n, 4]``, a run
+    each) as one array ``[n_moe, 4]``, ``None`` without a mixture: a result
+    of the program, which the caller feeds to the counters once the device
+    has it (``expert.record_load_when_ready``). A call-back of the program's
+    own would have the device wait for the host (2.2 ms a decode step on the
+    v5e: PERF.md section 6, PR 55)."""
+    return jnp.concatenate(loads) if loads else None
 
 
 def prefill(params: Dict[str, Any], tokens: jax.Array, lengths: jax.Array,
-            cfg: TransformerConfig) -> Tuple[jax.Array, DecodeState]:
+            cfg: TransformerConfig
+            ) -> Tuple[jax.Array, DecodeState, Optional[jax.Array]]:
     """The right-padded prompts ``tokens`` [B, L] of ``lengths`` [B] through
     the stack: each prompt's pre-final-norm state at its last real position
     [B, d] (``head`` makes the first token's logits of it) and what the B
     sequences keep, a ``DecodeState`` of B slots whose K and V hold L
-    positions. Each run of layers is a scan over its indices that hands out
-    what the layers keep (one prompt's is small: 2 MB a Mamba layer)."""
+    positions; a window layer's are handed over as its ring, the prompt's
+    last ``W`` positions each in row ``p % W`` (all ``L`` in order where ``L
+    <= W``). Each run of layers is a scan over its indices that hands out
+    what the layers keep (one prompt's is small: 2 MB a Mamba layer). The
+    third result is the mixtures' loads (``_joined_loads``)."""
     _decodable(cfg)
     B, L = tokens.shape
     x = _embed(params, tokens, cfg)
     positions = jnp.broadcast_to(jnp.arange(L)[None], (B, L))
     blocks = params["blocks"]
-    kept_by_kind: Dict[str, list] = {MAMBA: [], ATTN: []}
+    kept_by_cache: Dict[str, list] = {"state": [], "full": [], "ring": []}
+    run_loads = []
     for kind, start, n in _parts_runs(cfg):
         def layer(x, l, kind=kind):
             kept: list = []
-            x, _ = _parts_block(blocks[kind], l, x, positions, cfg, kind,
-                                lengths, kept)
-            return x, tuple(kept)
+            x, load = _parts_block(blocks[kind], l, x, positions, cfg, kind,
+                                   lengths, kept)
+            return x, (tuple(kept), None if load is None else load[0])
 
-        x, kept = jax.lax.scan(layer, x, start + jnp.arange(n))
-        kept_by_kind[kind].append(kept)
+        x, (kept, load) = jax.lax.scan(layer, x, start + jnp.arange(n))
+        kept_by_cache[_cache_of(kind)].append(kept)
+        if load is not None:
+            run_loads.append(load)
     empty = init_decode_state(cfg, B, L)
+    rows = empty.ring_k.shape[2]
 
-    def joined(kind, i, otherwise):
-        runs = kept_by_kind[kind]
+    def joined(cache, i, otherwise):
+        runs = kept_by_cache[cache]
         if not runs:
             return otherwise
         whole = jnp.concatenate([run[i] for run in runs])
-        if kind == ATTN:    # [n, B, L, kv_heads, head_dim]: a row a position
+        if cache != "state":  # [n, B, L, kv_heads, head_dim]: a row a position
             whole = whole.reshape(*whole.shape[:3], -1)
+        if cache == "ring" and rows < L:
+            # row r: the last position p < length with p % rows == r (a row
+            # the prompt has not reached holds whatever: its age masks it)
+            r = jnp.arange(rows)[None]
+            p = r + rows * ((lengths[:, None] - 1 - r) // rows)
+            whole = jnp.take_along_axis(
+                whole, jnp.clip(p, 0, L - 1)[None, :, :, None], axis=2)
         return whole.astype(otherwise.dtype)
 
     last = jnp.take_along_axis(x, (lengths - 1)[:, None, None], axis=1)[:, 0]
     return last, DecodeState(
-        ssm=joined(MAMBA, 0, empty.ssm), conv=joined(MAMBA, 1, empty.conv),
-        k=joined(ATTN, 0, empty.k), v=joined(ATTN, 1, empty.v),
-        lengths=lengths.astype(jnp.int32))
+        ssm=joined("state", 0, empty.ssm), conv=joined("state", 1, empty.conv),
+        k=joined("full", 0, empty.k), v=joined("full", 1, empty.v),
+        lengths=lengths.astype(jnp.int32),
+        ring_k=joined("ring", 0, empty.ring_k),
+        ring_v=joined("ring", 1, empty.ring_v)), _joined_loads(run_loads)
 
 
 def insert_state(state: DecodeState, piece: DecodeState, slot
                  ) -> DecodeState:
-    """``piece`` (a ``prefill`` of one or more sequences, its K and V no
-    longer than the slots') written into ``state`` from slot ``slot`` on: a
+    """``piece`` (a ``prefill`` of one or more sequences, its K and V and
+    its rings no longer than the slots') written into ``state`` from slot
+    ``slot`` on: a
     ``dynamic_update_slice`` a leaf, in place where ``state`` is donated.
     Every other slot keeps its bits."""
     def put(whole, part):
@@ -1592,15 +1789,18 @@ def insert_state(state: DecodeState, piece: DecodeState, slot
         ssm=put(state.ssm, piece.ssm), conv=put(state.conv, piece.conv),
         k=put(state.k, piece.k), v=put(state.v, piece.v),
         lengths=jax.lax.dynamic_update_slice(state.lengths, piece.lengths,
-                                             (slot,)))
+                                             (slot,)),
+        ring_k=put(state.ring_k, piece.ring_k),
+        ring_v=put(state.ring_v, piece.ring_v))
 
 
 def decode_step(params: Dict[str, Any], tokens: jax.Array,
                 state: DecodeState, cfg: TransformerConfig,
                 active: Optional[jax.Array] = None
-                ) -> Tuple[jax.Array, DecodeState]:
+                ) -> Tuple[jax.Array, DecodeState, Optional[jax.Array]]:
     """One token for every slot: ``tokens`` [S] -> float32 logits [S, V] of
-    the next, and the state with the token taken in. ``active`` [S] (all, if
+    the next, the state with the token taken in and the mixtures' loads
+    (``_joined_loads``). ``active`` [S] (all, if
     None) says which slots hold a sequence: only their ``lengths`` advance
     (an empty slot computes on whatever it holds, so the program has one
     shape). Each run of layers is a loop over its indices with the state as
@@ -1609,22 +1809,34 @@ def decode_step(params: Dict[str, Any], tokens: jax.Array,
     state is updated in place (as ``xs`` and ``ys`` of a scan it would be held
     twice). With ``cfg.use_flash`` a Mamba layer's recurrence is a Mosaic call
     that takes the whole stack of states and the layer's index and is its
-    own output: it moves that layer's bytes alone, once in and once out."""
+    own output: it moves that layer's bytes alone, once in and once out. An
+    attention layer reads and writes its own stack: a window layer its ring,
+    any other the full cache, each a masked product over the rows the stack
+    has (no kernel). A mixture runs on the slots' [S, d] rows as it runs on a
+    prompt's (``_mixture``: the dropless loop at ``S x top_k`` pairs), routed
+    from the block's input where the kind says so (``EARLY_ROUTED``)."""
     _decodable(cfg)
     norm = functools.partial(_rmsnorm, eps=cfg.norm_eps)
     blocks = params["blocks"]
     r = cfg.residual_scale
     x = _embed(params, tokens[:, None], cfg)[:, 0]              # [S, d]
-    ssm, conv, k_cache, v_cache, lengths = state
+    ssm, conv, k_cache, v_cache, lengths, ring_k, ring_v = state
+    taken = collections.Counter()   # a cache's layers in the runs before
+    step_loads = []
 
     for kind, start, n in _parts_runs(cfg):
         def part(name, l, kind=kind):
             return jax.tree.map(lambda p: _at(p, l), blocks[kind][name])
 
-        def ffn(x, l, part=part):
-            with jax.named_scope("mlp"):
-                return x + r * _mlp(part("mlp", l),
-                                    norm(x, part("ln2", l))[None])[0]
+        def ffn(x, l, routed=None, part=part, kind=kind):
+            """``(x + r FFN(N2(x)), the mixture's load or None)``."""
+            if PARTS[kind][1] == "mlp":
+                with jax.named_scope("mlp"):
+                    return x + r * _mlp(part("mlp", l),
+                                        norm(x, part("ln2", l))[None])[0], None
+            s, load, _ = _mixture(blocks[kind], l, x[None], routed, cfg)
+            with jax.named_scope("moe"):
+                return x + r * s, load
 
         def mamba_layer(i, carry, start=start, part=part, ffn=ffn):
             x, ssm, conv = carry
@@ -1634,27 +1846,42 @@ def decode_step(params: Dict[str, Any], tokens: jax.Array,
                     part("mamba", l), norm(x, part("ln1", l)), cfg, ssm,
                     conv, l)
                 x = x + r * out
-            return ffn(x, l), ssm, conv
+            return ffn(x, l)[0], ssm, conv
 
-        def attn_layer(i, carry, start=start, part=part, ffn=ffn):
-            x, k_cache, v_cache = carry
+        def attn_layer(i, carry, start=start, part=part, ffn=ffn, kind=kind,
+                       first=taken[_cache_of(kind)]):
+            x, k_cache, v_cache, loads = carry
             l = start + i
-            with jax.named_scope("attn"):
+            routed = _early_choice(kind, x, lambda: part("router", l), cfg)
+            rope, window = _kind_attention(cfg, kind)
+            with jax.named_scope("attn"), _kind_scope(kind):
                 out, k_cache, v_cache = _attention_step(
                     part("attn", l), norm(x, part("ln1", l)), cfg, k_cache,
-                    v_cache, l, lengths)
+                    v_cache, first + i, lengths, rope, window is not None)
                 x = x + r * out
-            return ffn(x, l), k_cache, v_cache
+            x, load = ffn(x, l, routed)
+            if load is not None:
+                loads = loads.at[i].set(load)
+            return x, k_cache, v_cache, loads
 
+        run_loads = (None if PARTS[kind][1] == "mlp"
+                     else jnp.zeros((n, 4), jnp.int32))
         if kind == MAMBA:
             x, ssm, conv = jax.lax.fori_loop(0, n, mamba_layer,
                                              (x, ssm, conv))
+        elif _cache_of(kind) == "ring":
+            x, ring_k, ring_v, run_loads = jax.lax.fori_loop(
+                0, n, attn_layer, (x, ring_k, ring_v, run_loads))
         else:
-            x, k_cache, v_cache = jax.lax.fori_loop(
-                0, n, attn_layer, (x, k_cache, v_cache))
+            x, k_cache, v_cache, run_loads = jax.lax.fori_loop(
+                0, n, attn_layer, (x, k_cache, v_cache, run_loads))
+        taken[_cache_of(kind)] += n
+        if run_loads is not None:
+            step_loads.append(run_loads)
     step = 1 if active is None else active.astype(lengths.dtype)
     logits = head(params, x[:, None], cfg)[:, 0]
-    return logits, DecodeState(ssm, conv, k_cache, v_cache, lengths + step)
+    return logits, DecodeState(ssm, conv, k_cache, v_cache, lengths + step,
+                               ring_k, ring_v), _joined_loads(step_loads)
 
 
 def _exit_nll(x, w_head, targets):

@@ -166,6 +166,15 @@ LATER_DEVICE_SCOPES = frozenset({
     # chunked scan in prefill, its one-token step in decode), the gated norm,
     # out-projection and the residual's add
     "mamba",
+    # inside ``attn``, round a layer's grouped-query attention (projections,
+    # rotation, ``core``, output projection and the residual's add): ``swa``
+    # a ``window_moe`` layer's (rotary positions, the window: the flash kernel
+    # with its window in prefill, the masked product over the ring in decode),
+    # ``nope`` a ``global_moe`` layer's (no positions, every key). No jaxpr
+    # primitive's path token is either (``window`` would match
+    # ``reduce_window``'s)
+    "swa",
+    "nope",
 })
 
 # The gauge a replica sets once, when its constructor returns.

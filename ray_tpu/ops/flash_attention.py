@@ -22,6 +22,14 @@ that costs the kernels 0.3-0.6%, the VPU having slack under the MXU, where
 a second, unmasked body for interior tiles cost every compiled shape 0.16 s
 more of tracing, and a ``lax.cond`` round the mask 50-70% of kernel time.
 
+A window. ``flash_attention(..., window=W)`` (causal only) lets position
+``t`` attend the keys ``t - W < j <= t``: ``_masked`` drops what lies before,
+``_kv_walk`` starts a query tile's walk at the first tile its first row's
+window reaches, as it stops it at the diagonal, and a grid step before that
+names the first block the tile needs, so nothing is copied for it. Forward
+only (serving's prefill): the call is a ``custom_vjp`` of its own whose
+gradient raises (the backward walks would take the same bounds).
+
 What is float32. Operands go to the MXU in the arrays' own dtype (bfloat16
 in, bfloat16 products accumulated in float32; float32 in, float32 dots).
 Scores, the running max and normaliser, lse, delta and the three
@@ -164,11 +172,13 @@ def _compiler_params(vmem: int, grid_rank: int):
 
 
 def _masked(s, q_axis: int, row0, col0, causal: bool,
-            seq_q: Optional[int], seq_k: Optional[int]):
+            seq_q: Optional[int], seq_k: Optional[int],
+            window: Optional[int] = None):
     """Scores ``s`` with NEG_INF where they are not attended. ``s``'s
     ``q_axis`` runs over query rows from ``row0``, the other axis over key
     columns from ``col0``; ``seq_q`` / ``seq_k`` are given only where that
-    side has a ragged end. A compare and a select for each condition,
+    side has a ragged end; with ``window`` a row ``t`` attends the columns
+    ``t - window < j`` alone. A compare and a select for each condition,
     against iotas that do not depend on the tile; nothing where the call
     has neither a diagonal nor a ragged end."""
     rows = jax.lax.broadcasted_iota(jnp.int32, s.shape, q_axis)
@@ -176,6 +186,8 @@ def _masked(s, q_axis: int, row0, col0, causal: bool,
     keep = []
     if causal:
         keep.append(rows - cols >= col0 - row0)
+    if window is not None:
+        keep.append(rows - cols < window + col0 - row0)
     if seq_k is not None:
         keep.append(cols < seq_k - col0)
     if seq_q is not None:
@@ -222,19 +234,27 @@ def _ragged(length: int, block: int) -> Optional[int]:
 # --------------------------------------------------------------------------- #
 
 
-def _kv_walk(iq, jk, *, causal, block_q, block_k, tiles, seq_k):
+def _window_start(iq, block_q, window):
+    """The first key a query tile's first row sees through ``window``."""
+    return jnp.maximum(iq * block_q - window + 1, 0)
+
+
+def _kv_walk(iq, jk, *, causal, block_q, block_k, tiles, seq_k, window=None):
     """The K/V tiles a query tile needs out of major block ``jk``, as global
-    tile numbers [lo, hi)."""
-    lo = jk * tiles
+    tile numbers [lo, hi), and the block's first tile (a tile's rows in the
+    block held count from there)."""
+    first = lo = jk * tiles
     hi = jnp.minimum(lo + tiles, pl.cdiv(seq_k, block_k))
     if causal:  # tiles wholly above the diagonal contribute nothing
         hi = jnp.minimum(hi, ((iq + 1) * block_q + block_k - 1) // block_k)
-    return lo, hi
+    if window is not None:  # nor those wholly before the window's reach
+        lo = jnp.maximum(lo, _window_start(iq, block_q, window) // block_k)
+    return first, lo, hi
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
                 *, scale: float, causal: bool, block_q: int, block_k: int,
-                tiles: int, seq_k: int):
+                tiles: int, seq_k: int, window: Optional[int] = None):
     iq, jk = pl.program_id(1), pl.program_id(2)
     Dv = v_ref.shape[-1]           # the accumulator's and the output's width
 
@@ -246,20 +266,25 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
 
     row0 = iq * block_q
     ragged_k = _ragged(seq_k, block_k)
-    lo, hi = _kv_walk(iq, jk, causal=causal, block_q=block_q,
-                      block_k=block_k, tiles=tiles, seq_k=seq_k)
+    first, lo, hi = _kv_walk(iq, jk, causal=causal, block_q=block_q,
+                             block_k=block_k, tiles=tiles, seq_k=seq_k,
+                             window=window)
 
     def tile(t):
         col0 = t * block_k
-        rows = pl.ds(pl.multiple_of((t - lo) * block_k, block_k), block_k)
+        rows = pl.ds(pl.multiple_of((t - first) * block_k, block_k), block_k)
         q = q_ref[0]                                          # [bq, d]
         k = k_ref[0, rows, :]                                 # [bk, d]
         v = _zero_pad_rows(v_ref[0, rows, :], col0, ragged_k)  # [bk, dv]
         s = _dot(q, k, _NT) * scale                           # [bq, bk]
-        s = _masked(s, 0, row0, col0, causal, None, ragged_k)
+        s = _masked(s, 0, row0, col0, causal, None, ragged_k, window)
         # Every row meets an attended column in the first tile it walks
         # (column 0 under the causal mask), so m is finite from there on and
-        # exp(NEG_INF - m) is an exact 0: p needs no second mask.
+        # exp(NEG_INF - m) is an exact 0: p needs no second mask. (Under a
+        # window a tile's later rows may see nothing of the first tiles
+        # walked: m stays NEG_INF there, p is exp(0) and what it adds is
+        # multiplied by alpha = exp(NEG_INF - m) = 0 at the first tile that
+        # holds a column the row attends, its own diagonal at the latest.)
         m_prev, l_prev = m_scr[...], l_scr[...]               # [bq, 128]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
@@ -280,11 +305,14 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
         lse_ref[0] = m_scr[:, :1] + jnp.log(l[:, :1])         # [bq, 1]
 
 
-def _query_stationary(blocks: _Blocks, q, k, causal: bool):
+def _query_stationary(blocks: _Blocks, q, k, causal: bool,
+                      window: Optional[int] = None):
     """The layout the forward and dq share: a query tile stays, ``major``
     rows of K and V stream past it. Returns the kernels' tile arguments, the
     VMEM estimate, the grid and the makers of a q-shaped and a K/V operand's
-    spec at a head width (q's and k's, or v's and o's)."""
+    spec at a head width (q's and k's, or v's and o's). With ``window`` (the
+    forward alone) the major blocks before a query tile's reach are not
+    copied either."""
     BH, Lq, D = q.shape
     Lk = k.shape[1]
     group = BH // k.shape[0]
@@ -303,20 +331,28 @@ def _query_stationary(blocks: _Blocks, q, k, causal: bool):
 
     # Query head b reads K/V head b // group; a step past the diagonal
     # names the block already held, so the pipeline copies nothing for it.
+    def held(i, j):     # the major block step (i, j) holds
+        j = jnp.minimum(j, last(i))
+        if window is None:
+            return j
+        return jnp.maximum(j, _window_start(i, block_q, window) // major)
+
     def kv_spec(width):
         return pl.BlockSpec(
-            (1, major, width),
-            lambda b, i, j: (b // group, jnp.minimum(j, last(i)), 0))
+            (1, major, width), lambda b, i, j: (b // group, held(i, j), 0))
 
     args = dict(causal=causal, block_q=block_q, block_k=block_k, tiles=tiles,
                 seq_k=Lk)
     return args, vmem, (BH, nq, nk), q_spec, kv_spec
 
 
-def _flash_fwd(q, k, v, scale, causal, blocks, interpret):
+def _flash_fwd(q, k, v, scale, causal, blocks, interpret, window=None):
     BH, Lq, D = q.shape
     Dv = v.shape[-1]
-    args, vmem, grid, q_spec, kv_spec = _query_stationary(blocks, q, k, causal)
+    args, vmem, grid, q_spec, kv_spec = _query_stationary(blocks, q, k,
+                                                          causal, window)
+    if window is not None:
+        args["window"] = window
     block_q = args["block_q"]
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, **args),
@@ -357,12 +393,12 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 
     row0 = iq * block_q
     ragged_k = _ragged(seq_k, block_k)
-    lo, hi = _kv_walk(iq, jk, causal=causal, block_q=block_q,
-                      block_k=block_k, tiles=tiles, seq_k=seq_k)
+    first, lo, hi = _kv_walk(iq, jk, causal=causal, block_q=block_q,
+                             block_k=block_k, tiles=tiles, seq_k=seq_k)
 
     def tile(t):
         col0 = t * block_k
-        rows = pl.ds(pl.multiple_of((t - lo) * block_k, block_k), block_k)
+        rows = pl.ds(pl.multiple_of((t - first) * block_k, block_k), block_k)
         q, do = q_ref[0], do_ref[0]                           # [bq, d]
         k = _zero_pad_rows(k_ref[0, rows, :], col0, ragged_k)  # [bk, d]
         v = _zero_pad_rows(v_ref[0, rows, :], col0, ragged_k)
@@ -512,6 +548,26 @@ def _flash_attention_bhld(q, k, v, scale, causal, blocks, interpret):
     return out
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash_window_bhld(q, k, v, scale, window, blocks, interpret):
+    """The causal forward through a window. Its backward walk would take the
+    same bounds (``_kv_walk``'s for dq, their mirror for dk/dv); nothing
+    trains a window yet, so differentiating it is refused."""
+    out, _ = _flash_fwd(q, k, v, scale, True, blocks, interpret, window)
+    return out
+
+
+def _no_window_backward(*_):
+    raise NotImplementedError(
+        "flash_attention: a window has no backward pass (flash_dq and "
+        "flash_dkv walk the causal triangle whole); train without the "
+        "kernel (use_flash=False) or give the backward walk the window's "
+        "bounds")
+
+
+_flash_window_bhld.defvjp(_no_window_backward, _no_window_backward)
+
+
 def _fwd_rule(q, k, v, scale, causal, blocks, interpret):
     # the named arrays are both the primal and the residuals: once a policy
     # keeps them, nothing reads a recomputed call's results
@@ -528,14 +584,18 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None,
                     block_major: Optional[int] = None,
-                    interpret: Optional[bool] = None) -> jax.Array:
+                    interpret: Optional[bool] = None,
+                    window: Optional[int] = None) -> jax.Array:
     """Flash attention. q: [batch, seqlen, heads, head_dim]; k:
     [batch, seqlen_k, kv_heads, head_dim] and v: [batch, seqlen_k, kv_heads,
     v_dim] with ``heads`` a multiple of ``kv_heads`` (query head h attends
     K/V head ``h // (heads // kv_heads)``).
 
     Returns [batch, seqlen, heads, v_dim]. Differentiable (custom VJP),
-    whatever ``v_dim`` is.
+    whatever ``v_dim`` is. ``window`` (causal only): position ``t`` attends
+    the keys ``t - window < j <= t``; the walk starts at the first tile the
+    window reaches, as it stops at the diagonal. Forward only: its gradient
+    raises.
     ``block_q x block_k`` is the score tile; ``block_major`` is how many rows
     of the streamed side (K/V in the forward and dq, q/dO in dk/dv) a grid
     step holds in VMEM. ``None`` = chosen from the shape.
@@ -547,6 +607,9 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
             f"flash_attention: q {q.shape} over K {k.shape} / V {v.shape}: K "
             "has q's head width, V has K's batch, length and heads (its head "
             "width is its own) and their heads divide the query's")
+    if window is not None and not (causal and window >= 1):
+        raise ValueError(f"flash_attention: window={window} needs causal "
+                         "attention and at least one key a row")
     Dv = v.shape[-1]
     scale = scale if scale is not None else 1.0 / math.sqrt(D)
     if interpret is None:
@@ -555,7 +618,11 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     qb = q.transpose(0, 2, 1, 3).reshape(B * H, Lq, D)
     kb = k.transpose(0, 2, 1, 3).reshape(B * KVH, Lk, D)
     vb = v.transpose(0, 2, 1, 3).reshape(B * KVH, Lk, Dv)
-    out = _flash_attention_bhld(qb, kb, vb, scale, causal,
-                                _Blocks(block_q, block_k, block_major),
-                                interpret)
+    blocks = _Blocks(block_q, block_k, block_major)
+    if window is None:
+        out = _flash_attention_bhld(qb, kb, vb, scale, causal, blocks,
+                                    interpret)
+    else:
+        out = _flash_window_bhld(qb, kb, vb, scale, int(window), blocks,
+                                 interpret)
     return out.reshape(B, H, Lq, Dv).transpose(0, 2, 1, 3)

@@ -92,10 +92,16 @@ class ExpertConfig:
     # (the layer's ``shared`` leaf), which every token takes on every device
     # alike; 0: none. The model computes it (``models/transformer.py``).
     shared_width: int = 0
+    # A routed expert's gate: ``act(x W_i) * (x W_g)``, ``silu`` (SwiGLU) or
+    # ``relu`` (ReGLU: SmallThinker's sparse experts).
+    activation: str = "silu"
 
     def __post_init__(self):
         if self.score not in ("softmax", "sigmoid"):
             raise ValueError(f"score {self.score!r}: 'softmax' or 'sigmoid'")
+        if self.activation not in ("silu", "relu"):
+            raise ValueError(
+                f"activation {self.activation!r}: 'silu' or 'relu'")
 
     @property
     def n_outputs(self) -> int:
@@ -204,13 +210,16 @@ def _chunks(rows, count, n_groups, layer, row_tok, row_w, bounds):
     return chunk
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _held_sum(rows, out, u, row_w, w, row_tok, bounds, layer, place):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _held_sum(rows, activation, out, u, row_w, w, row_tok, bounds, layer,
+              place):
     """``out + sum_s row_w[s] Expert_{e(s)}(u[row_tok[s]])`` over the listed
     pairs (``_held_rows``), ``rows`` of the list a step, for as many steps as
     the held pairs fill: the dropless loop. ``w``: the grouped weights
     ``wi``, ``wg`` [groups, d, width] and ``wo`` [groups, width, d], of which
-    ``layer``'s ``count = len(bounds) - 1`` groups are meant. float32 [T, d].
+    ``layer``'s ``count = len(bounds) - 1`` groups are meant; an expert is
+    ``(act(x wi) * (x wg)) wo``, ``act`` the ``activation`` (``silu`` |
+    ``relu``). float32 [T, d].
 
     How a step's weighed products reach their tokens' sums is ``place``'s to
     say. ``None`` (a device that holds a share of the experts: few of a
@@ -231,6 +240,7 @@ def _held_sum(rows, out, u, row_w, w, row_tok, bounds, layer, place):
     chunk = _chunks(rows, count, w["wi"].shape[0], layer, row_tok, row_w,
                     bounds)
     steps = (bounds[count] + rows - 1) // rows
+    act = jax.nn.silu if activation == "silu" else jax.nn.relu
 
     def weighed(i):
         tok, wt, sizes = chunk(i)
@@ -239,7 +249,7 @@ def _held_sum(rows, out, u, row_w, w, row_tok, bounds, layer, place):
         # there no sum reads (the scatter-add drops it, the list's gather
         # has no pair placed there)
         x = u.at[tok].get(mode="clip")
-        hidden = (jax.nn.silu(product(x, w["wi"], sizes))
+        hidden = (act(product(x, w["wi"], sizes))
                   * product(x, w["wg"], sizes))
         y = product(hidden, w["wo"], sizes,
                     preferred_element_type=jnp.float32)
@@ -268,12 +278,14 @@ def _held_sum(rows, out, u, row_w, w, row_tok, bounds, layer, place):
     return out + jnp.sum(picked.reshape(-1, T, d), axis=0)
 
 
-def _held_sum_fwd(rows, out, u, row_w, w, row_tok, bounds, layer, place):
-    return (_held_sum(rows, out, u, row_w, w, row_tok, bounds, layer, place),
+def _held_sum_fwd(rows, activation, out, u, row_w, w, row_tok, bounds, layer,
+                  place):
+    return (_held_sum(rows, activation, out, u, row_w, w, row_tok, bounds,
+                      layer, place),
             (u, row_w, w, row_tok, bounds, layer))
 
 
-def _held_sum_bwd(rows, kept, g):
+def _held_sum_bwd(rows, activation, kept, g):
     """The dropless loop backwards, a step of the same list at a time: the
     step's rows gathered again and the two first products made again; ``g``'s
     rows against ``wo^T`` (``d hidden`` before the pair's weight, whose
@@ -313,7 +325,9 @@ def _held_sum_bwd(rows, kept, g):
         x = u.at[tok].get(mode="clip")
         a = jnp.where(held, product(x, w["wi"], sizes), 0.0)
         b = jnp.where(held, product(x, w["wg"], sizes), 0.0)
-        gate = jax.nn.sigmoid(a)
+        # act(a) = a * gate: silu's sigmoid, or relu's step (its own slope)
+        gate = (jax.nn.sigmoid(a) if activation == "silu"
+                else (a > 0).astype(a.dtype))
         hidden = a * gate * b
         d_y = jnp.where(held, g.at[tok].get(mode="clip"), 0.0)
         # d hidden for a pair of weight 1
@@ -321,7 +335,10 @@ def _held_sum_bwd(rows, kept, g):
                         0.0)
         d_wt = jnp.sum(d_h * hidden, axis=-1)
         d_h = d_h * wt[:, None]
-        d_a = (d_h * b * gate * (1.0 + a * (1.0 - gate))).astype(u.dtype)
+        d_a = d_h * b * gate
+        if activation == "silu":
+            d_a = d_a * (1.0 + a * (1.0 - gate))
+        d_a = d_a.astype(u.dtype)
         d_b = (d_h * a * gate).astype(u.dtype)
         d_x = (product(d_a, w_t["wi"], sizes)
                + product(d_b, w_t["wg"], sizes))
@@ -388,8 +405,8 @@ def held_pairs_apply(u: jax.Array, idx: jax.Array, weights: jax.Array,
         w = {name: p.reshape(n * count, *p.shape[2:])
              for name, p in experts.items()}
         with jax.named_scope("experts"):
-            out = _held_sum(rows, out, u, row_w, w, row_tok, bounds, layer,
-                            place if cfg.all_held else None)
+            out = _held_sum(rows, cfg.activation, out, u, row_w, w, row_tok,
+                            bounds, layer, place if cfg.all_held else None)
         n_zero = jnp.sum(zero, dtype=jnp.int32)
         load = jnp.stack([n_held, idx.size - n_held - n_zero, n_zero,
                           jnp.max(bounds[1:] - bounds[:-1])])
@@ -404,7 +421,8 @@ def held_experts_apply(u: jax.Array, router: jax.Array,
     ``sum_j w_tj Expert_e(u_t)`` over the chosen experts e that are held
     here, plus ``sum_j w_tj u_t`` over the chosen zero-compute indices
     (``e >= n_routed``, identity). ``experts``: ``wi``, ``wg`` [n, count, d,
-    width] and ``wo`` [n, count, width, d] (SwiGLU), the held experts of a
+    width] and ``wo`` [n, count, width, d] (SwiGLU, or ReGLU where
+    ``cfg.activation`` is ``relu``), the held experts of a
     stack's ``n`` layers (one layer's own leaves: ``p[None]``, layer 0), in
     ``u``'s dtype; ``layer``, an index, traced or not, says whose are meant;
     ``bias``, the router's bias for the choice where ``cfg.choice_bias``.

@@ -25,6 +25,10 @@ module knows no model):
 ``read(handle) -> (tokens, logits)``
     Wait for a handle's arrays and bring them to the host.
 
+Two more are optional: ``check(prompt, n)`` raises for a request the model
+cannot hold, and ``live_rows(lengths)`` says how many K/V rows sequences of
+these lengths hold (a step's span carries it as ``live_rows``).
+
 The engine's loop: while a slot is free and a request waits, admit it; then
 one step over all slots; then read the step *before* (the host reads a
 step's tokens while the next runs); an answer whose last token was read is
@@ -256,7 +260,9 @@ class GenerationEngine:
     def _step(self) -> None:
         active = np.zeros((self.slots,), bool)
         active[list(self._running)] = True
-        takers = [(slot, entry[0]) for slot, entry in self._running.items()]
+        # each taker with the steps it has left, this one among them
+        takers = [(slot, request, left)
+                  for slot, (request, left) in self._running.items()]
         finished = 0
         for slot, entry in list(self._running.items()):
             entry[1] -= 1
@@ -265,8 +271,14 @@ class GenerationEngine:
                 self._free.append(slot)
                 finished += 1
         with observability.span("serve.generate.step", cat="serve",
-                                active=len(takers), finished=finished):
+                                active=len(takers), finished=finished) as sp:
             handle = self._model.step(active)
+            live_rows = getattr(self._model, "live_rows", None)
+            if sp.live and live_rows is not None:
+                # what each holds with the token this step takes in: its
+                # prompt, the steps it has taken and one
+                sp.set(live_rows=int(live_rows(
+                    [len(r.prompt) + r.n - left for _, r, left in takers])))
         self._metrics.steps.inc(tags=self._tags)
         self._metrics.tokens.inc(len(takers),
                                  tags={**self._tags, "phase": "decode"})
@@ -276,7 +288,7 @@ class GenerationEngine:
             self._occupied = len(takers)
 
         def deliver(tokens, logits):
-            for slot, request in takers:
+            for slot, request, _ in takers:
                 self._take(request, int(tokens[slot]), float(logits[slot]))
 
         self._unread.append((handle, deliver))

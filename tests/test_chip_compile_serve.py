@@ -265,13 +265,11 @@ def test_the_expert_load_cells_forward_compiles_and_copies_no_weight(
 # -- the generating cell: a decode step and a prefill beside the slots' state ------
 
 
-@pytest.fixture(scope="module")
-def generating(on_chip, mosaic):
-    """``granite4h-serve-chat``'s three programs as
+def _generating(on_chip, name):
+    """A generating cell's three programs as
     ``models.generation.TransformerGenerator`` jits them, at the cell's own
-    sizes (64 slots of 1,408 positions, the whole model), compiled once for
-    the module: the decode step, the longest prefill and its insert."""
-    cell, adapter, dims = cell_dims("granite4h-serve-chat")
+    sizes: the decode step, the longest prefill and its insert."""
+    cell, adapter, dims = cell_dims(name)
     opts = cell.deploy["deployment"]
     slots, cache = int(opts["slots"]), int(opts["cache_len"])
     longest = max(opts["length_buckets"])
@@ -287,16 +285,16 @@ def generating(on_chip, mosaic):
         lambda: transformer.init_decode_state(cfg, 1, longest)))
 
     def decode_step(params, tokens, state, active):
-        logits, state = transformer.decode_step(params, tokens, state, cfg,
-                                                active)
+        logits, state, loads = transformer.decode_step(params, tokens, state,
+                                                       cfg, active)
         return (jnp.argmax(logits, -1).astype(jnp.int32),
-                jnp.max(logits, -1), state)
+                jnp.max(logits, -1), state, loads)
 
     def prefill(params, prompt, length):
-        last, piece = transformer.prefill(params, prompt, length, cfg)
+        last, piece, loads = transformer.prefill(params, prompt, length, cfg)
         logits = transformer.head(params, last[:, None], cfg)[:, 0]
         return (jnp.argmax(logits, -1).astype(jnp.int32),
-                jnp.max(logits, -1), piece)
+                jnp.max(logits, -1), piece, loads)
 
     def insert(state, tokens, piece, token, slot):
         return (transformer.insert_state(state, piece, slot),
@@ -314,6 +312,21 @@ def generating(on_chip, mosaic):
         "insert": jax.jit(insert, donate_argnums=(0,)).lower(
             state, on_chip((slots,), jnp.int32), piece,
             on_chip((1,), jnp.int32), on_chip((), jnp.int32)).compile()}
+
+
+@pytest.fixture(scope="module")
+def generating(on_chip, mosaic):
+    """``granite4h-serve-chat``'s programs (64 slots of 1,408 positions, the
+    whole model), compiled once for the module."""
+    return _generating(on_chip, "granite4h-serve-chat")
+
+
+@pytest.fixture(scope="module")
+def generating_kv(on_chip, mosaic):
+    """``smallthinker-serve-mixed``'s programs (48 slots of two caches,
+    13,312 rows and a ring of 4,096; 8 layers of 64 experts), compiled once
+    for the module."""
+    return _generating(on_chip, "smallthinker-serve-mixed")
 
 
 def _tree_bytes(tree):
@@ -401,3 +414,54 @@ def test_the_longest_prefill_fits_beside_the_resident_state(generating):
           f"temporaries {m.temp_size_in_bytes / 1e9:.3f} GB")
     read = generating["cell"].config["reduced"]["generate.1"]["slots_read"]
     assert f"{beside:.2f} GB" in read
+
+
+# -- the second generating cell: two caches of different length and a mixture -------
+
+
+def test_the_slots_rule_of_the_two_caches_holds_at_48_slots(generating_kv):
+    """``smallthinker-serve-mixed``'s rule: the decode step with the weights
+    (7.93 GB) and the state (5.03 GB: 48 slots of 2 x 13,312 + 6 x 4,096
+    rows), the state aliased with the one it returns, and the [1, 12288]
+    prefill beside the resident state, each 15.0 GB or less; the
+    configuration's file holds both numbers as read here."""
+    g = generating_kv
+    assert g["slots"] == 48
+    assert g["bytes"]["params"] == pytest.approx(7.934e9, rel=1e-3)
+    assert g["bytes"]["state"] == pytest.approx(5.033e9, rel=1e-3)
+    step, prefill = g["decode_step"], g["prefill"]
+    m = step.memory_analysis()
+    assert m.alias_size_in_bytes >= g["bytes"]["state"]
+    assert m.temp_size_in_bytes < 0.5e9
+    assert _held_gb(step) <= 15.0
+    beside = _held_gb(prefill) + g["bytes"]["state"] / 1e9
+    assert beside <= 15.0
+    insert = g["insert"].memory_analysis()
+    assert insert.alias_size_in_bytes >= g["bytes"]["state"]
+    print(f"decode step at 48 slots: {_held_gb(step):.3f} GB, temporaries "
+          f"{m.temp_size_in_bytes / 1e9:.3f} GB; prefill [1, 12288] beside "
+          f"the state: {beside:.3f} GB, temporaries "
+          f"{prefill.memory_analysis().temp_size_in_bytes / 1e9:.3f} GB")
+    read = g["cell"].config["reduced"]["generate_kv.1"]["slots_read"]
+    assert f"{_held_gb(step):.2f} GB" in read
+    assert f"{beside:.2f} GB" in read
+
+
+def test_the_windows_prefill_runs_the_kernel_and_the_step_writes_in_place(
+        generating_kv):
+    """The compiled prefill holds the flash kernel (with its window in six
+    layers of eight) and the grouped products' Mosaic calls; the compiled
+    step holds no kernel for its attention (a masked product over the rows a
+    stack has) and both caches are its own outputs."""
+    prefill = generating_kv["prefill"].as_text()
+    step = generating_kv["decode_step"].as_text()
+    def calls(text, name):      # Mosaic calls by their own name
+        return [line for line in text.splitlines()
+                if " custom-call(" in line and KERNEL in line
+                and re.search(rf"\s%?{name}[.\d]* = ", line)]
+
+    # one compiled body a run of layers: global, window, global, window
+    assert len(calls(prefill, "flash_fwd")) == 4
+    assert not calls(step, "flash_fwd") and "ragged-dot" in step
+    for stack in ("bf16[2,48,13312,512]", "bf16[6,48,4096,512]"):
+        assert stack in step

@@ -25,10 +25,10 @@ def run():
         "cfg": cfg, "params": params, "tokens": tokens,
         "full": transformer.apply(params, tokens, cfg),
         "prefill": jax.jit(lambda t, n: transformer.prefill(params, t, n,
-                                                            cfg)),
+                                                            cfg)[:2]),
         "insert": jax.jit(transformer.insert_state),
         "step": jax.jit(lambda t, s, a: transformer.decode_step(
-            params, t, s, cfg, a)),
+            params, t, s, cfg, a)[:2]),
         "empty": transformer.init_decode_state(cfg, SLOTS, CACHE)}
 
 
@@ -118,7 +118,7 @@ def test_the_kernels_step_is_the_plain_step(run):
     cfg = tiny.config(use_flash=True)
     assert cfg.use_flash and not run["cfg"].use_flash
     kernels = jax.jit(lambda t, s, a: transformer.decode_step(
-        run["params"], t, s, cfg, a))
+        run["params"], t, s, cfg, a)[:2])
     _, piece = _prefilled(run, range(3))
     plain = with_kernels = run["insert"](run["empty"], piece, 1)
     active = jnp.array([False, True, True, True, False])

@@ -73,7 +73,7 @@ def _trained(cfg, length):
 
 
 def test_the_scopes_are_single_tokens_and_no_host_spans_name():
-    assert len(DEVICE_SCOPES) == 9 and len(LATER_DEVICE_SCOPES) == 2
+    assert len(DEVICE_SCOPES) == 9 and len(LATER_DEVICE_SCOPES) == 4
     assert all(re.fullmatch(r"[a-z]+", s)
                for s in DEVICE_SCOPES | LATER_DEVICE_SCOPES)
     assert not (DEVICE_SCOPES | LATER_DEVICE_SCOPES) & SPANS

@@ -101,22 +101,22 @@ def test_lengths_leave_a_padded_prompts_state_at_its_last_real_position():
     positions."""
     cfg, params = tiny.config(), tiny.params()
     tokens = jax.random.randint(jax.random.PRNGKey(2), (1, 16), 0, 64)
-    _, padded = transformer.prefill(params, tokens, jnp.array([11]), cfg)
-    _, alone = transformer.prefill(params, tokens[:, :11], jnp.array([11]),
-                                   cfg)
+    _, padded, _ = transformer.prefill(params, tokens, jnp.array([11]), cfg)
+    _, alone, _ = transformer.prefill(params, tokens[:, :11],
+                                      jnp.array([11]), cfg)
     np.testing.assert_allclose(padded.ssm, alone.ssm, rtol=1e-5, atol=1e-7)
     np.testing.assert_allclose(padded.conv, alone.conv, rtol=1e-5, atol=1e-7)
     np.testing.assert_allclose(padded.k[:, :, :11], alone.k, rtol=1e-5,
                                atol=1e-7)
     # without lengths the padding would have run through the state
-    _, through = transformer.prefill(params, tokens, jnp.array([16]), cfg)
+    _, through, _ = transformer.prefill(params, tokens, jnp.array([16]), cfg)
     assert np.abs(np.asarray(through.ssm - alone.ssm)).max() > 1e-3
 
 
 def test_the_kinds_guards_and_the_scope():
     assert transformer.PARTS[MAMBA] == ("mamba", "mlp")
     assert transformer.PARTS[ATTN] == ("attn", "mlp")
-    assert transformer.DECODABLE == (MAMBA, ATTN)
+    assert transformer.DECODABLE[:2] == (MAMBA, ATTN)
     with pytest.raises(ValueError, match="needs mamba="):
         TransformerConfig(n_layers=1, layer_kinds=(MAMBA,))
     with pytest.raises(ValueError, match="among each other only"):

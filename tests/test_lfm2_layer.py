@@ -623,10 +623,11 @@ def test_the_tied_head_is_the_embedding_transposed(params):
 # -- the configuration's guards ------------------------------------------------------
 
 
-def test_the_kinds_are_eleven_and_the_new_ones_are_a_mixer_and_an_ffn():
-    assert len(KINDS) == 11 and set(PARTS) == {
+def test_the_kinds_are_thirteen_and_the_new_ones_are_a_mixer_and_an_ffn():
+    assert len(KINDS) == 13 and set(PARTS) == {
         CONV, CONV_MOE, ATTN_MOE, transformer.LATENT, transformer.LATENT_MOE,
-        transformer.MAMBA, transformer.ATTN}
+        transformer.MAMBA, transformer.ATTN, transformer.WINDOW_MOE,
+        transformer.GLOBAL_MOE}
     assert {PARTS[k] for k in PARTS} == {("shortconv", "mlp"),
                                          ("shortconv", "moe"),
                                          ("attn", "moe"), ("latent", "mlp"),
@@ -713,7 +714,8 @@ def test_the_conv_mixer_runs_under_a_scope_of_its_own_at_the_layers_top(
     """``shortconv`` is the first scope on the path of the mixer's
     operations (not inside ``attn``), it is declared, and no accepted scope
     was renamed for it."""
-    assert metric_names.LATER_DEVICE_SCOPES == {"shortconv", "mamba"}
+    assert metric_names.LATER_DEVICE_SCOPES == {"shortconv", "mamba", "swa",
+                                                "nope"}
     assert not metric_names.LATER_DEVICE_SCOPES & metric_names.DEVICE_SCOPES
     tokens = jnp.zeros((1, 16), jnp.int32)
     text = jax.jit(lambda p, t: transformer.backbone(p, t, TINY)).lower(

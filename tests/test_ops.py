@@ -226,3 +226,46 @@ def test_flash_tiles_from_the_shape(Lq, Lk, D, itemsize, stream_q):
     assert 1 <= n and n * tile <= length
     assert 4 * n * tile * D * itemsize <= fa._STREAM_BYTES
     assert vmem < 64 * 2 ** 20
+
+
+def _windowed_reference(q, k, v, window):
+    """Plain masked attention through a window that counts the token itself."""
+    rep = q.shape[2] // k.shape[2]
+    k, v = jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(q.shape[-1])
+    t = jnp.arange(q.shape[1])
+    seen = (t[:, None] >= t[None]) & (t[:, None] - t[None] < window)
+    p = jax.nn.softmax(jnp.where(seen, s, -1e30), axis=-1)
+    return jnp.einsum("bhqk,bkhd->bqhd", p, v)
+
+
+@pytest.mark.parametrize("length,window,blocks", [
+    (300, 100, dict(block_q=128, block_k=128, block_major=128)),
+    (300, 100, dict(block_q=128, block_k=128, block_major=256)),
+    (512, 130, dict(block_q=128, block_k=128)),
+    (257, 8, dict(block_q=128, block_k=128, block_major=128)),
+    (640, 300, dict(block_q=256, block_k=128, block_major=256)),
+    (384, 1000, {}),
+])
+def test_flash_attention_through_a_window(length, window, blocks):
+    """The forward with a window that is no multiple of the block against the
+    plain masked product (interpreted): the walk starts at the first tile the
+    window reaches, whatever the major block, and a window longer than the
+    sequence is causal attention."""
+    keys = jax.random.split(jax.random.PRNGKey(length + window), 3)
+    q = jax.random.normal(keys[0], (2, length, 4, 32))
+    k, v = (jax.random.normal(key, (2, length, 2, 32)) for key in keys[1:])
+    out = flash_attention(q, k, v, window=window, **blocks)
+    np.testing.assert_allclose(out, _windowed_reference(q, k, v, window),
+                               atol=3e-6)
+    if window >= length:
+        np.testing.assert_array_equal(
+            out, flash_attention(q, k, v, causal=True, **blocks))
+
+
+def test_flash_attention_refuses_a_windows_gradient_and_a_window_uncaused():
+    q = jnp.ones((1, 128, 2, 32))
+    with pytest.raises(NotImplementedError, match="no backward pass"):
+        jax.grad(lambda q: flash_attention(q, q, q, window=8).sum())(q)
+    with pytest.raises(ValueError, match="needs causal"):
+        flash_attention(q, q, q, causal=False, window=8)
