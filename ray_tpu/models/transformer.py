@@ -1812,9 +1812,12 @@ def decode_step(params: Dict[str, Any], tokens: jax.Array,
     own output: it moves that layer's bytes alone, once in and once out. An
     attention layer reads and writes its own stack: a window layer its ring,
     any other the full cache, each a masked product over the rows the stack
-    has (no kernel). A mixture runs on the slots' [S, d] rows as it runs on a
-    prompt's (``_mixture``: the dropless loop at ``S x top_k`` pairs), routed
-    from the block's input where the kind says so (``EARLY_ROUTED``)."""
+    has (no kernel). A mixture runs on the slots' [S, d] rows through the
+    call a prompt's tokens take (``_mixture``), routed from the block's input
+    where the kind says so (``EARLY_ROUTED``): where the device holds every
+    expert and S is a row tile or fewer, one Mosaic call a layer that streams
+    the layer's experts past the S rows once (``expert._streams``,
+    ``ops.expert_stream``); else the dropless loop at ``S x top_k`` pairs."""
     _decodable(cfg)
     norm = functools.partial(_rmsnorm, eps=cfg.norm_eps)
     blocks = params["blocks"]

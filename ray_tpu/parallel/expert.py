@@ -19,16 +19,28 @@ weighed rows reach their tokens' sums depends on what the device holds
 scatter-adds them, a step at a time, into the sum the loop carries (few of a
 token's pairs have a row there); a device that holds every expert writes
 them into a float32 list where they lie, and one gather after the loop sums
-each token's ``k`` rows. What the absent experts would add is left out: on
-one device the layer runs without its exchange, and the partial sum is what
-goes on. The model's stack hands it the experts of all its layers and the
-layer's index: they are read as groups of the stacked leaves, not cut out.
+each token's ``k`` rows. A third way lists nothing: where the device holds
+every expert and a call brings one row tile of the MXU or fewer tokens
+(``STREAM_ROWS``: a decode step's row a slot) with as many pairs as there are
+experts, every expert is streamed past all the rows once, in one Mosaic call
+that sums the products the router chose (``ops.expert_stream``): at 4.5 rows
+an expert the call is bound by the experts' bytes, a weight tile in the MXU
+takes 48 rows for the price of one, and the three grouped products with their
+list, gathers and scatters read the weights at 59% of the chip's bandwidth
+where the one call reads them at 92% (PERF.md section 6, PR 56). Which way a
+call takes is read off its shapes and ``cfg`` (``_streams``). What the absent
+experts would add is left out: on one device the layer runs without its
+exchange, and the partial sum is what goes on. The model's stack hands it the
+experts of all its layers and the layer's index: they are read as groups of
+the stacked leaves (the streamed way: by the call's index maps), not cut out.
 
 It trains too: the dropless loop has a backward pass of its own
 (``_held_sum``, a ``custom_vjp``: the loop's trip count is traced) that
 walks the same list a chunk at a time from the kept operands, whichever way
 the forward combined (its ``d u`` is a scatter-add a step on every device: no
-cell trains a mixture held whole), and the router differentiates as
+cell trains a mixture held whole; the streamed forward's list is made for the
+backward alone, and a program that takes no gradient drops it), and the
+router differentiates as
 plain JAX, through its weights and not through its choice. A router's
 ``choice_bias`` is no parameter: ``choice_counts`` counts a call's choices
 and ``moved_bias`` moves the bias by them, outside the gradient
@@ -57,9 +69,15 @@ import numpy as np
 from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
+from ray_tpu.ops.expert_stream import experts_streamed
+
 # Rows of routed (token, expert) pairs one step of the dropless loop gathers,
 # multiplies and scatters: two row tiles of the TPU's ragged product.
 CHUNK_ROWS = 1024
+# The most tokens a call may bring for its mixture to be streamed
+# (``_streams``): one row tile of the MXU, which multiplies so many rows by a
+# weight tile for the price of one.
+STREAM_ROWS = 128
 # What ``normalize`` adds to the chosen weights' sum before it divides by it.
 NORM_EPS = 1e-6
 # The spread of a drawn ``choice_bias`` (``transformer.init_params``): a
@@ -121,6 +139,16 @@ def _precision(dtype) -> jax.lax.Precision:
     context asks for (the TPU's ragged product refuses any other)."""
     return (jax.lax.Precision.HIGHEST if jnp.dtype(dtype).itemsize >= 4
             else jax.lax.Precision.DEFAULT)
+
+
+def _streams(cfg: ExpertConfig, T: int) -> bool:
+    """Whether a call of ``T`` tokens streams every expert past its rows
+    (``ops.expert_stream``) and lists nothing: the device holds them all, the
+    rows are one MXU row tile or fewer, and the call's pairs are as many as
+    the experts (below that most experts have no row, and the dropless loop,
+    which does not read an empty group, reads less)."""
+    return (cfg.all_held and T <= STREAM_ROWS
+            and T * cfg.top_k >= cfg.held[1])
 
 
 def route(u: jax.Array, router: jax.Array, cfg: ExpertConfig,
@@ -212,7 +240,7 @@ def _chunks(rows, count, n_groups, layer, row_tok, row_w, bounds):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
 def _held_sum(rows, activation, out, u, row_w, w, row_tok, bounds, layer,
-              place):
+              place, by_expert):
     """``out + sum_s row_w[s] Expert_{e(s)}(u[row_tok[s]])`` over the listed
     pairs (``_held_rows``), ``rows`` of the list a step, for as many steps as
     the held pairs fill: the dropless loop. ``w``: the grouped weights
@@ -229,12 +257,26 @@ def _held_sum(rows, activation, out, u, row_w, w, row_tok, bounds, layer,
     float32 list [length, d] where they lie, and after the loop one gather
     sums each token's ``k`` rows, in the order of its choice; a pair that is
     not held (a zero-compute pick, placed at ``length``) reads as zeros,
-    whatever the list holds.
+    whatever the list holds. A third way takes no list at all: where
+    ``by_expert`` [T, count] is given (each token's weight on each held
+    expert, 0 where it did not choose it: the list's pairs again, laid out by
+    expert) every expert is streamed past all the rows once, in one Mosaic
+    call that sums the products ``by_expert`` selects
+    (``ops.expert_stream.experts_streamed``; ``held_pairs_apply`` says when),
+    and the list is read by the backward alone.
 
     The loop's trip count is traced, so autodiff cannot reverse it: the
     backward (``_held_sum_bwd``) walks the same list the same ``rows`` at a
-    time, from the operands alone, whichever way the forward combined."""
+    time, from the operands alone, whichever way the forward combined
+    (``by_expert`` gets no cotangent of its own: its weights are ``row_w``'s,
+    which carries their gradient)."""
     count = bounds.shape[0] - 1
+    if by_expert is not None:
+        # the groups as the stacked leaves they were cut from
+        stacked = {name: p.reshape(-1, count, *p.shape[1:])
+                   for name, p in w.items()}
+        return out + experts_streamed(u, by_expert, stacked, layer,
+                                      activation)
     product = functools.partial(jax.lax.ragged_dot,
                                 precision=_precision(u.dtype))
     chunk = _chunks(rows, count, w["wi"].shape[0], layer, row_tok, row_w,
@@ -279,9 +321,9 @@ def _held_sum(rows, activation, out, u, row_w, w, row_tok, bounds, layer,
 
 
 def _held_sum_fwd(rows, activation, out, u, row_w, w, row_tok, bounds, layer,
-                  place):
+                  place, by_expert):
     return (_held_sum(rows, activation, out, u, row_w, w, row_tok, bounds,
-                      layer, place),
+                      layer, place, by_expert),
             (u, row_w, w, row_tok, bounds, layer))
 
 
@@ -372,7 +414,7 @@ def _held_sum_bwd(rows, activation, kept, g):
                "wo": by_rows(lists["hidden"], lists["d_y"], sizes)}
         return (g, d_u.astype(u.dtype), d_row_w,
                 {name: p.astype(w[name].dtype) for name, p in d_w.items()},
-                None, None, None, None)
+                None, None, None, None, None)
 
 
 _held_sum.defvjp(_held_sum_fwd, _held_sum_bwd)
@@ -404,12 +446,28 @@ def held_pairs_apply(u: jax.Array, idx: jax.Array, weights: jax.Array,
         n_held = bounds[count]
         w = {name: p.reshape(n * count, *p.shape[2:])
              for name, p in experts.items()}
+        by_expert = None
+        if _streams(cfg, T):
+            # each token's weight on each expert; nothing of this way reads
+            # the list, so a program that takes no gradient drops its making
+            chosen = idx[:, :, None] == jnp.arange(count)       # [T, k, count]
+            by_expert = jnp.sum(jnp.where(chosen, weights[:, :, None], 0.0),
+                                axis=1)
+            place = None
         with jax.named_scope("experts"):
             out = _held_sum(rows, cfg.activation, out, u, row_w, w, row_tok,
-                            bounds, layer, place if cfg.all_held else None)
+                            bounds, layer, place if cfg.all_held else None,
+                            by_expert)
         n_zero = jnp.sum(zero, dtype=jnp.int32)
-        load = jnp.stack([n_held, idx.size - n_held - n_zero, n_zero,
-                          jnp.max(bounds[1:] - bounds[:-1])])
+        if by_expert is not None:
+            # the same loads without the list: every expert is held, so a
+            # pair is held or zero-compute, and the most-loaded expert is the
+            # one most chosen
+            n_held = idx.size - n_zero
+        load = jnp.stack([
+            n_held, idx.size - n_held - n_zero, n_zero,
+            jnp.max(bounds[1:] - bounds[:-1]) if by_expert is None
+            else jnp.max(jnp.sum(chosen, axis=(0, 1), dtype=jnp.int32))])
         return out.astype(u.dtype), load
 
 
@@ -450,10 +508,23 @@ def held_experts_apply(u: jax.Array, router: jax.Array,
     running count of integers and one placement of the T x k pairs, does),
     and every token sent to one expert or none is exact alike.
 
+    A row tile or fewer, all held (``_streams``: ``cfg.all_held``, ``T <=
+    STREAM_ROWS``, ``T x top_k >= count``; a decode step's slots): no list
+    and no loop. One Mosaic call reads each expert's three matrices once, in
+    expert order, off the stacked leaves (its index maps take ``layer``), with
+    the T rows resident in VMEM, multiplies every row by every expert and sums
+    in float32 the products that each row's weights select
+    (``ops.expert_stream.experts_streamed``; the same operands, accumulation
+    and rounding of ``hidden`` as the loop's). Its work is T x experts
+    products, which cost what the pairs' would: a weight tile in the MXU
+    takes a row tile for the price of one row, and the call is bound by the
+    weights' bytes. The load is the same four numbers, from ``idx`` alone.
+
     Differentiable in ``u``, ``router`` and ``experts``: the router's scores
     and weights and the list's making are plain JAX (the gradient flows
     through the weights, not through the choice, and ``bias`` gets none),
-    and the loop has a backward pass of its own (``_held_sum``).
+    and the loop has a backward pass of its own (``_held_sum``), which the
+    streamed way shares.
 
     Returns the partial sum [T, d] in ``u``'s dtype and the layer's load,
     int32 [4]: pairs routed to held, absent and zero-compute experts, and
@@ -509,8 +580,9 @@ def _counters():
             "moe_combined_pairs_total",
             "held pairs whose weighed products reached their tokens' sums, "
             "by how: gather (one after the loop, where the device holds "
-            "every expert), scatter_add (a step, where it holds a share)",
-            tag_keys=("by",)))
+            "every expert), scatter_add (a step, where it holds a share), "
+            "streamed (summed inside the one call that streams every expert "
+            "past a row tile of tokens or fewer)", tag_keys=("by",)))
 
 
 def _record(cfg: ExpertConfig, loads) -> None:
@@ -519,30 +591,39 @@ def _record(cfg: ExpertConfig, loads) -> None:
     session or the ring records, so that a reader finds a window's share."""
     from ray_tpu import observability
     loads = np.asarray(loads).reshape(-1, 4)
-    # the dropless loop's steps: a layer call's held pairs, ``CHUNK_ROWS`` (or
-    # all the call's pairs, if fewer) a step
-    rows = np.minimum(CHUNK_ROWS, loads[:, :3].sum(axis=1))
-    steps = int((-(-loads[:, 0] // np.maximum(rows, 1))).sum())
+    # which way a layer call combined is in ``cfg`` and the call's tokens
+    # (its pairs over ``top_k``), as it was when the program was traced
+    # (``held_pairs_apply``)
+    routed = loads[:, :3].sum(axis=1)
+    listed = np.array([not _streams(cfg, int(n) // cfg.top_k)
+                       for n in routed], bool)
+    # the dropless loop's steps: a listed layer call's held pairs,
+    # ``CHUNK_ROWS`` (or all the call's pairs, if fewer) a step
+    rows = np.minimum(CHUNK_ROWS, routed)
+    steps = int((-(-loads[:, 0] // np.maximum(rows, 1)))[listed].sum())
     held, absent, zero, most = (int(n) for n in loads.sum(axis=0))
+    streamed = held - int(loads[listed, 0].sum())
     pairs, load_max, calls, combined = _counters()
     for dest, n in (("held", held), ("absent", absent), ("zero", zero)):
         pairs.inc(n, tags={"dest": dest})
     load_max.inc(most)
     calls.inc(len(loads))
-    # which way the program combines is in ``cfg``, as it was when the
-    # program was traced (``_held_sum``)
-    gathered = held if cfg.all_held else 0
+    gathered = held - streamed if cfg.all_held else 0
     combined.inc(gathered, tags={"by": "gather"})
-    combined.inc(held - gathered, tags={"by": "scatter_add"})
+    combined.inc(held - streamed - gathered, tags={"by": "scatter_add"})
+    combined.inc(streamed, tags={"by": "streamed"})
     # ``placed``: the pairs the layer calls' one counting pass wrote into
-    # their lists, every held pair once (a program that searched for its
+    # their lists, every listed pair once (a program that searched for its
     # rows a step has no such attribute); ``gathered``: those of them that
     # the gather after the loop combined (a program that scatter-added every
-    # step's rows has no such attribute, a device that holds a share says 0)
+    # step's rows has no such attribute, a device that holds a share says 0);
+    # ``streamed``: the held pairs of the layer calls that listed nothing (a
+    # program without that way has no such attribute)
     with observability.span("moe.route", held=held, absent=absent, zero=zero,
                             load_max=most, layers=len(loads),
-                            experts=cfg.held[1], steps=steps, placed=held,
-                            gathered=gathered):
+                            experts=cfg.held[1], steps=steps,
+                            placed=held - streamed, gathered=gathered,
+                            streamed=streamed):
         pass
 
 

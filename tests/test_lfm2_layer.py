@@ -413,16 +413,31 @@ def test_the_route_span_carries_the_loops_steps(monkeypatch):
     seen.clear()
     expert._record(share, np.array([[20, 4, 0, 3]]))
     assert seen[0][1]["steps"] == 1
-    # a device that holds the whole mixture steps through its list alike,
-    # and says that its pairs were combined by the gather
+    # a device that holds the whole mixture steps through its list alike
+    # (calls of 150 and 130 tokens: over a row tile), and says that its
+    # pairs were combined by the gather
     seen.clear()
-    expert._record(EXPERTS, np.array([[200, 0, 0, 20], [65, 0, 0, 65]]))
-    assert seen[0][1]["steps"] == 4 + 2
-    assert seen[0][1]["gathered"] == seen[0][1]["held"] == 265
+    expert._record(EXPERTS, np.array([[600, 0, 0, 20], [520, 0, 0, 65]]))
+    assert seen[0][1]["steps"] == 10 + 9
+    assert seen[0][1]["gathered"] == seen[0][1]["held"] == 1120
+    assert seen[0][1]["streamed"] == 0
+    # ... and a call of a row tile or fewer (128 x 4 pairs, 50 x 4 with two
+    # zero-compute picks) lists nothing and takes no step: its held pairs
+    # were streamed; a call of 3 tokens routes fewer pairs than there are
+    # experts and takes the loop
+    seen.clear()
+    expert._record(EXPERTS, np.array([[512, 0, 0, 40], [600, 0, 0, 20],
+                                      [198, 0, 2, 30], [12, 0, 0, 2]]))
+    attrs = seen[0][1]
+    assert attrs["streamed"] == 512 + 198 and attrs["gathered"] == 612
+    assert attrs["placed"] == 612 and attrs["steps"] == 10 + 1
+    assert attrs["held"] == 1322
 
 
-@pytest.mark.parametrize("first,count", [(4, 8), (0, 16)])
-def test_the_route_span_says_the_pairs_were_placed(monkeypatch, first, count):
+@pytest.mark.parametrize("first,count,T", [(4, 8, 50), (0, 16, 150),
+                                           (0, 16, 50)])
+def test_the_route_span_says_the_pairs_were_placed(monkeypatch, first, count,
+                                                   T):
     """``placed``: the pairs the layer calls' counting pass wrote into their
     lists, through a jitted forward's own call-back: every held pair once,
     so ``placed == held`` (a share held: fewer than the routed pairs), and a
@@ -431,7 +446,10 @@ def test_the_route_span_says_the_pairs_were_placed(monkeypatch, first, count):
     ones where the device holds the whole mixture and none where it holds a
     share; the registry's ``moe_combined_pairs_total`` splits the same way.
     ``steps`` is what the program ran: counted here by a call-back in each
-    grouped product, three a step."""
+    grouped product, three a step. ``streamed``: where the device holds the
+    whole mixture and a call brings a row tile of tokens or fewer (50), all
+    its held pairs: nothing was listed or gathered, no grouped product ran,
+    and the counter says ``by="streamed"``."""
     from ray_tpu import observability
     from ray_tpu.util import metrics
     seen, ran = [], []
@@ -450,7 +468,9 @@ def test_the_route_span_says_the_pairs_were_placed(monkeypatch, first, count):
     layer = _mixture_layer(22)
     mine = jax.tree.map(lambda p: p[None, first:first + count],
                         layer["experts"])
-    u = jax.random.normal(jax.random.PRNGKey(23), (2, 50, 64))
+    u = jax.random.normal(jax.random.PRNGKey(23), (2, T, 64))
+    streams = expert._streams(cfg, T)
+    assert streams == (count == 16 and T == 50)
 
     @jax.jit
     def forward(u):
@@ -471,21 +491,23 @@ def test_the_route_span_says_the_pairs_were_placed(monkeypatch, first, count):
     jax.effects_barrier()
     (name, attrs), = seen
     held = int(loads[:, 0].sum())
-    assert name == "moe.route" and attrs["placed"] == attrs["held"] == held
+    assert name == "moe.route" and attrs["held"] == held
+    assert attrs["placed"] == (0 if streams else held)
     assert attrs["layers"] == 2
     idx = [expert.route(x, layer["router"], cfg, layer["router_bias"])[0]
            for x in u]
     assert held == sum(int(jnp.sum((i >= first) & (i < first + count)))
                        for i in idx)
-    assert (0 < held < 2 * 200) if count == 8 else held == 2 * 200
-    assert attrs["gathered"] == (held if cfg.all_held else 0)
+    assert (0 < held < 2 * 200) if count == 8 else held == 2 * T * 4
+    assert attrs["streamed"] == (held if streams else 0)
+    assert attrs["gathered"] == (held if cfg.all_held and not streams else 0)
     after = combined()
     by = {how: after[how] - before.get(how, 0)
-          for how in ("gather", "scatter_add")}
-    assert by == {"gather": attrs["gathered"],
-                  "scatter_add": held - attrs["gathered"]}
-    assert attrs["steps"] == len(ran) / 3 == sum(
-        -(-int(n) // 64) for n in loads[:, 0])
+          for how in ("gather", "scatter_add", "streamed")}
+    assert by == {"gather": attrs["gathered"], "streamed": attrs["streamed"],
+                  "scatter_add": held - attrs["gathered"] - attrs["streamed"]}
+    assert attrs["steps"] == len(ran) / 3 == (0 if streams else sum(
+        -(-int(n) // 64) for n in loads[:, 0]))
 
 
 class _Null:

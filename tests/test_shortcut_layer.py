@@ -234,12 +234,12 @@ def test_held_experts_part_is_the_plain_sum(monkeypatch, first, count, rows):
                           for e in range(count))
 
 
-def _forced_router(chosen, cfg=EXPERTS, d=64):
+def _forced_router(chosen, cfg=EXPERTS, d=64, tokens=40):
     """A router whose every token picks exactly ``chosen`` (by a large
     logit on a constant feature) and tokens that carry that feature."""
     router = np.zeros((d, cfg.n_outputs), np.float32)
     router[0, list(chosen)] = 50.0 + np.arange(len(chosen))
-    u = np.array(jax.random.normal(jax.random.PRNGKey(3), (40, d)))
+    u = np.array(jax.random.normal(jax.random.PRNGKey(3), (tokens, d)))
     u[:, 0] = 1.0
     return jnp.asarray(router), jnp.asarray(u)
 
@@ -511,20 +511,22 @@ def test_rows_past_the_held_pairs_add_nothing_whatever_the_product_left(
 @pytest.mark.parametrize("rows", [16, 1024])
 def test_all_held_the_gather_reads_no_row_past_the_held_pairs(monkeypatch,
                                                               rows):
-    """All 16 held, one pick in four a zero-compute one: 120 held pairs end
-    inside a step of 16 and inside the one step of 160, and the rows past
-    them, NaN in the list the steps write, belong to no pair: a
-    zero-compute pick is placed past the list's end and reads as zeros. The
-    sum is the same to the bit as with a clean product."""
+    """All 16 held, one pick in four a zero-compute one, 132 tokens (over
+    ``STREAM_ROWS``: the call lists its pairs): 396 held pairs end inside a
+    step of 16 and inside the one step of 528, and the rows past them, NaN
+    in the list the steps write, belong to no pair: a zero-compute pick is
+    placed past the list's end and reads as zeros. The sum is the same to
+    the bit as with a clean product."""
     monkeypatch.setattr(expert, "CHUNK_ROWS", rows)
-    router, u = _forced_router((0, 1, 17, 5))
+    router, u = _forced_router((0, 1, 17, 5), tokens=132)
     _, experts = _mixture_weights(4)
     assert EXPERTS.all_held and EXPERTS.n_zero
+    assert u.shape[0] > expert.STREAM_ROWS
     want, load = held_experts_apply(u, router, _alone(experts), EXPERTS, 0)
-    assert load.tolist() == [120, 0, 40, 40]
+    assert load.tolist() == [396, 0, 132, 132]
     poisoned = _poisoned_products(monkeypatch)
     got, _ = held_experts_apply(u, router, _alone(experts), EXPERTS, 0)
-    assert poisoned == [min(rows, 160)] * 3
+    assert poisoned == [min(rows, 528)] * 3
     np.testing.assert_array_equal(got, want)
     assert bool(jnp.all(jnp.isfinite(got))) and float(jnp.abs(got).max()) > 0
 
@@ -537,8 +539,8 @@ def _eqns(jaxpr):
             yield from _eqns(sub)
 
 
-@pytest.mark.parametrize("first,count", [(0, 16), (4, 4)])
-def test_a_step_of_the_loop_searches_for_nothing(first, count):
+@pytest.mark.parametrize("first,count,T", [(0, 16, 150), (4, 4, 50)])
+def test_a_step_of_the_loop_searches_for_nothing(first, count, T):
     """Read off ``jax.make_jaxpr``: the layer call holds one ``while`` (the
     dropless loop), whose body has no loop, sort or search of its own,
     gathers only the rows' tokens from ``u`` (the one gather), and is handed
@@ -548,10 +550,12 @@ def test_a_step_of_the_loop_searches_for_nothing(first, count):
     is the parent commit's jaxpr to the letter (read off commit 6fef1d7 by
     ``_digest``); all 16 held write them into the float32 list where they
     lie (a ``dynamic_update_slice``, no scatter-add anywhere) and one
-    gather from that list follows the loop."""
+    gather from that list follows the loop (at 150 tokens: a row tile or
+    fewer would be streamed and list nothing, ``expert._streams``)."""
     cfg = dataclasses.replace(EXPERTS, held=(first, count))
     router, experts = _mixture_weights(1, cfg=cfg)
-    u = jax.random.normal(jax.random.PRNGKey(2), (50, 64))
+    u = jax.random.normal(jax.random.PRNGKey(2), (T, 64))
+    assert not expert._streams(cfg, T)
 
     def call(u):
         return held_experts_apply(u, router, _alone(experts), cfg, 0)
@@ -569,24 +573,24 @@ def test_a_step_of_the_loop_searches_for_nothing(first, count):
     gather, = (e for e in _eqns(body) if e.primitive.name == "gather")
     assert gather.invars[0].aval.shape == u.shape
     shapes = {v.aval.shape for v in body.invars}
-    assert not shapes & {(50, count), (count, 50)}
-    assert (200,) in shapes             # the list: 200 pairs, one step's rows
+    assert not shapes & {(T, count), (count, T)}
+    assert (4 * T,) in shapes           # the list: T x 4 pairs, one step's rows
     everywhere = [e.primitive.name for e in _eqns(jaxpr)]
     assert everywhere.count("scatter") == 2 and "sort" not in everywhere
     # the step's own sizes are one ``dynamic_update_slice`` on either path
     if cfg.all_held:
         assert "scatter-add" not in everywhere
         assert inside.count("dynamic_update_slice") == 2
-        assert (200, 64) in shapes      # the carried list of weighed rows
+        assert (600, 64) in shapes      # the carried list of weighed rows
         after = [e for e in _eqns(jaxpr) if e.primitive.name == "gather"
-                 and e.invars[0].aval.shape == (200, 64)]
-        assert len(after) == 1 and after[0].outvars[0].aval.shape == (200, 64)
-        assert after[0].invars[1].aval.shape == (200, 1)    # every pair's row
+                 and e.invars[0].aval.shape == (600, 64)]
+        assert len(after) == 1 and after[0].outvars[0].aval.shape == (600, 64)
+        assert after[0].invars[1].aval.shape == (600, 1)    # every pair's row
     else:
         assert inside.count("scatter-add") == everywhere.count(
             "scatter-add") == 1
         assert inside.count("dynamic_update_slice") == 1
-        assert (200, 64) not in shapes
+        assert (4 * T, 64) not in shapes
         assert _digest(call, u) == "0109d6b0f3f67dbd"
 
 

@@ -231,12 +231,14 @@ def _plain_mixture(u, idx, w, experts, act):
 def test_the_relu_expert_and_its_gradient_are_plain_jnp(held):
     """``ExpertConfig(activation="relu")`` through the dropless loop and its
     backward pass, a device that holds every expert (gather) and one that
-    holds a share (scatter-add), against the plain sum under autodiff."""
+    holds a share (scatter-add), against the plain sum under autodiff (136
+    tokens: a row tile or fewer held whole would be streamed, below)."""
     from ray_tpu.parallel import expert
     cfg = dataclasses.replace(_config().experts, held=held)
     first, count = held
     ks = jax.random.split(jax.random.PRNGKey(4), 5)
-    u = jax.random.normal(ks[0], (40, 48))
+    u = jax.random.normal(ks[0], (136, 48))
+    assert not expert._streams(cfg, 136)
     router = jax.random.normal(ks[1], (48, 8))
     experts = {"wi": jax.random.normal(ks[2], (count, 48, 24)) / 7,
                "wg": jax.random.normal(ks[3], (count, 48, 24)) / 7,
@@ -267,19 +269,165 @@ def test_the_relu_expert_and_its_gradient_are_plain_jnp(held):
 def test_a_long_calls_mixture_goes_through_the_loop_in_blocks(tiny,
                                                               monkeypatch):
     """Above ``LIST_PAIRS`` routed pairs a call the tokens take the dropless
-    loop in equal blocks: the same sum, and the same load (the most-loaded
-    expert is the most chosen one)."""
+    loop in equal blocks (of 130 tokens here: over a row tile, so each block
+    lists its pairs as the whole call does): the same sum, and the same load
+    (the most-loaded expert is the most chosen one)."""
     cfg, params = tiny["cfg"], tiny["params"]
-    x = jax.random.normal(jax.random.PRNGKey(8), (1, 30, 48))
+    x = jax.random.normal(jax.random.PRNGKey(8), (1, 390, 48))
     stack = params["blocks"]["window_moe"]
     whole = transformer._mixture(stack, 2, x, None, cfg)
-    monkeypatch.setattr(transformer, "LIST_PAIRS", 50)  # 120 pairs: 3 blocks
+    monkeypatch.setattr(transformer, "LIST_PAIRS", 600)  # 1560 pairs: 3 blocks
     blocked = transformer._mixture(stack, 2, x, None, cfg)
-    np.testing.assert_allclose(blocked[0], whole[0], atol=1e-6)
+    np.testing.assert_allclose(blocked[0], whole[0], atol=2e-6)
     np.testing.assert_array_equal(blocked[1], whole[1])
     np.testing.assert_array_equal(blocked[2], whole[2])
-    assert int(whole[1][0]) == 120 and int(whole[1][3]) == int(
+    assert int(whole[1][0]) == 1560 and int(whole[1][3]) == int(
         whole[2].max())
+
+
+# -- a row tile of tokens or fewer: every expert streamed once ------------------------
+
+# what each case changes of: 20 tokens, 8 experts all held, 4 a token, ReLU,
+# float32, one layer's leaves, a seeded router
+STREAMED = {
+    "relu": {},
+    "silu": {"activation": "silu"},
+    "zero-compute picks": {"n_zero": 3},
+    "experts that no row chose": {"only": (1, 2, 4, 6, 7)},
+    "a traced layer of three, the others NaN": {"layers": 3, "layer": 1},
+    "rows not a multiple of the sublanes": {"T": 11},
+    "bfloat16, rows not a multiple of 16": {"dtype": jnp.bfloat16, "T": 24},
+    "a full row tile": {"T": 128},
+}
+
+
+def _streamed_case(activation="relu", n_zero=0, only=None, layers=1, layer=0,
+                   T=20, dtype=jnp.float32):
+    from ray_tpu.parallel import expert
+    cfg = dataclasses.replace(_config().experts, activation=activation,
+                              n_zero=n_zero)
+    ks = jax.random.split(jax.random.PRNGKey(41), 5)
+    u = jax.random.normal(ks[0], (T, 48)).astype(dtype)
+    router = jax.random.normal(ks[1], (48, 8 + n_zero))
+    if only is not None:    # the other experts' logits far below every one's
+        shut = jnp.array([e not in only for e in range(8)])
+        router = jnp.where(shut, 0.0, router)
+        u = u.at[:, 0].set(1.0)
+        router = router.at[0].set(jnp.where(shut, -60.0, router[0]))
+    experts = {"wi": jax.random.normal(ks[2], (layers, 8, 48, 24)) / 7,
+               "wg": jax.random.normal(ks[3], (layers, 8, 48, 24)) / 7,
+               "wo": jax.random.normal(ks[4], (layers, 8, 24, 48)) / 5}
+    experts = jax.tree.map(lambda p: p.astype(dtype), experts)
+    # the other layers' weights NaN: the index maps read ``layer``'s alone
+    # (the CPU's grouped product multiplies every group, so the loop and
+    # both backward passes are given the clean leaves)
+    poisoned = jax.tree.map(
+        lambda p: jnp.where(jnp.arange(layers).reshape(-1, 1, 1, 1) == layer,
+                            p, jnp.nan), experts)
+    idx, w = expert.route(u, router, cfg)
+    return cfg, u, experts, poisoned, idx, w, layer
+
+
+def _ways(fn, *args):
+    """The primitives of ``fn``'s jaxpr that tell the three ways apart."""
+    def eqns(jaxpr):
+        for eqn in jaxpr.eqns:
+            yield eqn
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from eqns(sub)
+
+    # a function of its own each time: a trace is kept by the function
+    jaxpr = jax.make_jaxpr(lambda *args: fn(*args))(*args).jaxpr
+    names = [e.primitive.name for e in eqns(jaxpr)]
+    return {name: names.count(name)
+            for name in ("pallas_call", "while", "ragged_dot_general")}
+
+
+@pytest.mark.parametrize("case", sorted(STREAMED))
+def test_a_row_tile_or_fewer_streams_every_expert_once(case, monkeypatch):
+    """Where the device holds every expert and a call brings a row tile of
+    tokens or fewer, ``held_pairs_apply`` is one Mosaic call
+    (``ops.expert_stream``, interpreted here) and no loop: against the plain
+    float32 sum, and against the dropless loop on the same inputs (which a
+    ``STREAM_ROWS`` of 0 brings back), sum, loads and gradient (the loop's
+    own backward, from the list that only a gradient makes)."""
+    from ray_tpu.parallel import expert
+    cfg, u, experts, poisoned, idx, w, layer = _streamed_case(
+        **STREAMED[case])
+    act = jax.nn.silu if cfg.activation == "silu" else jax.nn.relu
+    assert expert._streams(cfg, u.shape[0])
+
+    def ours(u, experts, layer):
+        return expert.held_pairs_apply(u, idx, w, experts, cfg, layer)
+
+    def plain(u, experts):
+        f32 = jax.tree.map(lambda p: p[layer].astype(jnp.float32), experts)
+        zero = jnp.sum(jnp.where(idx >= cfg.n_routed, w, 0.0), -1)
+        return (_plain_mixture(u.astype(jnp.float32), idx, w, f32, act)
+                + zero[:, None] * u.astype(jnp.float32))
+
+    def loss(fn):
+        return lambda u, e: jnp.sum(fn(u, e).astype(jnp.float32) ** 2)
+
+    def run(experts_fwd):
+        """The sum and the loads under a traced ``layer``, the gradient, and
+        which way the trace took (each traced anew)."""
+        traced = jnp.asarray(layer, jnp.int32)
+        return (*jax.jit(lambda *args: ours(*args))(u, experts_fwd, traced),
+                jax.grad(loss(lambda u, e: ours(u, e, layer)[0]), (0, 1))(
+                    u, experts), _ways(ours, u, experts, traced))
+
+    got, load, grad, ways = run(poisoned)
+    assert ways == {"pallas_call": 1, "while": 0, "ragged_dot_general": 0}
+    monkeypatch.setattr(expert, "STREAM_ROWS", 0)
+    looped, looped_load, looped_grad, ways = run(experts)
+    assert ways == {"pallas_call": 0, "while": 1, "ragged_dot_general": 3}
+    want = plain(u, experts)
+
+    exact = u.dtype == jnp.float32
+    assert got.dtype == u.dtype and bool(jnp.all(jnp.isfinite(got)))
+    np.testing.assert_allclose(got.astype(jnp.float32), want,
+                               atol=2e-5 if exact else 0.05)
+    np.testing.assert_allclose(got.astype(jnp.float32),
+                               looped.astype(jnp.float32),
+                               atol=2e-5 if exact else 0.05)
+    np.testing.assert_array_equal(load, looped_load)
+    assert int(load[2]) == int(jnp.sum(idx >= cfg.n_routed))
+    if case == "zero-compute picks":
+        assert 0 < int(load[2]) < idx.size
+    if case == "experts that no row chose":
+        assert set(np.unique(idx)) <= {1, 2, 4, 6, 7}
+    if exact:   # in bfloat16 the two sums' rounding reaches the gradients
+        jax.tree.map(lambda a, b: np.testing.assert_allclose(a, b, atol=2e-4),
+                     grad, looped_grad)
+    if not STREAMED[case]:
+        auto = jax.grad(loss(plain), (0, 1))(u, experts)
+        jax.tree.map(lambda a, b: np.testing.assert_allclose(a, b, atol=2e-4),
+                     grad, auto)
+
+
+@pytest.mark.parametrize("T,held", [(1, (0, 8)), (20, (2, 4)), (129, (0, 8))])
+def test_one_row_a_share_held_and_more_than_a_row_tile_take_the_loop(T, held):
+    """What picks the way is read off the call's shapes: a call whose pairs
+    are fewer than the experts (one token's 4 of 8), a device that holds a
+    share, and a call of more rows than the MXU's row tile list their pairs
+    and take the dropless loop, with no Mosaic call."""
+    from ray_tpu.parallel import expert
+    cfg = dataclasses.replace(_config().experts, held=held)
+    ks = jax.random.split(jax.random.PRNGKey(43), 5)
+    u = jax.random.normal(ks[0], (T, 48))
+    experts = {"wi": jax.random.normal(ks[2], (1, held[1], 48, 24)) / 7,
+               "wg": jax.random.normal(ks[3], (1, held[1], 48, 24)) / 7,
+               "wo": jax.random.normal(ks[4], (1, held[1], 24, 48)) / 5}
+    idx, w = expert.route(u, jax.random.normal(ks[1], (48, 8)), cfg)
+    assert not expert._streams(cfg, T)
+    assert _ways(lambda u: expert.held_pairs_apply(
+        u, idx, w, experts, cfg, 0), u) == {
+            "pallas_call": 0, "while": 1, "ragged_dot_general": 3}
+    got, _ = expert.held_pairs_apply(u, idx, w, experts, cfg, 0)
+    want = _plain_mixture(u, idx - held[0], w,
+                          jax.tree.map(lambda p: p[0], experts), jax.nn.relu)
+    np.testing.assert_allclose(got, want, atol=2e-5)
 
 
 def test_a_request_past_a_slots_13312_positions_is_refused_with_a_reply():
