@@ -81,15 +81,18 @@ PERF_HISTOGRAMS = frozenset({
 # ``rpc:<METHOD>`` spans and user ``profile_span``s are named at run time
 # and are not listed.
 SPANS = frozenset({
-    # serve: the proxy's handler thread, from arrival to the reply written
+    # serve: the proxy's handler thread, from arrival to the reply written;
+    # route, and accept_wait_us: from ``accept`` handing the connection over
+    # (stamped on the accept loop's thread) to this span's start, -1 on a
+    # kept-alive connection's later requests
     "serve.request",
     "serve.route",          # handle lookup + Router._pick + the submit
     "serve.await_replica",  # handle.remote(...).result() after the submit
     "serve.reply",          # the reply encoded and written
     # serve: the replica batcher's flusher thread
-    # wake-up with a non-empty queue -> batch cut; depth, cap,
-    # oldest_wait_us and left (queued at the cut and not taken by it);
-    # cut (the reason that fired: full, waited, passed, not_due) and the
+    # wake-up with a non-empty queue -> batch cut; depth (queued at the
+    # cut: less the batch's n, what the cut passed over), cap and
+    # oldest_wait_us; cut (the reason that fired: full, waited, passed, not_due) and the
     # two estimates the last one compares, gap_est_us (EWMA of the gaps
     # between admissions) and call_est_us (the per-item EWMA), -1 where
     # the replica has none yet
@@ -99,6 +102,18 @@ SPANS = frozenset({
     # the padded rectangle's fill is size_sum / (padded_n x size_max)
     "serve.batch.execute",
     "serve.batch.call",     # the user's callable alone
+    # serve: a caller's own thread, parked on its request (inside
+    # ``actor.call`` on a replica, so in the request's trace), with what
+    # the thread that served it wrote on the request.  by="batch"
+    # (``_Batcher.submit``): queue_wait_us (enqueue to the cut that took
+    # it), call_us (the cut to its call's end), batch (the ordinal its
+    # ``serve.batch.execute`` carries: the join of a member to its batch, on
+    # one replica's flusher), n, padded_n, size, size_max, retried (its
+    # batch failed and it ran again alone), shed (it aged out and never
+    # ran).  by="generate" (``GenerationEngine.submit``): waited_us (what
+    # its ``serve.generate.prefill`` carries; -1 if it was never admitted),
+    # slot, len, bucket, steps (decode steps it took), n_new
+    "serve.replica.wait",
     # serve: the generation engine's thread (serve/generation.py)
     # a request admitted: its prompt prefilled alone and inserted into a
     # free slot; len, bucket (what the model padded it to), slot, waited_us
@@ -108,6 +123,16 @@ SPANS = frozenset({
     # finished (of them, those whose answer the step completed)
     "serve.generate.step",
     "serve.generate.reply",  # a finished answer handed to its caller; n_new
+    # the stack sampler's thread (``observability/sampler.py``), opened and
+    # closed at the wake of a tick that was HOLD_S late or later: the
+    # process was held for [start - held_us, start].  Since the tick
+    # before: cpu_us (the whole process's CPU time), run_delay_us (the
+    # sampler's thread runnable with no core), throttled_us (the cgroup),
+    # gc_full (full collections), majflt, nivcsw; threads (alive at the
+    # wake), holder (the leaf-most frames of the thread that spent most
+    # CPU, "" if none spent a tenth of the hold), cause (``classify_hold``);
+    # -1 where the platform has no such source
+    "host.hold",
     # runtime
     "task.execute",         # one task on a worker thread
     "actor.call",           # one method call on an actor's thread
@@ -176,6 +201,12 @@ LATER_DEVICE_SCOPES = frozenset({
     "swa",
     "nope",
 })
+
+# The stack sampler's holds (``observability/sampler.py``; tagged by
+# ``cause``: throttled, gc, gil, runqueue, off_cpu): ticks that woke
+# ``HOLD_S`` late or later, and the seconds they were late by.
+HOST_HOLDS = "host_holds_total"
+HOST_HOLD_SECONDS = "host_hold_seconds_total"
 
 # The gauge a replica sets once, when its constructor returns.
 REPLICA_INIT_GAUGE = "serve_replica_init_seconds"

@@ -55,6 +55,21 @@ class _Server(ThreadingHTTPServer):
     64 slots; PERF.md section 6, PR 52)."""
     request_queue_size = 128
 
+    def __init__(self, *args, **kwargs):
+        # connection -> when ``accept`` handed it over, until its handler
+        # takes the stamp (single dict operations, on two threads)
+        self.accepted: Dict[object, float] = {}
+        super().__init__(*args, **kwargs)
+
+    def get_request(self):
+        """Stamps the accept, on the accept loop's thread: from here to the
+        handler's ``serve.request`` span (a thread started, the request
+        read) is the one part of a request's life inside the process that
+        no span covers, and a held interpreter shows there first."""
+        request, address = super().get_request()
+        self.accepted[request] = time.monotonic()  # raylint: allow(data-race) single dict store under the GIL; the handler's thread pops it
+        return request, address
+
 
 class HTTPProxy:
     def __init__(self, controller_handle, host: str = "127.0.0.1",
@@ -80,6 +95,10 @@ class HTTPProxy:
 
             def log_message(self, *a):  # quiet
                 pass
+
+            def setup(self):
+                super().setup()
+                self._t_accept = self.server.accepted.pop(self.request, None)
 
             def _json(self, code: int, payload: dict,
                       retry_after_s: float = 1.0):
@@ -113,6 +132,8 @@ class HTTPProxy:
 
             def _dispatch(self, body: Optional[bytes]):
                 t_arrival = time.monotonic() if perf.ENABLED else 0.0
+                # the connection's first request takes the accept's stamp
+                t_accept, self._t_accept = self._t_accept, None
                 path = self.path.split("?")[0].rstrip("/") or "/"
                 if path == "/-/healthz":
                     self._json(503 if proxy._draining else 200,
@@ -139,7 +160,12 @@ class HTTPProxy:
                     # thread), and the replica task submitted by
                     # handle.remote() inherits it via TaskSpec.
                     with observability.span("serve.request", cat="serve",
-                                            route=path):
+                                            route=path) as request:
+                        if request.live:
+                            # -1: a kept-alive connection's later request
+                            request.set(accept_wait_us=-1 if t_accept is None
+                                        else int((time.monotonic() - t_accept)
+                                                 * 1e6))
                         name = proxy._match(path)
                         if name is None:
                             self._json(404, {"error": "no route"})

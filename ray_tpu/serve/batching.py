@@ -180,7 +180,8 @@ class _Request:
     """One queued request, parked until its batch has run."""
 
     __slots__ = ("item", "size", "passed", "event", "value", "error",
-                 "request_id", "t_enqueue", "trace")
+                 "request_id", "t_enqueue", "trace", "t_cut", "t_done",
+                 "batch", "n", "padded_n", "size_max", "retried", "shed")
 
     def __init__(self, item):
         self.item = item
@@ -197,6 +198,14 @@ class _Request:
         # spans for the batch this request heads join its trace
         self.trace = (observability.current() if observability.live()
                       else None)
+        # What became of it, for the caller's ``serve.replica.wait`` span.
+        # The flusher writes these before ``event.set()`` and the caller
+        # reads them after ``event.wait()``: the Event orders the two.
+        self.t_cut = self.t_done = self.t_enqueue  # cut into a batch; call ended
+        self.batch = 0          # the ordinal ``serve.batch.execute`` carries
+        self.n = self.padded_n = self.size_max = 0
+        self.retried = False    # its batch failed and it was run again alone
+        self.shed = False       # it aged out of the queue and was never run
 
 
 def _sorted_buckets(pad: Optional[Sequence[int]]
@@ -297,6 +306,28 @@ class _Batcher:
 
     def submit(self, item) -> Any:
         slot = _Request(item)
+        # The caller's own wait, on its own thread and so in its own trace
+        # (inside ``actor.call`` on a replica), opened before the request
+        # is queued, so that the batch that serves it lies inside it.  The
+        # cut and the call happen on the flusher's thread: they cannot be
+        # spans here, so they are numbers on the span that ends the wait,
+        # which the flusher wrote on the request.
+        with observability.span("serve.replica.wait", cat="serve") as wait:
+            self._admit(slot)
+            slot.event.wait()
+            if wait.live:
+                wait.set(by="batch",
+                         queue_wait_us=int((slot.t_cut - slot.t_enqueue)
+                                           * 1e6),
+                         call_us=int((slot.t_done - slot.t_cut) * 1e6),
+                         batch=slot.batch, n=slot.n, padded_n=slot.padded_n,
+                         size=slot.size, size_max=slot.size_max,
+                         retried=int(slot.retried), shed=int(slot.shed))
+        if slot.error is not None:
+            raise slot.error
+        return slot.value
+
+    def _admit(self, slot: _Request) -> None:
         with self._lock:
             if self._thread is None:
                 self._thread = threading.Thread(
@@ -312,10 +343,6 @@ class _Batcher:
             self._t_admit = slot.t_enqueue
             self._queue.append(slot)
         self._wakeup.set()
-        slot.event.wait()
-        if slot.error is not None:
-            raise slot.error
-        return slot.value
 
     def shutdown(self) -> None:
         self._stop = True
@@ -348,7 +375,9 @@ class _Batcher:
                                     parent=head) as linger:
                 batch, expired, deadline_ms = self._cut_batch(linger)
             for s in expired:
-                wait_ms = (time.monotonic() - s.t_enqueue) * 1e3
+                s.t_cut = s.t_done = time.monotonic()
+                s.shed = True
+                wait_ms = (s.t_cut - s.t_enqueue) * 1e3
                 self._observe_queue_wait(wait_ms)
                 s.error = ServeOverloadedError(
                     f"request {s.request_id} aged {wait_ms:.0f}ms in the "
@@ -415,14 +444,11 @@ class _Batcher:
                 del self._queue[i]
             for s in self._queue:
                 s.passed = True
-            left = len(self._queue)
-            if not left:
+            if not self._queue:
                 self._wakeup.clear()
             self._cuts += 1
             if cut == "not_due":
                 self._cuts_not_due += 1
-        if linger.live:
-            linger.set(left=left)
         return batch, expired, deadline_ms
 
     def _call(self, items: List[Any]) -> List[Any]:
@@ -447,6 +473,9 @@ class _Batcher:
         # Pad, call, read back and deliver: device idle under this span
         # and outside serve.batch.call's device work is the batcher's own
         # host time.
+        for s in batch:
+            s.batch, s.n = self._batches, len(batch)
+            s.padded_n, s.size_max = padded_n, size_max
         with observability.span("serve.batch.execute", cat="serve",
                                 parent=batch[0].trace) as execute:
             if execute.live:
@@ -455,31 +484,36 @@ class _Batcher:
                             batch=self._batches)
             self._execute(batch)
 
-    def _observe_call(self, t_start: float, n: int) -> None:
+    def _observe_call(self, t_start: float, n: int) -> float:
         """One call covering ``n`` requests has ended, well or badly: the
         estimate gets ``ms / n`` (the amortized cost that sizes future
-        batches), the sensor the call's whole time."""
-        ms = (time.monotonic() - t_start) * 1e3
+        batches), the sensor the call's whole time.  Returns when it
+        ended."""
+        t_end = time.monotonic()
+        ms = (t_end - t_start) * 1e3
         self._estimate.observe(ms, n)
         self._observe_execute(ms, n)
+        return t_end
 
     def _execute(self, batch: List[_Request]) -> None:
         t_start = time.monotonic()
         for s in batch:
+            s.t_cut = t_start
             self._observe_queue_wait((t_start - s.t_enqueue) * 1e3)
         n = len(batch)
         try:
             if chaos.ENABLED and self._chaos_labels is not None:
                 chaos.inject("serve.replica.execute", **self._chaos_labels)
             results = self._call([s.item for s in batch])
-            self._observe_call(t_start, n)
+            t_done = self._observe_call(t_start, n)
             for s, v in zip(batch, results):
                 s.value = v
+                s.t_done = t_done
                 s.event.set()
             return
         except BaseException as e:
             error = e
-        self._observe_call(t_start, n)
+        t_done = self._observe_call(t_start, n)
         # Per-item error isolation.  A singleton's error is unambiguously
         # its own and is delivered raw.  Larger batches re-run members
         # alone once, so a poisoned request fails alone and innocent
@@ -488,6 +522,7 @@ class _Batcher:
         # callers can tell "my request was bad" from "I was collateral".
         if n == 1:
             batch[0].error = error
+            batch[0].t_done = t_done
             batch[0].event.set()
             return
         if _config.get("serve_batch_retry_singletons"):
@@ -497,13 +532,15 @@ class _Batcher:
                     s.value = self._call([s.item])[0]
                 except BaseException as single_err:
                     s.error = single_err
-                self._observe_call(t1, 1)
+                s.retried = True
+                s.t_done = self._observe_call(t1, 1)
                 s.event.set()
             return
         tagged = BatchExecutionError(
             self._name, n, [s.request_id for s in batch], error)
         for s in batch:
             s.error = tagged
+            s.t_done = t_done
             s.event.set()
 
 

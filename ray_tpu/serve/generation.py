@@ -89,7 +89,7 @@ class _Generation:
     """One request, parked until its answer is whole."""
 
     __slots__ = ("prompt", "n", "tokens", "logits", "event", "value", "error",
-                 "t_enqueue", "t_admit", "trace")
+                 "t_enqueue", "t_admit", "trace", "slot", "bucket")
 
     def __init__(self, prompt: List[int], n: int):
         self.prompt = prompt
@@ -103,6 +103,9 @@ class _Generation:
         self.t_admit = 0.0
         self.trace = (observability.current() if observability.live()
                       else None)
+        # where the engine put it, for the caller's ``serve.replica.wait``
+        # span: the engine's thread writes them before ``event.set()``
+        self.slot = self.bucket = -1
 
 
 def parse_request(item: Any) -> Tuple[List[int], int]:
@@ -167,14 +170,24 @@ class GenerationEngine:
         if check is not None:
             check(prompt, n)
         request = _Generation(prompt, n)
-        with self._lock:
-            if self._thread is None:
-                self._thread = threading.Thread(
-                    target=self._loop, daemon=True, name=self._thread_name)
-                self._thread.start()
-            self._queue.append(request)
-        self._wakeup.set()
-        request.event.wait()
+        # the caller's own wait, in its own trace, as ``_Batcher.submit``'s
+        with observability.span("serve.replica.wait", cat="serve") as wait:
+            with self._lock:
+                if self._thread is None:
+                    self._thread = threading.Thread(
+                        target=self._loop, daemon=True,
+                        name=self._thread_name)
+                    self._thread.start()
+                self._queue.append(request)
+            self._wakeup.set()
+            request.event.wait()
+            if wait.live:
+                wait.set(by="generate",
+                         waited_us=int((request.t_admit - request.t_enqueue)
+                                       * 1e6) if request.t_admit else -1,
+                         slot=request.slot, len=len(prompt),
+                         bucket=request.bucket, steps=n - 1,
+                         n_new=len(request.tokens))
         if request.error is not None:
             raise request.error
         return request.value
@@ -235,6 +248,7 @@ class GenerationEngine:
             slot = self._free.pop()
             self._admitted[id(request)] = request
             request.t_admit = time.monotonic()
+            request.slot = slot
             waited = request.t_admit - request.t_enqueue
             self._observe_queue_wait(waited * 1e3)
             with observability.span("serve.generate.prefill", cat="serve",
@@ -242,8 +256,9 @@ class GenerationEngine:
                                     len=len(request.prompt), slot=slot,
                                     waited_us=int(waited * 1e6)) as sp:
                 handle, bucket = self._model.admit(request.prompt, slot)
+                request.bucket = int(bucket)
                 if sp.live:
-                    sp.set(bucket=int(bucket))
+                    sp.set(bucket=request.bucket)
             self._metrics.admitted.inc(tags=self._tags)
             self._metrics.tokens.inc(len(request.prompt),
                                      tags={**self._tags, "phase": "prefill"})

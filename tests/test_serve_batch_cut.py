@@ -283,8 +283,10 @@ def test_a_passed_over_request_is_cut_without_a_second_linger(reordered):
     # next cut took them at depth 2 < cap with 600 s of linger ahead: three
     # calls in all, and the test is here
     assert len(reordered["calls"]) == 3
-    assert [s["left"] for s in reordered["linger"]] == [0, 2, 0]
     assert [s["depth"] for s in reordered["linger"]] == [1, 4, 2]
+    # what a cut passed over is its depth less what it took
+    assert [s["depth"] - e["n"] for s, e in zip(
+        reordered["linger"], reordered["execute"])] == [0, 2, 0]
 
 
 def test_the_fill_is_on_the_spans_and_in_get_metrics(reordered):
@@ -307,7 +309,8 @@ def test_scalars_and_dicts_are_cut_in_arrival_order(entry):
                    buckets=(2, 4))
     assert got["calls"] == [[1, 1], [1, 1, 1, 1], [1, 1]]
     assert got["replies"] == [(1, 4)] * 4 + [(1, 2)] * 2
-    assert [s["left"] for s in got["linger"]] == [0, 2, 0]
+    assert [s["depth"] - e["n"] for s, e in zip(
+        got["linger"], got["execute"])] == [0, 2, 0]
 
 
 # -- when the batcher stops waiting ------------------------------------------

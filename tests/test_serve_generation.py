@@ -107,7 +107,7 @@ def served():
                 t.join()
             spans = {name: _spans(name) for name in (
                 "serve.generate.prefill", "serve.generate.step",
-                "serve.generate.reply")}
+                "serve.generate.reply", "serve.replica.wait")}
         info = ray_tpu.get(
             serve.api._get_controller().get_replica_handles.remote(NAME))
         replica_metrics = ray_tpu.get(info["handles"][0].get_metrics.remote())
@@ -153,6 +153,27 @@ def test_the_engine_leaves_its_spans(served):
         r["max_new_tokens"] > 1 for r in requests)
     assert sorted(s["n_new"] for s in spans["serve.generate.reply"]) \
         == sorted(r["max_new_tokens"] for r in requests)
+
+
+def test_a_callers_wait_carries_what_the_engine_did_in_its_own_trace(served):
+    """Every caller's ``serve.replica.wait`` is in the trace its prefill
+    joined, and says ``by="generate"`` with the prefill's own numbers."""
+    requests, spans = served["requests"], served["spans"]
+    waits = spans["serve.replica.wait"]
+    assert len(waits) == len(requests)
+    prefill = {s["trace_id"]: s for s in spans["serve.generate.prefill"]}
+    replies = {s["trace_id"]: s for s in spans["serve.generate.reply"]}
+    assert len(prefill) == len(requests)      # one trace a request
+    for w in waits:
+        mine = prefill[w["trace_id"]]
+        assert w["by"] == "generate"
+        assert w["waited_us"] == mine["waited_us"]
+        assert (w["slot"], w["len"], w["bucket"]) == (
+            mine["slot"], mine["len"], mine["bucket"])
+        assert w["n_new"] == replies[w["trace_id"]]["n_new"]
+        assert w["steps"] == w["n_new"] - 1
+    assert sorted(w["len"] for w in waits) == sorted(
+        len(r["prompt"]) for r in requests)
 
 
 def test_get_metrics_carries_the_engines_counts(served):
