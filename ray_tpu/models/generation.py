@@ -100,6 +100,20 @@ class TransformerGenerator:
         return sum(n_full * int(n) + n_ring * min(int(n), ring)
                    for n in lengths)
 
+    def read_rows(self, lengths: Sequence[int]) -> int:
+        """The K/V rows a step's attention reads for sequences of these
+        lengths: with the kernels the tiles ``ops.decode_attention`` walks
+        up to each one's newest row (``live_rows`` rounded up to whole
+        tiles, a layer and slot), without them every row allocated."""
+        from ray_tpu.ops.decode_attention import read_rows
+        stacks = [a for a in (self.state.k, self.state.ring_k) if a.size]
+        if not self.cfg.use_flash:
+            return sum(n * S * T for n, S, T, _ in (a.shape for a in stacks))
+        held = np.asarray(lengths, np.int64)
+        return sum(
+            n * int(read_rows(np.minimum(held, T) - 1, T).sum())
+            for a in stacks for n, _, T, _ in (a.shape,))
+
     def _count_loads(self, loads) -> None:
         if loads is not None:
             from ray_tpu.parallel import expert

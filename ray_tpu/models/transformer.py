@@ -106,8 +106,10 @@ the window layers, a ring of ``window`` rows in which position ``p`` lives in
 row ``p % window`` (k is kept rotated, so the rows' order does not matter to
 the softmax). ``prefill`` hands over a prompt's last ``window`` positions in
 ring order, ``decode_step`` writes the token at ``lengths % window``, rotates
-q and k at each slot's own position and masks a row until the sequence has
-reached it; the mixture runs in the step on the slots' ``[S, d]`` rows.
+q and k at each slot's own position and reads a slot's rows up to the newest
+the sequence has reached (``ops.decode_attention`` under ``use_flash``: a
+step's bytes are the rows the slots hold; the plain path masks all the rows
+the stack has); the mixture runs in the step on the slots' ``[S, d]`` rows.
 
 Training a stack of ``PARTS``' kinds (``loss_and_metrics`` ->
 ``_parts_states``) scans each run over its stacked leaves, so that a layer's
@@ -134,8 +136,8 @@ import jax.numpy as jnp
 from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec
 
-from ray_tpu.ops import flash_attention, linear_attention, sparse_attention
-from ray_tpu.ops import ssd
+from ray_tpu.ops import decode_attention, flash_attention, linear_attention
+from ray_tpu.ops import sparse_attention, ssd
 from ray_tpu.ops.flash_attention import KEPT as FLASH_KEPT
 from ray_tpu.ops.linear_attention import decay_rates
 from ray_tpu.ops.sparse_attention import SparseConfig
@@ -851,13 +853,17 @@ def _attention_step(attn, h, cfg: TransformerConfig, k_cache, v_cache, l,
     position)`` of the stack itself (a scatter of S rows, in place: written
     into the layer's slice and the slice written back, the compiler moved the
     whole slice three times) and the query reads the slot's cache up to and
-    with it, as a masked product over all T (no kernel: the cache is a small
-    part of a step's bytes). Each query head is laid out over all the K/V
-    heads' lanes with zeros outside its own group's, so that both products
-    read the cache as it lies (a product batched over the K/V heads had the
-    TPU compiler transpose the whole cache every step, and back for the
-    write): 8 times the scores' FLOPs, which are nothing beside the cache's
-    bytes. Returns the mixer's output and the two stacks."""
+    with it. Each query head is laid out over all the K/V heads' lanes with
+    zeros outside its own group's, so that both products read a cache row as
+    it lies (a product batched over the K/V heads had the TPU compiler
+    transpose the whole cache every step, and back for the write): 8 times
+    the scores' FLOPs, which are nothing beside the cache's bytes. With
+    ``cfg.use_flash`` the two products and the softmax between them are one
+    Mosaic call on the stacks themselves that walks each slot's tiles up to
+    its newest row and no further (``ops.decode_attention``: a step's bytes
+    are the rows its slots hold, not the rows allocated); without, and for a
+    cache whose rows are no multiple of 8, a masked product over all T rows.
+    Returns the mixer's output and the two stacks."""
     _, S, T, _ = k_cache.shape
     H, G, D = cfg.n_heads, cfg.kv_heads, cfg.head_dim
     q, k, v = _attention_qkv(attn, h[:, None], lengths[:, None], cfg, rope)
@@ -873,14 +879,8 @@ def _attention_step(attn, h, cfg: TransformerConfig, k_cache, v_cache, l,
         own = (jnp.arange(H)[:, None] // (H // G)
                == jnp.arange(G)[None])[None, :, :, None]
         spread = jnp.where(own, q[:, 0, :, None, :], 0).reshape(S, H, G * D)
-        s = jnp.einsum("shc,stc->sht", spread,
-                       jax.lax.dynamic_index_in_dim(k_cache, l, 0, False),
-                       preferred_element_type=jnp.float32)
-        s = s / math.sqrt(D)
-        seen = jnp.arange(T)[None] <= newest[:, None]           # [S, T]
-        p = jax.nn.softmax(jnp.where(seen[:, None], s, -1e30), axis=-1)
-        o = jnp.einsum("sht,stc->shc", p.astype(h.dtype),
-                       jax.lax.dynamic_index_in_dim(v_cache, l, 0, False))
+        o = decode_attention(spread, k_cache, v_cache, l, newest,
+                             1 / math.sqrt(D), use_kernel=cfg.use_flash)
         o = jnp.sum(jnp.where(own, o.reshape(S, H, G, D), 0), axis=2)
     return (jnp.einsum("shk,hkd->sd", o, attn["wo"].astype(h.dtype)),
             k_cache, v_cache)
@@ -1810,9 +1810,11 @@ def decode_step(params: Dict[str, Any], tokens: jax.Array,
     twice). With ``cfg.use_flash`` a Mamba layer's recurrence is a Mosaic call
     that takes the whole stack of states and the layer's index and is its
     own output: it moves that layer's bytes alone, once in and once out. An
-    attention layer reads and writes its own stack: a window layer its ring,
-    any other the full cache, each a masked product over the rows the stack
-    has (no kernel). A mixture runs on the slots' [S, d] rows through the
+    attention layer reads and writes its own stack, a window layer its ring,
+    any other the full cache: with ``cfg.use_flash`` a Mosaic call that reads
+    the rows each slot has reached (``_attention_step``), else a masked
+    product over the rows the stack has. A mixture runs on the slots' [S, d]
+    rows through the
     call a prompt's tokens take (``_mixture``), routed from the block's input
     where the kind says so (``EARLY_ROUTED``): where the device holds every
     expert and S is a row tile or fewer, one Mosaic call a layer that streams

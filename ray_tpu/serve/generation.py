@@ -26,8 +26,9 @@ module knows no model):
     Wait for a handle's arrays and bring them to the host.
 
 Two more are optional: ``check(prompt, n)`` raises for a request the model
-cannot hold, and ``live_rows(lengths)`` says how many K/V rows sequences of
-these lengths hold (a step's span carries it as ``live_rows``).
+cannot hold, ``live_rows(lengths)`` says how many K/V rows sequences of
+these lengths hold and ``read_rows(lengths)`` how many a step reads for them
+(a step's span carries them as ``live_rows`` and ``read_rows``).
 
 The engine's loop: while a slot is free and a request waits, admit it; then
 one step over all slots; then read the step *before* (the host reads a
@@ -288,12 +289,14 @@ class GenerationEngine:
         with observability.span("serve.generate.step", cat="serve",
                                 active=len(takers), finished=finished) as sp:
             handle = self._model.step(active)
-            live_rows = getattr(self._model, "live_rows", None)
-            if sp.live and live_rows is not None:
+            if sp.live:
                 # what each holds with the token this step takes in: its
                 # prompt, the steps it has taken and one
-                sp.set(live_rows=int(live_rows(
-                    [len(r.prompt) + r.n - left for _, r, left in takers])))
+                held = [len(r.prompt) + r.n - left for _, r, left in takers]
+                for name in ("live_rows", "read_rows"):
+                    rows = getattr(self._model, name, None)
+                    if rows is not None:
+                        sp.set(**{name: int(rows(held))})
         self._metrics.steps.inc(tags=self._tags)
         self._metrics.tokens.inc(len(takers),
                                  tags={**self._tags, "phase": "decode"})

@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+from ray_tpu.ops.decode_attention import KERNEL_NAME as ATTEND
 from ray_tpu.ops.expert_stream import KERNEL_NAME as STREAM
 
 from chip_programs import (CFG, KERNEL, SEQ, cell_dims, computations,
@@ -342,6 +343,23 @@ def _held_gb(compiled):
             + m.output_size_in_bytes - m.alias_size_in_bytes) / 1e9
 
 
+def _reads_its_stacks_where_they_lie(calls, step, runs):
+    """The compiled step's ``decode_attn`` calls, ``runs[stack]`` of them on
+    each stack's shape (one a run of attention layers): each takes K's and
+    V's whole stack of its run (no layer cut out of one), under ``attn`` and
+    ``core``, and nothing in the step copies a stack or holds a slot's
+    scores over every row."""
+    assert len(calls) == sum(runs.values())
+    for stack, n in runs.items():
+        assert sum(line.count(stack) == 2 for line in calls) == n
+    assert all("/attn/" in line and "/core/" in line for line in calls)
+    rows = "|".join({stack.split(",")[2] for stack in runs})
+    for line in step.splitlines():
+        if " copy(" in line or " dynamic-slice(" in line:
+            assert not any(stack in line for stack in runs), line[:200]
+        assert not re.search(rf"= f32\[\d+,\d+,({rows})\]", line), line[:200]
+
+
 def test_a_decode_step_updates_the_slots_state_in_place(generating):
     """The rule the issue fixed for 64 slots: the step with the weights and
     the state reads 15.0 GB or less. The state (5.63 GB) is aliased, not
@@ -395,6 +413,16 @@ def test_a_decode_step_steps_the_state_in_one_call_on_the_stack(generating):
     # layer of the stack
     assert not any("add_dynamic-update-slice_fusion" in line
                    and stack in line for line in lines)
+    # and an attention layer's query walks its slots' rows of the K/V stack
+    # in one call on the stack itself, one a run of attention layers
+    attend = [line for line in lines
+              if " custom-call(" in line and KERNEL in line
+              and re.search(rf"\s%?{ATTEND}[.\d]* = ", line)]
+    _reads_its_stacks_where_they_lie(
+        attend, compiled.as_text(),
+        {f"bf16[4,{generating['slots']},1408,512]":
+         len(runs) - runs.count("mamba")})
+    assert len(attend) == 4
 
 
 def test_the_longest_prefill_fits_beside_the_resident_state(generating):
@@ -479,5 +507,9 @@ def test_the_windows_prefill_runs_the_kernel_and_the_step_writes_in_place(
             "bf16[6,64,2560,768]") == 2
     assert not re.search(r"= \S+ (scatter|gather)\([^\n]*\[288", step)
     assert "f32[288,2560]" not in step
-    for stack in ("bf16[2,48,13312,512]", "bf16[6,48,4096,512]"):
+    stacks = ("bf16[2,48,13312,512]", "bf16[6,48,4096,512]")
+    for stack in stacks:
         assert stack in step
+    # global, window, global, window
+    _reads_its_stacks_where_they_lie(calls(step, ATTEND), step,
+                                     dict.fromkeys(stacks, 2))

@@ -2,6 +2,8 @@
 prefilled and then stepped gives the full forward's logits at every position,
 whatever shares the slots with it."""
 
+import importlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -110,11 +112,20 @@ def test_an_insert_leaves_the_other_slots_to_the_bit(run):
         after.lengths, state.lengths.at[1].set(LENGTHS[2]))
 
 
-def test_the_kernels_step_is_the_plain_step(run):
+@pytest.mark.parametrize("rows", [None, 8],
+                         ids=["a_slots_rows_one_tile", "tiles_of_8_rows"])
+def test_the_kernels_step_is_the_plain_step(run, rows, monkeypatch):
     """``decode_step`` with ``use_flash`` (the recurrence a Mosaic call on the
-    stacked state, interpreted here) against without, from one ``prefill``
-    over ``NEW`` steps: every step's logits and all five leaves of the state
-    it leaves. Two runs of Mamba layers, so the layer's index is traced."""
+    stacked state and the attention one that walks each slot's tiles of the
+    cache, interpreted here) against without, from one ``prefill`` over
+    ``NEW`` steps: every step's logits and all five leaves of the state it
+    leaves. Two runs of Mamba layers, so the layer's index is traced. With
+    tiles of 8 rows the three slots' lengths (10, 16 and 7, then 8 more)
+    cross a tile's edge while empty slots stay in their first."""
+    if rows:
+        monkeypatch.setattr(
+            importlib.import_module("ray_tpu.ops.decode_attention"), "_ROWS",
+            rows)
     cfg = tiny.config(use_flash=True)
     assert cfg.use_flash and not run["cfg"].use_flash
     kernels = jax.jit(lambda t, s, a: transformer.decode_step(

@@ -149,6 +149,10 @@ def test_the_engine_leaves_its_spans(served):
     assert sum(s["active"] for s in steps) == sum(
         r["max_new_tokens"] - 1 for r in requests)
     assert all(1 <= s["active"] <= SLOTS for s in steps)
+    # the K/V rows the step's sequences hold and the rows its attention
+    # reads: without the kernels every row of the one attention layer's cache
+    assert all(s["active"] <= s["live_rows"] <= s["read_rows"]
+               == SLOTS * CACHE for s in steps)
     assert sum(s["finished"] for s in steps) == sum(
         r["max_new_tokens"] > 1 for r in requests)
     assert sorted(s["n_new"] for s in spans["serve.generate.reply"]) \

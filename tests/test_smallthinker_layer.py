@@ -5,6 +5,7 @@ prefill, insert and decode through the two caches, with four planted faults
 that the comparison must catch."""
 
 import dataclasses
+import importlib
 
 import jax
 import jax.numpy as jnp
@@ -150,12 +151,25 @@ def sound(tiny):
     return _served(tiny)
 
 
+@pytest.mark.parametrize("program", ["plain", "kernels",
+                                     "kernels_tiles_of_8_rows"])
 def test_prefill_insert_and_steps_through_both_caches_are_the_reference(
-        tiny, sound):
+        tiny, sound, program, monkeypatch):
     """Logits at every generated position of the three, decoded beside two
     other occupied slots for 2.5 turns of the ring; and what the two caches
-    hold after the last step is the reference's k at those positions."""
-    got, state = sound
+    hold after the last step is the reference's k at those positions. With
+    the kernels a step's attention is ``ops.decode_attention`` over the full
+    cache and over the ring (a ring of 8 rows is one tile; in tiles of 8 the
+    full cache's 48 rows are six, and the three sequences cross their
+    edges)."""
+    if program == "plain":
+        got, state = sound
+    else:
+        if program.endswith("rows"):
+            monkeypatch.setattr(
+                importlib.import_module("ray_tpu.ops.decode_attention"),
+                "_ROWS", 8)
+        got, state = _served(tiny, _config(use_flash=True))
     for r, (n, _, _) in enumerate(PROMPTS):
         np.testing.assert_allclose(got[r], tiny["logits"][r, n - 1:n + NEW],
                                    rtol=2e-4, atol=2e-5)
@@ -450,6 +464,13 @@ def test_a_request_past_a_slots_13312_positions_is_refused_with_a_reply():
     with pytest.raises(ValueError, match="do not fit a slot's 13312"):
         model.check([1] * 12288, 1025)
     assert model.live_rows([5, 20]) == 2 * 25 + 6 * (5 + W)
+    # the masked product reads every allocated row of both slots; the kernel
+    # a slot's tiles up to its newest row: one of 512 a global layer, the
+    # ring's one of 8
+    assert model.read_rows([5, 20]) == 2 * 2 * 13312 + 6 * 2 * W
+    model.cfg = dataclasses.replace(cfg, use_flash=True)
+    assert model.read_rows([5, 20]) == 2 * (512 + 512) + 6 * (W + W)
+    assert model.read_rows([5, 513]) == 2 * (512 + 1024) + 6 * (W + W)
     # the generator's programs hand the mixtures' loads out as a result: no
     # call-back that the device would wait for at every step
     step = model._decode_step.lower(model.params, model.tokens, model.state,
