@@ -182,7 +182,8 @@ def request_plan(mix: Dict[str, Any], seed: int) -> Dict[str, Any]:
     plan = traffic.request_plan(mix, 0.0, seed)
     n, clients = int(mix["n_lengths"]), int(mix["clients"])
     answers = traffic.prompt_lengths(mix["answer_len"], n,
-                                     int(mix["answer_pattern_seed"]))
+                                     int(mix["answer_pattern_seed"]),
+                                     traffic.clients_arranged(mix))
     start = clients * traffic.start_index(seed, max(1, n // clients))
     return {**plan, "answers": np.roll(answers, -start).tolist()}
 

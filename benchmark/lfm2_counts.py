@@ -46,12 +46,6 @@ from benchmark.trace_reduce import NS, Interval, merge
 ATTENTION = "full_attention"        # ``layer_types``' value
 EXPERTS_SCOPE = "experts"           # the dropless loop (parallel/expert.py)
 
-# The accepted readers under this module's name, as ``sala_counts`` and
-# ``longcat_counts`` have them (the accepted suite counts the files that
-# name ``program_spans``).
-idle_class_pct = program_spans.idle_class_pct
-gauge = program_spans.gauge
-
 
 # -- counts ------------------------------------------------------------------
 

@@ -25,8 +25,7 @@ from typing import Any, Dict, List, Optional, Tuple
 from benchmark import peaks, program_spans
 from benchmark.flops import MATMUL
 from benchmark.reducers import Context
-from benchmark.sala_counts import (_device_ops, gauge,  # noqa: F401
-                                   idle_class_pct, min_seconds, window_calls)
+from benchmark.sala_counts import _device_ops, min_seconds, window_calls
 from benchmark.trace_reduce import NS, merge
 
 FLASH_CALL = "flash_fwd"

@@ -135,12 +135,6 @@ def flash_fwd_calls_per_step(ctx: Context, p: Dict[str, Any]
     return float(statistics.median(counts))
 
 
-# The kernel's time a call is the accepted reader's (flash_*_ms.train); the
-# looped cell's metric files name it through this module, as they name the
-# others, since the accepted suite counts the files that name its module.
-kernel_ms = program_spans.kernel_ms
-
-
 @functools.lru_cache(maxsize=4)
 def _device_ops(path: str) -> List[Event]:
     """The first device's operations with their whole HLO text as the

@@ -37,12 +37,10 @@ LINEAR_CALL = "linear_attn_fwd"
 SPARSE_SCORES, SPARSE_ATTEND = "sparse_attn_scores", "sparse_attn_fwd"
 RESULT = re.compile(r" = \(?[a-z]+\d*\[([\d,]*)\]")
 
-# The accepted readers under this module's name: the cell's metric files
-# name their readers here, as the looped cell's do in ``looped_counts``
-# (the accepted suite counts the files that name ``program_spans``).
-idle_class_pct = program_spans.idle_class_pct
+# The accepted reader under this module's name: ``proxy_self_ms.longdoc``
+# names it here (the accepted suite counts the files that name
+# ``program_spans``).
 span_self_ms = program_spans.span_self_ms
-gauge = program_spans.gauge
 
 
 # -- counts ------------------------------------------------------------------

@@ -27,7 +27,8 @@ import bisect
 import statistics
 from typing import Any, Dict, List, Optional
 
-from benchmark import device_scopes, granite_counts, lfm2_counts, peaks
+from benchmark import (device_scopes, granite_counts, lfm2_counts,
+                       longcat_counts, peaks)
 from benchmark.flops import MATMUL
 from benchmark.longcat_counts import FLASH_CALL, causal_pairs
 from benchmark.reducers import Context
@@ -38,14 +39,8 @@ WINDOW, GLOBAL = "window", "global"          # ``layer_types``' values
 SWA_SCOPE = "swa"                            # inside ``attn``
 DECODE = granite_counts.DECODE
 STEP_SPAN = granite_counts.STEP_SPAN
+STREAM_CALL = "experts_stream"               # ``ops.expert_stream``'s kernel
 ACT_BYTES = 2                                # bfloat16 weights and caches
-
-# The accepted readers under this module's name (the cell's metric files name
-# their readers here, as the other configurations' do).
-execution_busy_ms = lfm2_counts.execution_busy_ms
-scope_share_pct = lfm2_counts.scope_share_pct
-span_attr_mean = granite_counts.span_attr_mean
-gauge = lfm2_counts.gauge
 
 
 # -- counts ------------------------------------------------------------------
@@ -317,13 +312,25 @@ def swa_attn_roofline_pct(ctx: Context, p: Dict[str, Any]
     return 100.0 * least / spent
 
 
+def _is_mixture_product(op) -> bool:
+    """Whether the operation is one of the mixture's products, whatever
+    implements them: a grouped product (``ragged-dot``) or the call that
+    streams every expert past the rows (``STREAM_CALL``), by its own
+    name (its HLO text up to `` = ``)."""
+    return (lfm2_counts._is_product(op)
+            or STREAM_CALL in op.record.name.split(" = ")[0])
+
+
 def expert_matmul_roofline_pct(ctx: Context, p: Dict[str, Any]
                                ) -> Optional[float]:
-    """The decode steps' grouped products: the least time for the pairs the
-    steps routed (their ``moe.route`` spans: a step's pairs are ``slots x
-    top_k`` a layer) against every expert's weights read once a layer call,
-    over the device time of the ``ragged-dot`` calls inside the window's
-    ``jit_decode_step`` executions."""
+    """The decode steps' mixture: the least time for the pairs the steps
+    routed (their ``moe.route`` spans: a step's pairs are ``slots x top_k``
+    a layer) against every expert's weights read once a layer call, over
+    the device time of the mixture's products (``_is_mixture_product``: the
+    ``ragged-dot`` calls of a program that lists its pairs, the
+    ``experts_stream`` calls of one that streams its experts) inside the
+    window's ``jit_decode_step`` executions. The count is the work's and
+    does not ask which of the two ran."""
     dims = _dims(ctx)
     runs = _runs(ctx, DECODE) if dims else []
     slots = ctx.counters.get("slots")
@@ -332,23 +339,22 @@ def expert_matmul_roofline_pct(ctx: Context, p: Dict[str, Any]
     starts = [r.start for r in runs]
     spent = sum(op.seconds for op in
                 device_scopes._run_leaves(tuple(ctx.trace.window)) or ()
-                if lfm2_counts._is_product(op) and _inside(op, starts, runs))
+                if _is_mixture_product(op) and _inside(op, starts, runs))
     a_step = int(slots) * dims["top_k"] * dims["n_layers"]
-    routed = [r for r in lfm2_counts._route_spans(ctx)
+    routed = [r for r in longcat_counts._route_spans(ctx)
               if r["held"] == a_step]
     if not spent or not routed:
         return None
     pairs = sum(r["held"] for r in routed)
-    reads = sum(lfm2_counts.expert_reads(r["layers"], r["steps"],
-                                         r["experts"]) for r in routed)
+    reads = sum(r["layers"] * r["experts"] for r in routed)
     least, bound = min_seconds(
         lfm2_counts.expert_matmul_flops(pairs, dims),
         lfm2_counts.expert_matmul_bytes(pairs, reads, dims), ctx.device_kind)
     ctx.notes.append(
-        f"decode grouped product roofline: {pairs:.0f} pairs of "
-        f"{len(routed)} steps ({pairs / reads:.2f} rows an expert read), "
-        f"least {least * 1e3:.3f} ms of {spent * 1e3:.3f} inside "
-        f"{len(runs)} steps; bound by {bound}")
+        f"decode mixture roofline: {pairs:.0f} pairs of {len(routed)} steps "
+        f"({pairs / reads:.2f} rows an expert read), least "
+        f"{least * 1e3:.3f} ms of {spent * 1e3:.3f} inside {len(runs)} "
+        f"steps; bound by {bound}")
     return 100.0 * least / spent
 
 

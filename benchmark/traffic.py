@@ -8,7 +8,9 @@ starts (another order of the same arrivals and sizes) and the token values.
 So every window holds each request of the cycle exactly once, and the
 spread between runs is the system's, not the generator's: on the chip,
 arrangements drawn anew for every seed moved ``ttft_p95_ms`` between 200 and
-417 ms while two runs of one arrangement agreed within 3% (PR 25).
+417 ms while two runs of one arrangement agreed within 3% (PR 25). A closed
+loop that says ``"arrange": "by_client"`` deals its lengths to the clients by
+strata instead, so that its rounds are alike (``arranged_by_client``).
 
 Imports numpy only: the load generator's process must never import JAX.
 """
@@ -34,10 +36,11 @@ def _quantile_points(n: int) -> np.ndarray:
     return (np.arange(n) + 0.5) / n
 
 
-def prompt_lengths(spec: Dict[str, Any], n: int, pattern_seed: int
-                   ) -> np.ndarray:
+def prompt_lengths(spec: Dict[str, Any], n: int, pattern_seed: int,
+                   by_client: int = 0) -> np.ndarray:
     """``n`` prompt lengths: the quantiles of the mix's distribution,
-    clipped to its limits, in the pattern's order."""
+    clipped to its limits, in the pattern's order; with ``by_client``
+    clients, in ``arranged_by_client``'s order."""
     dist = spec["dist"]
     if dist == "lognormal":
         normal = statistics.NormalDist()
@@ -48,7 +51,40 @@ def prompt_lengths(spec: Dict[str, Any], n: int, pattern_seed: int
     else:
         raise ValueError(f"unknown length distribution {dist!r}")
     lengths = np.clip(np.rint(lengths), spec["min"], spec["max"]).astype(int)
+    if by_client:
+        return arranged_by_client(lengths, by_client, pattern_seed)
     return rng(pattern_seed, 1).permutation(lengths)
+
+
+def arranged_by_client(lengths: np.ndarray, clients: int, pattern_seed: int
+                       ) -> np.ndarray:
+    """A closed loop's cycle whose rounds are alike by construction
+    (``"arrange": "by_client"``). The ``r x clients`` lengths are sorted and
+    cut into ``clients`` strata of ``r`` neighbours; client ``c`` sends the
+    stratum the pattern's permutation gives it, one member a round,
+    ascending in an even stratum and descending in an odd one. So every
+    round holds one length of every stratum, a client's requests are nearly
+    equal, and the rotation a seed picks reorders rounds that carry the
+    same tokens within about a per cent, at any step time. Item
+    ``j * clients + c`` is client ``c``'s request of round ``j``."""
+    r, rest = divmod(len(lengths), clients)
+    if rest or not r:
+        raise ValueError(f"arrange by_client: {len(lengths)} lengths are "
+                         f"not whole rounds of {clients} clients")
+    strata = np.sort(lengths).reshape(clients, r)
+    strata[1::2] = strata[1::2, ::-1].copy()
+    return strata[rng(pattern_seed, 1).permutation(clients)].T.reshape(-1)
+
+
+def clients_arranged(traffic: Dict[str, Any]) -> int:
+    """The clients a mix arranges its cycle by, 0 where it has no
+    ``"arrange"``: ``prompt_lengths``' ``by_client``."""
+    arrange = traffic.get("arrange")
+    if arrange is None:
+        return 0
+    if arrange != "by_client":
+        raise ValueError(f"unknown arrangement {arrange!r}")
+    return int(traffic["clients"])
 
 
 def arrival_cycle(rate_rps: float, seconds: float, pattern_seed: int
@@ -109,7 +145,8 @@ def request_plan(traffic: Dict[str, Any], seconds: float, seed: int
                 + lengths[order].tolist()}
     if traffic["loop"] == "closed":
         n, clients = int(traffic["n_lengths"]), int(traffic["clients"])
-        lengths = prompt_lengths(traffic["prompt_len"], n, pattern)
+        lengths = prompt_lengths(traffic["prompt_len"], n, pattern,
+                                 clients_arranged(traffic))
         # client c sends items c, c + clients, ...: a start that is a
         # multiple of the clients keeps each round's set of prompts
         start = clients * start_index(seed, max(1, n // clients))

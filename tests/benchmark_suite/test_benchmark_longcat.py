@@ -90,7 +90,10 @@ def test_the_cell_reports_throughput_set_up_and_its_nine_metrics(real):
                                                     "setup_s"]
     assert {m["name"]: (m["source"], m["moves"], m["layer"])
             for m in cell.per_layer} == {
-        "fwd_device_ms.longcat": ("device_trace", "serve_tokens_per_s",
+        # PR 60: the offline cell's entries hold the forward's time and
+        # the batch's host share (one reader, one entry), the serving
+        # cells' start-up stands for the retired constructor's gauge
+        "fwd_device_ms.offline": ("device_trace", "serve_tokens_per_s",
                                   "Model"),
         "fwd_mfu_pct.longcat": ("device_trace", "serve_tokens_per_s",
                                 "Model"),
@@ -104,10 +107,10 @@ def test_the_cell_reports_throughput_set_up_and_its_nine_metrics(real):
             "device_trace", "serve_tokens_per_s", "Expert layer"),
         "expert_load_max_over_mean.longcat": (
             "program_counter", "serve_tokens_per_s", "Expert layer"),
-        "idle_batch_host_pct.longcat": ("program_span", "serve_tokens_per_s",
+        "idle_batch_host_pct.offline": ("program_span", "serve_tokens_per_s",
                                         "Serve: replica batcher"),
-        "replica_init_s.longcat": ("program_counter", "setup_s",
-                                   "Entry: serve API"),
+        "serve_startup_s.serve": ("host_clock", "setup_s",
+                                  "Entry: serve API"),
     }
     for m in cell.per_layer:
         assert m["workloads"] == [CELL] or CELL in m["workloads"]
@@ -565,10 +568,10 @@ def test_a_traced_tiny_cell_reads_the_programs_counters(longcat_root,
     result = harness.run_cell("tiny-longcat-serve", SEED, 1.0, True,
                               root=longcat_root, require_tpu=False)
     assert not set(result["metrics"]) & {
-        "fwd_device_ms.longcat", "fwd_mfu_pct.longcat",
+        "fwd_device_ms.offline", "fwd_mfu_pct.longcat",
         "mla_attn_share_pct.longcat", "mla_attn_roofline_pct.longcat",
         "expert_share_pct.longcat", "expert_matmul_roofline_pct.longcat"}
-    assert "replica_init_s.longcat" in result["metrics"]
+    assert "serve_startup_s.serve" in result["metrics"]
     assert result["metrics"]["expert_load_max_over_mean.longcat"][
         "value"] >= 1.0
 

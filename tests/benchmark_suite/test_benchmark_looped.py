@@ -88,24 +88,26 @@ def test_the_looped_cell_reports_the_training_metrics_and_its_own(real):
     assert names == [
         "fit_startup_s.train", "data_wait_ms.train", "host_gap_ms.train",
         "step_device_ms.train", "flash_share_pct.train",
+        "flash_fwd_ms.train", "flash_dq_ms.train", "flash_dkv_ms.train",
         "flash_roofline_pct.looped", "flash_calls_per_step.looped",
-        "exit_head_share_pct.looped", "flash_fwd_ms.looped",
-        "flash_dq_ms.looped", "flash_dkv_ms.looped"]
+        "exit_head_share_pct.looped"]
     # the dense decoder's count would read a quarter of the truth here
     assert "flash_roofline_pct.train" not in names
-    for m in cell.per_layer[5:]:
+    for m in cell.per_layer[8:]:
         assert m["workloads"] == [CELL]
         assert callable(reducers.resolve(m["reducer"]))
-    assert [m["reducer"] for m in cell.per_layer[5:8]] == [
+    assert [m["reducer"] for m in cell.per_layer[8:]] == [
         "benchmark.looped_counts:flash_roofline_pct",
         "benchmark.looped_counts:flash_fwd_calls_per_step",
         "benchmark.looped_counts:exit_head_share_pct"]
-    # the kernel's three times by the accepted reader, in files of their
-    # own: the accepted test holds flash_*_ms.train to their files' lists
-    for m, kind in zip(cell.per_layer[8:], ("fwd", "dq", "dkv")):
-        assert m["reducer"] == "benchmark.looped_counts:kernel_ms"
+    # the kernel's three times by the accepted reader: since PR 60 the
+    # ``.train`` entries themselves (the ``.looped`` ones named the same
+    # reader and folded into them), with this cell in their lists
+    for m, kind in zip(cell.per_layer[5:8], ("fwd", "dq", "dkv")):
         assert reducers.resolve(m["reducer"]) is program_spans.kernel_ms
         assert m["params"] == {"kernel": f"flash_{kind}"}
+        assert m["workloads"][:3] == [
+            "mistral7b-train-4k", "mistral7b-train-4k-fsdp4", CELL]
     assert cell.traffic["seq_len"] == 4096
     assert cell.deploy["model"] == {"dtype": "bfloat16", "remat": True,
                                     "use_flash": True}

@@ -51,12 +51,15 @@ PUBLISHED = {
 SPARSE = {"kernel_size": 32, "kernel_stride": 16, "block_size": 64,
           "topk": 64, "init_blocks": 1, "window_size": 2048,
           "dense_len": 8192}
+# PR 60: the forward's time, the batch's host share and the start-up are the
+# first entries of their readers (the offline cell's, the serving cells');
+# the constructor's gauge is retired for the start-up it read
 METRICS = [
-    "fwd_device_ms.longdoc", "idle_batch_host_pct.longdoc",
+    "serve_startup_s.serve", "fwd_device_ms.offline",
+    "idle_batch_host_pct.offline",
     "proxy_self_ms.longdoc", "linear_attn_share_pct.longdoc",
     "sparse_attn_share_pct.longdoc", "linear_attn_roofline_pct.longdoc",
-    "sparse_attn_roofline_pct.longdoc", "replica_init_s.longdoc",
-    "serve_startup_s.longdoc"]
+    "sparse_attn_roofline_pct.longdoc"]
 TINY_SPARSE = {"kernel_size": 4, "kernel_stride": 2, "block_size": 8,
                "topk": 4, "init_blocks": 1, "window_size": 16,
                "dense_len": 32}
@@ -108,34 +111,30 @@ def test_the_manifest_holds_the_cell_and_every_guard_holds(real):
     assert throughput["bound"] == 0.06
 
 
-def test_the_cell_reports_throughput_set_up_and_its_nine_metrics(real):
+def test_the_cell_reports_throughput_set_up_and_its_eight_metrics(real):
     cell = real.cell(CELL)
     assert [m["name"] for m in cell.end_to_end] == ["serve_tokens_per_s",
                                                     "setup_s"]
     assert [m["name"] for m in cell.per_layer] == METRICS
     for m in cell.per_layer:
-        assert m["workloads"] == [CELL]
+        assert (m["workloads"] == [CELL]) == m["name"].endswith(".longdoc")
+        assert CELL in m["workloads"]
         assert m["moves"] == ("setup_s" if m["name"].startswith(
-            ("replica_init", "serve_startup")) else "serve_tokens_per_s")
+            "serve_startup") else "serve_tokens_per_s")
         assert callable(reducers.resolve(m["reducer"])) and m["what"]
-        # new readers live in this configuration's own module; the accepted
-        # suite counts the files that name program_spans
-        assert m["reducer"] in ("execution_busy_ms", "counter") or m[
+        # the cell's own readers live in this configuration's own module
+        assert not m["name"].endswith(".longdoc") or m[
             "reducer"].startswith("benchmark.sala_counts:")
     by_name = {m["name"]: m for m in cell.per_layer}
-    assert by_name["fwd_device_ms.longdoc"]["params"] == {
+    assert by_name["fwd_device_ms.offline"]["params"] == {
         "program": "jit_first_token", "stat": "mean"}
-    assert by_name["idle_batch_host_pct.longdoc"]["params"] == {
+    assert by_name["idle_batch_host_pct.offline"]["params"] == {
         "class": "batch_execute"}
     assert by_name["proxy_self_ms.longdoc"]["params"] == {
         "span": "serve.request", "less": "serve.await_replica"}
-    assert by_name["replica_init_s.longdoc"]["params"] == {
-        "name": "serve_replica_init_seconds"}
-    assert by_name["serve_startup_s.longdoc"]["params"] == {
+    assert by_name["serve_startup_s.serve"]["params"] == {
         "key": "serve_startup_s"}
-    assert sala_counts.idle_class_pct is program_spans.idle_class_pct
     assert sala_counts.span_self_ms is program_spans.span_self_ms
-    assert sala_counts.gauge is program_spans.gauge
     for share in ("linear_attn_roofline_pct.longdoc",
                   "sparse_attn_roofline_pct.longdoc"):
         assert (by_name[share]["unit"], by_name[share]["better"]) == (
@@ -496,10 +495,10 @@ def test_a_traced_tiny_cell_leaves_out_what_it_cannot_read(sala_root,
     result = harness.run_cell("tiny-sala-serve", SEED, 1.0, True,
                               root=sala_root, require_tpu=False)
     assert not set(result["metrics"]) & {
-        "fwd_device_ms.longdoc", "linear_attn_share_pct.longdoc",
+        "fwd_device_ms.offline", "linear_attn_share_pct.longdoc",
         "sparse_attn_share_pct.longdoc", "linear_attn_roofline_pct.longdoc",
         "sparse_attn_roofline_pct.longdoc"}
-    assert "replica_init_s.longdoc" in result["metrics"]
+    assert "serve_startup_s.serve" in result["metrics"]
     assert "proxy_self_ms.longdoc" in result["metrics"]
 
 
