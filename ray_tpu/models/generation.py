@@ -44,6 +44,12 @@ class TransformerGenerator:
         # program's results are): one compilation a shape, not a second one
         # for the first call's uncommitted arguments
         self.state = jax.device_put(state, self.device)
+        # (layers that read it, rows a slot) of each K/V stack that holds
+        # rows
+        readers = transformer.cache_readers(cfg)
+        self._stacks = [(readers[name], a.shape[2])
+                        for name, a in (("full", state.k),
+                                        ("ring", state.ring_k)) if a.size]
         self.tokens = self._put(np.zeros((self.slots,), np.int32))
 
         def best(logits):
@@ -92,13 +98,12 @@ class TransformerGenerator:
                 f"fit a slot's {self.cache_len} positions")
 
     def live_rows(self, lengths: Sequence[int]) -> int:
-        """The K/V rows that sequences of these lengths hold over the
-        attention layers: a window layer keeps the last ``window`` positions
-        of each, any other every position."""
-        (n_full, *_), (n_ring, _, ring, _) = (self.state.k.shape,
-                                              self.state.ring_k.shape)
-        return sum(n_full * int(n) + n_ring * min(int(n), ring)
-                   for n in lengths)
+        """The K/V rows that sequences of these lengths hold as the
+        attention layers see them: a window layer the last ``window``
+        positions of each, any other every position (a row of a cache that
+        several layers read counts once for each)."""
+        return sum(n * min(int(held), T) for n, T in self._stacks
+                   for held in lengths)
 
     def read_rows(self, lengths: Sequence[int]) -> int:
         """The K/V rows a step's attention reads for sequences of these
@@ -106,13 +111,11 @@ class TransformerGenerator:
         up to each one's newest row (``live_rows`` rounded up to whole
         tiles, a layer and slot), without them every row allocated."""
         from ray_tpu.ops.decode_attention import read_rows
-        stacks = [a for a in (self.state.k, self.state.ring_k) if a.size]
         if not self.cfg.use_flash:
-            return sum(n * S * T for n, S, T, _ in (a.shape for a in stacks))
+            return sum(n * self.slots * T for n, T in self._stacks)
         held = np.asarray(lengths, np.int64)
-        return sum(
-            n * int(read_rows(np.minimum(held, T) - 1, T).sum())
-            for a in stacks for n, _, T, _ in (a.shape,))
+        return sum(n * int(read_rows(np.minimum(held, T) - 1, T).sum())
+                   for n, T in self._stacks)
 
     def _count_loads(self, loads) -> None:
         if loads is not None:

@@ -140,6 +140,7 @@ from ray_tpu.ops import decode_attention, flash_attention, linear_attention
 from ray_tpu.ops import sparse_attention, ssd
 from ray_tpu.ops.flash_attention import KEPT as FLASH_KEPT
 from ray_tpu.ops.linear_attention import decay_rates
+from ray_tpu.ops.selective_scan import selective_scan, selective_scan_step
 from ray_tpu.ops.sparse_attention import SparseConfig
 from ray_tpu.parallel import expert
 from ray_tpu.parallel.expert import ExpertConfig
@@ -149,9 +150,11 @@ from ray_tpu.parallel.sharding import ShardingRules
 
 # a layer's kind
 (DENSE, SPARSE, LINEAR, SHORTCUT, CONV, CONV_MOE, ATTN_MOE, LATENT,
- LATENT_MOE, MAMBA, ATTN, WINDOW_MOE, GLOBAL_MOE) = KINDS = (
+ LATENT_MOE, MAMBA, ATTN, WINDOW_MOE, GLOBAL_MOE, MAMBA1, DIFF_WINDOW,
+ DIFF_GLOBAL, DIFF_CROSS, GMU) = KINDS = (
     "dense", "sparse", "linear", "shortcut", "conv", "conv_moe", "attn_moe",
-    "latent", "latent_moe", "mamba", "attn", "window_moe", "global_moe")
+    "latent", "latent_moe", "mamba", "attn", "window_moe", "global_moe",
+    "mamba1", "diff_window", "diff_global", "diff_cross", "gmu")
 # the kinds made of a token mixer and an FFN chosen apart: (mixer, FFN), each
 # the name of the layer's sub-tree; the FFN's name, ``mlp`` | ``moe``, is its
 # device scope too, and the mixer's scope is ``shortconv``, ``mamba`` or, for
@@ -160,12 +163,21 @@ PARTS = {CONV: ("shortconv", "mlp"), CONV_MOE: ("shortconv", "moe"),
          ATTN_MOE: ("attn", "moe"), LATENT: ("latent", "mlp"),
          LATENT_MOE: ("latent", "moe"), MAMBA: ("mamba", "mlp"),
          ATTN: ("attn", "mlp"), WINDOW_MOE: ("attn", "moe"),
-         GLOBAL_MOE: ("attn", "moe")}
+         GLOBAL_MOE: ("attn", "moe"), MAMBA1: ("mamba1", "mlp"),
+         DIFF_WINDOW: ("diff", "mlp"), DIFF_GLOBAL: ("diff", "mlp"),
+         DIFF_CROSS: ("diff", "mlp"), GMU: ("gmu", "mlp")}
 # the kinds whose router reads the block's input, before the mixer's norm, and
 # hands its choice across the attention to the experts (SmallThinker)
 EARLY_ROUTED = (WINDOW_MOE, GLOBAL_MOE)
+# SambaY's kinds (Phi-4-mini-flash): a self-decoder of Mamba-1 scans and
+# differential attention through a window, one full differential attention
+# whose K and V every later attention reads, and a cross-decoder of gated
+# memory units and attention with no K/V of its own. They stand among each
+# other only: a ``GMU`` reads the last ``MAMBA1`` layer's scan output, a
+# ``DIFF_CROSS`` layer the one ``DIFF_GLOBAL`` layer's K and V
+SAMBAY = (MAMBA1, DIFF_WINDOW, DIFF_GLOBAL, DIFF_CROSS, GMU)
 # the kinds that keep a state across calls (``prefill``, ``decode_step``)
-DECODABLE = (MAMBA, ATTN, WINDOW_MOE, GLOBAL_MOE)
+DECODABLE = (MAMBA, ATTN, WINDOW_MOE, GLOBAL_MOE) + SAMBAY
 
 
 @dataclass(frozen=True)
@@ -203,6 +215,20 @@ class MambaConfig:
     @property
     def conv_dim(self) -> int:
         return self.d_inner + 2 * self.d_state
+
+
+@dataclass(frozen=True)
+class Mamba1Config:
+    """A Mamba-1 mixer's sizes: ``d_inner`` channels, each with a state
+    ``d_state`` wide and a decay of its own a state, a step a channel through
+    a projection of rank ``dt_rank``, and a causal depthwise convolution of
+    ``conv_width`` taps over ``x`` (``phi4flash``'s ``mamba_expand`` x
+    ``hidden_size``, ``mamba_d_state``, ``mamba_dt_rank`` and
+    ``mamba_d_conv``)."""
+    d_inner: int
+    d_state: int
+    dt_rank: int
+    conv_width: int = 4
 
 
 @dataclass(frozen=True)
@@ -270,6 +296,12 @@ class TransformerConfig:
     window: Optional[int] = None
     # A ``MAMBA`` layer's mixer.
     mamba: Optional[MambaConfig] = None
+    # A ``MAMBA1`` layer's mixer (and the width of what a ``GMU`` gates).
+    mamba1: Optional[Mamba1Config] = None
+    # ``SAMBAY``'s kinds: every norm of the stack (a block's two, the final
+    # one) is a LayerNorm with a weight and a bias (leaves ``<name>_b``), and
+    # the attention's projections have biases.
+    layer_norm: bool = False
     # Training: steps over which ``train.step``'s default optimizer raises its
     # learning rate linearly to its full value (0: constant from the first
     # step, as the dense cells train). A router trained from seeded weights
@@ -297,9 +329,12 @@ class TransformerConfig:
                 f"layer_kinds {kinds}: the kinds {sorted(PARTS)} stand "
                 "among each other only (their runs scan a stack they close "
                 "over)")
-        if WINDOW_MOE in kinds and not self.window:
+        if {WINDOW_MOE, DIFF_WINDOW} & set(kinds) and not self.window:
             raise ValueError(
-                f"layer_kinds {kinds}: a {WINDOW_MOE!r} layer needs window=")
+                f"layer_kinds {kinds}: a {WINDOW_MOE!r} or {DIFF_WINDOW!r} "
+                "layer needs window=")
+        if set(kinds) & set(SAMBAY):
+            self._check_sambay(kinds)
         if MAMBA in kinds and self.mamba is None:
             raise ValueError(
                 f"layer_kinds {kinds}: a {MAMBA!r} layer needs mamba= "
@@ -321,6 +356,38 @@ class TransformerConfig:
                 "(ExpertConfig)")
         if self.layer_ids is not None and len(self.layer_ids) != len(kinds):
             raise ValueError(f"layer_ids {self.layer_ids} for {kinds}")
+
+    def _check_sambay(self, kinds) -> None:
+        def first(kind):
+            return kinds.index(kind) if kind in kinds else len(kinds)
+
+        def last(kind):
+            return len(kinds) - 1 - kinds[::-1].index(kind)
+
+        if not set(kinds) <= set(SAMBAY):
+            raise ValueError(
+                f"layer_kinds {kinds}: the kinds {SAMBAY} stand among each "
+                "other only (one layer's scan output and one layer's K and V "
+                "are handed down the stack)")
+        if self.mamba1 is None or not self.layer_norm:
+            raise ValueError(
+                f"layer_kinds {kinds}: {SAMBAY} need mamba1= (Mamba1Config) "
+                "and layer_norm=True")
+        if (kinds.count(DIFF_GLOBAL) > 1
+                or DIFF_CROSS in kinds and not (
+                    DIFF_GLOBAL in kinds
+                    and last(DIFF_GLOBAL) < first(DIFF_CROSS))
+                or GMU in kinds and not (
+                    MAMBA1 in kinds and last(MAMBA1) < first(GMU))):
+            raise ValueError(
+                f"layer_kinds {kinds}: at most one {DIFF_GLOBAL!r} layer, "
+                f"before every {DIFF_CROSS!r} layer, and every {MAMBA1!r} "
+                f"layer before every {GMU!r} layer")
+        if self.n_heads % 2 or self.kv_heads % 2 or self.n_passes != 1:
+            raise ValueError(
+                "differential attention pairs neighbouring heads: n_heads "
+                f"{self.n_heads} and n_kv_heads {self.kv_heads} are even, "
+                "in a stack applied once")
 
     @property
     def kinds(self) -> Tuple[str, ...]:
@@ -385,10 +452,6 @@ def init_params(key: jax.Array, cfg: TransformerConfig) -> Dict[str, Any]:
         leaves them as they are."""
         m = cfg.mamba
         ks = jax.random.split(k, 6)
-
-        def on_grid(v):
-            return v.astype(jnp.bfloat16).astype(jnp.float32)
-
         step = jnp.exp(jax.random.uniform(
             ks[4], (m.n_heads,), jnp.float32, math.log(1e-3), math.log(1e-1)))
         return {
@@ -437,6 +500,82 @@ def init_params(key: jax.Array, cfg: TransformerConfig) -> Dict[str, Any]:
                  "k_norm": jnp.ones((hd,), jnp.float32)}
                 if cfg.qk_norm else {})
 
+    def on_grid(v):
+        return v.astype(jnp.bfloat16).astype(jnp.float32)
+
+    def small(k, shape, scale=0.02):
+        """A bias or a vector drawn at ``scale`` on bfloat16's grid (zeros
+        would leave its path unread; a cast to the serving dtype leaves it as
+        drawn)."""
+        return on_grid(scale * jax.random.normal(k, shape, jnp.float32))
+
+    def mamba1_mixer(k):
+        """A Mamba-1 mixer's weights: ``A = -exp(a_log)`` [d_inner, d_state]
+        uniform in -1..-16 and ``dt_bias`` so that ``softplus(dt_bias)`` is
+        log-uniform in 0.001..0.1 a channel (as ``mamba_mixer`` draws a
+        head's), ``d_skip`` ones; no bias on a projection."""
+        m = cfg.mamba1
+        ks = jax.random.split(k, 8)
+        step = jnp.exp(jax.random.uniform(
+            ks[5], (m.d_inner,), jnp.float32, math.log(1e-3),
+            math.log(1e-1)))
+        return {
+            "w_in": dense(ks[0], (d, 2 * m.d_inner), d),           # [x | z]
+            "conv": dense(ks[1], (m.d_inner, m.conv_width), m.conv_width),
+            "conv_bias": small(ks[2], (m.d_inner,)),
+            # [dt's rank | B | C]
+            "w_x": dense(ks[3], (m.d_inner, m.dt_rank + 2 * m.d_state),
+                         m.d_inner),
+            "w_dt": dense(ks[4], (m.dt_rank, m.d_inner), m.dt_rank),
+            "dt_bias": on_grid(step + jnp.log(-jnp.expm1(-step))),
+            "a_log": on_grid(jnp.log(jax.random.uniform(
+                ks[6], (m.d_inner, m.d_state), jnp.float32, 1.0, 16.0))),
+            "d_skip": jnp.ones((m.d_inner,), jnp.float32),
+            "w_out": dense(ks[7], (m.d_inner, d), m.d_inner)}
+
+    def diff_attention(k, kind):
+        """Differential attention's weights: a fused ``[q | k | v]``
+        projection with its bias (a ``DIFF_CROSS`` layer has q alone: it
+        reads another layer's K and V), the output projection with its bias,
+        the four ``lambda`` vectors of ``head_dim`` (drawn at 0.1, as the
+        Differential Transformer initialises them) and the sub-norm's weight
+        over ``2 head_dim``."""
+        ks = jax.random.split(k, 8)
+        q_only = kind == DIFF_CROSS
+        width = h * hd if q_only else (h + 2 * kvh) * hd
+        name = "q" if q_only else "qkv"
+        return {
+            "w" + name: dense(ks[0], (d, width), d),
+            "b" + name: small(ks[1], (width,)),
+            "wo": dense(ks[2], (h * hd, d), h * hd),
+            "bo": small(ks[3], (d,)),
+            **{n: small(kk, (hd,), 0.1) for n, kk in zip(
+                ("lambda_q1", "lambda_k1", "lambda_q2", "lambda_k2"),
+                ks[4:])},
+            "subln": jnp.ones((2 * hd,), jnp.float32)}
+
+    def sambay_layer(k, kind):
+        """One of ``SAMBAY``'s layers: the mixer and the dense FFN from the
+        halves of the layer's key, as ``parts_layer``, and the two
+        LayerNorms' biases from the key folded with 2."""
+        mixer, _ = PARTS[kind]
+        k_mixer, k_ffn = jax.random.split(k)
+        k_b1, k_b2 = jax.random.split(jax.random.fold_in(k, 2))
+        if mixer == "mamba1":
+            mixed = mamba1_mixer(k_mixer)
+        elif mixer == "gmu":
+            ks = jax.random.split(k_mixer)
+            mixed = {"w_in": dense(ks[0], (d, cfg.mamba1.d_inner), d),
+                     "w_out": dense(ks[1], (cfg.mamba1.d_inner, d),
+                                    cfg.mamba1.d_inner)}
+        else:
+            mixed = diff_attention(k_mixer, kind)
+        return {mixer: mixed, "mlp": ffn(k_ffn, f),
+                "ln1": jnp.ones((d,), jnp.float32),
+                "ln1_b": small(k_b1, (d,)),
+                "ln2": jnp.ones((d,), jnp.float32),
+                "ln2_b": small(k_b2, (d,))}
+
     def parts_layer(k, kind):
         """A mixer and an FFN (``PARTS``), each from its own half of the
         layer's key. The router's bias, used for the choice alone, is drawn
@@ -446,6 +585,8 @@ def init_params(key: jax.Array, cfg: TransformerConfig) -> Dict[str, Any]:
         from a key folded with its published index, as a shortcut layer's;
         the shared experts (``experts.shared_width``) from the FFN's key
         folded with 3, beside the three it is split into."""
+        if kind in SAMBAY:
+            return sambay_layer(k, kind)
         mixer, feed = PARTS[kind]
         k_mixer, k_ffn = jax.random.split(k)
         if mixer == "latent":
@@ -548,6 +689,9 @@ def init_params(key: jax.Array, cfg: TransformerConfig) -> Dict[str, Any]:
                                    jnp.float32) * 0.02,
         "blocks": blocks(),
         "ln_f": jnp.ones((d,), jnp.float32),
+        # the final LayerNorm's bias, from the key folded with 4
+        **({"ln_f_b": small(jax.random.fold_in(key, 4), (d,))}
+           if cfg.layer_norm else {}),
         # tied: no leaf, the head reads the embedding
         **({} if cfg.tie_embeddings
            else {"lm_head": dense(k_head, (d, cfg.vocab_size), d)}),
@@ -614,6 +758,28 @@ def logical_axes(cfg: TransformerConfig) -> Dict[str, Any]:
                     "ln_attn": ("layers", None, None),
                     "ln_mlp": ("layers", None, None),
                 }
+            if kind in SAMBAY:
+                # on one device too: only the layers' axis is named
+                mixer, _ = PARTS[kind]
+                vec, mat = ("layers", None), ("layers", None, None)
+                if mixer == "mamba1":
+                    mixed = {"w_in": ("layers", "embed", None), "conv": mat,
+                             "conv_bias": vec, "w_x": mat, "w_dt": mat,
+                             "dt_bias": vec, "a_log": mat, "d_skip": vec,
+                             "w_out": ("layers", None, "embed")}
+                elif mixer == "gmu":
+                    mixed = {"w_in": ("layers", "embed", None),
+                             "w_out": ("layers", None, "embed")}
+                else:
+                    name = "q" if kind == DIFF_CROSS else "qkv"
+                    mixed = {"w" + name: ("layers", "embed", None),
+                             "b" + name: vec,
+                             "wo": ("layers", None, "embed"), "bo": vec,
+                             "lambda_q1": vec, "lambda_k1": vec,
+                             "lambda_q2": vec, "lambda_k2": vec,
+                             "subln": vec}
+                return {mixer: mixed, "mlp": blk["mlp"], "ln1": vec,
+                        "ln1_b": vec, "ln2": vec, "ln2_b": vec}
             if kind in PARTS:
                 # on one device, as the shortcut kind
                 mixer, feed = PARTS[kind]
@@ -657,6 +823,8 @@ def logical_axes(cfg: TransformerConfig) -> Dict[str, Any]:
         "blocks": blk,
         "ln_f": (None,),
     }
+    if cfg.layer_norm:
+        axes["ln_f_b"] = (None,)
     if not cfg.tie_embeddings:
         axes["lm_head"] = ("embed", "vocab")
     if cfg.exit_beta is not None:
@@ -775,12 +943,16 @@ def _kind_attention(cfg: TransformerConfig, kind: str
 
 def _kind_scope(kind: str):
     """The scope inside ``attn`` that tells a kind's attention from the
-    others': ``swa``, ``nope``, or none (the names are literals: the
-    registry's test reads the source)."""
-    if kind == WINDOW_MOE:
+    others': ``swa``, ``nope``, ``global``, ``cross`` or none (the names are
+    literals: the registry's test reads the source)."""
+    if kind in (WINDOW_MOE, DIFF_WINDOW):
         return jax.named_scope("swa")
     if kind == GLOBAL_MOE:
         return jax.named_scope("nope")
+    if kind == DIFF_GLOBAL:
+        return jax.named_scope("global")
+    if kind == DIFF_CROSS:
+        return jax.named_scope("cross")
     return contextlib.nullcontext()
 
 
@@ -1006,6 +1178,410 @@ def _shortconv_mixer(conv, h):
         c = c + shifted * taps[:, k - 1 - back]
     return jnp.einsum("bld,de->ble", c_gate * c.astype(h.dtype),
                       conv["w_out"].astype(h.dtype))
+
+
+def _layernorm(x: jax.Array, w: jax.Array, b: jax.Array, eps: float
+               ) -> jax.Array:
+    xf = x.astype(jnp.float32)
+    xf = xf - jnp.mean(xf, axis=-1, keepdims=True)
+    var = jnp.mean(xf * xf, axis=-1, keepdims=True)
+    return (xf * jax.lax.rsqrt(var + eps)).astype(x.dtype) \
+        * w.astype(x.dtype) + b.astype(x.dtype)
+
+
+# -- SambaY: Mamba-1, differential attention, the gated memory unit ----------
+
+
+def _mamba1_scan_inputs(p, window, cfg: TransformerConfig):
+    """From the convolution's window ``window`` [..., taps, d_inner] (the
+    position's own row last): ``x = silu(conv + bias)`` in the compute dtype,
+    ``[r | B | C] = x W_x`` and ``dt = softplus(r W_dt + dt_bias)`` a channel
+    (both products leave the MXU in float32: the step's size is the state's
+    rounding), and ``A = -exp(a_log)`` laid [d_state, d_inner], float32."""
+    m = cfg.mamba1
+    f32 = jnp.float32
+    taps = p["conv"].astype(f32).T                              # [taps, C]
+    x = jax.nn.silu(jnp.sum(window.astype(f32) * taps, axis=-2)
+                    + p["conv_bias"].astype(f32)).astype(window.dtype)
+    rbc = jnp.einsum("...c,ce->...e", x, p["w_x"].astype(x.dtype),
+                     preferred_element_type=f32)
+    r, b, c = (rbc[..., :m.dt_rank],
+               rbc[..., m.dt_rank:m.dt_rank + m.d_state],
+               rbc[..., m.dt_rank + m.d_state:])
+    dt = jax.nn.softplus(
+        jnp.einsum("...r,rc->...c", r.astype(x.dtype),
+                   p["w_dt"].astype(x.dtype), preferred_element_type=f32)
+        + p["dt_bias"].astype(f32))
+    return x, b, c, dt, -jnp.exp(p["a_log"].astype(f32)).T
+
+
+def _mamba1_output(p, y, x, z):
+    """``(W_out(m * silu(z)), m)`` with ``m = y + D x``, the scan's output
+    before its gate: what a ``GMU`` layer gates again (in the compute
+    dtype)."""
+    m = y.astype(jnp.float32) + x.astype(jnp.float32) \
+        * p["d_skip"].astype(jnp.float32)
+    g = (m * jax.nn.silu(z.astype(jnp.float32))).astype(z.dtype)
+    return (jnp.einsum("...e,ed->...d", g, p["w_out"].astype(z.dtype)),
+            m.astype(z.dtype))
+
+
+def _mamba1_mixer(p, h, cfg: TransformerConfig, lengths=None):
+    """A Mamba-1 mixer on the normed states ``h`` [B, L, d]: ``[x | z] = h
+    W_in``; ``x = silu(conv(x) + b)``, depthwise and causal; ``[r | B | C] =
+    x W_x``; ``dt = softplus(r W_dt + dt_bias)``; a channel's ``S_t =
+    exp(dt_t A) S_{t-1} + dt_t x_t B_t^T``, ``y_t = S_t C_t + D x_t``
+    (``ops.selective_scan``: ``core``; its Mosaic call with
+    ``cfg.use_flash``); ``W_out(y * silu(z))``. With
+    ``lengths`` [B] a position past a sequence's length steps by 0. Returns
+    the output and what is kept: the state after the last real position [B,
+    d_state, d_inner] float32, the convolution's tail there (the last
+    ``conv_width - 1`` rows of ``x`` before the convolution) and ``y`` itself
+    [B, L, d_inner], the memory. The caller enters the ``mamba`` scope."""
+    m = cfg.mamba1
+    B, L, _ = h.shape
+    u = jnp.einsum("bld,de->ble", h, p["w_in"].astype(h.dtype))
+    x, z = u[..., :m.d_inner], u[..., m.d_inner:]
+    back = m.conv_width - 1
+    padded = jnp.pad(x, ((0, 0), (back, 0), (0, 0)))
+    window = jnp.stack([padded[:, j:j + L] for j in range(m.conv_width)],
+                       axis=2)                              # [B, L, taps, C]
+    x, b, c, dt, a = _mamba1_scan_inputs(p, window, cfg)
+    if lengths is not None:
+        dt = jnp.where((jnp.arange(L)[None] < lengths[:, None])[..., None],
+                       dt, 0.0)
+    with jax.named_scope("core"):
+        y, state = selective_scan(x, dt, a, b, c, use_kernel=cfg.use_flash)
+    ends = jnp.full((B,), L) if lengths is None else lengths
+    tail = jax.vmap(lambda rows, n: jax.lax.dynamic_slice_in_dim(
+        rows, n, back))(padded, ends)                       # [B, back, C]
+    out, memory = _mamba1_output(p, y, x, z)
+    return out, (state, tail, memory)
+
+
+def _mamba1_step(p, h, cfg: TransformerConfig, ssm, conv, l):
+    """One token a slot in Mamba-1 layer ``l`` of the stacked state: ``h`` [S,
+    d] normed states, ``ssm`` [n, S, d_state, d_inner] float32 and ``conv``
+    [n, S, conv_width - 1, d_inner]. ``_mamba1_mixer``'s arithmetic with the
+    recurrence itself (``ops.selective_scan_step``) on the layer's state, read
+    from the stack and written back where it was read. Returns the output,
+    the memory [S, d_inner] and the two stacks."""
+    m = cfg.mamba1
+    u = jnp.einsum("sd,de->se", h, p["w_in"].astype(h.dtype))
+    x, z = u[..., :m.d_inner], u[..., m.d_inner:]
+    tail = jax.lax.dynamic_index_in_dim(conv, l, 0, keepdims=False)
+    window = jnp.concatenate([tail.astype(x.dtype), x[:, None]], axis=1)
+    x, b, c, dt, a = _mamba1_scan_inputs(p, window, cfg)
+    with jax.named_scope("core"):
+        y, state = selective_scan_step(
+            x, dt, a, b, c,
+            jax.lax.dynamic_index_in_dim(ssm, l, 0, keepdims=False))
+        ssm = jax.lax.dynamic_update_index_in_dim(ssm, state, l, 0)
+    conv = jax.lax.dynamic_update_index_in_dim(
+        conv, window[:, 1:].astype(conv.dtype), l, 0)
+    out, memory = _mamba1_output(p, y, x, z)
+    return out, memory, ssm, conv
+
+
+def _gmu(p, h, memory):
+    """A gated memory unit on the normed states ``h`` [..., d]: ``W_out(m *
+    silu(h W_in))``, ``m`` [..., d_inner] the kept scan output at the same
+    position. The caller enters the ``gmu`` scope."""
+    gate = jnp.einsum("...d,de->...e", h, p["w_in"].astype(h.dtype))
+    g = (memory.astype(jnp.float32)
+         * jax.nn.silu(gate.astype(jnp.float32))).astype(h.dtype)
+    return jnp.einsum("...e,ed->...d", g, p["w_out"].astype(h.dtype))
+
+
+def lambda_init(depth):
+    """Differential attention's ``lambda_init`` at layer index ``depth`` (a
+    number or a traced one): ``0.8 - 0.6 exp(-0.3 depth)``."""
+    return 0.8 - 0.6 * jnp.exp(-0.3 * jnp.asarray(depth, jnp.float32))
+
+
+def _diff_projected(p, h, cfg: TransformerConfig):
+    """``[q | k | v] = h W + b`` as heads, q [..., H, D] and k, v [..., G,
+    D]; of a layer with q alone (``DIFF_CROSS``) ``(q, None, None)``."""
+    H, G, D = cfg.n_heads, cfg.kv_heads, cfg.head_dim
+    if "wq" in p:
+        q = jnp.einsum("...d,de->...e", h, p["wq"].astype(h.dtype)) \
+            + p["bq"].astype(h.dtype)
+        return q.reshape(*q.shape[:-1], H, D), None, None
+    u = jnp.einsum("...d,de->...e", h, p["wqkv"].astype(h.dtype)) \
+        + p["bqkv"].astype(h.dtype)
+    lead = u.shape[:-1]
+    return (u[..., :H * D].reshape(*lead, H, D),
+            u[..., H * D:(H + G) * D].reshape(*lead, G, D),
+            u[..., (H + G) * D:].reshape(*lead, G, D))
+
+
+def _diff_combine(p, o, depth, cfg: TransformerConfig):
+    """From each query head's own map times its group's values, ``o`` [...,
+    H, 2 D] (head ``2 p + i`` is map ``i`` of pair ``p``): ``lambda =
+    exp(lq1 . lk1) - exp(lq2 . lk2) + lambda_init``; ``o_p = RMSNorm_2D(o_2p
+    - lambda o_2p+1) * (1 - lambda_init)``, float32; the pairs side by side
+    through ``W_out`` with its bias."""
+    f32 = jnp.float32
+    init = lambda_init(depth)
+    lam = (jnp.exp(jnp.sum(p["lambda_q1"].astype(f32)
+                           * p["lambda_k1"].astype(f32)))
+           - jnp.exp(jnp.sum(p["lambda_q2"].astype(f32)
+                             * p["lambda_k2"].astype(f32))) + init)
+    lead = o.shape[:-2]
+    pairs = o.astype(f32).reshape(*lead, cfg.n_heads // 2, 2, o.shape[-1])
+    d = pairs[..., 0, :] - lam * pairs[..., 1, :]
+    d = d * jax.lax.rsqrt(jnp.mean(d * d, axis=-1, keepdims=True)
+                          + cfg.norm_eps) \
+        * p["subln"].astype(f32) * (1.0 - init)
+    flat = d.astype(o.dtype).reshape(*lead, -1)
+    return jnp.einsum("...e,ed->...d", flat, p["wo"].astype(o.dtype)) \
+        + p["bo"].astype(o.dtype)
+
+
+def _diff_core(q, k, v, cfg: TransformerConfig, window: Optional[int]):
+    """Every query head's causal softmax map over its own key head, times its
+    group's values: q [B, L, H, D], k and v [B, Lk, G, D] -> [B, L, H, 2 D].
+    Query head ``2 p + i`` (map ``i`` of pair ``p``) reads key head ``2 g +
+    i`` of its group ``g = p // (H / G)`` and the group's values ``[v_2g |
+    v_2g+1]``. The heads of a group are put map by map (``i`` before ``p``),
+    which is the order in which head ``h`` reads K/V head ``h // (H / G)``, as
+    ``_attention`` groups them; K head ``2 g + i`` brings the group's values,
+    twice as wide as a key (the flash kernel takes a value width of its own;
+    a window is the kernel's)."""
+    B, L, H, D = q.shape
+    G, rep = k.shape[2], H // k.shape[2]
+
+    wide = jnp.repeat(v.reshape(*v.shape[:2], G // 2, 2 * D), 2, axis=2)
+    q = q.reshape(B, L, G // 2, rep, 2, D).swapaxes(3, 4).reshape(B, L, H, D)
+    o = _attention(q, k, wide, cfg, None, None, window)
+    return o.reshape(B, L, G // 2, 2, rep, 2 * D).swapaxes(3, 4).reshape(
+        B, L, H, 2 * D)
+
+
+def _diff_row(p, h, cfg: TransformerConfig, k_cache, v_cache, l, newest,
+              depth, write_at=None):
+    """One query a slot in differential attention over layer ``l`` of the
+    stacks ``k_cache``, ``v_cache`` [n, S, T, G x D]: ``h`` [S, d] normed
+    states, ``newest`` [S] the last row each slot's query sees. With
+    ``write_at`` [S] the layer's own k and v are first written as a row
+    there (a window layer's ring at ``position % T``, the full cache at the
+    position); a layer with q alone reads what another layer wrote. Both maps
+    are one call of ``ops.decode_attention`` as grouped-query attention has
+    it (``_attention_step``): a query head laid over its own key head's
+    lanes, 2 H softmaxes each normalised by itself, and each returns its map
+    times *all* the V heads, of which the head's group's two are taken. Then
+    ``_diff_combine``. Returns the output and the two stacks."""
+    S = h.shape[0]
+    H, G, D = cfg.n_heads, cfg.kv_heads, cfg.head_dim
+    q, k, v = _diff_projected(p, h, cfg)
+    if write_at is not None:
+        slot = jnp.arange(S)
+        k_cache = k_cache.at[l, slot, write_at].set(
+            k.reshape(S, G * D).astype(k_cache.dtype))
+        v_cache = v_cache.at[l, slot, write_at].set(
+            v.reshape(S, G * D).astype(v_cache.dtype))
+    with jax.named_scope("core"):
+        group = jnp.arange(H) // (2 * (H // G))
+        # own[h, j]: query head h reads K head j
+        own = ((2 * group + jnp.arange(H) % 2)[:, None]
+               == jnp.arange(G)[None])[None, :, :, None]
+        spread = jnp.where(own, q[:, :, None, :], 0).reshape(S, H, G * D)
+        o = decode_attention(spread.astype(k_cache.dtype), k_cache, v_cache,
+                             l, newest, 1 / math.sqrt(D),
+                             use_kernel=cfg.use_flash)
+        mine = (group[:, None] == jnp.arange(G // 2)[None])[None, :, :, None]
+        o = jnp.sum(jnp.where(mine, o.reshape(S, H, G // 2, 2 * D), 0),
+                    axis=2)
+    return _diff_combine(p, o, depth, cfg), k_cache, v_cache
+
+
+def _sambay_runs(cfg: TransformerConfig, lo: int = 0,
+                 hi: Optional[int] = None):
+    """The layers ``lo .. hi - 1`` of a ``SAMBAY`` stack as runs of a period
+    repeated: ``(the period's kinds, each kind's first index in blocks[kind],
+    repeats, the first layer's place in the stack)``. A period is two
+    neighbouring layers of different kinds, else one (the published stack is
+    8 x (scan, window), (scan, full), 7 x (GMU, cross)): a run is one loop
+    whose body holds the period's layers, so 32 layers compile as five."""
+    kinds = cfg.kinds
+    hi = len(kinds) if hi is None else hi
+    taken = collections.Counter(kinds[:lo])
+
+    def repeats(at, width):
+        """How often the ``width`` layers from ``at`` on come in a row (0: a
+        pair of one kind, or past the end)."""
+        period = kinds[at:at + width]
+        if at + width > hi or len(set(period)) < width:
+            return 0
+        n = 1
+        while (at + (n + 1) * width <= hi
+               and kinds[at + n * width:at + (n + 1) * width] == period):
+            n += 1
+        return n
+
+    at = lo
+    while at < hi:
+        # a pair, unless it comes once and its second layer starts a pair
+        # that repeats
+        pairs = repeats(at, 2)
+        width = 2 if pairs > 1 or pairs == 1 and repeats(at + 1, 2) < 2 else 1
+        period, n = kinds[at:at + width], repeats(at, width)
+        yield period, tuple(taken[kind] for kind in period), n, at
+        taken.update({kind: n for kind in period})
+        at += n * width
+
+
+def _layer_depths(cfg: TransformerConfig) -> jax.Array:
+    """Each layer's index in the published stack (``lambda_init`` reads
+    it)."""
+    return jnp.asarray(cfg.layer_ids or range(cfg.n_layers), jnp.int32)
+
+
+def _sambay_parts(stack, l, cfg: TransformerConfig):
+    """``(part, norm)`` of layer ``l`` of a stacked ``SAMBAY`` tree: a
+    sub-tree read from the stack where it is used (``_at``) and the block's
+    LayerNorm ``name`` of ``x``."""
+    def part(name):
+        return jax.tree.map(lambda p: _at(p, l), stack[name])
+
+    def norm(x, name):
+        return _layernorm(x, part(name), part(name + "_b"), cfg.norm_eps)
+
+    return part, norm
+
+
+def _sambay_block(stack, l, x, cfg: TransformerConfig, kind: str, depth,
+                  carried: Dict[str, jax.Array], lengths=None):
+    """Layer ``l`` of the stacked tree ``stack`` of one of ``SAMBAY``'s kinds
+    over every position: ``h = x + Mixer(LN1(x))``; ``y = h + MLP(LN2(h))``,
+    the mixer a Mamba-1 scan, a gated memory unit over ``carried["memory"]``,
+    or differential attention (through ``cfg.window`` for ``DIFF_WINDOW``; a
+    ``DIFF_CROSS`` layer's over ``carried["k"]``, ``carried["v"]``).
+    ``depth`` is the layer's published index. Returns the states and what
+    the mixer hands on: ``(state, tail, memory)`` of a scan, ``(k, v)`` of an
+    attention with K and V of its own."""
+    part, norm = _sambay_parts(stack, l, cfg)
+    mixer = PARTS[kind][0]
+    kept: tuple = ()
+    if mixer == "mamba1":
+        with jax.named_scope("mamba"):
+            out, kept = _mamba1_mixer(part("mamba1"), norm(x, "ln1"), cfg,
+                                      lengths)
+            x = x + out
+    elif mixer == "gmu":
+        with jax.named_scope("gmu"):
+            x = x + _gmu(part("gmu"), norm(x, "ln1"), carried["memory"])
+    else:
+        with jax.named_scope("attn"), _kind_scope(kind):
+            p = part("diff")
+            q, k, v = _diff_projected(p, norm(x, "ln1"), cfg)
+            if kind == DIFF_CROSS:
+                k, v = carried["k"], carried["v"]
+            else:
+                kept = (k, v)
+            with jax.named_scope("core"):
+                o = _diff_core(q, k, v, cfg,
+                               cfg.window if kind == DIFF_WINDOW else None)
+            x = x + _diff_combine(p, o, depth, cfg)
+    with jax.named_scope("mlp"):
+        return x + _mlp(part("mlp"), norm(x, "ln2")), kept
+
+
+def _apply_sambay(blocks, x, cfg: TransformerConfig, lengths=None,
+                  hi: Optional[int] = None):
+    """The layers before place ``hi`` (all, if None) of a ``SAMBAY`` stack
+    over every position of ``x`` [B, L, d]: a scan a run (``_sambay_runs``)
+    over the run's periods, the stacked trees closed over. The last scan
+    layer's output and the full attention layer's K and V are handed down
+    the stack to the layers that read them. Returns the states and, by kind,
+    what each run's layers kept, stacked over the run's repeats (``MAMBA1``:
+    state, tail and, of the run that holds the kind's last layer alone, the
+    memory; an attention kind: k, v)."""
+    depths = _layer_depths(cfg)
+    last_scan = cfg.kinds.count(MAMBA1) - 1
+    carried: Dict[str, jax.Array] = {}
+    kept_by_kind: Dict[str, list] = collections.defaultdict(list)
+    for period, starts, n, at in _sambay_runs(cfg, 0, hi):
+        # the memory leaves the run that holds the last scan layer
+        remembers = [kind == MAMBA1 and start + n - 1 == last_scan
+                     for kind, start in zip(period, starts)]
+
+        def one(x, i, period=period, starts=starts, at=at,
+                remembers=remembers):
+            out = []
+            for j, (kind, start) in enumerate(zip(period, starts)):
+                fn = functools.partial(_sambay_block, cfg=cfg, kind=kind)
+                if cfg.remat:
+                    fn = jax.checkpoint(fn)
+                x, kept = fn(blocks[kind], start + i, x,
+                             depth=depths[at + i * len(period) + j],
+                             carried=carried, lengths=lengths)
+                out.append(kept if remembers[j] or kind != MAMBA1
+                           else kept[:2])
+            return x, tuple(out)
+
+        x, outs = jax.lax.scan(one, x, jnp.arange(n))
+        for kind, kept, remembered in zip(period, outs, remembers):
+            kept_by_kind[kind].append(kept)
+            if remembered:
+                carried["memory"] = kept[2][-1]
+            if kind == DIFF_GLOBAL:
+                carried["k"], carried["v"] = kept[0][-1], kept[1][-1]
+    return x, kept_by_kind
+
+
+def _sambay_rows(blocks, x, cfg: TransformerConfig, lo: int, pieces, memory,
+                 lengths, newest, write: bool):
+    """The layers from place ``lo`` on of a ``SAMBAY`` stack on one row a
+    sequence, ``x`` [S, d]: the decode step's layers (``write``: a scan layer
+    steps its state, an attention layer writes its k and v at position
+    ``lengths``, a window layer into its ring) and, without ``write``, the
+    cross-decoder on a prompt's last position (``prefill``: nothing is
+    written; the full attention reads what the prompt's positions left).
+    ``pieces`` is ``(ssm, conv, k, v, ring_k, ring_v)``, ``memory`` [S,
+    d_inner] what a ``GMU`` gates until a scan layer of the run has made its
+    own, ``newest`` [S] the last row of the full cache a query sees. Each run
+    is a loop over its periods with the stacks as the carry, read and written
+    where they lie. Returns the states and the stacks."""
+    depths = _layer_depths(cfg)
+    rows = pieces[4].shape[2]
+    for period, starts, n, at in _sambay_runs(cfg, lo):
+        def one(i, carry, period=period, starts=starts, at=at):
+            x, ssm, conv, k_cache, v_cache, ring_k, ring_v, memory = carry
+            for j, (kind, start) in enumerate(zip(period, starts)):
+                l = start + i
+                depth = depths[at + i * len(period) + j]
+                part, norm = _sambay_parts(blocks[kind], l, cfg)
+                mixer = PARTS[kind][0]
+                if mixer == "mamba1":
+                    with jax.named_scope("mamba"):
+                        out, memory, ssm, conv = _mamba1_step(
+                            part("mamba1"), norm(x, "ln1"), cfg, ssm, conv, l)
+                        x = x + out
+                elif mixer == "gmu":
+                    with jax.named_scope("gmu"):
+                        x = x + _gmu(part("gmu"), norm(x, "ln1"), memory)
+                elif kind == DIFF_WINDOW:
+                    with jax.named_scope("attn"), _kind_scope(kind):
+                        out, ring_k, ring_v = _diff_row(
+                            part("diff"), norm(x, "ln1"), cfg, ring_k, ring_v,
+                            l, jnp.minimum(lengths, rows - 1), depth,
+                            _ring_row(lengths, rows))
+                        x = x + out
+                else:       # the one full cache: its writer or a reader
+                    with jax.named_scope("attn"), _kind_scope(kind):
+                        out, k_cache, v_cache = _diff_row(
+                            part("diff"), norm(x, "ln1"), cfg, k_cache,
+                            v_cache, 0, newest, depth,
+                            newest if write and kind == DIFF_GLOBAL else None)
+                        x = x + out
+                with jax.named_scope("mlp"):
+                    x = x + _mlp(part("mlp"), norm(x, "ln2")[None])[0]
+            return x, ssm, conv, k_cache, v_cache, ring_k, ring_v, memory
+
+        x, *pieces, memory = jax.lax.fori_loop(0, n, one,
+                                               (x, *pieces, memory))
+    return x, pieces
 
 
 def _block(params, x, positions, cfg: TransformerConfig, mesh, rules=None):
@@ -1402,6 +1978,8 @@ def _apply_mixed(blocks, x, positions, cfg: TransformerConfig, mesh):
     _one_device(cfg, mesh)
     if SHORTCUT in cfg.kinds:       # a stack of its own kind
         return _apply_shortcut(blocks[SHORTCUT], x, positions, cfg)
+    if set(cfg.kinds) <= set(SAMBAY):
+        return _apply_sambay(blocks, x, cfg)[0]
     if set(cfg.kinds) <= set(PARTS):
         return _apply_parts(blocks, x, positions, cfg)
     ids = cfg.layer_ids or tuple(range(cfg.n_layers))
@@ -1625,12 +2203,19 @@ def _head_weight(params: Dict[str, Any], cfg: TransformerConfig) -> jax.Array:
     return params["lm_head"].astype(cfg.dtype)
 
 
+def _final_norm(params: Dict[str, Any], x: jax.Array,
+                cfg: TransformerConfig) -> jax.Array:
+    if cfg.layer_norm:
+        return _layernorm(x, params["ln_f"], params["ln_f_b"], cfg.norm_eps)
+    return _rmsnorm(x, params["ln_f"], cfg.norm_eps)
+
+
 def head(params: Dict[str, Any], x: jax.Array,
          cfg: TransformerConfig) -> jax.Array:
     """Final norm + lm-head projection -> float32 logits. The single logits
     path shared by inference (``apply``) and training (``token_nll``)."""
     with jax.named_scope("head"):
-        x = _rmsnorm(x, params["ln_f"], cfg.norm_eps)
+        x = _final_norm(params, x, cfg)
         if cfg.logit_scale != 1.0:
             x = x * cfg.logit_scale
         logits = jnp.einsum("bld,dv->blv", x, _head_weight(params, cfg))
@@ -1660,7 +2245,15 @@ class DecodeState(NamedTuple):
     a ring of ``W = min(window, T)`` rows in which position ``p`` lives in row
     ``p % W`` (``_attention_step``). ``k`` and ``v`` are the layers' that
     attend over everything (``ATTN``, ``GLOBAL_MOE``). A stack without a kind
-    of layer holds an empty array in its place."""
+    of layer holds an empty array in its place.
+
+    A ``SAMBAY`` stack keeps three kinds of state: a ``MAMBA1`` layer's
+    ``ssm`` [n, S, d_state, d_inner] float32 (a channel's states down a lane)
+    and ``conv`` [n, S, conv_width - 1, d_inner]; a ``DIFF_WINDOW`` layer's
+    ring; and **one** ``k`` / ``v`` [1, S, T, ...] that the ``DIFF_GLOBAL``
+    layer writes and that layer and every ``DIFF_CROSS`` layer read (a
+    ``GMU`` and a ``DIFF_CROSS`` layer keep nothing: what a ``GMU`` gates is
+    made again each step by the last scan layer)."""
     ssm: jax.Array
     conv: jax.Array
     k: jax.Array
@@ -1673,9 +2266,22 @@ class DecodeState(NamedTuple):
 def _cache_of(kind: str) -> str:
     """Which of ``DecodeState``'s stacks a ``DECODABLE`` kind's layer keeps
     its share in: ``state`` (``ssm`` and ``conv``), ``full`` (``k`` and
-    ``v``) or ``ring`` (``ring_k`` and ``ring_v``)."""
-    return ("state" if kind == MAMBA else "ring" if kind == WINDOW_MOE
-            else "full")
+    ``v``), ``ring`` (``ring_k`` and ``ring_v``) or ``none`` (a layer that
+    reads what other layers keep)."""
+    return ("state" if kind in (MAMBA, MAMBA1)
+            else "ring" if kind in (WINDOW_MOE, DIFF_WINDOW)
+            else "none" if kind in (DIFF_CROSS, GMU) else "full")
+
+
+def cache_readers(cfg: TransformerConfig) -> Dict[str, int]:
+    """How many layers of the stack read each K/V stack of ``DecodeState`` in
+    a decode step: ``full`` (``k`` and ``v``: the layers that keep a cache of
+    their own, and every ``DIFF_CROSS`` layer beside the one ``DIFF_GLOBAL``
+    layer whose cache it reads) and ``ring`` (the window layers, a ring
+    each)."""
+    held = collections.Counter(_cache_of(kind) for kind in cfg.kinds)
+    return {"full": held["full"] + cfg.kinds.count(DIFF_CROSS),
+            "ring": held["ring"]}
 
 
 def _decodable(cfg: TransformerConfig) -> None:
@@ -1695,11 +2301,15 @@ def init_decode_state(cfg: TransformerConfig, slots: int,
     row = cfg.kv_heads * cfg.head_dim
     kv = (n["full"], slots, cache_len, row)
     ring = (n["ring"], slots, min(cfg.window or 0, cache_len), row)
+    if cfg.mamba1 is not None:
+        m1 = cfg.mamba1
+        ssm, conv = ((n_mamba, slots, m1.d_state, m1.d_inner),
+                     (n_mamba, slots, m1.conv_width - 1, m1.d_inner))
+    else:
+        ssm, conv = ((n_mamba, slots, m.n_heads, m.head_dim, m.d_state),
+                     (n_mamba, slots, m.conv_width - 1, m.conv_dim))
     return DecodeState(
-        ssm=jnp.zeros((n_mamba, slots, m.n_heads, m.head_dim, m.d_state),
-                      jnp.float32),
-        conv=jnp.zeros((n_mamba, slots, m.conv_width - 1, m.conv_dim),
-                       cfg.dtype),
+        ssm=jnp.zeros(ssm, jnp.float32), conv=jnp.zeros(conv, cfg.dtype),
         k=jnp.zeros(kv, cfg.dtype), v=jnp.zeros(kv, cfg.dtype),
         lengths=jnp.zeros((slots,), jnp.int32),
         ring_k=jnp.zeros(ring, cfg.dtype), ring_v=jnp.zeros(ring, cfg.dtype))
@@ -1728,6 +2338,8 @@ def prefill(params: Dict[str, Any], tokens: jax.Array, lengths: jax.Array,
     what the layers keep (one prompt's is small: 2 MB a Mamba layer). The
     third result is the mixtures' loads (``_joined_loads``)."""
     _decodable(cfg)
+    if set(cfg.kinds) <= set(SAMBAY):
+        return _sambay_prefill(params, tokens, lengths, cfg)
     B, L = tokens.shape
     x = _embed(params, tokens, cfg)
     positions = jnp.broadcast_to(jnp.arange(L)[None], (B, L))
@@ -1755,13 +2367,8 @@ def prefill(params: Dict[str, Any], tokens: jax.Array, lengths: jax.Array,
         whole = jnp.concatenate([run[i] for run in runs])
         if cache != "state":  # [n, B, L, kv_heads, head_dim]: a row a position
             whole = whole.reshape(*whole.shape[:3], -1)
-        if cache == "ring" and rows < L:
-            # row r: the last position p < length with p % rows == r (a row
-            # the prompt has not reached holds whatever: its age masks it)
-            r = jnp.arange(rows)[None]
-            p = r + rows * ((lengths[:, None] - 1 - r) // rows)
-            whole = jnp.take_along_axis(
-                whole, jnp.clip(p, 0, L - 1)[None, :, :, None], axis=2)
+        if cache == "ring":
+            whole = _as_ring(whole, lengths, rows)
         return whole.astype(otherwise.dtype)
 
     last = jnp.take_along_axis(x, (lengths - 1)[:, None, None], axis=1)[:, 0]
@@ -1771,6 +2378,75 @@ def prefill(params: Dict[str, Any], tokens: jax.Array, lengths: jax.Array,
         lengths=lengths.astype(jnp.int32),
         ring_k=joined("ring", 0, empty.ring_k),
         ring_v=joined("ring", 1, empty.ring_v)), _joined_loads(run_loads)
+
+
+def _as_ring(whole, lengths, rows: int):
+    """K or V of the window layers over a prompt's positions, ``whole`` [n,
+    B, L, row], as their rings of ``rows`` rows: row r holds the last
+    position p < length with p % rows == r (a row the prompt has not reached
+    holds whatever: its age masks it); all L in order where L <= rows."""
+    L = whole.shape[2]
+    if rows >= L:
+        return whole
+    r = jnp.arange(rows)[None]
+    p = r + rows * ((lengths[:, None] - 1 - r) // rows)
+    return jnp.take_along_axis(
+        whole, jnp.clip(p, 0, L - 1)[None, :, :, None], axis=2)
+
+
+def _sambay_prefill(params, tokens, lengths, cfg: TransformerConfig):
+    """``prefill`` of a ``SAMBAY`` stack. The self-decoder (every layer
+    before the full attention) runs over every position; the full attention
+    layer makes K and V of every position, which is all the cross-decoder
+    ever reads of the prompt, so that layer's query and every layer after it
+    run **on the last real position alone** (``_sambay_rows`` without a
+    write: the decode step's layers over the prompt's K and V as a cache of
+    L rows, the last scan layer's output there as the memory). Of a prompt's
+    FLOPs 14 of 32 published layers are then one row's."""
+    B, L = tokens.shape
+    blocks, kinds = params["blocks"], cfg.kinds
+    at = kinds.index(DIFF_GLOBAL) if DIFF_GLOBAL in kinds else len(kinds)
+    x, kept = _apply_sambay(blocks, _embed(params, tokens, cfg), cfg, lengths,
+                            at)
+    empty = init_decode_state(cfg, B, L)
+
+    def joined(kind, i, otherwise):
+        if not kept[kind]:
+            return otherwise
+        return jnp.concatenate([run[i] for run in kept[kind]])
+
+    def rows(a):    # [n, B, L, G, D] -> a row a position
+        return a.reshape(*a.shape[:3], -1)
+
+    ring = [_as_ring(rows(joined(DIFF_WINDOW, i, e)), lengths,
+                     e.shape[2]).astype(e.dtype)
+            if kept[DIFF_WINDOW] else e
+            for i, e in enumerate((empty.ring_k, empty.ring_v))]
+    ssm, conv = (joined(MAMBA1, i, e).astype(e.dtype)
+                 for i, e in enumerate((empty.ssm, empty.conv)))
+    last = (lengths - 1).astype(jnp.int32)
+
+    def at_last(a):     # [B, L, w] -> [B, w] at each prompt's last position
+        return jnp.take_along_axis(a, last[:, None, None], axis=1)[:, 0]
+
+    k, v = empty.k, empty.v
+    if at < len(kinds):
+        with jax.named_scope("attn"), _kind_scope(DIFF_GLOBAL):
+            # the full attention's K and V at every position; its query is
+            # made again at the last one, with the layers after it
+            part, norm = _sambay_parts(blocks[DIFF_GLOBAL], 0, cfg)
+            _, k, v = _diff_projected(part("diff"), norm(x, "ln1"), cfg)
+            k, v = (rows(a[None]).astype(e.dtype)
+                    for a, e in ((k, empty.k), (v, empty.v)))
+        memory = (at_last(kept[MAMBA1][-1][2][-1]) if kept[MAMBA1] else
+                  jnp.zeros((B, cfg.mamba1.d_inner), cfg.dtype))
+        x, _ = _sambay_rows(blocks, at_last(x), cfg, at,
+                            (ssm, conv, k, v, *ring), memory, lengths, last,
+                            write=False)
+    else:
+        x = at_last(x)
+    return x, DecodeState(ssm, conv, k, v, lengths.astype(jnp.int32),
+                          *ring), None
 
 
 def insert_state(state: DecodeState, piece: DecodeState, slot
@@ -1821,6 +2497,8 @@ def decode_step(params: Dict[str, Any], tokens: jax.Array,
     the layer's experts past the S rows once (``expert._streams``,
     ``ops.expert_stream``); else the dropless loop at ``S x top_k`` pairs."""
     _decodable(cfg)
+    if set(cfg.kinds) <= set(SAMBAY):
+        return _sambay_decode_step(params, tokens, state, cfg, active)
     norm = functools.partial(_rmsnorm, eps=cfg.norm_eps)
     blocks = params["blocks"]
     r = cfg.residual_scale
@@ -1887,6 +2565,29 @@ def decode_step(params: Dict[str, Any], tokens: jax.Array,
     logits = head(params, x[:, None], cfg)[:, 0]
     return logits, DecodeState(ssm, conv, k_cache, v_cache, lengths + step,
                                ring_k, ring_v), _joined_loads(step_loads)
+
+
+def _sambay_decode_step(params, tokens, state: DecodeState,
+                        cfg: TransformerConfig, active):
+    """``decode_step`` of a ``SAMBAY`` stack: every layer on the slots' rows
+    (``_sambay_rows`` with a write). The full attention layer writes the
+    token's k and v into the one cache and reads it; every cross layer after
+    it reads the same rows, the token's own among them; a ``GMU`` gates the
+    step's own output of the last scan layer."""
+    ssm, conv, k_cache, v_cache, lengths, ring_k, ring_v = state
+    x = _embed(params, tokens[:, None], cfg)[:, 0]              # [S, d]
+    S = x.shape[0]
+    memory = jnp.zeros((S, cfg.mamba1.d_inner), cfg.dtype)
+    newest = jnp.minimum(lengths, max(k_cache.shape[2], 1) - 1)
+    x, pieces = _sambay_rows(
+        params["blocks"], x, cfg, 0,
+        (ssm, conv, k_cache, v_cache, ring_k, ring_v), memory, lengths,
+        newest, write=True)
+    ssm, conv, k_cache, v_cache, ring_k, ring_v = pieces
+    step = 1 if active is None else active.astype(lengths.dtype)
+    logits = head(params, x[:, None], cfg)[:, 0]
+    return logits, DecodeState(ssm, conv, k_cache, v_cache, lengths + step,
+                               ring_k, ring_v), None
 
 
 def _exit_nll(x, w_head, targets):
@@ -1981,7 +2682,7 @@ def token_nll(params, x: jax.Array, targets: jax.Array,
     [B, L] float32 (evaluation and the tests' oracles; training goes
     through ``weighted_nll``)."""
     with jax.named_scope("head"):
-        x = _rmsnorm(x, params["ln_f"], cfg.norm_eps)
+        x = _final_norm(params, x, cfg)
         return _exit_nll(x, _head_weight(params, cfg), targets)[3]
 
 
@@ -2021,7 +2722,7 @@ def loss_from_states(params, states: jax.Array, targets: jax.Array,
     weight: one exit's float32 logits [B, L, vocab] are alive at a time,
     and only while the forward pass is there."""
     def heads(states, weights):
-        return weighted_nll(_rmsnorm(states, params["ln_f"], cfg.norm_eps),
+        return weighted_nll(_final_norm(params, states, cfg),
                             _head_weight(params, cfg), targets,
                             weights / targets.size)
 

@@ -200,6 +200,18 @@ LATER_DEVICE_SCOPES = frozenset({
     # ``reduce_window``'s)
     "swa",
     "nope",
+    # SambaY's kinds (Phi-4-mini-flash). ``mamba`` is a Mamba-1 mixer's too
+    # (``core`` inside it: ``ops/selective_scan.py``'s scan in prefill, its
+    # one-token step in decode) and ``swa`` a ``diff_window`` layer's. Inside
+    # ``attn``, round a layer's differential attention: ``global`` the one
+    # ``diff_global`` layer's, which writes the K/V cache every later
+    # attention reads, ``cross`` a ``diff_cross`` layer's, which reads it
+    # (q and output projections alone). ``gmu``, a class of its own beside
+    # ``attn`` and ``mamba``: a gated memory unit (norm, in-projection, the
+    # gate over the kept scan output, out-projection, the residual's add)
+    "global",
+    "cross",
+    "gmu",
 })
 
 # The stack sampler's holds (``observability/sampler.py``; tagged by

@@ -25,7 +25,8 @@ from ray_tpu.parallel import (ShardingRules, batch_sharding, expert,
 
 # the kinds of layer whose operations have no backward pass
 FORWARD_ONLY = frozenset({transformer.SPARSE, transformer.LINEAR,
-                          transformer.SHORTCUT, transformer.MAMBA})
+                          transformer.SHORTCUT, transformer.MAMBA,
+                          *transformer.SAMBAY})
 BIAS = "router_bias"        # a mixture layer's leaf that load moves
 
 

@@ -101,11 +101,11 @@ def mosaic(topo, no_compile_cache):
     """The process's backend is the CPU, where the kernels would take
     interpret mode; a module that asks for this compiles for the described
     chip (module-scoped, so that a module-scoped fixture can compile)."""
-    import ray_tpu.ops  # noqa: F401  (the six kernel modules)
+    import ray_tpu.ops  # noqa: F401  (the seven kernel modules)
     with pytest.MonkeyPatch.context() as patch:
         for module in ("flash_attention", "linear_attention",
                        "sparse_attention", "ssd", "expert_stream",
-                       "decode_attention"):
+                       "decode_attention", "selective_scan"):
             patch.setattr(sys.modules[f"ray_tpu.ops.{module}"],
                           "_backend_is_cpu", lambda: False)
         yield

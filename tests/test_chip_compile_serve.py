@@ -513,3 +513,90 @@ def test_the_windows_prefill_runs_the_kernel_and_the_step_writes_in_place(
     # global, window, global, window
     _reads_its_stacks_where_they_lie(calls(step, ATTEND), step,
                                      dict.fromkeys(stacks, 2))
+
+
+# -- the third generating cell: a shared cache, rings and Mamba-1 states ------------
+
+
+@pytest.fixture(scope="module")
+def generating_sambay(on_chip, mosaic):
+    """``phi4flash-serve-reason``'s programs (96 slots of nine states, eight
+    rings of 512 and one full cache of 3,072 rows; the whole model),
+    compiled once for the module."""
+    return _generating(on_chip, "phi4flash-serve-reason")
+
+
+def test_the_whole_sambay_model_and_96_slots_fit_the_chip(generating_sambay):
+    """7.70 GB of weights and 3.83 GB of state (what ``phi4flash_counts``
+    counts, to the byte), the state aliased with the one the step returns,
+    and the [1, 1024] prefill beside the resident state, each 15.0 GB or
+    less."""
+    from benchmark import phi4flash_counts
+    g = generating_sambay
+    dims = cell_dims("phi4flash-serve-reason")[2]
+    assert g["slots"] == 96
+    assert g["bytes"]["params"] == 2 * phi4flash_counts.param_count(dims)
+    assert g["bytes"]["state"] - 96 * 4 == sum(
+        phi4flash_counts.state_bytes(96, 3072, dims).values())
+    step, prefill = g["decode_step"], g["prefill"]
+    m = step.memory_analysis()
+    assert m.alias_size_in_bytes >= g["bytes"]["state"] - 96 * 4
+    assert m.temp_size_in_bytes < 0.2e9
+    assert 11.4 <= _held_gb(step) <= 11.7
+    beside = _held_gb(prefill) + g["bytes"]["state"] / 1e9
+    assert beside <= 15.0
+    assert prefill.memory_analysis().temp_size_in_bytes < 0.5e9
+    insert = g["insert"].memory_analysis()
+    assert insert.alias_size_in_bytes >= g["bytes"]["state"] - 96 * 4
+    # what a step must move is what the compiled step holds, and the rows
+    # its eight readers see of the one cache: at every slot full, the
+    # weights, the states in and out and 96 x (8 x 512 + 8 x 3,072) rows
+    full = phi4flash_counts.decode_step_bytes(
+        96, 96 * phi4flash_counts.slot_rows(3072, dims), dims)
+    assert full == pytest.approx(
+        g["bytes"]["params"] + 0.62e9 + 96 * 28672 * 5120, rel=2e-3)
+    print(f"decode step at 96 slots: {_held_gb(step):.3f} GB, temporaries "
+          f"{m.temp_size_in_bytes / 1e9:.3f} GB; prefill [1, 1024] beside "
+          f"the state: {beside:.3f} GB")
+
+
+def test_the_sambay_step_reads_one_cache_from_eight_layers(
+        generating_sambay):
+    """32 layers are three loops (8 x scan and window, scan and full, 7 x
+    GMU and cross), each with one ``decode_attn`` call on the stack itself:
+    the rings', and two on **the one** full cache, which no layer copies; a
+    scan layer's float32 states are read from the stack and written back
+    where they lie (plain ``jax.numpy``, no Mosaic call). The prefill holds
+    the flash kernel once (the window layers' loop) and ``decode_attn`` over
+    the prompt's rows for the full layer's and the cross layers' one
+    query."""
+    step = generating_sambay["decode_step"].as_text()
+    prefill = generating_sambay["prefill"].as_text()
+
+    def calls(text, name):      # Mosaic calls by their own name
+        return [line for line in text.splitlines()
+                if " custom-call(" in line and KERNEL in line
+                and re.search(rf"\s%?{name}[.\d]* = ", line)]
+
+    ring, cache, states = ("bf16[8,96,512,1280]", "bf16[1,96,3072,1280]",
+                           "f32[9,96,16,5120]")
+    attend = calls(step, ATTEND)
+    assert len(attend) == 3
+    assert sum(line.count(ring) == 2 for line in attend) == 1
+    assert sum(line.count(cache) == 2 for line in attend) == 2
+    assert all("/attn/" in line and "/core/" in line for line in attend)
+    assert sum("/swa/" in l for l in attend) == 1 == sum(
+        "/global/" in l for l in attend) == sum("/cross/" in l
+                                                for l in attend)
+    for line in step.splitlines():
+        if " copy(" in line or " dynamic-slice(" in line:
+            assert not any(s in line for s in (ring, cache)), line[:200]
+        # the plain scan step reads a layer's states out of the stack inside
+        # its fusion and writes them back where they lie: no copy of the stack
+        assert not (" copy(" in line and states in line), line[:200]
+    from ray_tpu.ops.selective_scan import KERNEL_NAME
+    assert not calls(step, "flash_fwd")
+    assert len(calls(prefill, "flash_fwd")) == 1
+    assert len(calls(prefill, KERNEL_NAME)) == 2
+    assert len(calls(prefill, ATTEND)) == 2
+    assert "bf16[1,1,1024,1280]" in prefill

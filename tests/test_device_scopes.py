@@ -73,7 +73,7 @@ def _trained(cfg, length):
 
 
 def test_the_scopes_are_single_tokens_and_no_host_spans_name():
-    assert len(DEVICE_SCOPES) == 9 and len(LATER_DEVICE_SCOPES) == 4
+    assert len(DEVICE_SCOPES) == 9 and len(LATER_DEVICE_SCOPES) == 7
     assert all(re.fullmatch(r"[a-z]+", s)
                for s in DEVICE_SCOPES | LATER_DEVICE_SCOPES)
     assert not (DEVICE_SCOPES | LATER_DEVICE_SCOPES) & SPANS
@@ -134,6 +134,51 @@ def test_the_switch_layer_names_its_router_and_experts():
              if n.startswith("jit(")}
     assert {("moe",), ("moe", "router")} <= found
     assert all(path[0] == "moe" for path in found if path)
+
+
+def _sambay_programs():
+    """The tiny SambaY stack's three programs: the whole forward, a prefill
+    and a decode step (``tests/phi4flash_tiny.py``; the kernels on)."""
+    import phi4flash_tiny as tiny
+    cfg = tiny.config(use_flash=True)
+    params = jax.eval_shape(
+        lambda: transformer.init_params(jax.random.PRNGKey(0), cfg))
+    tokens = jax.ShapeDtypeStruct((1, 16), jnp.int32)
+    state = jax.eval_shape(lambda: transformer.init_decode_state(cfg, 2, 32))
+    return {
+        "forward": _served(cfg, 16),
+        "prefill": jax.jit(lambda p, t, n: transformer.prefill(
+            p, t, n, cfg)).lower(
+                params, tokens,
+                jax.ShapeDtypeStruct((1,), jnp.int32)).compile(),
+        "decode_step": jax.jit(lambda p, t, s: transformer.decode_step(
+            p, t, s, cfg)).lower(
+                params, jax.ShapeDtypeStruct((2,), jnp.int32),
+                state).compile()}
+
+
+@pytest.mark.parametrize("program", ["forward", "prefill", "decode_step"])
+def test_the_sambay_stack_names_its_mixers(program):
+    """Phi-4-mini-flash's kinds: a scan is ``mamba`` (its recurrence ``core``
+    inside), an attention ``attn`` with ``swa``, ``global`` or ``cross``
+    inside it round its ``core``, a gated memory unit ``gmu``; every matmul
+    and kernel of the three programs is under one of them."""
+    scopes = DEVICE_SCOPES | LATER_DEVICE_SCOPES
+    names = _op_names(_sambay_programs()[program])
+    paths = {n: [t for t in re.split(r"[/():]", n) if t in scopes]
+             for n in names}
+    found = {s for path in paths.values() for s in path}
+    want = {"embed", "mamba", "core", "attn", "swa", "global", "cross",
+            "gmu", "mlp"}
+    assert found == want | ({"head"} if program != "prefill" else set())
+    for n, path in paths.items():
+        assert not {"swa", "global", "cross"} & set(path) \
+            or path[0] == "attn", n
+        assert "core" not in path or path[0] in ("attn", "mamba"), n
+        assert "gmu" not in path or path[0] == "gmu", n
+    bare = sorted(n for n in names if any(w in n for w in WORK)
+                  and not paths[n])
+    assert bare == []
 
 
 def _named_scope_calls():

@@ -645,16 +645,17 @@ def test_the_tied_head_is_the_embedding_transposed(params):
 # -- the configuration's guards ------------------------------------------------------
 
 
-def test_the_kinds_are_thirteen_and_the_new_ones_are_a_mixer_and_an_ffn():
-    assert len(KINDS) == 13 and set(PARTS) == {
+def test_the_kinds_are_eighteen_and_the_new_ones_are_a_mixer_and_an_ffn():
+    assert len(KINDS) == 18 and set(PARTS) == {
         CONV, CONV_MOE, ATTN_MOE, transformer.LATENT, transformer.LATENT_MOE,
         transformer.MAMBA, transformer.ATTN, transformer.WINDOW_MOE,
-        transformer.GLOBAL_MOE}
+        transformer.GLOBAL_MOE, *transformer.SAMBAY}
     assert {PARTS[k] for k in PARTS} == {("shortconv", "mlp"),
                                          ("shortconv", "moe"),
                                          ("attn", "moe"), ("latent", "mlp"),
                                          ("latent", "moe"), ("mamba", "mlp"),
-                                         ("attn", "mlp")}
+                                         ("attn", "mlp"), ("mamba1", "mlp"),
+                                         ("diff", "mlp"), ("gmu", "mlp")}
     with pytest.raises(ValueError, match="among each other only"):
         dataclasses.replace(TINY, n_layers=2,
                             layer_kinds=(CONV, transformer.LINEAR),
@@ -737,7 +738,8 @@ def test_the_conv_mixer_runs_under_a_scope_of_its_own_at_the_layers_top(
     operations (not inside ``attn``), it is declared, and no accepted scope
     was renamed for it."""
     assert metric_names.LATER_DEVICE_SCOPES == {"shortconv", "mamba", "swa",
-                                                "nope"}
+                                                "nope", "global", "cross",
+                                                "gmu"}
     assert not metric_names.LATER_DEVICE_SCOPES & metric_names.DEVICE_SCOPES
     tokens = jnp.zeros((1, 16), jnp.int32)
     text = jax.jit(lambda p, t: transformer.backbone(p, t, TINY)).lower(

@@ -720,7 +720,7 @@ def test_layer_kinds_message_lists_the_kinds_from_one_tuple():
     with pytest.raises(ValueError) as e:
         TransformerConfig(n_layers=1, layer_kinds=("window",))
     assert all(repr(kind) in str(e.value) for kind in KINDS)
-    assert len(KINDS) == 13 and SHORTCUT in KINDS
+    assert len(KINDS) == 18 and SHORTCUT in KINDS
 
 
 def test_a_shortcut_layer_stands_among_its_own_kind_and_needs_its_sizes():
